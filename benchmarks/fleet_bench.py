@@ -33,7 +33,7 @@ traces merged through scripts/trace_merge.py. ``scripts/check_obs.py
 
 Usage::
 
-    JAX_PLATFORMS=cpu python benchmarks/fleet_bench.py --smoke \
+    python benchmarks/fleet_bench.py --smoke \
         --metrics-out /tmp/fleet.prom --json-out /tmp/fleet.json
 """
 
@@ -388,6 +388,14 @@ def main() -> int:
     if args.workers < 2:
         print("need --workers >= 2 (cross-worker reuse is the point)")
         return 2
+
+    # N worker processes plus this parent all run JAX, and what is measured
+    # is the host p2p wire between them: every one is pinned to the CPU
+    # (the spawned workers inherit the pin) so none holds a chip
+    from uccl_tpu.utils.device import describe, pin_cpu
+
+    pin_cpu()
+    print(f"device: {describe()}", flush=True)
 
     oracle_cache: dict = {}
     arms = {}

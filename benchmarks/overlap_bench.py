@@ -59,7 +59,11 @@ def _run_rank(rank, world, port, grad_mb, chunks, gemm_d, gemm_chain,
     ).strip()
     import jax
 
+    # both ranks are pinned to the CPU: this measures the host DCN wire
+    # under host GEMMs, and two processes cannot share a chip
     jax.config.update("jax_platforms", "cpu")
+    if rank == 0:
+        print(f"device: {jax.devices()[0].platform}", flush=True)
     import jax.numpy as jnp
 
     from uccl_tpu.collective.hierarchical import DcnGroup
@@ -198,5 +202,4 @@ if __name__ == "__main__":
     _args = ap.parse_args()
     obs.setup_from_args(_args)
     obs.dump_at_exit(_args)  # covers crashes too
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     run(grad_mb=_args.grad_mb, chunks=_args.chunks)

@@ -32,11 +32,13 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-def _maybe_force_cpu():
-    if os.environ.get("UCCL_TPU_EXAMPLE_CPU") == "1":
-        import jax
+def _pin_cpu():
+    """This example moves KV over the host p2p wire between two OS
+    processes: parent and worker are pinned to the CPU backend, so neither
+    reaches for a chip the other would need."""
+    from uccl_tpu.utils.device import pin_cpu
 
-        jax.config.update("jax_platforms", "cpu")
+    pin_cpu()
 
 
 CFG_KW = dict(
@@ -86,7 +88,7 @@ def stream_decode_worker(port_q, result_q, n_requests, trace_out="",
     ``trace_out``/``metrics_out`` it dumps its OWN role-labeled
     observability artifacts — the decode half of the fleet trace (its
     clock metadata carries the offset the HELLO exchange estimated)."""
-    _maybe_force_cpu()
+    _pin_cpu()
     import numpy as np
 
     from uccl_tpu import obs
@@ -219,7 +221,7 @@ def _stream_main(args) -> int:
 # -- legacy: one-shot whole-cache handoff ------------------------------------
 def decode_worker(port_q, result_q, new_tokens):
     """Decode side: advertises cache buffers, receives them, continues."""
-    _maybe_force_cpu()
+    _pin_cpu()
     import jax.numpy as jnp
     import numpy as np
 
@@ -378,7 +380,6 @@ def _legacy_main(args) -> int:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--new-tokens", type=int, default=12)
-    ap.add_argument("--cpu", action="store_true", help="force CPU jax")
     ap.add_argument(
         "--compress", nargs="?", const="fp8", default="off",
         choices=["off", "fp8", "lossless"],
@@ -399,13 +400,14 @@ def main():
 
     obs.add_cli_args(ap)
     args = ap.parse_args()
-    if args.cpu:
-        os.environ["UCCL_TPU_EXAMPLE_CPU"] = "1"  # inherited by the worker
     if args.compress != "off":
         os.environ["UCCL_TPU_EXAMPLE_COMPRESS"] = args.compress
     if args.elastic:
         os.environ["UCCL_TPU_EXAMPLE_ELASTIC"] = "1"
-    _maybe_force_cpu()
+    _pin_cpu()
+    from uccl_tpu.utils.device import describe
+
+    print(f"device: {describe()}", flush=True)
     obs.setup_from_args(args)
     obs.dump_at_exit(args)
 

@@ -37,7 +37,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # one shared disaggregation fixture: model config, prompt shape, and the
 # CPU-forcing gate live in disagg_kv so the two exact-match demos can
 # never drift apart
-from examples.disagg_kv import BATCH, MAX_SEQ, _make, _maybe_force_cpu
+from examples.disagg_kv import BATCH, MAX_SEQ, _make, _pin_cpu
 
 
 def _model():
@@ -95,7 +95,7 @@ class _JsonHandler(BaseHTTPRequestHandler):
 def prefill_worker(port_q):
     """POST /prefill {"prompt_ids"} -> kv_transfer_params (the reference's
     max_tokens=1 leg: populate the cache, describe how to pull it)."""
-    _maybe_force_cpu()
+    _pin_cpu()
     import jax.numpy as jnp
 
     from uccl_tpu.models.inference import prefill
@@ -143,7 +143,7 @@ def decode_worker(port_q):
     """POST /decode {"max_tokens", "first_token", "kv_transfer_params"} ->
     generated tokens. Pulls the KV cache with one-sided READs (the NIXL
     do_remote_prefill pull, reference :64-67)."""
-    _maybe_force_cpu()
+    _pin_cpu()
     import jax.numpy as jnp
 
     from uccl_tpu.models.inference import KVCache
@@ -209,7 +209,7 @@ def proxy_worker(port_q, prefill_port, decode_port):
 
 
 def _single_worker_reference(prompt, new_tokens):
-    _maybe_force_cpu()
+    _pin_cpu()
     from uccl_tpu.serving.disagg import oneshot_reference
 
     cfg, params = _model()

@@ -29,7 +29,12 @@ def pod_main(rank, world, store_port, elems, result_q):
     ).strip()
     import jax
 
+    # each pod is an OS process with its own virtual CPU mesh; pods talk over
+    # the host DCN wire, and several processes cannot share a chip
     jax.config.update("jax_platforms", "cpu")
+    if rank == 0:
+        print(f"device: {jax.devices()[0].platform} x{LOCAL_DEVICES} per pod",
+              flush=True)
     import numpy as np
 
     from uccl_tpu.collective import Communicator

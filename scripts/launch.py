@@ -8,12 +8,22 @@ worker processes on this node with the session environment set
 ``uccl_tpu.parallel.distributed.initialize_from_env()``), streams their
 output with rank prefixes, and propagates the first failure.
 
-Single node (ranks 0..N-1):
-    python scripts/launch.py --nproc 4 train.py --epochs 3
+Chips: this launcher does NOT divide a host's chips among its children. A
+chip belongs to one process, and one process drives all the chips of its
+host — so on chips run ``--nproc 1`` per host. ``--nproc > 1`` is for CPU
+ranks and is REFUSED unless the children are pinned to the CPU: either
+``JAX_PLATFORMS=cpu`` in the environment or a ``--devices N`` argument to
+the script (the entry points' virtual-CPU-mesh flag). Otherwise N children
+would each reach for every chip of the host and all but one would fail or
+hang.
 
-Multi-host (run once per node; rank 0 must live on the coordinator node):
+Single node, CPU ranks 0..N-1:
+    python scripts/launch.py --nproc 4 train.py --devices 2 --epochs 3
+
+Multi-host on chips (run once per node, one process each; rank 0 must live
+on the coordinator node):
     python scripts/launch.py --nnodes 2 --node-rank 0 \\
-        --coordinator 10.0.0.1:9333 --nproc 4 train.py
+        --coordinator 10.0.0.1:9333 --nproc 1 train.py
 """
 
 from __future__ import annotations
@@ -50,6 +60,16 @@ def main() -> int:
     ap.add_argument("script")
     ap.add_argument("args", nargs=argparse.REMAINDER)
     opts = ap.parse_args()
+
+    if (opts.nproc > 1 and os.environ.get("JAX_PLATFORMS") != "cpu"
+            and "--devices" not in opts.args):
+        sys.exit(
+            f"launch.py: refusing --nproc {opts.nproc}: the launcher does "
+            "not divide a host's chips among processes, and a chip belongs "
+            "to one process. On chips run one process per host (--nproc 1; "
+            "it drives all of them). For CPU ranks set JAX_PLATFORMS=cpu or "
+            "pass the script --devices N."
+        )
 
     world = opts.nnodes * opts.nproc
     base_rank = opts.node_rank * opts.nproc

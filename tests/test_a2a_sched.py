@@ -31,7 +31,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from uccl_tpu.ep import Buffer, a2a_sched, pallas_a2a
 from uccl_tpu.ep import ops as ep_ops
-from uccl_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 
 WORLDS_T1 = (4,
              pytest.param(8, marks=pytest.mark.slow),
@@ -46,7 +46,8 @@ def _run(mesh, fn, *args, out_specs=None):
     in_specs = tuple(P("ep") for _ in args)
     out_specs = P("ep") if out_specs is None else out_specs
     return jax.jit(
-        shard_map(fn, mesh, in_specs, out_specs, check_vma=False)
+        shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=False)
     )(*args)
 
 
@@ -517,8 +518,7 @@ class TestPlanEpA2a:
 
 class TestPlannedReduceScatter:
     def _comm(self, devices, n=4):
-        # single-named-axis mesh: the legacy discharge interpreter can only
-        # address flat logical ids, so the ring arm needs Mesh(("dp",))
+        # single-named-axis mesh, like the other ring-kernel tests
         from uccl_tpu.collective import Communicator
 
         return Communicator(

@@ -148,8 +148,10 @@ class TestRingFlash:
                 np.asarray(a), np.asarray(b), rtol=3e-4, atol=3e-5
             )
 
-    def test_small_seq_falls_back(self, cp_mesh, rng):
-        """s_loc below the minimum block size silently uses the XLA path."""
+    def test_small_seq_interprets(self, cp_mesh, rng):
+        """s_loc below Mosaic's minimum tile still runs the kernel under the
+        interpreter (any tile works there); compiled, it is an error
+        (test_pallas_attention) — never a quiet switch to the XLA path."""
         q, k, v = _qkv(rng, s=8)  # s_loc = 2
         want = np.asarray(attention_reference(q, k, v))
         got = _run_cp(

@@ -10,8 +10,7 @@ quantized wire bytes (the PR 7 rule, via the budget probe).
 Wire audit: the psum-baseline reduction is a COUNTER delta on
 ``ep_bytes_total{verb="bcast"}``, never model math.
 
-Worlds 4/8/5 on 1-axis meshes (runnable under the legacy discharge
-interpreter, like TestBidir); heavy arms are ``slow`` — tier-1 keeps the
+Worlds 4/8/5 on 1-axis meshes (like TestBidir); heavy arms are ``slow`` — tier-1 keeps the
 world-4 kernel core + the world-8 counter regressions.
 """
 
@@ -22,7 +21,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from uccl_tpu.collective import Communicator, dma, pallas_ccl, plan
-from uccl_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 
 
 def _run(mesh, fn, x, in_spec=P("dp"), out_spec=P("dp", None)):

@@ -13,8 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from uccl_tpu.utils import jaxcompat
-
 ocp = pytest.importorskip("orbax.checkpoint")
 
 
@@ -53,10 +51,6 @@ class TestOrbaxRoundTrip:
         _tree_equal(params, restored_p)
         _tree_equal(opt_state, restored_o)
 
-    @pytest.mark.skipif(
-        not jaxcompat.MODERN_SHARD_MAP,
-        reason="legacy shard_map vjp mishandles rank-0 residuals",
-    )
     def test_resume_is_bit_identical(self, tiny_setup, tmp_path, rng):
         """step; checkpoint; step again = restore; step — same trajectory."""
         cfg, mesh, params, train_step, init_opt = tiny_setup

@@ -22,10 +22,10 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run(extra, timeout=420):
-    env = dict(os.environ, UCCL_TPU_EXAMPLE_CPU="1", JAX_PLATFORMS="cpu")
+    env = dict(os.environ)
     return subprocess.run(
         [sys.executable, os.path.join(_REPO, "examples", "disagg_kv.py"),
-         "--cpu", "--new-tokens", "12", *extra],
+         "--new-tokens", "12", *extra],
         capture_output=True, text=True, timeout=timeout, env=env, cwd=_REPO,
     )
 

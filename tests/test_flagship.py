@@ -19,15 +19,6 @@ from uccl_tpu.models.flagship import (
     shard_params,
 )
 from uccl_tpu.parallel.mesh import MeshConfig, make_mesh
-from uccl_tpu.utils import jaxcompat
-
-# The grad paths differentiate shard_mapped programs from outside the
-# shard_map; the legacy (0.4.x) experimental shard_map raises a _SpecError
-# on the rank-0 residuals that creates (fixed in modern jax.shard_map).
-_needs_modern_vjp = pytest.mark.skipif(
-    not jaxcompat.MODERN_SHARD_MAP,
-    reason="legacy shard_map vjp mishandles rank-0 residuals",
-)
 
 
 def _cfg(**kw):
@@ -125,7 +116,6 @@ class TestForwardParity:
         np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
-@_needs_modern_vjp
 class TestGradParity:
     def test_grads_match_dense(self, mesh_cfg, rng):
         """Gradients through the fully sharded model == dense autodiff."""
@@ -155,7 +145,6 @@ class TestGradParity:
             )
 
 
-@_needs_modern_vjp
 class TestManualSchedule:
     """pp_schedule='1f1b': the manual pipeline training path must reproduce
     the autodiff-GPipe path's loss and gradients on the full MoE model."""
@@ -220,7 +209,6 @@ class TestManualSchedule:
         assert losses[-1] < losses[0] * 0.7, losses
 
 
-@_needs_modern_vjp
 class TestTraining:
     def test_loss_decreases(self, devices, rng):
         mesh = make_mesh(MeshConfig(pp=2, dp=2, cp=1, tp=2), devices)
@@ -245,7 +233,6 @@ class TestTraining:
         assert float(total) > float(ce)
 
 
-@_needs_modern_vjp
 class TestRematModes:
     """remat="full"|"dots"|"mlp"|"none" change only the backward recompute
     schedule (_remat_wrap) — training must be bit-identical across them."""

@@ -51,3 +51,32 @@ def test_launch_failure_propagates(tmp_path):
         capture_output=True, text=True, timeout=60,
     )
     assert r.returncode == 3
+
+
+def test_refuses_multiple_ranks_that_could_reach_for_the_chips(tmp_path):
+    """The launcher does not divide a host's chips among processes: without
+    JAX_PLATFORMS=cpu (or a --devices argument to the script) --nproc > 1 is
+    refused before anything is spawned."""
+    script = tmp_path / "never_runs.py"
+    script.write_text("print('spawned')\n")
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    r = subprocess.run(
+        [
+            sys.executable, os.path.join(_REPO, "scripts", "launch.py"),
+            "--nproc", "2", "--no-jax-dist", str(script),
+        ],
+        capture_output=True, text=True, timeout=60, env=env,
+    )
+    assert r.returncode != 0
+    assert "refusing --nproc 2" in r.stderr and "spawned" not in r.stdout
+    # the script's own virtual-CPU-mesh flag is enough to let it through
+    r = subprocess.run(
+        [
+            sys.executable, os.path.join(_REPO, "scripts", "launch.py"),
+            "--nproc", "2", "--no-jax-dist",
+            "--coordinator", _free_coordinator(), str(script),
+            "--devices", "2",
+        ],
+        capture_output=True, text=True, timeout=60, env=env,
+    )
+    assert r.returncode == 0 and r.stdout.count("spawned") == 2, r.stdout

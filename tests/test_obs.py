@@ -375,35 +375,6 @@ class TestFallbackCounters:
         # layer reads 1, never a stale earlier depth
         assert obs.gauge("ep_chunk_depth").get(what="moe_layer") == 1
 
-    def test_buffer_verb_downgrade_counted_once(self, devices):
-        """Buffer host paths memoize static wire decisions: a hot loop of
-        verb calls over one config records ONE fallback event, matching
-        the per-compile semantics of the traced gates."""
-        import jax.numpy as jnp
-        from jax.sharding import Mesh
-
-        from uccl_tpu.ep.buffer import Buffer
-        from uccl_tpu.parallel.mesh import MeshConfig, make_mesh
-
-        # multi-axis mesh under the legacy interpreter: pallas cannot
-        # address it and every verb transparently rides the XLA wire
-        mesh = make_mesh(MeshConfig(dp=2), devices[:2])
-        if len(mesh.axis_names) == 1:  # pragma: no cover
-            pytest.skip("mesh collapsed to one axis; nothing to downgrade")
-        buf = Buffer(mesh, axis="dp", num_experts=4, num_selected=2,
-                     capacity_factor=8.0, wire="pallas")
-        if buf._pallas_wire_ok():  # pragma: no cover (faithful interp)
-            pytest.skip("pallas can address this mesh; no downgrade here")
-        x = buf.device_put(jnp.zeros((2, 4, 8), jnp.float32))
-        idx = buf.device_put(jnp.zeros((2, 4, 2), jnp.int32))
-        b = self._snap()
-        for _ in range(3):
-            recv, handle = buf.dispatch(x, idx)
-            buf.combine(recv, handle)
-        d = self._delta(b)
-        k = (("reason", "legacy_interpret_mesh"), ("what", "buffer_verb"))
-        assert d == {k: 1}, d
-
     def test_budget_gate_counts_and_quiet_probe_does_not(self):
         b = self._snap()
         assert not dma.check_budget(1 << 40, "ep_all_to_all", True)
@@ -421,7 +392,7 @@ class TestFallbackCounters:
         from jax.sharding import Mesh, PartitionSpec as P
 
         from uccl_tpu.ep import pallas_a2a
-        from uccl_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
 
         from jax import lax
 

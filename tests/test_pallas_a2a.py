@@ -8,11 +8,9 @@ the lax-wire lowering of the same program, at worlds 4 and 8 plus odd
 worlds (5, and 3 for the raw kernel), over f32/bf16 payloads and the
 fp8+scales wire format.
 
-All meshes here are single-axis, which keeps every test runnable under BOTH
-TPU interpreters: the faithful one (pltpu.InterpretParams — remote DMAs,
-semaphores and the credit flow simulated) and the legacy discharge one
-(jax 0.4.x — remote DMA data movement only; the kernel statically elides
-the barrier/credit traffic there, see uccl_tpu.collective.dma)."""
+All meshes here are single-axis; the kernels run under the TPU interpreter
+(pltpu.InterpretParams — remote DMAs, semaphores and the credit flow
+simulated)."""
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from uccl_tpu.ep import Buffer, pallas_a2a
 from uccl_tpu.ep import ll as ep_ll
 from uccl_tpu.ep import ops as ep_ops
-from uccl_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 
 WORLDS = (4, 8, 5)  # the acceptance grid: powers of two plus one odd world
 
@@ -49,7 +47,8 @@ def _run(mesh, fn, *args, out_specs=None):
     in_specs = tuple(P("ep") for _ in args)
     out_specs = P("ep") if out_specs is None else out_specs
     return jax.jit(
-        shard_map(fn, mesh, in_specs, out_specs, check_vma=False)
+        shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=False)
     )(*args)
 
 

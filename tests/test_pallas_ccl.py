@@ -12,17 +12,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from uccl_tpu.collective import pallas_ccl, plan
 from uccl_tpu.parallel.mesh import MeshConfig, make_mesh
-from uccl_tpu.utils import jaxcompat
-from uccl_tpu.utils.jaxcompat import shard_map
-
-# The canonical 4-axis make_mesh fixtures need the faithful multi-device
-# interpreter (pltpu.InterpretParams): the legacy discharge interpreter
-# (jax 0.4.x) can only address single-named-axis meshes. The odd-world
-# tests below use 1-axis meshes and run everywhere.
-_needs_faithful = pytest.mark.skipif(
-    not jaxcompat.FAITHFUL_PALLAS_INTERPRET,
-    reason="legacy pallas interpreter cannot address multi-axis meshes",
-)
+from jax import shard_map
 
 
 @pytest.fixture(scope="module")
@@ -43,7 +33,6 @@ def _run(mesh, fn, x, in_spec, out_spec):
     return np.asarray(jax.jit(mapped)(x))
 
 
-@_needs_faithful
 class TestAllGather:
     @pytest.mark.parametrize("direction", [1, -1])
     def test_matches_tile(self, mesh, rng, direction):
@@ -87,7 +76,6 @@ class TestAllGather:
         np.testing.assert_array_equal(got, want)
 
 
-@_needs_faithful
 class TestReduceScatter:
     @pytest.mark.parametrize("direction", [1, -1])
     def test_matches_numpy(self, mesh, rng, direction):
@@ -115,7 +103,6 @@ class TestReduceScatter:
             )
 
 
-@_needs_faithful
 class TestAllReduce:
     @pytest.mark.parametrize("bidi", [False, True])
     @pytest.mark.parametrize("payload", [64, 257])  # 257: padding path
@@ -206,9 +193,8 @@ class TestAllReduce:
 class TestBidir:
     """The paired counter-rotating ring kernels (round 8, the FlexLink
     pair): two unidirectional kernels on paired collective ids, each
-    carrying half the payload. 1-axis meshes so every arm runs under the
-    legacy discharge interpreter too; worlds 4/8/5 — the odd world is what
-    catches the credit fenceposts, exactly like TestOddWorlds."""
+    carrying half the payload. 1-axis meshes; worlds 4/8/5 — the odd world
+    is what catches the credit fenceposts, exactly like TestOddWorlds."""
 
     @staticmethod
     def _mesh(devices, n):
@@ -341,8 +327,7 @@ class TestOddWorlds:
     """Rings at n ∈ {3, 5} on 1-axis meshes: odd n is exactly what catches
     the ``s <= n - 4`` credit-window arithmetic (n=5 has ONE credited step
     per direction, n=3 none — a fencepost slip deadlocks or unbalances the
-    semaphores), and the 1-axis mesh keeps these runnable under the legacy
-    discharge interpreter as well as the faithful one."""
+    semaphores)."""
 
     @staticmethod
     def _mesh(devices, n):

@@ -20,8 +20,8 @@ partial sums grow. This suite pins:
   bit-identically, and ``ep_bytes_total`` carrying the quantized wire-byte
   arithmetic (payload + scale sidecar) under the ``wire_dtype`` label.
 
-All meshes here are single-named-axis so every case runs under the legacy
-discharge interpreter too (same choice as test_pallas_ccl's odd worlds).
+All meshes here are single-named-axis (same choice as test_pallas_ccl's odd
+worlds).
 
 Tier-1 time budget: the suite sits at the 870s cap (ROADMAP), so tier-1
 keeps only a representative core — the world-4 fp8 bound arms of each
@@ -30,7 +30,7 @@ contract (~9s) — and every other arm (world 8/5, int8, bf16, zero-exact
 kernels, outlier, the kernel==mirror double-compile, the chunked
 composition, counted downgrades, the moe_ffn knob) is marked ``slow``:
 they run in qa.sh / ci.yml's unfiltered pytest, and the CI fail-fast
-quantized smoke (pallas_a2a_proof --interpret --wire-dtype) re-proves
+quantized smoke (pallas_a2a_proof --wire-dtype) re-proves
 zero-exactness, the error bound, and pallas==lax bit-identity at worlds
 4/5 per push anyway.
 """
@@ -43,7 +43,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from uccl_tpu.collective import dma, pallas_ccl
 from uccl_tpu.ep import ops as ep_ops
-from uccl_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 
 # per-round-trip error divisors of the codec (uccl_tpu.ops.quant module
 # docstring): fp8 half-ulp at 448 + f16 double-rounding slack, int8 half a

@@ -136,7 +136,7 @@ class TestExampleRuns:
         import os
 
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env = dict(os.environ, UCCL_TPU_EXAMPLE_CPU="1")
+        env = dict(os.environ)
         r = subprocess.run(
             [sys.executable, os.path.join(repo, "examples",
                                           "disagg_proxy.py"),

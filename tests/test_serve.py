@@ -10,18 +10,6 @@ import pytest
 
 pytest.importorskip("orbax.checkpoint")
 
-from uccl_tpu.utils import jaxcompat
-
-# Every test here first TRAINS a checkpoint in a subprocess, which
-# needs the modern shard_map vjp (legacy 0.4.x raises _SpecError on
-# rank-0 residuals) — same gate as test_trainer/test_flagship. The
-# serving paths themselves are covered without training by
-# tests/test_serving.py and the CI serving smoke tier.
-pytestmark = pytest.mark.skipif(
-    not jaxcompat.MODERN_SHARD_MAP,
-    reason="legacy shard_map vjp mishandles rank-0 residuals",
-)
-
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 

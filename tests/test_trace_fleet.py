@@ -445,12 +445,12 @@ def test_fleet_smoke_end_to_end(tmp_path):
     cross-process request timeline, causally ordered, and fleet TTFT
     histogram percentiles within one bucket width of the per-replica
     sample-derived ones."""
-    env = dict(os.environ, UCCL_TPU_EXAMPLE_CPU="1", JAX_PLATFORMS="cpu")
+    env = dict(os.environ)
     trace = tmp_path / "fleet.json"
     metrics = tmp_path / "fleet.prom"
     r = subprocess.run(
         [sys.executable, os.path.join(_REPO, "examples", "disagg_kv.py"),
-         "--cpu", "--trace-out", str(trace), "--metrics-out", str(metrics)],
+         "--trace-out", str(trace), "--metrics-out", str(metrics)],
         capture_output=True, text=True, timeout=420, env=env, cwd=_REPO,
     )
     assert r.returncode == 0, r.stdout + r.stderr

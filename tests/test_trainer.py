@@ -17,17 +17,6 @@ import pytest
 
 pytest.importorskip("orbax.checkpoint")
 
-from uccl_tpu.utils import jaxcompat
-
-# The trainer subprocess differentiates shard_mapped programs from
-# outside the shard_map; the legacy (0.4.x) experimental shard_map vjp
-# raises a _SpecError on rank-0 residuals there (fixed in modern
-# jax.shard_map) — same gate as test_flagship/test_checkpoint.
-_needs_modern_vjp = pytest.mark.skipif(
-    not jaxcompat.MODERN_SHARD_MAP,
-    reason="legacy shard_map vjp mishandles rank-0 residuals",
-)
-
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _COMMON = [
     "--devices", "8", "--mesh", "dp=2,cp=2,tp=2", "--batch", "4",
@@ -46,7 +35,6 @@ def _run(extra):
     return summary, r.stdout
 
 
-@_needs_modern_vjp
 def test_resume_matches_uninterrupted(tmp_path):
     straight, _ = _run(["--steps", "6"])
     ck = str(tmp_path / "ck")
@@ -71,7 +59,6 @@ def test_mesh_size_mismatch_fails_cleanly(tmp_path):
     assert "mesh size 3 != device count 8" in r.stderr
 
 
-@_needs_modern_vjp
 def test_joins_launcher_session(tmp_path):
     """UCCL_TPU_COORD et al (set by scripts/launch.py) make the trainer
     join the multi-host session before touching devices."""
@@ -109,7 +96,6 @@ def _free_port_pair():
     raise RuntimeError("no free port pair")
 
 
-@_needs_modern_vjp
 def test_two_process_training_matches_single(tmp_path):
     """TRUE multi-controller training: two processes under jax.distributed,
     each owning 4 virtual devices of the same 8-device global mesh, must
@@ -148,7 +134,6 @@ def test_two_process_training_matches_single(tmp_path):
     assert abs(resumed["final_loss"] - single["final_loss"]) < 1e-4
 
 
-@_needs_modern_vjp
 def test_data_corpus_mode(tmp_path):
     """--data: batches are next-token windows from a memmapped token file,
     deterministic per step (resume-consistent) — loss should drop fast on

@@ -18,12 +18,6 @@ sequence/context-parallel layer SURVEY.md §5 requires), ``uccl_tpu.ops`` (Palla
 kernels), and ``uccl_tpu.models`` (flagship model families exercising every axis).
 """
 
-# Version-bridge the jax APIs the codebase targets (jax.shard_map,
-# lax.axis_size, ...) at package import, so EVERY subpackage — including
-# ones that never import the shim themselves (ops.attention traces
-# lax.axis_size inside shard_map) — sees them on legacy jax 0.4.x
-# containers. No-op on modern jax.
-from uccl_tpu.utils import jaxcompat as _jaxcompat  # noqa: F401
 from uccl_tpu.version import __version__
 
 __all__ = ["__version__"]

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from uccl_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 
 from uccl_tpu.parallel.mesh import AXIS, get_mesh, mesh_axis_size
 from uccl_tpu.utils.logging import get_logger
@@ -107,18 +107,9 @@ class Communicator:
         return tuple(x.shape[1:]) if x.ndim > 1 else (1,)
 
     def _pallas_ok(self) -> bool:
-        """Can the device-kernel candidates (bidir) address this mesh? A
-        single comm axis always; plus either a real TPU lowering, the
-        faithful interpreter (MESH coordinates), or a single-named-axis
-        mesh for the legacy discharge interpreter (flat logical ids)."""
-        if len(self.axes) != 1:
-            return False
-        from uccl_tpu.collective import dma as _dma
-
-        interpret = _dma.resolve_interpret(None)
-        if not interpret or _dma.faithful_sync(interpret):
-            return True
-        return len(self.mesh.shape) == 1
+        """Can the device-kernel candidates (bidir) address this mesh? They
+        take a single comm axis."""
+        return len(self.axes) == 1
 
     def _resolve_ar_plan(self, x, op, algo, wire_dtype):
         """Resolve one all_reduce request to (algo, chunks, wire_dtype),

@@ -21,7 +21,7 @@ from jax import lax
 from jax.experimental.pallas import tpu as pltpu
 
 from uccl_tpu.utils import config as _config
-from uccl_tpu.utils import jaxcompat as _jc
+from uccl_tpu.utils import device as _device
 from uccl_tpu.obs import counters as _obsc
 
 LANES = 128
@@ -227,31 +227,27 @@ def pad_chunks(flat: jax.Array, parts: int) -> Tuple[jax.Array, int, int]:
 
 
 def interpret_default() -> bool:
-    """Real Mosaic lowering only exists on TPU backends; anywhere else the
-    kernels run under the TPU interpreter (which simulates remote DMAs and
-    semaphores faithfully on host devices)."""
-    return jax.default_backend() != "tpu"
+    """The one compile-vs-interpret rule (utils.device.pallas_interpret):
+    Mosaic on a TPU backend, the TPU interpreter (which simulates remote
+    DMAs and semaphores on host devices) on the CPU, an error elsewhere."""
+    return _device.pallas_interpret()
 
 
 def resolve_interpret(interpret) -> bool:
     return interpret_default() if interpret is None else bool(interpret)
 
 
-# pallas_call's interpret= value and the compiler params, version-bridged
-# (uccl_tpu.utils.jaxcompat): the faithful InterpretParams interpreter on
-# modern jax, the legacy discharge interpreter (plain True) on jax 0.4.x.
-interp = _jc.tpu_interpret_params
-compiler_params = _jc.tpu_compiler_params
+def interp(interpret: bool):
+    """Value for ``pl.pallas_call(interpret=...)``: the TPU interpreter
+    (simulates remote DMAs, semaphores and barriers on host devices) or
+    ``False`` for real Mosaic lowering."""
+    return pltpu.InterpretParams() if interpret else False
 
 
-def faithful_sync(interpret: bool) -> bool:
-    """True when semaphore/barrier traffic is real: compiled Mosaic, or the
-    faithful InterpretParams interpreter. False under the legacy discharge
-    interpreter (jax 0.4.x), where remote semaphore signals are not
-    implemented — but where every remote DMA discharges into a synchronous
-    cross-device gather, so per-DMA global ordering (and thus correctness of
-    the data movement) is implied and the elided sync is not load-bearing."""
-    return not (interpret and not _jc.FAITHFUL_PALLAS_INTERPRET)
+def compiler_params(collective_id: int = 0):
+    return pltpu.CompilerParams(
+        has_side_effects=True, collective_id=collective_id
+    )
 
 
 def neighbors(axis, n: int, d: int):
@@ -278,15 +274,10 @@ def mesh_id(axis, idx):
     return {axis: idx}
 
 
-def remote_kwargs(axis, idx, faithful: bool) -> dict:
-    """device_id kwargs for make_async_remote_copy / semaphore_signal.
-
-    Faithful mode addresses by MESH coordinates (sub-axis safe). The legacy
-    discharge interpreter supports neither MESH dicts nor multi-axis meshes —
-    there the flat index along the (single) shard axis IS the logical id."""
-    if faithful:
-        return dict(device_id=mesh_id(axis, idx), device_id_type=MESH)
-    return dict(device_id=idx, device_id_type=pltpu.DeviceIdType.LOGICAL)
+def remote_kwargs(axis, idx) -> dict:
+    """device_id kwargs for make_async_remote_copy / semaphore_signal:
+    MESH coordinates, so kernels are sub-axis safe."""
+    return dict(device_id=mesh_id(axis, idx), device_id_type=MESH)
 
 
 def ring_barrier(axis, left, right):

@@ -150,15 +150,12 @@ def _dequantize_rows(q, scales, dtype):
     return _quant.dequantize_block(q, scales, _LANES, dtype)
 
 
-def _ag_phase(axis, n, dirs, buf_ref, send_sem, recv_sem, ack_sem,
-              faithful=True):
+def _ag_phase(axis, n, dirs, buf_ref, send_sem, recv_sem, ack_sem):
     """All-gather rings on ``buf_ref[:, h]`` for each stream h (one ring per
     direction in ``dirs``, all DMAs of a step issued before any wait): n-1
     steps of direct buf→buf remote DMA — chunk j lives at slot j on every
     member, so the destination slot equals the source slot and every slot is
-    write-once. ``faithful`` is static: the legacy discharge interpreter
-    (jax 0.4.x) implements no remote semaphore signals, so the credit
-    traffic is elided there — subsumed by its per-DMA global ordering."""
+    write-once."""
     nbrs = [_neighbors(axis, n, d) for d in dirs]
 
     def step(s, _):
@@ -167,11 +164,9 @@ def _ag_phase(axis, n, dirs, buf_ref, send_sem, recv_sem, ack_sem,
             r, right, _left = nbrs[h]
             send_slot = lax.rem(r - d * s + s * n + n, n)
 
-            if faithful:
-
-                @pl.when(s >= 2)
-                def _(h=h):  # credit from downstream: slot s%2 consumed
-                    pltpu.semaphore_wait(ack_sem.at[h], 1)
+            @pl.when(s >= 2)
+            def _(h=h):  # credit from downstream: slot s%2 consumed
+                pltpu.semaphore_wait(ack_sem.at[h], 1)
 
             sl = lax.rem(s, 2)
             rdma = pltpu.make_async_remote_copy(
@@ -179,7 +174,7 @@ def _ag_phase(axis, n, dirs, buf_ref, send_sem, recv_sem, ack_sem,
                 dst_ref=buf_ref.at[send_slot, h],
                 send_sem=send_sem.at[h, sl],
                 recv_sem=recv_sem.at[h, sl],
-                **_dma.remote_kwargs(axis, right, faithful),
+                **_dma.remote_kwargs(axis, right),
             )
             rdma.start()
             descs.append(rdma)
@@ -187,14 +182,12 @@ def _ag_phase(axis, n, dirs, buf_ref, send_sem, recv_sem, ack_sem,
             _r, _right, left = nbrs[h]
             descs[h].wait_recv()  # slot (r - d(s+1)) arrived
 
-            if faithful:
-
-                @pl.when(s <= n - 4)
-                def _(h=h, left=left):  # grant upstream its step-(s+2) send
-                    pltpu.semaphore_signal(
-                        ack_sem.at[h], inc=1,
-                        **_dma.remote_kwargs(axis, left, faithful),
-                    )
+            @pl.when(s <= n - 4)
+            def _(h=h, left=left):  # grant upstream its step-(s+2) send
+                pltpu.semaphore_signal(
+                    ack_sem.at[h], inc=1,
+                    **_dma.remote_kwargs(axis, left),
+                )
 
         for rdma in descs:
             rdma.wait_send()
@@ -204,11 +197,11 @@ def _ag_phase(axis, n, dirs, buf_ref, send_sem, recv_sem, ack_sem,
 
 
 def _rs_phase(axis, n, dirs, buf_ref, stage_ref, send_sem, recv_sem,
-              ack_sem, faithful=True):
+              ack_sem):
     """Reduce-scatter rings on ``buf_ref[:, h]`` per stream: partial sums
     circulate through 2-slot staging; member r ends holding slot r fully
     reduced. Slot arithmetic matches plan.plan_reduce_scatter
-    (send_off=-(s+1), recv_off=-(s+2)). ``faithful``: see :func:`_ag_phase`."""
+    (send_off=-(s+1), recv_off=-(s+2))."""
     nbrs = [_neighbors(axis, n, d) for d in dirs]
 
     def step(s, _):
@@ -217,11 +210,9 @@ def _rs_phase(axis, n, dirs, buf_ref, stage_ref, send_sem, recv_sem,
             r, right, _left = nbrs[h]
             send_slot = lax.rem(r - d * (s + 1) + (s + 1) * n + n, n)
 
-            if faithful:
-
-                @pl.when(s >= 2)
-                def _(h=h):  # credit: downstream consumed staging slot s%2
-                    pltpu.semaphore_wait(ack_sem.at[h], 1)
+            @pl.when(s >= 2)
+            def _(h=h):  # credit: downstream consumed staging slot s%2
+                pltpu.semaphore_wait(ack_sem.at[h], 1)
 
             sl = lax.rem(s, 2)
             rdma = pltpu.make_async_remote_copy(
@@ -229,7 +220,7 @@ def _rs_phase(axis, n, dirs, buf_ref, stage_ref, send_sem, recv_sem,
                 dst_ref=stage_ref.at[h, sl],
                 send_sem=send_sem.at[h, sl],
                 recv_sem=recv_sem.at[h, sl],
-                **_dma.remote_kwargs(axis, right, faithful),
+                **_dma.remote_kwargs(axis, right),
             )
             rdma.start()
             descs.append(rdma)
@@ -243,14 +234,12 @@ def _rs_phase(axis, n, dirs, buf_ref, stage_ref, send_sem, recv_sem,
                 buf_ref[recv_slot, h] + stage_ref[h, sl]
             )
 
-            if faithful:
-
-                @pl.when(s <= n - 4)
-                def _(h=h, left=left):  # staging consumed — grant step s+2
-                    pltpu.semaphore_signal(
-                        ack_sem.at[h], inc=1,
-                        **_dma.remote_kwargs(axis, left, faithful),
-                    )
+            @pl.when(s <= n - 4)
+            def _(h=h, left=left):  # staging consumed — grant step s+2
+                pltpu.semaphore_signal(
+                    ack_sem.at[h], inc=1,
+                    **_dma.remote_kwargs(axis, left),
+                )
 
         for rdma in descs:
             rdma.wait_send()
@@ -261,7 +250,7 @@ def _rs_phase(axis, n, dirs, buf_ref, stage_ref, send_sem, recv_sem,
 
 def _rs_phase_q(axis, n, dirs, buf_ref, qsend_ref, ssend_ref, qstage_ref,
                 sstage_ref, send_sem, recv_sem, ssend_sem, srecv_sem,
-                ack_sem, faithful, wire_dtype, rows, srows, dtype):
+                ack_sem, wire_dtype, rows, srows, dtype):
     """The quantized-wire reduce-scatter phase: identical slot/credit
     schedule to :func:`_rs_phase`, but each hop's send path quantizes the
     partial sum into a wire-dtype scratch + packed row scales (TWO remote
@@ -280,11 +269,9 @@ def _rs_phase_q(axis, n, dirs, buf_ref, qsend_ref, ssend_ref, qstage_ref,
             r, right, _left = nbrs[h]
             send_slot = lax.rem(r - d * (s + 1) + (s + 1) * n + n, n)
 
-            if faithful:
-
-                @pl.when(s >= 2)
-                def _(h=h):  # credit: downstream consumed staging slot s%2
-                    pltpu.semaphore_wait(ack_sem.at[h], 1)
+            @pl.when(s >= 2)
+            def _(h=h):  # credit: downstream consumed staging slot s%2
+                pltpu.semaphore_wait(ack_sem.at[h], 1)
 
             # quantize the send path: wire payload + packed row scales
             q, sc = _quantize_rows(buf_ref[send_slot, h], wire_dtype)
@@ -296,14 +283,14 @@ def _rs_phase_q(axis, n, dirs, buf_ref, qsend_ref, ssend_ref, qstage_ref,
                 dst_ref=qstage_ref.at[h, sl],
                 send_sem=send_sem.at[h, sl],
                 recv_sem=recv_sem.at[h, sl],
-                **_dma.remote_kwargs(axis, right, faithful),
+                **_dma.remote_kwargs(axis, right),
             )
             rs_ = pltpu.make_async_remote_copy(
                 src_ref=ssend_ref.at[h],
                 dst_ref=sstage_ref.at[h, sl],
                 send_sem=ssend_sem.at[h, sl],
                 recv_sem=srecv_sem.at[h, sl],
-                **_dma.remote_kwargs(axis, right, faithful),
+                **_dma.remote_kwargs(axis, right),
             )
             rq.start()
             rs_.start()
@@ -320,14 +307,12 @@ def _rs_phase_q(axis, n, dirs, buf_ref, qsend_ref, ssend_ref, qstage_ref,
             deq = _dequantize_rows(qstage_ref[h, sl], sc[..., None], dtype)
             buf_ref[recv_slot, h] = buf_ref[recv_slot, h] + deq
 
-            if faithful:
-
-                @pl.when(s <= n - 4)
-                def _(h=h, left=left):  # staging consumed — grant step s+2
-                    pltpu.semaphore_signal(
-                        ack_sem.at[h], inc=1,
-                        **_dma.remote_kwargs(axis, left, faithful),
-                    )
+            @pl.when(s <= n - 4)
+            def _(h=h, left=left):  # staging consumed — grant step s+2
+                pltpu.semaphore_signal(
+                    ack_sem.at[h], inc=1,
+                    **_dma.remote_kwargs(axis, left),
+                )
 
         for rq, rs_ in descs:
             rq.wait_send()
@@ -431,8 +416,7 @@ def _mirror_quant_ar_stream(buf, axis, n, d, wire_dtype, dtype):
     return _dequantize_rows(qbuf, sbuf, dtype)
 
 
-def _ag_ring(chunk, axis, n, *, direction, interpret, faithful,
-             collective_id):
+def _ag_ring(chunk, axis, n, *, direction, interpret, collective_id):
     """One write-once all-gather ring kernel on a [1, rows, LANES] chunk of
     any dtype → [n, 1, rows, LANES]. The payload core of ring_all_gather,
     reused verbatim for the quantized wire's payload and scale exchanges
@@ -441,11 +425,10 @@ def _ag_ring(chunk, axis, n, *, direction, interpret, faithful,
 
     def kernel(x_ref, buf_ref, send_sem, recv_sem, ack_sem):
         r, right, left = _neighbors(axis, n, direction)
-        if faithful:
-            _barrier(axis, left, right)
+        _barrier(axis, left, right)
         buf_ref[r, 0] = x_ref[0]
         _ag_phase(axis, n, (direction,), buf_ref, send_sem, recv_sem,
-                  ack_sem, faithful)
+                  ack_sem)
 
     return pl.pallas_call(
         kernel,
@@ -483,16 +466,6 @@ def ring_all_gather(x: jax.Array, axis, *, direction: int = 1,
     flat = x.reshape(-1)
     chunk, _, m = _pad_chunks(flat, 1)  # [1, rows, 128]
     rows = m // _LANES
-    faithful = _dma.faithful_sync(interpret)
-    if wire_dtype is not None and direction == -1 and not faithful:
-        # The legacy discharge interpreter (jax 0.4.x) mis-propagates the
-        # sharding of the REVERSE-ring payload+scale gather pair (XLA
-        # Array::Reshape check failure at compile). An all-gather's result
-        # is direction-independent — write-once verbatim forwarding — so
-        # ride the forward ring there: the counter-rotation only buys
-        # concurrency on substrates with real DMAs, which the discharge
-        # interpreter serializes anyway. Bit-identical output either way.
-        direction = 1
     itemsize = x.dtype.itemsize
     hop_bytes = _hop_wire_bytes(m, itemsize, wire_dtype)
 
@@ -509,7 +482,7 @@ def ring_all_gather(x: jax.Array, axis, *, direction: int = 1,
             _count_wire_bytes("ring_all_gather", "pallas", None,
                               (n - 1) * hop_bytes)
         buf = _ag_ring(chunk, axis, n, direction=direction,
-                       interpret=interpret, faithful=faithful,
+                       interpret=interpret,
                        collective_id=collective_id)
         out = buf.reshape(n, m)[:, : flat.size]
         return out.reshape((n * k,) + x.shape[1:])
@@ -532,10 +505,10 @@ def ring_all_gather(x: jax.Array, axis, *, direction: int = 1,
                               (n - 1) * hop_bytes)
         sp = _dma.pack_row_scales(sc[..., 0], srows)  # [1, srows, 128]
         qbuf = _ag_ring(q, axis, n, direction=direction,
-                        interpret=interpret, faithful=faithful,
+                        interpret=interpret,
                         collective_id=collective_id)
         sbuf = _ag_ring(sp, axis, n, direction=direction,
-                        interpret=interpret, faithful=faithful,
+                        interpret=interpret,
                         collective_id=collective_id + _dma.CID_SCALE_OFFSET)
         scg = _dma.unpack_row_scales(sbuf, rows)  # [n, 1, rows]
         out = _dequantize_rows(qbuf, scg[..., None], x.dtype)
@@ -566,7 +539,6 @@ def ring_reduce_scatter(x: jax.Array, axis, *, direction: int = 1,
     rows = m // _LANES
     itemsize = x.dtype.itemsize
     hop_bytes = _hop_wire_bytes(m, itemsize, wire_dtype)
-    faithful = _dma.faithful_sync(interpret)
 
     if wire_dtype is None:
         if not _check_budget(rs_charge(x.size, itemsize, n, None, interpret),
@@ -583,11 +555,10 @@ def ring_reduce_scatter(x: jax.Array, axis, *, direction: int = 1,
         def kernel(x_ref, out_ref, buf_ref, stage_ref, send_sem, recv_sem,
                    ack_sem):
             r, right, left = _neighbors(axis, n, direction)
-            if faithful:
-                _barrier(axis, left, right)
+            _barrier(axis, left, right)
             buf_ref[...] = x_ref[...]
             _rs_phase(axis, n, (direction,), buf_ref, stage_ref, send_sem,
-                      recv_sem, ack_sem, faithful)
+                      recv_sem, ack_sem)
             out_ref[...] = buf_ref[r, 0]
 
         out = pl.pallas_call(
@@ -621,12 +592,11 @@ def ring_reduce_scatter(x: jax.Array, axis, *, direction: int = 1,
     def kernel(x_ref, out_ref, buf_ref, qsend, ssend, qstage, sstage,
                send_sem, recv_sem, ssend_sem, srecv_sem, ack_sem):
         r, right, left = _neighbors(axis, n, direction)
-        if faithful:
-            _barrier(axis, left, right)
+        _barrier(axis, left, right)
         buf_ref[...] = x_ref[...]
         _rs_phase_q(axis, n, (direction,), buf_ref, qsend, ssend, qstage,
                     sstage, send_sem, recv_sem, ssend_sem, srecv_sem,
-                    ack_sem, faithful, wire_dtype, rows, srows, x.dtype)
+                    ack_sem, wire_dtype, rows, srows, x.dtype)
         out_ref[...] = buf_ref[r, 0]
 
     out = pl.pallas_call(
@@ -677,7 +647,6 @@ def ring_all_reduce(x: jax.Array, axis, *, bidirectional: bool = True,
     itemsize = x.dtype.itemsize
     hop_bytes = _hop_wire_bytes(m, itemsize, wire_dtype)
     wire_total = 2 * (n - 1) * n_streams * hop_bytes
-    faithful = _dma.faithful_sync(interpret)
 
     if wire_dtype is None:
         if not _check_budget(x.size * itemsize, "all_reduce", interpret):
@@ -693,19 +662,16 @@ def ring_all_reduce(x: jax.Array, axis, *, bidirectional: bool = True,
             r = lax.axis_index(axis)
             right = lax.rem(r + 1, n)
             left = lax.rem(r - 1 + n, n)
-            if faithful:
-                _barrier(axis, left, right)
+            _barrier(axis, left, right)
             buf_ref[...] = x_ref[...]
             _rs_phase(axis, n, dirs, buf_ref, stage_ref, send_sem,
-                      recv_sem, ack_sem, faithful)
+                      recv_sem, ack_sem)
             # Phase barrier: my AG write into a neighbor's buf slot must
             # land after that neighbor's RS sends from it have drained (its
             # RS loop waits every send_sem, so "RS done" implies the reads
             # completed).
-            if faithful:
-                _barrier(axis, left, right)
-            _ag_phase(axis, n, dirs, buf_ref, send_sem, recv_sem, ack_sem,
-                      faithful)
+            _barrier(axis, left, right)
+            _ag_phase(axis, n, dirs, buf_ref, send_sem, recv_sem, ack_sem)
 
         buf = pl.pallas_call(
             kernel,
@@ -745,28 +711,24 @@ def ring_all_reduce(x: jax.Array, axis, *, bidirectional: bool = True,
         r = lax.axis_index(axis)
         right = lax.rem(r + 1, n)
         left = lax.rem(r - 1 + n, n)
-        if faithful:
-            _barrier(axis, left, right)
+        _barrier(axis, left, right)
         buf_ref[...] = x_ref[...]
         _rs_phase_q(axis, n, dirs, buf_ref, qsend, ssend, qstage, sstage,
                     send_sem, recv_sem, ssend_sem, srecv_sem, ack_sem,
-                    faithful, wire_dtype, rows, srows, x.dtype)
+                    wire_dtype, rows, srows, x.dtype)
         # Phase barrier: the payload AG reuses the RS payload semaphores —
         # an early AG signal must not race a neighbor still in its RS loop.
-        if faithful:
-            _barrier(axis, left, right)
+        _barrier(axis, left, right)
         # quantize the reduced slot ONCE; AG forwards wire bytes verbatim
         # (write-once slots), every member dequantizing the same bytes
         for h in range(n_streams):
             q, sc = _quantize_rows(buf_ref[r, h], wire_dtype)
             qbuf[r, h] = q
             sbuf[r, h] = _dma.pack_row_scales(sc[..., 0], srows)
-        _ag_phase(axis, n, dirs, qbuf, send_sem, recv_sem, ack_sem,
-                  faithful)
+        _ag_phase(axis, n, dirs, qbuf, send_sem, recv_sem, ack_sem)
         # the scale AG rides the scale semaphores + its own credits —
         # disjoint from the payload AG's set, so no barrier between them
-        _ag_phase(axis, n, dirs, sbuf, ssend_sem, srecv_sem, sack_sem,
-                  faithful)
+        _ag_phase(axis, n, dirs, sbuf, ssend_sem, srecv_sem, sack_sem)
         scg = _dma.unpack_row_scales(sbuf[...], rows)  # [n, S, rows]
         buf_ref[...] = _dequantize_rows(qbuf[...], scg[..., None], x.dtype)
 

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from uccl_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from uccl_tpu.ep import ll as ep_ll
@@ -193,8 +193,7 @@ class Buffer:
     exchanges of BOTH the normal (sorted) and low-latency row formats
     through the device-initiated remote-DMA all-to-all kernel
     (:mod:`uccl_tpu.ep.pallas_a2a`), keeping ``lax`` as the transparent
-    fallback past its VMEM budget or where the kernel cannot address the
-    mesh (legacy interpreters on multi-axis meshes).
+    fallback past its VMEM budget.
 
     ``n_chunks`` sets the pallas wire's chunk-pipeline depth: the
     capacity/slot axis splits into that many double-buffered per-chunk
@@ -311,43 +310,14 @@ class Buffer:
     def _axis_name(self):
         return self.axes if len(self.axes) > 1 else self.axes[0]
 
-    def _pallas_wire_ok(self) -> bool:
-        """Whether the Pallas all-to-all can address this mesh in the mode
-        it would trace under: always, for real Mosaic lowering or the
-        faithful TPU interpreter; on the legacy discharge interpreter (jax
-        0.4.x CPU runs) only single-named-axis meshes are addressable."""
-        from uccl_tpu.collective import dma
-
-        return (
-            dma.faithful_sync(dma.interpret_default())
-            or len(self.mesh.axis_names) == 1
-        )
-
     def _resolve_wire(self, requested, config) -> str:
         """Effective wire for a verb: explicit call value, else the Config,
-        else the Buffer's. "pallas" downgrades to "auto" (with a log) where
-        the kernel cannot address the mesh, so the surface stays
-        transparent."""
+        else the Buffer's."""
         wire = requested if requested is not None else "auto"
         if wire == "auto" and config is not None:
             wire = config.wire
         if wire == "auto":
             wire = self.wire
-        if wire == "pallas" and not self._pallas_wire_ok():
-            # static per Buffer (mesh + interpreter): count/log the
-            # downgrade once, not per verb call
-            if "wire_downgrade" not in self._resolve_memo:
-                self._resolve_memo["wire_downgrade"] = True
-                from uccl_tpu.collective import dma
-
-                dma.record_fallback(
-                    "buffer_verb", "legacy_interpret_mesh",
-                    detail=tuple(self.mesh.axis_names),
-                    msg="wire='pallas' cannot address a multi-axis mesh "
-                        "under the legacy interpret mode; falling back to "
-                        "the XLA wire",
-                )
-            wire = "auto"
         return wire
 
     def _resolve_chunks(self, requested, config, wire: str) -> int:

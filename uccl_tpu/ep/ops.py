@@ -519,7 +519,7 @@ def combine(
 
 def resolve_chunks(n_chunks: int, wire: str, world: int, capacity: int,
                    e_local: int, hidden: int, itemsize: int,
-                   axis=None, wire_dtype=None) -> int:
+                   wire_dtype=None) -> int:
     """Effective chunk count for the pipelined EP layer. ``0`` = auto: the
     :class:`~uccl_tpu.collective.plan.CollectivePlanner` picks the depth
     off its cost model (2 — the minimum that buys dispatch/compute/combine
@@ -527,9 +527,7 @@ def resolve_chunks(n_chunks: int, wire: str, world: int, capacity: int,
     dwarfs the per-launch gamma) on the pallas wire when the world and
     capacity can chunk, else 1. Any request
     collapses to 1 off the pallas wire (XLA owns the lax schedule), at world
-    1 (no wire), on meshes the kernel cannot address (a tuple EP axis under
-    the legacy discharge interpreter — every chunk would silently ride lax
-    and the split would be pure overhead), or when the pipeline's resident
+    1 (no wire), or when the pipeline's resident
     footprint — 4 send+recv chunk pairs: two airborne kernels in EACH of
     the dispatch and combine families — is over budget. All of these are
     the automatic fallback to the unchunked wire. Every downgrade of an
@@ -544,7 +542,7 @@ def resolve_chunks(n_chunks: int, wire: str, world: int, capacity: int,
     so benches label their chunk arms off the real resolution, not the
     requested knob."""
     n = _resolve_chunks_value(n_chunks, wire, world, capacity, e_local,
-                              hidden, itemsize, axis)
+                              hidden, itemsize)
     from uccl_tpu.collective import plan as _plan
     from uccl_tpu.obs import counters as _obsc
 
@@ -559,7 +557,7 @@ def resolve_chunks(n_chunks: int, wire: str, world: int, capacity: int,
 
 
 def _resolve_chunks_value(n_chunks, wire, world, capacity, e_local, hidden,
-                          itemsize, axis) -> int:
+                          itemsize) -> int:
     requested = n_chunks > 1 and wire == "pallas"
     if wire != "pallas" or world <= 1 or capacity < 2:
         if requested:
@@ -568,16 +566,6 @@ def _resolve_chunks_value(n_chunks, wire, world, capacity, e_local, hidden,
                 "world_size" if world <= 1 else "capacity",
                 detail=(world, capacity),
             )
-        return 1
-    if (
-        axis is not None
-        and isinstance(axis, (tuple, list))
-        and len(axis) > 1
-        and not _dma.faithful_sync(_dma.resolve_interpret(None))
-    ):
-        if requested:
-            _dma.record_fallback("ep_moe_chunked", "tuple_axis_mesh",
-                                 detail=tuple(axis))
         return 1
     if n_chunks == 0:
         # auto: the planner's cost model picks the depth from the modeled
@@ -744,7 +732,7 @@ def moe_ffn(
         n_chunks = resolve_chunks(
             n_chunks, wire, w, capacity, e // w, h,
             wire_itemsize(wire_fp8, h, x.dtype, wire_dtype=wire_dtype),
-            axis=axis, wire_dtype=wire_dtype,
+            wire_dtype=wire_dtype,
         )
         if n_chunks > 1:
             plan = SlotPlan(rs.token_for_slot, rs.slot, rs.counts)

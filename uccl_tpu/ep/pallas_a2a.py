@@ -60,9 +60,8 @@ front (``dma.chunk_budget``).
 
 Fallback: payloads over the VMEM budget (or the interpreter's single-core
 ceiling), chunk pipelines over the 2x double-buffer budget, worlds of 1,
-and meshes the legacy discharge interpreter cannot address fall back to the
-unchunked kernel and ultimately ``lax.all_to_all`` with identical
-semantics — the ``wire="pallas"`` surface is transparent either way.
+fall back to the unchunked kernel and ultimately ``lax.all_to_all`` with
+identical semantics — the ``wire="pallas"`` surface is transparent either way.
 """
 
 from __future__ import annotations
@@ -80,13 +79,8 @@ def _lax_fallback(x: jax.Array, axis) -> jax.Array:
     return lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
 
 
-def _a2a_kernel(axis, n: int, faithful: bool):
-    """Build the kernel body for an n-member all-to-all over ``axis``.
-
-    ``faithful`` is static: under the legacy discharge interpreter (jax
-    0.4.x) remote semaphore signals are unimplemented, but every DMA
-    discharges into a synchronous cross-device gather — the barrier and
-    credits it elides are subsumed by that global ordering."""
+def _a2a_kernel(axis, n: int):
+    """Build the kernel body for an n-member all-to-all over ``axis``."""
     s_fwd = (n - 1 + 1) // 2  # fwd stream steps: dsts r+1 .. r+S
     s_bwd = (n - 1) // 2  # bwd stream steps: dsts r-1 .. r-S'
 
@@ -95,11 +89,10 @@ def _a2a_kernel(axis, n: int, faithful: bool):
         """One direction's DMA at step s: d=+1 fwd / -1 bwd; ``last`` is the
         stream's static step count (credit window arithmetic)."""
         dst = lax.rem(r + d * s + s * n, n)
-        if faithful:
 
-            @pl.when(s >= 3)
-            def _():  # credit: my step-(s-2) parity slot drained downstream
-                pltpu.semaphore_wait(ack_sem.at[h], 1)
+        @pl.when(s >= 3)
+        def _():  # credit: my step-(s-2) parity slot drained downstream
+            pltpu.semaphore_wait(ack_sem.at[h], 1)
 
         sl = lax.rem(s, 2)
         rdma = pltpu.make_async_remote_copy(
@@ -109,29 +102,26 @@ def _a2a_kernel(axis, n: int, faithful: bool):
             dst_ref=out_ref.at[r],
             send_sem=send_sem.at[h, sl],
             recv_sem=recv_sem.at[h, sl],
-            **_dma.remote_kwargs(axis, dst, faithful),
+            **_dma.remote_kwargs(axis, dst),
         )
         rdma.start()
         return rdma
 
     def stream_finish(ack_sem, rdma, r, s, h, d, last):
         rdma.wait_recv()  # slot (r - d*s) arrived
-        if faithful:
 
-            @pl.when(s <= last - 2)
-            def _():  # grant the peer that targets me at step s+2
-                pltpu.semaphore_signal(
-                    ack_sem.at[h], inc=1,
-                    **_dma.remote_kwargs(
-                        axis, lax.rem(r - d * (s + 2) + (s + 2) * n, n),
-                        faithful,
-                    ),
-                )
+        @pl.when(s <= last - 2)
+        def _():  # grant the peer that targets me at step s+2
+            pltpu.semaphore_signal(
+                ack_sem.at[h], inc=1,
+                **_dma.remote_kwargs(
+                    axis, lax.rem(r - d * (s + 2) + (s + 2) * n, n)
+                ),
+            )
 
     def kernel(x_ref, out_ref, send_sem, recv_sem, ack_sem):
         r = lax.axis_index(axis)
-        if faithful:
-            _dma.all_barrier(axis, n)
+        _dma.all_barrier(axis, n)
         out_ref[r] = x_ref[r]  # local chunk short-circuits
 
         def step(s, _):
@@ -246,18 +236,6 @@ def all_to_all(
     if collective_id is None:
         collective_id = _dma.CID_A2A  # the generic lane ({6,7} when chunked)
     interpret = _dma.resolve_interpret(interpret)
-    if (
-        isinstance(axis, (tuple, list))
-        and len(axis) > 1
-        and not _dma.faithful_sync(interpret)
-    ):
-        # the legacy discharge interpreter addresses peers by flat LOGICAL
-        # id along ONE named axis; a tuple EP axis (e.g. flagship's
-        # ("dp", "cp")) is unaddressable there — same transparent downgrade
-        # Buffer._pallas_wire_ok applies at the verb level
-        _dma.record_fallback("ep_all_to_all", "tuple_axis_mesh",
-                             detail=tuple(axis))
-        return _lax_fallback(x, axis)
     if n_chunks > 1:
         if chunk_axis == 0:
             raise ValueError("chunk_axis 0 is the member axis; chunk a "
@@ -273,10 +251,9 @@ def all_to_all(
                              interpret):
         return _lax_fallback(x, axis)
     rows = m // _dma.LANES
-    faithful = _dma.faithful_sync(interpret)
 
     buf = pl.pallas_call(
-        _a2a_kernel(axis, n, faithful),
+        _a2a_kernel(axis, n),
         out_shape=jax.ShapeDtypeStruct((n, rows, _dma.LANES), x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -309,13 +286,11 @@ def all_to_all(
 # unscheduled kernel and to lax.all_to_all.
 #
 # Rounds must be FULL permutations (self-loops allowed — a self-DMA is a
-# local copy): under the legacy discharge interpreter a remote DMA lowers to
-# a rendezvous collective over ALL mesh members, so a member predicated out
-# of a round would deadlock the rendezvous; on real hardware full rounds
-# also keep the entry barrier and semaphore accounting uniform.
+# local copy): full rounds keep the entry barrier and semaphore accounting
+# uniform across members.
 
 
-def _sched_round_kernel(axis, n: int, faithful: bool):
+def _sched_round_kernel(axis, n: int):
     """One permutation round: member ``r`` DMAs its chunk for ``pi[r]`` into
     that member's single round-output slot. Write-once per kernel (every
     member receives exactly one chunk), so no credit protocol is needed —
@@ -324,15 +299,14 @@ def _sched_round_kernel(axis, n: int, faithful: bool):
 
     def kernel(pi_ref, x_ref, out_ref, send_sem, recv_sem):
         r = lax.axis_index(axis)
-        if faithful:
-            _dma.all_barrier(axis, n)
+        _dma.all_barrier(axis, n)
         dst = pi_ref[r]
         rdma = pltpu.make_async_remote_copy(
             src_ref=x_ref.at[dst],
             dst_ref=out_ref,
             send_sem=send_sem,
             recv_sem=recv_sem,
-            **_dma.remote_kwargs(axis, dst, faithful),
+            **_dma.remote_kwargs(axis, dst),
         )
         rdma.start()
         rdma.wait_send()
@@ -350,8 +324,7 @@ def _run_rounds(view, axis, n: int, perms, interpret, base_cid: int,
     kernels airborne — the invariant that makes the {base, base+1} id
     rotation sound across chunk AND round boundaries."""
     rows = view.shape[1]
-    faithful = _dma.faithful_sync(interpret)
-    kern = _sched_round_kernel(axis, n, faithful)
+    kern = _sched_round_kernel(axis, n)
     outs = []
     for pi in perms:
         i = len(launch_seq)
@@ -502,14 +475,6 @@ def scheduled_all_to_all(
             f"all_to_all leading dim {x.shape[0]} != axis size {n}"
         )
     interpret = _dma.resolve_interpret(interpret)
-    if (
-        isinstance(axis, (tuple, list))
-        and len(axis) > 1
-        and not _dma.faithful_sync(interpret)
-    ):
-        _dma.record_fallback("ep_a2a_sched", "tuple_axis_mesh",
-                             detail=tuple(axis))
-        return _lax_fallback(x, axis)
     perms, k_mat = _normalize_schedule(schedule, n)
     if not perms:  # empty schedule: nothing crosses the wire at n > 1
         raise ValueError("scheduled a2a needs at least one round at n > 1")

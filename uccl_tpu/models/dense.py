@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from uccl_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from uccl_tpu.models import flagship as _fs

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from uccl_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from uccl_tpu.ep import ops as ep_ops
@@ -146,6 +146,20 @@ def shard_params(params, mesh: Mesh, cfg: FlagshipConfig):
 # Per-shard forward (inside shard_map)
 
 
+def resolve_attn_impl(cfg: FlagshipConfig) -> str:
+    """The attention path ``cfg`` takes on this backend: ``"flash"`` (the
+    Pallas kernel) or ``"xla"`` (the einsum reference). ``"auto"`` is flash
+    on TPU, where it is compiled, and xla on CPU, where the kernel could
+    only be interpreted."""
+    if cfg.attn_impl in ("flash", "xla"):
+        return cfg.attn_impl
+    if cfg.attn_impl != "auto":
+        raise ValueError(
+            f"unknown attn_impl {cfg.attn_impl!r} (want auto|flash|xla)"
+        )
+    return "flash" if jax.default_backend() == "tpu" else "xla"
+
+
 def _attention(x, lp, cfg: FlagshipConfig):
     """x: [B, S_loc, H_model] -> [B, S_loc, H_model] (pre-psum over tp)."""
     b, s_loc, _ = x.shape
@@ -159,24 +173,15 @@ def _attention(x, lp, cfg: FlagshipConfig):
     positions = cp_idx * s_loc + jnp.arange(s_loc)
     q = rope(q, positions, cfg.rope_theta)
     kk = rope(kk, positions, cfg.rope_theta)
-    from uccl_tpu.ops.attention import _auto_block
-    from uccl_tpu.ops.pallas_attention import _is_tpu, flash_attention
-
-    use_flash = cfg.attn_impl == "flash" or (
-        cfg.attn_impl == "auto" and _is_tpu()
-    )
-    impl = "flash" if use_flash else "xla"
+    impl = resolve_attn_impl(cfg)
     if lax.axis_size(AXIS.CP) == 1:
-        # No context parallelism: the single-shard Pallas flash kernel is the
-        # fast path on TPU (MXU blockwise online softmax in VMEM).
-        blk = _auto_block(s_loc)
-        if use_flash and blk >= 8:
-            attn = flash_attention(q, kk, v, True, blk, blk)
-        elif cfg.attn_impl == "flash":
-            raise ValueError(
-                f"attn_impl='flash' requested but local seq {s_loc} has no "
-                f"usable block size (largest power-of-two divisor {blk} < 8)"
-            )
+        if impl == "flash":
+            # No context parallelism: the single-shard Pallas flash kernel
+            # (MXU blockwise online softmax in VMEM). A sequence it cannot
+            # tile is an error there, never a quiet switch to the reference.
+            from uccl_tpu.ops.pallas_attention import flash_attention
+
+            attn = flash_attention(q, kk, v, True)
         else:
             # Direct single-shard attention, NOT ring_attention at n=1: the
             # math is identical, but the ring's self-ppermute would poison
@@ -186,8 +191,6 @@ def _attention(x, lp, cfg: FlagshipConfig):
             # exists to catch).
             attn = attention_reference(q, kk, v, causal=True)
     elif cfg.seq_mode == "ulysses":
-        # Flash feasibility is ulysses's own call: it attends over the
-        # all-to-all-gathered full sequence, not the local shard.
         attn = ulysses_attention(q, kk, v, AXIS.CP, causal=True, impl=impl)
     else:
         attn = ring_attention(q, kk, v, AXIS.CP, causal=True, impl=impl)

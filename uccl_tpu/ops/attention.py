@@ -93,12 +93,8 @@ def _block_attend(q, k, v, m, l, o, scale, mask):
 def _auto_block(s: int, cap: int = 1024) -> int:
     """Largest power-of-two block <= cap dividing s (1 if s is odd).
 
-    cap=1024 is the measured v5e optimum at head_dim 64: the on-chip block
-    sweep (PERF.md round-5, flagship shapes B=16/32 S=1024 and B=4 S=4096)
-    is monotone in block size — bq=bk=1024 beats 128 by 2.9x fwd+bwd at
-    S=1024 and 4.7x at S=4096, and beats XLA's fused attention 1.7-4x.
-    VMEM stays comfortable: the f32 score tile is 4 MB; q/k/v/o tiles are
-    O(block*head_dim)."""
+    cap=1024 was the fastest tile at head_dim 64 in the July v5e block sweep
+    (PERF.md, carried-forward table)."""
     b = cap
     while b > 1 and s % b:
         b //= 2
@@ -202,11 +198,9 @@ def ring_attention(
     via LSEs (:func:`_flash_ring`); impl="xla" uses einsum block attends.
     """
     if impl == "flash":
-        bq = block_q or _auto_block(q.shape[1])
-        bk = block_k or _auto_block(k.shape[1])
-        if min(bq, bk) >= 8:
-            return _flash_ring(q, k, v, axis, causal, bq, bk, interpret)
-        # fall through to the XLA path when blocks would be degenerate
+        # block sizing (and the error for a sequence Mosaic cannot tile)
+        # is flash_attention_lse's; there is no quiet switch to the XLA path
+        return _flash_ring(q, k, v, axis, causal, block_q, block_k, interpret)
     n = lax.axis_size(axis)
     r = lax.axis_index(axis)
     n_rep = q.shape[2] // k.shape[2]
@@ -280,10 +274,7 @@ def ulysses_attention(
     if impl == "flash":
         from uccl_tpu.ops.pallas_attention import flash_attention
 
-        bq = _auto_block(qg.shape[1])
-        bk = _auto_block(kg.shape[1])
-        if min(bq, bk) >= 8:
-            out = flash_attention(qg, kg, vg, causal, bq, bk, interpret)
-            return heads_to_seq(out)
+        out = flash_attention(qg, kg, vg, causal, interpret=interpret)
+        return heads_to_seq(out)
     out = attention_reference(qg, kg, vg, causal=causal)
     return heads_to_seq(out)

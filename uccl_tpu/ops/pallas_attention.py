@@ -18,8 +18,10 @@ Three public entry points:
   output is differentiable: its cotangent folds into the backward row term
   (``dS = P∘(dP − (Δ − g_lse))``), which is exactly what blockwise/ring
   merging needs to train through merged blocks.
-* The kernels fall back to interpret mode automatically off-TPU so every
-  test runs anywhere.
+
+``interpret=None`` resolves once from the backend
+(:func:`uccl_tpu.utils.device.pallas_interpret`): compiled by Mosaic on TPU,
+interpreted on CPU (the tests), an error anywhere else.
 """
 
 from __future__ import annotations
@@ -34,19 +36,20 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from uccl_tpu.utils.device import pallas_interpret
+
 _NEG_INF = -1e30
 
-# Renamed from TPUCompilerParams in older jax releases.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
-
-def _is_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+def _compiler_params():
+    # scratch carries state only across the innermost grid axis; the two
+    # outer axes' programs are independent, so megacore may split them.
+    # No vmem_limit_bytes: at the auto-sized 1024x1024 tiles all three
+    # kernels compile under Mosaic's default scoped-VMEM limit on v5e
+    # (libtpu 0.0.34; PERF.md "Bring-up").
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -130,8 +133,6 @@ def _flash_fwd(
         raise ValueError(
             f"seq lengths ({sq},{sk}) must divide blocks ({block_q},{block_k})"
         )
-    if interpret is None:
-        interpret = not _is_tpu()
     scale = 1.0 / math.sqrt(d)
 
     # [B, S, H, D] -> [B*H, S, D] program-major layout
@@ -164,11 +165,7 @@ def _flash_fwd(
             pltpu.VMEM((block_q, 1), jnp.float32),  # normalizer l
             pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
         ],
-        compiler_params=_CompilerParams(
-            # scratch carries state only across jk (innermost); bh/iq programs
-            # are independent, so let megacore split them.
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(qt, kt, vt)
     return (
@@ -294,8 +291,6 @@ def _flash_bwd(q, k, v, out, lse, g_out, g_lse, causal, block_q, block_k,
     n_rep = h // hkv
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    if interpret is None:
-        interpret = not _is_tpu()
     scale = 1.0 / math.sqrt(d)
 
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
@@ -329,9 +324,7 @@ def _flash_bwd(q, k, v, out, lse, g_out, g_lse, causal, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, iq, jk: (bh, iq, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(qt, kt, vt, dot, lse_t, delta)
 
@@ -358,9 +351,7 @@ def _flash_bwd(q, k, v, out, lse, g_out, g_lse, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(qt, kt, vt, dot, lse_t, delta)
 
@@ -450,8 +441,9 @@ def flash_attention_lse(
     # would reject it obscurely. Explicitly passed blocks (args or env)
     # are the caller's own; interpret mode accepts any tile and keeps
     # working (short decode-style sequences included).
-    will_compile = interpret is False or (interpret is None and _is_tpu())
-    if will_compile and (
+    if interpret is None:
+        interpret = pallas_interpret()
+    if not interpret and (
         (auto_q and block_q < 8) or (auto_k and block_k < 8)
     ):
         raise ValueError(

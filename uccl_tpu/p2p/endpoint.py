@@ -69,17 +69,14 @@ def _build_if_needed() -> str:
     # there is no source tree to hash or rebuild against.
     if not os.path.isdir(_NATIVE_DIR) and os.path.exists(_WHEEL_SO):
         return _WHEEL_SO
-    srcs = [
-        os.path.join(_NATIVE_DIR, "src", "engine.cc"),
-        os.path.join(_NATIVE_DIR, "src", "c_api.cc"),
-        os.path.join(_NATIVE_DIR, "src", "net_plugin.cc"),
-        os.path.join(_NATIVE_DIR, "src", "float_codec.cc"),
-        os.path.join(_NATIVE_DIR, "include", "uccl_tpu", "engine.h"),
-        os.path.join(_NATIVE_DIR, "include", "uccl_tpu", "net_plugin.h"),
-        os.path.join(_NATIVE_DIR, "include", "uccl_tpu", "ring.h"),
-        os.path.join(_NATIVE_DIR, "include", "uccl_tpu", "lrpc.h"),
-        os.path.join(_NATIVE_DIR, "include", "uccl_tpu", "pool.h"),
-    ]
+    # Everything `make all` depends on: the Makefile and every file under
+    # src/ and include/ (its HDRS list names all eight headers; a hand-kept
+    # list here once trusted a stale .so after an edit to the other three).
+    srcs = [os.path.join(_NATIVE_DIR, "Makefile")]
+    for sub in ("src", "include"):
+        for root, _dirs, files in os.walk(os.path.join(_NATIVE_DIR, sub)):
+            srcs.extend(os.path.join(root, f) for f in files)
+    srcs.sort()
     # `make all` produces every artifact; freshness requires them all so a
     # consumer of any one (e.g. the net plugin tests) can trust the build.
     _artifacts = [
@@ -95,9 +92,9 @@ def _build_if_needed() -> str:
     def src_digest() -> str:
         hasher = hashlib.sha256()
         for s in srcs:
-            if os.path.exists(s):
-                with open(s, "rb") as f:
-                    hasher.update(f.read())
+            hasher.update(os.path.relpath(s, _NATIVE_DIR).encode())
+            with open(s, "rb") as f:
+                hasher.update(f.read())
         return hasher.hexdigest()
 
     digest_path = os.path.join(_NATIVE_DIR, "build", ".src_hash")

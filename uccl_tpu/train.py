@@ -57,6 +57,7 @@ def parse_mesh(spec: str):
 def build(args, mesh):
     """Returns (cfg, params, train_step, init_opt) for the model family."""
     import jax
+    import jax.numpy as jnp
 
     if args.model == "flagship":
         from uccl_tpu.models import flagship as fam
@@ -68,6 +69,11 @@ def build(args, mesh):
         n_heads=args.heads, n_kv_heads=args.kv_heads,
         head_dim=args.dim // args.heads,
         n_microbatches=args.microbatches,
+        # activations in the MXU's dtype on a TPU; float32 on the CPU, where
+        # the tests compare trajectories bit for bit (params, gradients and
+        # optimizer state are float32 everywhere)
+        dtype=jnp.bfloat16 if jax.default_backend() == "tpu"
+        else jnp.float32,
     )
     if args.model == "flagship":
         size_kw.update(
@@ -131,6 +137,22 @@ def _open_corpus(path, vocab, seq):
             f"[0, {vocab})"
         )
     return corpus
+
+
+def _on_mesh(tree, mesh):
+    """Leaves born on one device (optimizer scalars like adam's count)
+    replicated over the mesh; mesh-sharded leaves untouched. Mixed, the
+    single-device leaves come back mesh-replicated from the first step and
+    the second step compiles again for the new argument shardings."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    replicated = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda x: x if isinstance(x.sharding, NamedSharding)
+        else jax.device_put(x, replicated),
+        tree,
+    )
 
 
 def _latest_step(ckpt_dir):
@@ -241,6 +263,10 @@ def main(argv=None):
     else:
         import jax
 
+    from uccl_tpu.utils import device
+
+    compiles0 = device.compile_counts()
+
     session = None
     if "UCCL_TPU_COORD" in os.environ:
         # Launched by scripts/launch.py (torchrun-shaped): join the
@@ -254,6 +280,10 @@ def main(argv=None):
         )
 
     from uccl_tpu.parallel.mesh import make_mesh
+
+    # after the session join: this looks at the backend, and
+    # jax.distributed must initialize before anything does
+    device.enable_compile_cache()
 
     # Multi-controller mode (scripts/launch.py with jax.distributed on):
     # every process sees the GLOBAL device list; batches must be assembled
@@ -277,7 +307,7 @@ def main(argv=None):
     corpus = _open_corpus(args.data, args.vocab, args.seq) if args.data \
         else None
     cfg, params, train_step, init_opt = build(args, mesh)
-    opt_state = init_opt(params)
+    opt_state = _on_mesh(init_opt(params), mesh)
 
     start = 0
     if args.resume:
@@ -303,7 +333,13 @@ def main(argv=None):
             "continue from them or choose a fresh --ckpt-dir"
         )
 
-    step = jax.jit(train_step)
+    # params/opt_state are DONATED: XLA aliases them into the outputs, so
+    # the step holds one copy of the largest buffers, not two. The outputs
+    # are pinned to the inputs' shardings — left to XLA they can come back
+    # canonicalized differently, and the second step would compile again.
+    state_shardings = jax.tree.map(lambda x: x.sharding, (params, opt_state))
+    step = jax.jit(train_step, donate_argnums=(0, 1),
+                   out_shardings=(*state_shardings, None))
     if multihost:
         # Every process builds the SAME deterministic global batch (cheap,
         # synthetic); make_array_from_callback hands each process only its
@@ -320,7 +356,9 @@ def main(argv=None):
     else:
         place = None
     t0 = time.perf_counter()
+    t_first = None
     metrics = None
+    losses = []  # the logged ones: reading a loss waits for the device
     for i in range(start, args.steps):
         tokens, targets = _batch_for_step(
             i, args.batch, args.seq, args.vocab, corpus
@@ -328,12 +366,18 @@ def main(argv=None):
         if place is not None:
             tokens, targets = place(tokens), place(targets)
         params, opt_state, metrics = step(params, opt_state, tokens, targets)
+        if t_first is None:
+            # the first step carries trace + compile: timed apart, so the
+            # rate below is the steady state's
+            jax.block_until_ready(metrics)
+            t_first = time.perf_counter()
         if chatty and args.log_every and (i + 1) % args.log_every == 0:
             extra = (
                 f" ce {float(metrics['ce']):.6f}" if "ce" in metrics else ""
             )
+            losses.append(float(metrics["loss"]))
             print(
-                f"step {i + 1:5d} loss {float(metrics['loss']):.6f}{extra}",
+                f"step {i + 1:5d} loss {losses[-1]:.6f}{extra}",
                 flush=True,
             )
         if args.ckpt_dir and args.ckpt_every and (i + 1) % args.ckpt_every == 0:
@@ -345,22 +389,41 @@ def main(argv=None):
             })
             if chatty:
                 print(f"checkpointed step {i + 1}", flush=True)
-    dt = time.perf_counter() - t0
+    final_loss = float(metrics["loss"]) if metrics else None  # device done
+    t_end = time.perf_counter()
     done = args.steps - start
     summary = {
         "model": args.model,
+        "device": device.describe(),
         "mesh": {"pp": mcfg.pp, "dp": mcfg.dp, "cp": mcfg.cp, "tp": mcfg.tp}
         if args.mesh else {"dp": len(devices)},
+        "batch": args.batch, "seq": args.seq,
+        "dtype": str(jax.numpy.dtype(cfg.dtype)),
+        "remat": cfg.remat,
         "steps": done,
-        "final_loss": round(float(metrics["loss"]), 6) if metrics else None,
-        "steps_per_sec": round(done / dt, 3) if done else 0.0,
+        "final_loss": round(final_loss, 6) if metrics else None,
+        "losses": [round(x, 6) for x in losses],
+        "steps_per_sec": round(done / (t_end - t0), 3) if done else 0.0,
+        # first step = trace + compile + one step; the rest is steady state
+        "first_step_s": round(t_first - t0, 3) if done else None,
+        "step_s": round((t_end - t_first) / (done - 1), 4)
+        if done > 1 else None,
+        "peak_bytes_in_use": device.peak_bytes_in_use(),
+        **device.counts_since(compiles0),
     }
+    if args.model == "flagship":
+        from uccl_tpu.models.flagship import resolve_attn_impl
+
+        summary["attn_impl"] = resolve_attn_impl(cfg)
+        summary["moe_impl"] = cfg.moe_impl
+        summary["moe_wire"] = cfg.moe_wire
     if multihost:
         summary["processes"] = session.world
     if chatty:
         print(json.dumps(summary), flush=True)
     if session is not None:
         session.close()  # release the OOB store port/threads promptly
+    return summary
 
 
 if __name__ == "__main__":

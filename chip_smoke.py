@@ -15,11 +15,12 @@ seed), in ONE process on every chip that process sees:
 
 It catches nothing: a phase that raises or a check that fails ends the run
 non-zero. Without a TPU it exits non-zero before anything else and prints no
-result. The last line of standard output is one JSON object with ``"ok":
-true``, the device as JAX reports it, and per phase the set-up (compile)
-seconds, steady seconds per step or request, peak bytes in use and the
-resolved attention path, MoE path and activation dtype. The numbers are
-facts about the bring-up, not a benchmark: ``"claim": null``.
+result. Standard output ends with two JSON lines: first the report — per
+phase the set-up (compile) seconds, steady seconds per step or request, peak
+bytes in use and the resolved attention path, MoE path and activation dtype
+(facts about the bring-up, not a benchmark: ``"claim": null``) — and last the
+verdict, exactly ``{"ok": true, "device": {"platform", "kind", "count"}}``
+with the device as JAX reports it.
 
     python chip_smoke.py
 """
@@ -217,9 +218,11 @@ def main() -> int:
         if name != "attention":
             _check(p["device"] == device, f"{name} ran on {p['device']}")
             _check(p["peak_bytes_in_use"], f"{name} reports no peak memory")
-    print(json.dumps({"ok": True, "device": device, "phases": phases,
+    print(json.dumps({"report": "chip_smoke", "phases": phases,
                       "total_s": round(time.perf_counter() - t0, 1),
                       "claim": None}), flush=True)
+    # the verdict: these keys and no others, the last line of stdout
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

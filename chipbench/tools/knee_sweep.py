@@ -1,0 +1,71 @@
+"""The knee of a serving cell, found once by a sweep on the chip:
+
+    python -m chipbench.tools.knee_sweep <workload> <seconds> <rate>[:<seed>] ...
+
+One process, one engine; for each rate a window of <seconds> of the cell's
+own traffic at that rate (the arrangement drawn from <seed>, 1000 + the
+rate's place in the list where none is given), then a drain. A rate is sustained when the
+backlog (requests submitted and without a slot) does not grow over the
+window: compared here between the window's second and last quarters. The
+cell then runs at 0.8 x the highest sustained rate (``rate_rps`` in its
+traffic file), with this sweep's table in PERF.md."""
+
+import json
+import os
+import sys
+
+import numpy as np
+
+
+def main(workload, seconds, *rates):
+    from chipbench import run as R
+    from chipbench import spans as sp
+    from chipbench.generators import requests as gen
+    from chipbench.runners import serve
+
+    seconds = float(seconds)
+    bench = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
+    cell, cfg, mix = R.load_cell(bench, workload)
+    R.enable_compile_cache()
+    R.require_chip(cell["chips"])
+    rec = sp.Recorder(annotate=False)
+    engine, backend, vocab = serve.build(cfg, 7, rec)
+    serve.warm(engine, cfg["serving"]["prefill_chunk"])
+    for k, arg in enumerate(rates):
+        rate, _, seed = arg.partition(":")
+        rate, seed = float(rate), int(seed) if seed else 1000 + k
+        m = dict(mix, rate_rps=rate)
+        traffic = gen.generate(m, seed, seconds, vocab)
+        rec.spans.clear()
+        win = serve.drive(engine, traffic, seconds, 60.0, rec)
+        served = win["served"]
+        e2e = serve.reduce_window(served, seconds)
+
+        def waiting(t):
+            return sum(1 for sv in served if sv.submit_s <= t and (
+                sv.req.t_admit is None
+                or sv.req.t_admit - rec.t0 > t))
+
+        q = [np.mean([waiting(t) for t in np.linspace(a * seconds, b * seconds, 50)])
+             for a, b in ((0.25, 0.5), (0.5, 0.75), (0.75, 1.0))]
+        inflight_end = sum(1 for sv in served if not sv.stamps
+                           or sv.stamps[-1] > seconds)
+        print("sweep " + json.dumps({
+            "rate_rps": rate, "seed": seed, "requests": len(served),
+            "waiting_q2_q3_q4": [round(x, 2) for x in q],
+            "unfinished_at_window_end": inflight_end,
+            "ttft_p50_ms": e2e["ttft_p50_ms"], "ttft_p90_ms": e2e["ttft_p90_ms"],
+            "itl_p50_ms": e2e["itl_p50_ms"], "itl_p95_ms": e2e["itl_p95_ms"],
+            "serve_tok_s": e2e["serve_tok_s"],
+            "offered_tok_s": sum(p.size for p in traffic.prompts) / seconds
+            + float(np.sum(traffic.output_lens)) / seconds,
+            "slowest_steps_s_at_s": sorted(
+                ((round(t1 - t0, 4), round(t0 - rec.t0, 3))
+                 for n, t0, t1, _ in rec.spans if n == serve.NAME_STEP),
+                reverse=True)[:5],
+            "drain_s": win["end_s"] - seconds, "failed": e2e["failed"],
+            "late_max_ms": e2e["late_max_ms"]}), flush=True)
+
+
+if __name__ == "__main__":
+    main(*sys.argv[1:])

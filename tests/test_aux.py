@@ -1,10 +1,10 @@
-"""Aux subsystems: stats registry/thread and tracing scopes."""
+"""Aux subsystems: stats registry/thread."""
 
 import time
 
 import pytest
 
-from uccl_tpu.utils import stats, tracing
+from uccl_tpu.utils import stats
 
 
 class TestStats:
@@ -49,22 +49,3 @@ class TestStats:
             stats._interval.reset()
         assert calls == []
 
-
-class TestTracing:
-    def test_timed_scope(self):
-        tracing.reset_scopes()
-        for _ in range(5):
-            with tracing.timed_scope("unit_test_scope"):
-                time.sleep(0.001)
-        s = tracing.scope_stats("unit_test_scope")
-        assert s is not None and s["count"] == 5 and s["p50_us"] >= 500
-
-    def test_unknown_scope(self):
-        assert tracing.scope_stats("nope") is None
-
-    def test_annotate_runs(self):
-        import jax.numpy as jnp
-
-        with tracing.annotate("region"):
-            x = jnp.ones((4,)).sum()
-        assert float(x) == 4.0

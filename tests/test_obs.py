@@ -481,32 +481,6 @@ class TestPrometheusExport:
             stats.registry.unregister("obs_shim_test")
         assert "obs_shim_test" not in obs.REGISTRY.sources_snapshot()
 
-    def test_timed_scope_thread_safety_and_obs_source(self):
-        from uccl_tpu.utils import tracing
-
-        tracing.reset_scopes()
-        errs = []
-
-        def worker():
-            try:
-                for _ in range(200):
-                    with tracing.timed_scope("obs_scope_stress"):
-                        pass
-            except Exception as e:  # pragma: no cover
-                errs.append(e)
-
-        threads = [threading.Thread(target=worker) for _ in range(8)]
-        [t.start() for t in threads]
-        [t.join() for t in threads]
-        assert not errs
-        s = tracing.scope_stats("obs_scope_stress")
-        assert s is not None and s["count"] == 1600  # no racy-lost samples
-        # re-pointed at obs: the scopes source exports the same summary
-        src = obs.REGISTRY.sources_snapshot()["scopes"]
-        assert src["obs_scope_stress"]["count"] == 1600
-        tracing.reset_scopes()
-        assert tracing.scope_stats("obs_scope_stress") is None
-
     def test_json_snapshot_shape(self):
         snap = obs.json_snapshot()
         assert snap["schema_version"] == obs.SCHEMA_VERSION

@@ -514,22 +514,27 @@ def ll_moe_ffn(
     from uccl_tpu.ep.ops import _gate_topk
 
     e = router_logits.shape[-1]
-    topk_vals, topk_idx, aux_loss, z_loss = _gate_topk(
-        router_logits, num_selected, renormalize
-    )
-    r = ll_dispatch(
-        x, topk_idx, topk_vals, e, axis,
-        num_max_dispatch_tokens_per_rank=num_max_dispatch_tokens_per_rank,
-        pair_capacity_factor=pair_capacity_factor,
-        wire=wire, wire_fp8=wire_fp8, n_chunks=n_chunks,
-        wire_dtype=wire_dtype,
-    )
-    y = grouped_ffn(
-        r.recv_x, r.group_sizes,
-        w_gate.astype(r.recv_x.dtype),
-        w_up.astype(r.recv_x.dtype),
-        w_down.astype(r.recv_x.dtype),
-    )
-    out = ll_combine(y, r.state, axis, wire_fp8=wire_fp8,
-                     wire_dtype=wire_dtype)
-    return out.astype(x.dtype), aux_loss, z_loss
+    with jax.named_scope("moe.route"):
+        topk_vals, topk_idx, aux_loss, z_loss = _gate_topk(
+            router_logits, num_selected, renormalize
+        )
+    with jax.named_scope("moe.dispatch"):
+        r = ll_dispatch(
+            x, topk_idx, topk_vals, e, axis,
+            num_max_dispatch_tokens_per_rank=num_max_dispatch_tokens_per_rank,
+            pair_capacity_factor=pair_capacity_factor,
+            wire=wire, wire_fp8=wire_fp8, n_chunks=n_chunks,
+            wire_dtype=wire_dtype,
+        )
+    with jax.named_scope("moe.experts"):
+        y = grouped_ffn(
+            r.recv_x, r.group_sizes,
+            w_gate.astype(r.recv_x.dtype),
+            w_up.astype(r.recv_x.dtype),
+            w_down.astype(r.recv_x.dtype),
+        )
+    with jax.named_scope("moe.combine"):
+        out = ll_combine(y, r.state, axis, wire_fp8=wire_fp8,
+                         wire_dtype=wire_dtype)
+        out = out.astype(x.dtype)
+    return out, aux_loss, z_loss

@@ -599,11 +599,14 @@ def _expert_gemms(xe, w_gate, w_up, w_down):
     gain was <1%, and the unrolled dots lose their `e` batch dim, which
     silently drags every expert GEMM into the remat="dots" saved set and
     OOMs the documented-working B=32 dots config.)"""
-    xe = checkpoint_name(xe, _XE)
-    h_gate = checkpoint_name(jnp.einsum("ebh,ehf->ebf", xe, w_gate), _HG)
-    h_up = checkpoint_name(jnp.einsum("ebh,ehf->ebf", xe, w_up), _HU)
-    act = jax.nn.silu(h_gate) * h_up
-    return checkpoint_name(jnp.einsum("ebf,efh->ebh", act, w_down), _YE)
+    with jax.named_scope("moe.experts"):
+        xe = checkpoint_name(xe, _XE)
+        h_gate = checkpoint_name(
+            jnp.einsum("ebh,ehf->ebf", xe, w_gate), _HG)
+        h_up = checkpoint_name(jnp.einsum("ebh,ehf->ebf", xe, w_up), _HU)
+        act = jax.nn.silu(h_gate) * h_up
+        return checkpoint_name(
+            jnp.einsum("ebf,efh->ebh", act, w_down), _YE)
 
 
 def _moe_ffn_sort_chunked(
@@ -634,41 +637,46 @@ def _moe_ffn_sort_chunked(
     cs = tfs_chunks.shape[-1]
     recv_chunks, y_chunks = [], []
     for c in range(n_chunks):
-        buf = jnp.take(x, tfs_chunks[c].reshape(-1), axis=0, mode="fill",
-                       fill_value=0)
-        buf = buf.reshape(w, e_local, cs, h)
-        # launch-granularity credit (dma.tie_chunk): chunk c's wire waits
-        # on chunk c-2's — its collective-id parity twin — so at most two
-        # kernels per family are airborne, matching the 2-id rotation and
-        # the 2-resident-pair budget charge
-        buf = _dma.tie_chunk(
-            buf, recv_chunks[c - 2] if c >= 2 else None
-        )
-        buf = _wire_all_to_all(
-            buf, axis, wire_fp8, quant_group, x.dtype, "pallas",
-            collective_id=_dma.chunk_collective_id(_dma.CID_EP_DISPATCH, c),
-            wire_dtype=wire_dtype,
-        )
-        xe = buf.transpose(1, 0, 2, 3).reshape(e_local, w * cs, h)
+        with jax.named_scope("moe.dispatch"):
+            buf = jnp.take(x, tfs_chunks[c].reshape(-1), axis=0,
+                           mode="fill", fill_value=0)
+            buf = buf.reshape(w, e_local, cs, h)
+            # launch-granularity credit (dma.tie_chunk): chunk c's wire
+            # waits on chunk c-2's — its collective-id parity twin — so at
+            # most two kernels per family are airborne, matching the 2-id
+            # rotation and the 2-resident-pair budget charge
+            buf = _dma.tie_chunk(
+                buf, recv_chunks[c - 2] if c >= 2 else None
+            )
+            buf = _wire_all_to_all(
+                buf, axis, wire_fp8, quant_group, x.dtype, "pallas",
+                collective_id=_dma.chunk_collective_id(
+                    _dma.CID_EP_DISPATCH, c),
+                wire_dtype=wire_dtype,
+            )
+            xe = buf.transpose(1, 0, 2, 3).reshape(e_local, w * cs, h)
         recv_chunks.append(xe)
         ye = _expert_gemms(xe, w_gate, w_up, w_down)
-        back = ye.reshape(e_local, w, cs, h).transpose(1, 0, 2, 3)
-        back = _dma.tie_chunk(
-            back, y_chunks[c - 2] if c >= 2 else None
-        )
-        back = _wire_all_to_all(
-            back, axis, wire_fp8, quant_group, ye.dtype, "pallas",
-            collective_id=_dma.chunk_collective_id(_dma.CID_EP_COMBINE, c),
-            wire_dtype=wire_dtype,
-        )
+        with jax.named_scope("moe.combine"):
+            back = ye.reshape(e_local, w, cs, h).transpose(1, 0, 2, 3)
+            back = _dma.tie_chunk(
+                back, y_chunks[c - 2] if c >= 2 else None
+            )
+            back = _wire_all_to_all(
+                back, axis, wire_fp8, quant_group, ye.dtype, "pallas",
+                collective_id=_dma.chunk_collective_id(
+                    _dma.CID_EP_COMBINE, c),
+                wire_dtype=wire_dtype,
+            )
         y_chunks.append(back.reshape(num_experts, cs, h))
     # reassemble the expert-major [E, C, H] buffer (chunks are contiguous
     # slices of each expert's padded capacity), drop the wire-only padding,
     # then ONE token gather + weighted sum — same math as combine_sorted
-    y = jnp.concatenate(y_chunks, axis=1)[:, :capacity]
-    y = y.reshape(num_experts * capacity, h)
-    yk = jnp.take(y, plan.slot, axis=0, mode="fill", fill_value=0)
-    return jnp.einsum("tk,tkh->th", weights.astype(yk.dtype), yk)
+    with jax.named_scope("moe.combine"):
+        y = jnp.concatenate(y_chunks, axis=1)[:, :capacity]
+        y = y.reshape(num_experts * capacity, h)
+        yk = jnp.take(y, plan.slot, axis=0, mode="fill", fill_value=0)
+        return jnp.einsum("tk,tkh->th", weights.astype(yk.dtype), yk)
 
 
 def moe_ffn(
@@ -728,7 +736,8 @@ def moe_ffn(
             n_chunks=n_chunks,
         )
     if impl == "sort":
-        rs = route_topk_sorted(router_logits, num_selected, capacity)
+        with jax.named_scope("moe.route"):
+            rs = route_topk_sorted(router_logits, num_selected, capacity)
         n_chunks = resolve_chunks(
             n_chunks, wire, w, capacity, e // w, h,
             wire_itemsize(wire_fp8, h, x.dtype, wire_dtype=wire_dtype),
@@ -741,15 +750,18 @@ def moe_ffn(
                 capacity, n_chunks, False, 128, wire_dtype=wire_dtype,
             )
             return out.astype(x.dtype), rs.aux_loss, rs.z_loss
-        xe = dispatch_sorted(
-            x, rs.token_for_slot, e, capacity, axis, wire=wire,
-            wire_dtype=wire_dtype,
-        )
+        with jax.named_scope("moe.dispatch"):
+            xe = dispatch_sorted(
+                x, rs.token_for_slot, e, capacity, axis, wire=wire,
+                wire_dtype=wire_dtype,
+            )
         aux_loss, z_loss = rs.aux_loss, rs.z_loss
     elif impl == "dense":
-        r = route_topk(router_logits, num_selected, capacity)
-        xe = dispatch(x, r.dispatch_mask, axis, wire=wire,
-                      wire_dtype=wire_dtype)
+        with jax.named_scope("moe.route"):
+            r = route_topk(router_logits, num_selected, capacity)
+        with jax.named_scope("moe.dispatch"):
+            xe = dispatch(x, r.dispatch_mask, axis, wire=wire,
+                          wire_dtype=wire_dtype)
         aux_loss, z_loss = r.aux_loss, r.z_loss
     else:
         raise ValueError(
@@ -758,10 +770,12 @@ def moe_ffn(
     # tagged SwiGLU GEMMs shared with the chunked layer (the tags and the
     # batched einsum form are load-bearing for remat — see _expert_gemms)
     ye = _expert_gemms(xe, w_gate, w_up, w_down)
-    if impl == "sort":
-        out = combine_sorted(ye, rs.slot, rs.weights, axis, wire=wire,
-                             wire_dtype=wire_dtype)
-    else:
-        out = combine(ye, r.combine_weights, axis, wire=wire,
-                      wire_dtype=wire_dtype)
-    return out.astype(x.dtype), aux_loss, z_loss
+    with jax.named_scope("moe.combine"):
+        if impl == "sort":
+            out = combine_sorted(ye, rs.slot, rs.weights, axis, wire=wire,
+                                 wire_dtype=wire_dtype)
+        else:
+            out = combine(ye, r.combine_weights, axis, wire=wire,
+                          wire_dtype=wire_dtype)
+        out = out.astype(x.dtype)
+    return out, aux_loss, z_loss

@@ -166,13 +166,22 @@ def _attention(x, lp, cfg: FlagshipConfig):
     d = cfg.head_dim
     nh_loc = lp["wq"].shape[-1] // d
     nkv_loc = lp["wk"].shape[-1] // d
-    q = (x @ lp["wq"].astype(x.dtype)).reshape(b, s_loc, nh_loc, d)
-    kk = (x @ lp["wk"].astype(x.dtype)).reshape(b, s_loc, nkv_loc, d)
-    v = (x @ lp["wv"].astype(x.dtype)).reshape(b, s_loc, nkv_loc, d)
-    cp_idx = lax.axis_index(AXIS.CP)
-    positions = cp_idx * s_loc + jnp.arange(s_loc)
-    q = rope(q, positions, cfg.rope_theta)
-    kk = rope(kk, positions, cfg.rope_theta)
+    with jax.named_scope("attn.qkv"):
+        q = (x @ lp["wq"].astype(x.dtype)).reshape(b, s_loc, nh_loc, d)
+        kk = (x @ lp["wk"].astype(x.dtype)).reshape(b, s_loc, nkv_loc, d)
+        v = (x @ lp["wv"].astype(x.dtype)).reshape(b, s_loc, nkv_loc, d)
+        cp_idx = lax.axis_index(AXIS.CP)
+        positions = cp_idx * s_loc + jnp.arange(s_loc)
+        q = rope(q, positions, cfg.rope_theta)
+        kk = rope(kk, positions, cfg.rope_theta)
+    with jax.named_scope("attn.core"):
+        attn = _attention_core(q, kk, v, cfg)
+    with jax.named_scope("attn.out"):
+        return attn.reshape(b, s_loc, nh_loc * d) @ lp["wo"].astype(x.dtype)
+
+
+def _attention_core(q, kk, v, cfg: FlagshipConfig):
+    """Causal attention over the (possibly context-parallel) sequence."""
     impl = resolve_attn_impl(cfg)
     if lax.axis_size(AXIS.CP) == 1:
         if impl == "flash":
@@ -181,7 +190,7 @@ def _attention(x, lp, cfg: FlagshipConfig):
             # tile is an error there, never a quiet switch to the reference.
             from uccl_tpu.ops.pallas_attention import flash_attention
 
-            attn = flash_attention(q, kk, v, True)
+            return flash_attention(q, kk, v, True)
         else:
             # Direct single-shard attention, NOT ring_attention at n=1: the
             # math is identical, but the ring's self-ppermute would poison
@@ -189,24 +198,23 @@ def _attention(x, lp, cfg: FlagshipConfig):
             # cotangents under check_vma=False when the vjp runs inside a
             # non-uniformly-predicated cond — the sharp edge check_vma=True
             # exists to catch).
-            attn = attention_reference(q, kk, v, causal=True)
-    elif cfg.seq_mode == "ulysses":
-        attn = ulysses_attention(q, kk, v, AXIS.CP, causal=True, impl=impl)
-    else:
-        attn = ring_attention(q, kk, v, AXIS.CP, causal=True, impl=impl)
-    out = attn.reshape(b, s_loc, nh_loc * d) @ lp["wo"].astype(x.dtype)
-    return out
+            return attention_reference(q, kk, v, causal=True)
+    if cfg.seq_mode == "ulysses":
+        return ulysses_attention(q, kk, v, AXIS.CP, causal=True, impl=impl)
+    return ring_attention(q, kk, v, AXIS.CP, causal=True, impl=impl)
 
 
 def _layer(x, lp, cfg: FlagshipConfig):
     """One transformer block (per-shard). x: [B, S_loc, H]. Returns (x, aux)."""
     b, s_loc, h = x.shape
     attn_out = _attention(rms_norm(x, lp["ln1"], cfg.norm_eps), lp, cfg)
-    x = x + lax.psum(attn_out, AXIS.TP)
+    with jax.named_scope("attn.out"):
+        x = x + lax.psum(attn_out, AXIS.TP)
 
     h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
     flat = h2.reshape(b * s_loc, h)
-    router_logits = flat.astype(jnp.float32) @ lp["router"]
+    with jax.named_scope("moe.router"):
+        router_logits = flat.astype(jnp.float32) @ lp["router"]
     moe_out, aux, z = ep_ops.moe_ffn(
         flat,
         router_logits,
@@ -263,6 +271,7 @@ def _remat_wrap(f, mode: str):
     )
 
 
+@jax.named_scope("embed")
 def _embed(tokens, embed_local, cfg: FlagshipConfig):
     """Vocab-parallel embedding lookup. tokens: [B, S_loc] -> [B, S_loc, H]."""
     v_loc = embed_local.shape[0]
@@ -296,8 +305,9 @@ def _per_shard_logits_aux(params, tokens, cfg: FlagshipConfig):
 
     out, aux = gpipe_spmd(stage_fn, xmb, AXIS.PP)
     x = out.reshape(b_loc, s_loc, cfg.dim)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = x.astype(jnp.float32) @ params["head"]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = x.astype(jnp.float32) @ params["head"]
     return logits, aux
 
 
@@ -387,8 +397,9 @@ def _per_shard_manual_grads(params, tokens, targets, cfg: FlagshipConfig):
     n_tok = b_loc * s_loc  # per-shard tokens: summed mb losses == local mean
 
     def loss_head(lp, y, tgt):
-        xln = rms_norm(y, lp["final_norm"], cfg.norm_eps)
-        logits = xln.astype(jnp.float32) @ lp["head"]
+        with jax.named_scope("head"):
+            xln = rms_norm(y, lp["final_norm"], cfg.norm_eps)
+            logits = xln.astype(jnp.float32) @ lp["head"]
         v_loc = logits.shape[-1]
         off = lax.axis_index(AXIS.TP) * v_loc
         per_token = tp_cross_entropy(

@@ -51,21 +51,23 @@ def _attend_cached(q, k_cache, v_cache, length, cfg: DenseConfig):
     b, sq, h, d = q.shape
     smax = k_cache.shape[1]
     n_rep = h // cfg.n_kv_heads
-    kk = jnp.repeat(k_cache, n_rep, axis=2)
-    vv = jnp.repeat(v_cache, n_rep, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk, preferred_element_type=jnp.float32)
-    s = s / jnp.sqrt(jnp.float32(d))
-    kpos = jnp.arange(smax)
-    if jnp.ndim(length) == 0:
-        qpos = length + jnp.arange(sq)[:, None]  # [Sq, 1]
-        mask = kpos[None, :] <= qpos  # attend at or before own position
-        s = jnp.where(mask[None, None], s, -1e30)
-    else:
-        qpos = length[:, None] + jnp.arange(sq)[None, :]  # [B, Sq]
-        mask = kpos[None, None, :] <= qpos[:, :, None]  # [B, Sq, Smax]
-        s = jnp.where(mask[:, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv)
+    with jax.named_scope("attn.core"):
+        kk = jnp.repeat(k_cache, n_rep, axis=2)
+        vv = jnp.repeat(v_cache, n_rep, axis=2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
+                       preferred_element_type=jnp.float32)
+        s = s / jnp.sqrt(jnp.float32(d))
+        kpos = jnp.arange(smax)
+        if jnp.ndim(length) == 0:
+            qpos = length + jnp.arange(sq)[:, None]  # [Sq, 1]
+            mask = kpos[None, :] <= qpos  # attend at or before own position
+            s = jnp.where(mask[None, None], s, -1e30)
+        else:
+            qpos = length[:, None] + jnp.arange(sq)[None, :]  # [B, Sq]
+            mask = kpos[None, None, :] <= qpos[:, :, None]  # [B, Sq, Smax]
+            s = jnp.where(mask[:, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv)
 
 
 def _forward_cached(
@@ -77,28 +79,34 @@ def _forward_cached(
     — the hook the MoE serving loop uses so the attention/KV-cache math
     exists exactly once (uccl_tpu/models/moe_inference.py)."""
     b, s = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cache.k.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cache.k.dtype)
     positions = cache.length + jnp.arange(s)
     new_k, new_v = [], []
     for i in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[i], params["blocks"])
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         d = cfg.head_dim
-        q = (h @ lp["wq"].astype(h.dtype)).reshape(b, s, cfg.n_heads, d)
-        kk = (h @ lp["wk"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads, d)
-        v = (h @ lp["wv"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads, d)
-        q = rope(q, positions, cfg.rope_theta)
-        kk = rope(kk, positions, cfg.rope_theta)
-        k_cache = lax.dynamic_update_slice(
-            cache.k[i], kk, (0, cache.length, 0, 0)
-        )
-        v_cache = lax.dynamic_update_slice(
-            cache.v[i], v, (0, cache.length, 0, 0)
-        )
+        with jax.named_scope("attn.qkv"):
+            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q = (h @ lp["wq"].astype(h.dtype)).reshape(b, s, cfg.n_heads, d)
+            kk = (h @ lp["wk"].astype(h.dtype)).reshape(
+                b, s, cfg.n_kv_heads, d)
+            v = (h @ lp["wv"].astype(h.dtype)).reshape(
+                b, s, cfg.n_kv_heads, d)
+            q = rope(q, positions, cfg.rope_theta)
+            kk = rope(kk, positions, cfg.rope_theta)
+        with jax.named_scope("attn.kv_write"):
+            k_cache = lax.dynamic_update_slice(
+                cache.k[i], kk, (0, cache.length, 0, 0)
+            )
+            v_cache = lax.dynamic_update_slice(
+                cache.v[i], v, (0, cache.length, 0, 0)
+            )
         new_k.append(k_cache)
         new_v.append(v_cache)
         attn = _attend_cached(q, k_cache, v_cache, cache.length, cfg)
-        x = x + attn.reshape(b, s, -1) @ lp["wo"].astype(attn.dtype)
+        with jax.named_scope("attn.out"):
+            x = x + attn.reshape(b, s, -1) @ lp["wo"].astype(attn.dtype)
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         if ffn is None:
             act = jax.nn.silu(h2 @ lp["w_gate"].astype(h2.dtype)) * (
@@ -107,8 +115,9 @@ def _forward_cached(
             x = x + act @ lp["w_down"].astype(act.dtype)
         else:
             x = x + ffn(h2, lp)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = x.astype(jnp.float32) @ params["head"]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = x.astype(jnp.float32) @ params["head"]
     cache = KVCache(
         jnp.stack(new_k), jnp.stack(new_v), cache.length + s
     )
@@ -298,7 +307,8 @@ def _forward_slots(
     """
     b, s = tokens.shape
     smax = cache.k.shape[2]
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cache.k.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cache.k.dtype)
     positions = start[:, None] + jnp.arange(s)[None, :]  # [B, S]
     # masked slots write at index smax → dropped by the scatter; rows beyond
     # the cache end (a bucket overhanging S_max) drop the same way
@@ -307,24 +317,28 @@ def _forward_slots(
     new_k, new_v = [], []
     for i in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[i], params["blocks"])
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         d = cfg.head_dim
-        q2 = h @ lp["wq"].astype(h.dtype)
-        v2 = h @ lp["wv"].astype(h.dtype)
-        if adapters is not None:
-            q2 = q2 + _lora_delta(h, adapters["wq"], adapter_ids, i)
-            v2 = v2 + _lora_delta(h, adapters["wv"], adapter_ids, i)
-        q = q2.reshape(b, s, cfg.n_heads, d)
-        kk = (h @ lp["wk"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads, d)
-        v = v2.reshape(b, s, cfg.n_kv_heads, d)
-        q = rope(q, positions, cfg.rope_theta)
-        kk = rope(kk, positions, cfg.rope_theta)
-        k_cache = cache.k[i].at[bidx, pos_write].set(kk, mode="drop")
-        v_cache = cache.v[i].at[bidx, pos_write].set(v, mode="drop")
+        with jax.named_scope("attn.qkv"):
+            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q2 = h @ lp["wq"].astype(h.dtype)
+            v2 = h @ lp["wv"].astype(h.dtype)
+            if adapters is not None:
+                q2 = q2 + _lora_delta(h, adapters["wq"], adapter_ids, i)
+                v2 = v2 + _lora_delta(h, adapters["wv"], adapter_ids, i)
+            q = q2.reshape(b, s, cfg.n_heads, d)
+            kk = (h @ lp["wk"].astype(h.dtype)).reshape(
+                b, s, cfg.n_kv_heads, d)
+            v = v2.reshape(b, s, cfg.n_kv_heads, d)
+            q = rope(q, positions, cfg.rope_theta)
+            kk = rope(kk, positions, cfg.rope_theta)
+        with jax.named_scope("attn.kv_write"):
+            k_cache = cache.k[i].at[bidx, pos_write].set(kk, mode="drop")
+            v_cache = cache.v[i].at[bidx, pos_write].set(v, mode="drop")
         new_k.append(k_cache)
         new_v.append(v_cache)
         attn = _attend_cached(q, k_cache, v_cache, start, cfg)
-        x = x + attn.reshape(b, s, -1) @ lp["wo"].astype(attn.dtype)
+        with jax.named_scope("attn.out"):
+            x = x + attn.reshape(b, s, -1) @ lp["wo"].astype(attn.dtype)
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         if ffn is None:
             act = jax.nn.silu(h2 @ lp["w_gate"].astype(h2.dtype)) * (
@@ -333,8 +347,9 @@ def _forward_slots(
             x = x + act @ lp["w_down"].astype(act.dtype)
         else:
             x = x + ffn(h2, lp)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = x.astype(jnp.float32) @ params["head"]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = x.astype(jnp.float32) @ params["head"]
     return logits, SlotKVCache(
         jnp.stack(new_k), jnp.stack(new_v), cache.lengths
     )
@@ -524,7 +539,7 @@ def generate(
 
     def build():
         if sampling is None:
-            def run(p, t):
+            def uccl_dense_generate(p, t):
                 logits, cache = prefill(p, t, cfg, max_seq)
 
                 def body(carry, _):
@@ -538,9 +553,9 @@ def generate(
                 )
                 return toks.T  # [B, T]
 
-            return jax.jit(run)
+            return jax.jit(uccl_dense_generate)
 
-        def run(p, t, seed, temp, top_p, top_k):
+        def uccl_dense_generate_sampled(p, t, seed, temp, top_p, top_k):
             b = t.shape[0]
             seeds, temps, tps, tks = broadcast_params(
                 b, seed, temp, top_p, top_k
@@ -562,7 +577,7 @@ def generate(
             )
             return toks.T  # [B, T]
 
-        return jax.jit(run)
+        return jax.jit(uccl_dense_generate_sampled)
 
     fn = _GEN_CACHE.get(key, build)
     if sampling is None:

@@ -196,7 +196,8 @@ def _moe_block(cfg: MoEServeConfig, impl: str):
     def moe_block(h2, lp):
         b, sq, hd = h2.shape
         flat = h2.reshape(b * sq, hd)
-        router_logits = flat.astype(jnp.float32) @ lp["router"]
+        with jax.named_scope("moe.router"):
+            router_logits = flat.astype(jnp.float32) @ lp["router"]
         out, _, _ = ep_ops.moe_ffn(
             flat, router_logits,
             lp["we_gate"], lp["we_up"], lp["we_down"],
@@ -345,7 +346,10 @@ class MoEServer:
         }
 
     def _shard_mapped(self, f, n_in, n_out):
-        """jit(shard_map(f)) with params first, then n_in P(dp) arrays."""
+        """jit(shard_map(f)) with params first, then n_in P(dp) arrays.
+        The compiled program is named after ``f`` (``jit_<f.__name__>`` on
+        the profiler's ``XLA Modules`` line, and part of the persistent
+        compile cache's key), so each closure carries a name of its own."""
         return jax.jit(
             shard_map(
                 f, mesh=self.mesh,
@@ -358,14 +362,15 @@ class MoEServer:
     def _forward(self, params, tokens, cache: MoEKVCache, impl: str):
         cfg = self.cfg
 
-        def f(p, tok, kc, vc, ln):
+        def uccl_moe_forward(p, tok, kc, vc, ln):
             logits, nk, nv, nlen = _forward_shard(
                 _strip_shard(p), tok[0], kc[0], vc[0], ln[0], cfg, impl
             )
             return logits[None], nk[None], nv[None], nlen[None]
 
         key = ("fwd", impl, tokens.shape, cache.k.shape)
-        fn = self._fn(key, lambda: self._shard_mapped(f, 4, 4))
+        fn = self._fn(
+            key, lambda: self._shard_mapped(uccl_moe_forward, 4, 4))
         logits, nk, nv, nlen = fn(params, tokens, cache.k, cache.v,
                                   cache.length)
         return logits, MoEKVCache(nk, nv, nlen)
@@ -479,7 +484,8 @@ class MoEServer:
         sampled, adapted = sampling is not None, adapters is not None
         extra = self._extra_args(sampling, adapters, adapter_ids)
 
-        def f(p, tok, lens, mask, off, kc, vc, ln, *rest):
+        def uccl_moe_prefill_slots(p, tok, lens, mask, off, kc, vc, ln,
+                                   *rest):
             samp, adp, ids = self._split_extra(rest, sampled, adapted)
             logits, nk, nv = _forward_shard_slots(
                 _strip_shard(p), tok[0], kc[0], vc[0], ln[0],
@@ -502,7 +508,8 @@ class MoEServer:
 
         key = ("prefill_slots", tokens.shape, cache.k.shape,
                sampled, adapted)
-        fn = self._fn(key, lambda: self._shard_mapped(f, 7 + len(extra), 4))
+        fn = self._fn(key, lambda: self._shard_mapped(
+            uccl_moe_prefill_slots, 7 + len(extra), 4))
         tok, nk, nv, nlen = fn(params, tokens, prompt_lens, new_mask,
                                start, cache.k, cache.v, cache.lengths,
                                *extra)
@@ -536,7 +543,7 @@ class MoEServer:
         sampled, adapted = sampling is not None, adapters is not None
         extra = self._extra_args(sampling, adapters, adapter_ids)
 
-        def f(p, tok, mask, kc, vc, ln, *rest):
+        def uccl_moe_verify_slots(p, tok, mask, kc, vc, ln, *rest):
             samp, adp, ids = self._split_extra(rest, sampled, adapted)
             logits, nk, nv = _forward_shard_slots(
                 _strip_shard(p), tok[0], kc[0], vc[0], ln[0],
@@ -554,7 +561,8 @@ class MoEServer:
 
         key = ("verify_slots", impl, tokens.shape, cache.k.shape,
                sampled, adapted)
-        fn = self._fn(key, lambda: self._shard_mapped(f, 5 + len(extra), 5))
+        fn = self._fn(key, lambda: self._shard_mapped(
+            uccl_moe_verify_slots, 5 + len(extra), 5))
         tok, n_acc, nk, nv, nlen = fn(params, tokens, active,
                                       cache.k, cache.v, cache.lengths,
                                       *extra)
@@ -603,7 +611,7 @@ class MoEServer:
             key = ("gen", impl, new_tokens, tok0.shape, cache.k.shape)
 
             def build():
-                def gen(p, tok, kc, vc, ln):
+                def uccl_moe_generate(p, tok, kc, vc, ln):
                     def body(carry, _):
                         tok, kc, vc, ln = carry
                         lg, c2 = self._forward(
@@ -618,7 +626,7 @@ class MoEServer:
                     )
                     return jnp.moveaxis(toks, 0, -1)  # [W, B_loc, N]
 
-                return jax.jit(gen)
+                return jax.jit(uccl_moe_generate)
 
             fn = self._fn(key, build)
             return fn(params, tok0, cache.k, cache.v, cache.length)
@@ -626,7 +634,8 @@ class MoEServer:
         key = ("gen_sampled", impl, new_tokens, logits.shape, cache.k.shape)
 
         def build():
-            def gen(p, lg0, kc, vc, ln, seed, temp, top_p, top_k):
+            def uccl_moe_generate_sampled(p, lg0, kc, vc, ln, seed, temp,
+                                          top_p, top_k):
                 w, b, v = lg0.shape
                 seeds, temps, tps, tks = broadcast_params(
                     w * b, seed, temp, top_p, top_k
@@ -656,7 +665,7 @@ class MoEServer:
                 )
                 return jnp.moveaxis(toks, 0, -1)  # [W, B_loc, N]
 
-            return jax.jit(gen)
+            return jax.jit(uccl_moe_generate_sampled)
 
         fn = self._fn(key, build)
         return fn(params, logits, cache.k, cache.v, cache.length,

@@ -28,9 +28,13 @@ Instrumentation idiom::
         ...
     obs.instant("first_token", track=req.track)
 
-Everything is a no-op (one bool check) until ``obs.enable_tracing()`` /
+Nothing is recorded (one bool check) until ``obs.enable_tracing()`` /
 ``--trace-out`` turns the tracer on; counters are always live (they are
-just dict adds).
+just dict adds). Independently of that switch, every ``obs.span`` is also
+written into any open ``jax.profiler`` session as ``uccl.<name>`` — on the
+profiler's clock, beside the device's operations (obs/tracer.py; about a
+microsecond a span when no session is open, nothing in a process without
+JAX).
 """
 
 from uccl_tpu.obs.counters import (  # noqa: F401

@@ -2,22 +2,34 @@
 
 The reference ships NPKit GPU event tracing (SURVEY.md §5): fixed-size
 per-channel event buffers filled by the kernels and dumped to a
-Chrome-trace post-hoc. The TPU reproduction's device timeline already
-belongs to ``jax.profiler`` (utils/tracing.py); what was missing is the
-HOST event spine — request lifecycles, engine steps, wire windows — with
-the same properties the NPKit design proves out:
+Chrome-trace post-hoc. The TPU reproduction's device timeline belongs to
+``jax.profiler``; this is the HOST event spine — request lifecycles, engine
+steps, wire windows — with the properties the NPKit design proves out:
 
 * **bounded memory**: events land in a ring buffer (``deque(maxlen=...)``);
   a long-lived server can trace forever, old events fall off the back and
   are counted in ``dropped``.
 * **thread-safe**: any runtime thread may record; one lock per record,
   nothing else shared.
-* **zero-cost when disabled**: the module-level helpers check one bool and
-  return a cached no-op context manager — no allocation, no lock, no
-  timestamp read.
+* **near-zero cost when disabled**: the module-level helpers check one
+  bool; no allocation in the ring, no lock, no timestamp read. A
+  :func:`span` additionally enters a ``jax.profiler.TraceAnnotation``
+  (about a microsecond when no profiler session is open) once JAX is in
+  the process.
 * **monotonic timestamps**: ``time.perf_counter`` relative to the tracer's
   epoch, in microseconds (the Chrome-trace unit), so spans from different
   threads land on one consistent timeline.
+
+The profiler bridge: :func:`span` ALSO writes the block into any open
+``jax.profiler`` session as a ``TraceAnnotation`` named ``"uccl." + name``
+with the span's entry arguments — the host plane of the profiler's trace,
+on the same clock as the device's operations — whether or not the ring is
+enabled. That is how a profiled run attributes device idle time to what the
+host was doing (chipbench's ``program_trace.py`` reads them back). This
+module never imports JAX: the annotation class is bound the first time a
+span is opened in a process that already has ``jax`` in ``sys.modules``
+(no JAX, no profiler session to write into). After-the-fact records
+(:meth:`Tracer.complete`, instants, flows) stay ring-only.
 
 Tracks: every event carries a ``track`` label — the Chrome-trace exporter
 maps each distinct label to a tid row. ``track=None`` means "this thread's
@@ -43,6 +55,7 @@ per-process traces on one causally ordered timeline.
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 from collections import deque
@@ -158,7 +171,9 @@ class Tracer:
                  track: Optional[str] = None, **args) -> None:
         """Record a finished span ("X") from explicit timestamps — the form
         instrumentation uses when ONE measured window yields spans on
-        several tracks (e.g. a batched prefill covering many requests)."""
+        several tracks (e.g. a batched prefill covering many requests).
+        Ring-only: a window that is already over cannot be written into a
+        profiler session (only :func:`span` is bridged)."""
         self._record(Event(name, "X", ts_us, max(0.0, dur_us),
                            self._track(track), args or None))
 
@@ -206,17 +221,65 @@ _tracer: Optional[Tracer] = None  # None = disabled: the zero-cost check
 
 
 class _NullSpan:
-    """Reusable no-op context manager — the disabled-tracer fast path
-    allocates nothing."""
+    """Reusable no-op context manager — the fast path of a process with
+    neither the ring enabled nor JAX loaded allocates nothing."""
 
     def __enter__(self):
-        return None
+        return self
 
     def __exit__(self, *exc):
         return False
 
+    def add(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
+
+_annotation = None  # jax.profiler.TraceAnnotation, once JAX is in the process
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation`` if this process has imported JAX
+    (never imported from here: obs stays JAX-free), else None."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class _Span:
+    """One :func:`span` block: a profiler annotation for its length and,
+    when the ring is enabled, one "X" event. :meth:`add` attaches what is
+    only known at exit to the ring's record (the annotation's arguments are
+    fixed on entry)."""
+
+    __slots__ = ("_tracer", "_ann", "_name", "_track", "_args", "_t0")
+
+    def __init__(self, tracer, ann, name, track, args):
+        self._tracer, self._ann = tracer, ann
+        self._name, self._track, self._args = name, track, args
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tracer is not None:
+            self._t0 = self._tracer.now_us()
+        return self
+
+    def __exit__(self, *exc):
+        t = self._tracer
+        if t is not None:
+            t.complete(self._name, self._t0, t.now_us() - self._t0,
+                       self._track, **self._args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+    def add(self, **args) -> None:
+        self._args.update(args)
 
 
 def enable(capacity: int = 65536) -> Tracer:
@@ -240,11 +303,17 @@ def get_tracer() -> Optional[Tracer]:
 
 
 def span(name: str, track: Optional[str] = None, **args):
-    """Span on the global tracer; a cached no-op when tracing is off."""
+    """Span over the with-block: into the ring when tracing is on, and into
+    any open ``jax.profiler`` session as ``"uccl." + name`` either way (see
+    the module docstring). ``with span(...) as sp: ...; sp.add(k=v)`` adds
+    exit-time arguments to the ring's record."""
     t = _tracer
-    if t is None:
+    ann = _annotation_cls()
+    if ann is not None:
+        ann = ann("uccl." + name, **args)
+    elif t is None:
         return _NULL_SPAN
-    return t.span(name, track, **args)
+    return _Span(t, ann, name, track, args)
 
 
 def instant(name: str, track: Optional[str] = None, **args) -> None:

@@ -118,6 +118,7 @@ def _fwd_kernel(
         lse_ref[0] = m_ref[:, :] + jnp.log(l)
 
 
+@jax.named_scope("attn.flash")
 def _flash_fwd(
     q, k, v, causal, block_q, block_k, interpret
 ) -> Tuple[jax.Array, jax.Array]:
@@ -283,6 +284,7 @@ def _bwd_dkv_kernel(
         dv_ref[0] = dv_acc[:, :].astype(dv_ref.dtype)
 
 
+@jax.named_scope("attn.flash")
 def _flash_bwd(q, k, v, out, lse, g_out, g_lse, causal, block_q, block_k,
                interpret):
     """Pallas backward: returns (dq, dk, dv) without materializing [S, S]."""

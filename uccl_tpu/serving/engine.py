@@ -151,6 +151,13 @@ class ChunkEvent:
     reused: bool
 
 
+def _kv_rows(decoding) -> int:
+    """Cached rows the decoding slots attend over (prompt + generated so
+    far): what a decode step's attention must read, from the engine's own
+    state — the argument a roofline reader needs on the wire span."""
+    return sum(int(r.prompt.size) + r.n_generated for r in decoding.values())
+
+
 def _bucket(n: int, cap: int) -> int:
     """Prefill bucket length: next power of two (bounded compile count —
     at most log2(max_seq) distinct prefill programs), clipped to cap."""
@@ -189,7 +196,8 @@ class DenseBackend:
         def build():
             from uccl_tpu.models.inference import SlotKVCache, prefill_slots
 
-            def run(p, tok, lens, mask, off, kc, vc, ln, *rest):
+            def uccl_dense_prefill_slots(p, tok, lens, mask, off, kc, vc,
+                                         ln, *rest):
                 samp, adp, ids = _split_extra(rest, sampled, adapted)
                 t, cache = prefill_slots(
                     p, tok, lens, mask, SlotKVCache(kc, vc, ln), cfg,
@@ -198,7 +206,7 @@ class DenseBackend:
                 )
                 return t, cache.k, cache.v, cache.lengths
 
-            return jax.jit(run)
+            return jax.jit(uccl_dense_prefill_slots)
 
         return self._fns.get(("prefill", s, sampled, adapted), build)
 
@@ -211,7 +219,7 @@ class DenseBackend:
                 SlotKVCache, decode_step_slots,
             )
 
-            def run(p, tok, mask, kc, vc, ln, *rest):
+            def uccl_dense_decode_slots(p, tok, mask, kc, vc, ln, *rest):
                 samp, adp, ids = _split_extra(rest, sampled, adapted)
                 t, cache = decode_step_slots(
                     p, tok, mask, SlotKVCache(kc, vc, ln), cfg,
@@ -219,7 +227,7 @@ class DenseBackend:
                 )
                 return t, cache.k, cache.v, cache.lengths
 
-            return jax.jit(run)
+            return jax.jit(uccl_dense_decode_slots)
 
         return self._fns.get(("decode", sampled, adapted), build)
 
@@ -230,7 +238,7 @@ class DenseBackend:
         def build():
             from uccl_tpu.models.inference import SlotKVCache, verify_slots
 
-            def run(p, tok, mask, kc, vc, ln, *rest):
+            def uccl_dense_verify_slots(p, tok, mask, kc, vc, ln, *rest):
                 samp, adp, ids = _split_extra(rest, sampled, adapted)
                 t, n_acc, cache = verify_slots(
                     p, tok, mask, SlotKVCache(kc, vc, ln), cfg,
@@ -238,7 +246,7 @@ class DenseBackend:
                 )
                 return t, n_acc, cache.k, cache.v, cache.lengths
 
-            return jax.jit(run)
+            return jax.jit(uccl_dense_verify_slots)
 
         return self._fns.get(("verify", s, sampled, adapted), build)
 
@@ -248,26 +256,34 @@ class DenseBackend:
                 sampling=None, adapters=None) -> np.ndarray:
         from uccl_tpu.models.inference import SlotKVCache
 
-        if start is None:
-            start = np.zeros(tokens.shape[0], np.int32)
-        fn = self._prefill_fn(tokens.shape[1], sampling is not None,
-                              adapters is not None)
-        t, k, v, ln = fn(self.params, tokens, lens, mask, start,
-                         self.cache.k, self.cache.v, self.cache.lengths,
-                         *_flat_extra(sampling, adapters))
-        self.cache = SlotKVCache(k, v, ln)
-        return np.asarray(t)
+        with obs.span("backend.stage", "wire"):
+            if start is None:
+                start = np.zeros(tokens.shape[0], np.int32)
+            fn = self._prefill_fn(tokens.shape[1], sampling is not None,
+                                  adapters is not None)
+            extra = _flat_extra(sampling, adapters)
+        with obs.span("backend.launch", "wire"):
+            t, k, v, ln = fn(self.params, tokens, lens, mask, start,
+                             self.cache.k, self.cache.v, self.cache.lengths,
+                             *extra)
+            self.cache = SlotKVCache(k, v, ln)
+        with obs.span("backend.fetch", "wire"):
+            return np.asarray(t)
 
     def decode(self, tokens: np.ndarray, active: np.ndarray,
                sampling=None, adapters=None) -> np.ndarray:
         from uccl_tpu.models.inference import SlotKVCache
 
-        fn = self._decode_fn(sampling is not None, adapters is not None)
-        t, k, v, ln = fn(self.params, tokens, active,
-                         self.cache.k, self.cache.v, self.cache.lengths,
-                         *_flat_extra(sampling, adapters))
-        self.cache = SlotKVCache(k, v, ln)
-        return np.asarray(t)
+        with obs.span("backend.stage", "wire"):
+            fn = self._decode_fn(sampling is not None, adapters is not None)
+            extra = _flat_extra(sampling, adapters)
+        with obs.span("backend.launch", "wire"):
+            t, k, v, ln = fn(self.params, tokens, active,
+                             self.cache.k, self.cache.v, self.cache.lengths,
+                             *extra)
+            self.cache = SlotKVCache(k, v, ln)
+        with obs.span("backend.fetch", "wire"):
+            return np.asarray(t)
 
     def verify(self, tokens: np.ndarray, active: np.ndarray,
                sampling=None, adapters=None):
@@ -276,14 +292,17 @@ class DenseBackend:
         greedy argmaxes, or lockstep-keyed samples under ``sampling``."""
         from uccl_tpu.models.inference import SlotKVCache
 
-        fn = self._verify_fn(tokens.shape[1], sampling is not None,
-                             adapters is not None)
-        t, n_acc, k, v, ln = fn(self.params, tokens, active,
-                                self.cache.k, self.cache.v,
-                                self.cache.lengths,
-                                *_flat_extra(sampling, adapters))
-        self.cache = SlotKVCache(k, v, ln)
-        return np.asarray(t), np.asarray(n_acc)
+        with obs.span("backend.stage", "wire"):
+            fn = self._verify_fn(tokens.shape[1], sampling is not None,
+                                 adapters is not None)
+            extra = _flat_extra(sampling, adapters)
+        with obs.span("backend.launch", "wire"):
+            t, n_acc, k, v, ln = fn(self.params, tokens, active,
+                                    self.cache.k, self.cache.v,
+                                    self.cache.lengths, *extra)
+            self.cache = SlotKVCache(k, v, ln)
+        with obs.span("backend.fetch", "wire"):
+            return np.asarray(t), np.asarray(n_acc)
 
     # slot KV movement (prefix-cache hits + the disagg p2p stream) — thin
     # shims over the cache's export/import views (models/inference.py)
@@ -351,41 +370,55 @@ class MoEBackend:
                 mask: np.ndarray,
                 start: Optional[np.ndarray] = None,
                 sampling=None, adapters=None) -> np.ndarray:
-        if start is None:
-            start = np.zeros(tokens.shape[0], np.int32)
-        samp, adp, ids = self._extra(sampling, adapters)
-        t, self.cache = self.server.prefill_slots(
-            self.params, self._grid(tokens, np.int32),
-            self._grid(lens, np.int32), self._grid(mask, bool), self.cache,
-            start=self._grid(start, np.int32),
-            sampling=samp, adapters=adp, adapter_ids=ids,
-        )
-        return np.asarray(t).reshape(self.n_slots)
+        with obs.span("backend.stage", "wire"):
+            if start is None:
+                start = np.zeros(tokens.shape[0], np.int32)
+            samp, adp, ids = self._extra(sampling, adapters)
+            tokens = self._grid(tokens, np.int32)
+            lens = self._grid(lens, np.int32)
+            mask = self._grid(mask, bool)
+            start = self._grid(start, np.int32)
+        with obs.span("backend.launch", "wire"):
+            t, self.cache = self.server.prefill_slots(
+                self.params, tokens, lens, mask, self.cache, start=start,
+                sampling=samp, adapters=adp, adapter_ids=ids,
+            )
+        with obs.span("backend.fetch", "wire"):
+            return np.asarray(t).reshape(self.n_slots)
 
     def decode(self, tokens: np.ndarray, active: np.ndarray,
                sampling=None, adapters=None) -> np.ndarray:
-        samp, adp, ids = self._extra(sampling, adapters)
-        t, self.cache = self.server.decode_step_slots(
-            self.params, self._grid(tokens, np.int32),
-            self._grid(active, bool), self.cache, impl=self.decode_impl,
-            sampling=samp, adapters=adp, adapter_ids=ids,
-        )
-        return np.asarray(t).reshape(self.n_slots)
+        with obs.span("backend.stage", "wire"):
+            samp, adp, ids = self._extra(sampling, adapters)
+            tokens = self._grid(tokens, np.int32)
+            active = self._grid(active, bool)
+        with obs.span("backend.launch", "wire"):
+            t, self.cache = self.server.decode_step_slots(
+                self.params, tokens, active, self.cache,
+                impl=self.decode_impl,
+                sampling=samp, adapters=adp, adapter_ids=ids,
+            )
+        with obs.span("backend.fetch", "wire"):
+            return np.asarray(t).reshape(self.n_slots)
 
     def verify(self, tokens: np.ndarray, active: np.ndarray,
                sampling=None, adapters=None):
         """One batched [n_slots, k+1] draft-verify window (spec decode),
         through the sorted EP path — the multi-token regime, like prefill.
         Returns (target tokens [n_slots, k+1], n_accepted [n_slots])."""
-        samp, adp, ids = self._extra(sampling, adapters)
-        t, n_acc, self.cache = self.server.verify_slots(
-            self.params, self._grid(tokens, np.int32),
-            self._grid(active, bool), self.cache,
-            sampling=samp, adapters=adp, adapter_ids=ids,
-        )
         s = tokens.shape[1]
-        return (np.asarray(t).reshape(self.n_slots, s),
-                np.asarray(n_acc).reshape(self.n_slots))
+        with obs.span("backend.stage", "wire"):
+            samp, adp, ids = self._extra(sampling, adapters)
+            tokens = self._grid(tokens, np.int32)
+            active = self._grid(active, bool)
+        with obs.span("backend.launch", "wire"):
+            t, n_acc, self.cache = self.server.verify_slots(
+                self.params, tokens, active, self.cache,
+                sampling=samp, adapters=adp, adapter_ids=ids,
+            )
+        with obs.span("backend.fetch", "wire"):
+            return (np.asarray(t).reshape(self.n_slots, s),
+                    np.asarray(n_acc).reshape(self.n_slots))
 
     # slot KV movement — MoESlotCache maps flat slot ids to its [W, B_loc]
     # grid internally, so the engine-facing surface matches DenseBackend's
@@ -908,30 +941,49 @@ class ServingEngine:
                 "recover its requests via Router health handling"
             )
         t0 = now()
-        tr = obs.get_tracer()
-        ts0 = tr.now_us() if tr is not None else 0.0
         finished: List[Request] = []
-        # queue aging first: an expired request must not take this step's
-        # admission (its deadline already passed at the step boundary)
-        for req in self.sched.expire(t0):
-            self.metrics.on_expire(req)
-            _DROPPED.inc(reason="deadline")
-            obs.instant("expire", track=req.track, rid=req.rid,
-                        deadline_ms=req.deadline_ms)
-        if self.prefill_chunk is None:
-            newly, _ = self._gate_admitted(self.sched.admit(self.pool))
-            if newly:
-                self._prefill(newly, finished)
-            if self._by_slot:
-                self._decode(finished)
-        else:
-            self._step_chunked(finished)
-        dt = now() - t0
-        self.metrics.on_step(dt)
-        if tr is not None:
-            tr.complete("engine.step", ts0, tr.now_us() - ts0, "engine",
-                        active=len(self._by_slot), queued=self.sched.qsize,
-                        finished=len(finished))
+        # spans nest on this one thread (engine.step > engine.admit |
+        # wire.* > backend.* | engine.retire): the innermost one covering an
+        # instant is what the host was doing then. Arguments are the state
+        # on entry; the ring's record also gets the exit-time ones.
+        with obs.span("engine.step", "engine", queued=self.sched.qsize,
+                      active=len(self._by_slot),
+                      prefilling=len(self._prefilling),
+                      decoding=len(self._by_slot) - len(self._prefilling),
+                      ) as sp:
+            with obs.span("engine.admit", "engine",
+                          queued=self.sched.qsize):
+                # queue aging first: an expired request must not take this
+                # step's admission (its deadline already passed at the step
+                # boundary)
+                for req in self.sched.expire(t0):
+                    self.metrics.on_expire(req)
+                    _DROPPED.inc(reason="deadline")
+                    obs.instant("expire", track=req.track, rid=req.rid,
+                                deadline_ms=req.deadline_ms)
+                if self.prefill_chunk is None:
+                    newly, _ = self._gate_admitted(
+                        self.sched.admit(self.pool))
+                else:
+                    events = self._admit_chunked()
+            if self.prefill_chunk is None:
+                if newly:
+                    self._prefill(newly, finished)
+                if self._by_slot:
+                    self._decode(finished)
+            else:
+                # one batched chunk over every mid-prefill slot, then the
+                # step's single decode pass (requests whose cursor just
+                # reached the prompt end join it immediately — same step,
+                # like the whole-prompt path)
+                if self._prefilling:
+                    self._prefill_chunk_step(finished, events)
+                if len(self._by_slot) > len(self._prefilling):
+                    self._decode(finished)
+            dt = now() - t0
+            self.metrics.on_step(dt)
+            sp.add(active=len(self._by_slot), queued=self.sched.qsize,
+                   finished=len(finished))
         _OCCUPANCY.set(self.pool.occupancy)
         _HIGH_WATER.set(self.pool.high_water)
         if self.step_stall_s is not None and dt > self.step_stall_s:
@@ -943,12 +995,11 @@ class ServingEngine:
         self._check_conservation()
         return finished
 
-    def _step_chunked(self, finished) -> None:
-        """Chunked-mode iteration: budget-gated admission (evicting LRU
-        prefix-cache donors when the pool is full), one batched chunk over
-        every mid-prefill slot, then the step's single decode pass
-        (requests whose cursor just reached the prompt end join it
-        immediately — same step, like the whole-prompt path)."""
+    def _admit_chunked(self) -> List[ChunkEvent]:
+        """Chunked-mode admission: budget-gated (evicting LRU prefix-cache
+        donors when the pool is full), prefix-cache matches landed and
+        preemption victims resumed. Returns the admission-time chunk events
+        (cache copies, resumed rows) for this step's chunk sink."""
         c = self.prefill_chunk
         limit = None
         if self.step_tokens is not None:
@@ -1052,10 +1103,7 @@ class ServingEngine:
             self._prefilling[slot] = req
             self.metrics.on_admit(req)
             obs.instant("admit", track=req.track, slot=slot)
-        if self._prefilling:
-            self._prefill_chunk_step(finished, events)
-        if len(self._by_slot) > len(self._prefilling):
-            self._decode(finished)
+        return events
 
     def _make_room(self) -> bool:
         """Admission's last resort when no slot is free: evict the LRU
@@ -1422,25 +1470,27 @@ class ServingEngine:
         tr = obs.get_tracer()
         ts0 = tr.now_us() if tr is not None else 0.0
         t0 = now()
-        tok = self.backend.prefill(tokens, lens, mask,
-                                   **self._extra_kw(newly))
+        with obs.span("wire.prefill", "wire", n=len(newly),
+                      bucket=s_bucket):
+            tok = self.backend.prefill(tokens, lens, mask,
+                                       **self._extra_kw(newly))
         self.metrics.on_prefill(now() - t0, len(newly))
         t_done = now()
         if tr is not None:
             # one measured window, spans on every covered track: the wire
             # row shows the batched device call, each request row its share
             dur = tr.now_us() - ts0
-            tr.complete("wire.prefill", ts0, dur, "wire",
-                        n=len(newly), bucket=s_bucket)
             for slot, req in newly:
                 tr.complete("prefill", ts0, dur, req.track, slot=slot)
-        for slot, req in newly:
-            self._by_slot[slot] = req
-            # the whole prompt is in KV now — keep the cursor truthful so
-            # pending_tokens() (the router's debt signal) never counts an
-            # already-prefilled prompt as outstanding work
-            req.prefill_pos = req.prompt.size
-            self._emit_first_token(slot, req, tok[slot], t_done, finished)
+        with obs.span("engine.retire", "engine"):
+            for slot, req in newly:
+                self._by_slot[slot] = req
+                # the whole prompt is in KV now — keep the cursor truthful
+                # so pending_tokens() (the router's debt signal) never
+                # counts an already-prefilled prompt as outstanding work
+                req.prefill_pos = req.prompt.size
+                self._emit_first_token(slot, req, tok[slot], t_done,
+                                       finished)
 
     def _prefill_chunk_step(self, finished,
                             events: Optional[List[ChunkEvent]] = None,
@@ -1469,46 +1519,48 @@ class ServingEngine:
         ts0 = tr.now_us() if tr is not None else 0.0
         t0 = now()
         rows = list(self._prefilling.items())
-        tok = self.backend.prefill(tokens, lens, mask, start=start,
-                                   **self._extra_kw(rows))
+        with obs.span("wire.prefill", "wire", n=len(rows), chunk=c):
+            tok = self.backend.prefill(tokens, lens, mask, start=start,
+                                       **self._extra_kw(rows))
         self.metrics.on_prefill(now() - t0, len(self._prefilling),
                                 chunked=True)
         t_done = now()
         if tr is not None:
             dur = tr.now_us() - ts0
-            tr.complete("wire.prefill", ts0, dur, "wire",
-                        n=len(self._prefilling), chunk=c)
             for slot, req in self._prefilling.items():
                 tr.complete("prefill_chunk", ts0, dur, req.track,
                             slot=slot, offset=req.prefill_pos)
-        if events is None:
-            events = []
-        computed = 0
-        advanced = []
-        for slot, req in self._prefilling.items():
-            old = req.prefill_pos
-            req.prefill_pos = min(old + c, req.prompt.size)
-            done = req.prefill_pos >= req.prompt.size
-            computed += req.prefill_pos - old
-            events.append(ChunkEvent(
-                req, slot, old, req.prefill_pos, done,
-                int(tok[slot]) if done else None, False,
-            ))
-            advanced.append((slot, req, done))
-        _PREFILL_TOKENS.inc(computed, kind="computed")
-        if self.chunk_sink is not None:
-            # drop events whose slot changed hands since they were queued:
-            # an admission-time prefix-copy event whose request was
-            # preempted later in the SAME admission loop would otherwise
-            # export rows now owned by the request that took the slot
-            self.chunk_sink([ev for ev in events
-                             if self._by_slot.get(ev.slot) is ev.req])
-        for slot, req, done in advanced:
-            if not done:
-                continue  # more chunks to go — next step
-            del self._prefilling[slot]
-            req.state = RequestState.ACTIVE
-            self._emit_first_token(slot, req, tok[slot], t_done, finished)
+        with obs.span("engine.retire", "engine"):
+            if events is None:
+                events = []
+            computed = 0
+            advanced = []
+            for slot, req in self._prefilling.items():
+                old = req.prefill_pos
+                req.prefill_pos = min(old + c, req.prompt.size)
+                done = req.prefill_pos >= req.prompt.size
+                computed += req.prefill_pos - old
+                events.append(ChunkEvent(
+                    req, slot, old, req.prefill_pos, done,
+                    int(tok[slot]) if done else None, False,
+                ))
+                advanced.append((slot, req, done))
+            _PREFILL_TOKENS.inc(computed, kind="computed")
+            if self.chunk_sink is not None:
+                # drop events whose slot changed hands since they were
+                # queued: an admission-time prefix-copy event whose request
+                # was preempted later in the SAME admission loop would
+                # otherwise export rows now owned by the request that took
+                # the slot
+                self.chunk_sink([ev for ev in events
+                                 if self._by_slot.get(ev.slot) is ev.req])
+            for slot, req, done in advanced:
+                if not done:
+                    continue  # more chunks to go — next step
+                del self._prefilling[slot]
+                req.state = RequestState.ACTIVE
+                self._emit_first_token(slot, req, tok[slot], t_done,
+                                       finished)
 
     def _decode(self, finished) -> None:
         decoding = {s: r for s, r in self._by_slot.items()
@@ -1522,21 +1574,19 @@ class ServingEngine:
             active[slot] = True
             pos0[slot] = req.n_generated  # this step's output index
         rows = list(decoding.items())
-        tr = obs.get_tracer()
-        ts0 = tr.now_us() if tr is not None else 0.0
         t0 = now()
-        tok = self.backend.decode(self._last_tok.copy(), active,
-                                  **self._extra_kw(rows, pos0))
+        with obs.span("wire.decode", "wire", n=len(decoding),
+                      kv_rows=_kv_rows(decoding)):
+            tok = self.backend.decode(self._last_tok.copy(), active,
+                                      **self._extra_kw(rows, pos0))
         self.metrics.on_decode_step(now() - t0, len(decoding),
                                     tokens=len(decoding))
         t_done = now()
-        if tr is not None:
-            tr.complete("wire.decode", ts0, tr.now_us() - ts0, "wire",
-                        n=len(decoding))
-        for slot, req in list(decoding.items()):
-            self._last_tok[slot] = tok[slot]
-            req.out_tokens.append(int(tok[slot]))
-            self._maybe_retire(slot, req, t_done, finished)
+        with obs.span("engine.retire", "engine"):
+            for slot, req in rows:
+                self._last_tok[slot] = tok[slot]
+                req.out_tokens.append(int(tok[slot]))
+                self._maybe_retire(slot, req, t_done, finished)
 
     def _spec_decode(self, decoding, finished) -> None:
         """One speculative decode iteration: draft k tokens per decoding
@@ -1564,20 +1614,27 @@ class ServingEngine:
             active[slot] = True
             pos0[slot] = req.n_generated  # window column j → pos0 + j
         rows = list(decoding.items())
-        tr = obs.get_tracer()
-        ts0 = tr.now_us() if tr is not None else 0.0
         t0 = now()
-        tok, n_acc = self.backend.verify(tokens, active,
-                                         **self._extra_kw(rows, pos0))
+        # the device window only — the host commit loop below is
+        # engine.retire's (same placement as _decode's wire.decode)
+        with obs.span("wire.verify", "wire", n=len(decoding), k=k,
+                      kv_rows=_kv_rows(decoding)):
+            tok, n_acc = self.backend.verify(tokens, active,
+                                             **self._extra_kw(rows, pos0))
         dt = now() - t0
         t_done = now()
-        if tr is not None:
-            # the device window only — the host commit loop below must not
-            # inflate the span (same placement as _decode's wire.decode)
-            tr.complete("wire.verify", ts0, tr.now_us() - ts0, "wire",
-                        n=len(decoding), k=k)
+        with obs.span("engine.retire", "engine"):
+            committed_total = self._commit_verified(
+                rows, tok, n_acc, proposed, t_done, finished)
+        self.metrics.on_decode_step(dt, len(decoding),
+                                    tokens=committed_total)
+
+    def _commit_verified(self, rows, tok, n_acc, proposed, t_done,
+                         finished) -> int:
+        """Commit each slot's accepted draft prefix plus the target's own
+        token from one verify window; returns the tokens committed."""
         committed_total = 0
-        for slot, req in list(decoding.items()):
+        for slot, req in rows:
             m = int(n_acc[slot])
             committed = 0
             for j in range(m + 1):
@@ -1609,8 +1666,7 @@ class ServingEngine:
             _SPEC_ACCEPTED_LEN.inc(1, len=str(acc))
             self.metrics.on_spec(proposed=p, accepted=acc)
             self._maybe_retire(slot, req, t_done, finished)
-        self.metrics.on_decode_step(dt, len(decoding),
-                                    tokens=committed_total)
+        return committed_total
 
     def _emit_first_token(self, slot: int, req: Request, tok_val, t: float,
                           finished) -> None:

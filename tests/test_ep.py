@@ -659,3 +659,154 @@ class TestCrossImplFuzz:
             outs["ll"], outs["dense"], rtol=2e-3, atol=1e-5,
             err_msg=f"ll vs dense at {shapes}",
         )
+
+
+def _dot_operand_shapes(jaxpr):
+    """Operand shapes of every dot_general in a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield tuple(v.aval.shape for v in eqn.invars)
+        for p in eqn.params.values():
+            sub = getattr(p, "jaxpr", p)
+            if hasattr(sub, "eqns"):
+                yield from _dot_operand_shapes(sub)
+
+
+class TestExpertCapacity:
+    """Expert queues no longer than routing can fill: top-k ids are distinct
+    per token, so capacity stops at the source's token count — same kept
+    assignments, half the padded rows at the serving factor."""
+
+    F = 16
+
+    @pytest.mark.parametrize("t,k,e,factor,want", [
+        (1024, 2, 8, 8.0, 1024),  # serving prefill: the bound decides
+        (16, 2, 8, 8.0, 16),  # serving decode
+        (1024, 2, 8, 4.0, 1024),  # factor * k == E: exactly t either way
+        (8192, 2, 8, 1.5, 3072),  # training: the factor decides
+        (16, 2, 8, 0.5, 2),
+        (1, 2, 8, 0.01, 1),  # never below one row
+    ])
+    def test_table(self, t, k, e, factor, want):
+        assert ep_ops.expert_capacity(t, k, e, factor) == want
+
+    def _same_two_experts(self, rng):
+        """Inputs whose router sends EVERY token to experts 2 and 5."""
+        e_local = E // W
+        x = rng.standard_normal((W, T, H)).astype(np.float32)
+        logits = (rng.standard_normal((W, T, E)) * 0.1).astype(np.float32)
+        logits[..., 2] += 9.0
+        logits[..., 5] += 7.0
+        ws = [
+            (rng.standard_normal((W, e_local, *s)) * 0.1).astype(np.float32)
+            for s in ((H, self.F), (H, self.F), (self.F, H))
+        ]
+        return x, logits, ws
+
+    def _moe(self, ep_mesh, x, logits, ws, impl, factor):
+        def f(xv, lg, g, u, d):
+            out, _, _ = ep_ops.moe_ffn(
+                xv[0], lg[0], g[0], u[0], d[0], ("dp", "cp"),
+                num_selected=2, capacity_factor=factor, impl=impl,
+            )
+            return out[None]
+
+        return np.asarray(_shard_run(
+            ep_mesh, f, (x, logits, *ws), (2, 2, 3, 3, 3), 2
+        ))
+
+    @pytest.mark.parametrize("impl", ["sort", "dense"])
+    def test_worst_case_routing_is_drop_free(self, ep_mesh, rng, impl):
+        """All T tokens of every member aim at the same two experts: the
+        bounded queue (factor 8.0 -> T rows) keeps every one of them — bit
+        for bit what factor 4.0 (capacity exactly T) computes, and the
+        dropless per-token computation."""
+        x, logits, ws = self._same_two_experts(rng)
+        out8 = self._moe(ep_mesh, x, logits, ws, impl, 8.0)
+        out4 = self._moe(ep_mesh, x, logits, ws, impl, 4.0)
+        np.testing.assert_array_equal(out8, out4)
+
+        gates = jax.nn.softmax(jnp.asarray(logits), axis=-1)
+        tv, ti = jax.lax.top_k(gates, 2)
+        tv = np.asarray(tv / tv.sum(-1, keepdims=True))
+        ti = np.asarray(ti)
+        assert set(ti.reshape(-1).tolist()) == {2, 5}
+        wg, wu, wd = (
+            w.reshape(E, *w.shape[2:]) for w in ws
+        )
+        oracle = TestDispatchCombine()._oracle_moe
+        for w_i in range(W):
+            np.testing.assert_allclose(
+                out8[w_i], oracle(x[w_i], ti[w_i], tv[w_i], wg, wu, wd),
+                rtol=5e-4, atol=5e-5,
+            )
+
+        cap = ep_ops.expert_capacity(T, 2, E, 8.0)
+        assert cap == T
+        route = (ep_ops.route_topk_sorted if impl == "sort"
+                 else ep_ops.route_topk)
+        kept = np.asarray(route(jnp.asarray(logits[0]), 2, cap).counts)
+        assert kept.tolist() == [T if e in (2, 5) else 0 for e in range(E)]
+
+    @pytest.mark.parametrize("impl", ["sort", "dense"])
+    def test_expert_gemm_rows(self, ep_mesh, impl):
+        """The expert einsums' row operand is [E_local, W*T, .] at the
+        serving factor (E_local*T rows per source member) — a later edit
+        that pads past T fails here, not in a chip trace."""
+        e_local = E // W
+        shapes = [(W, T, H), (W, T, E), (W, e_local, H, self.F),
+                  (W, e_local, H, self.F), (W, e_local, self.F, H)]
+
+        def f(xv, lg, g, u, d):
+            out, _, _ = ep_ops.moe_ffn(
+                xv[0], lg[0], g[0], u[0], d[0], ("dp", "cp"),
+                num_selected=2, capacity_factor=8.0, impl=impl,
+            )
+            return out[None]
+
+        specs = tuple(P(("dp", "cp"), *([None] * (len(s) - 1)))
+                      for s in shapes)
+        mapped = jax.shard_map(f, mesh=ep_mesh, in_specs=specs,
+                               out_specs=P(("dp", "cp"), None, None),
+                               check_vma=False)
+        jaxpr = jax.make_jaxpr(mapped)(
+            *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes)
+        )
+        lhs = [ops[0] for ops in _dot_operand_shapes(jaxpr.jaxpr)]
+        rows = {s[1] for s in lhs if len(s) == 3 and s[0] == e_local}
+        assert (e_local, W * T, H) in lhs and (e_local, W * T, self.F) in lhs
+        assert rows == {W * T}, lhs
+
+    def test_gauge_and_counter(self, ep_mesh):
+        """Bounded at the serving factor, not at a training factor; the
+        gauge holds the rows the layer was traced with."""
+        from uccl_tpu import obs
+
+        gauge = obs.gauge("ep_expert_capacity")
+        bounded = obs.counter("ep_capacity_bounded_total")
+        e_local = E // W
+        shapes = [(T, H), (T, E), (e_local, H, self.F),
+                  (e_local, H, self.F), (e_local, self.F, H)]
+
+        def trace(factor, impl):
+            def f(*a):
+                return ep_ops.moe_ffn(*a, ("dp", "cp"), num_selected=2,
+                                      capacity_factor=factor, impl=impl)[0]
+
+            jax.eval_shape(
+                jax.shard_map(f, mesh=ep_mesh, in_specs=(P(),) * 5,
+                              out_specs=P(), check_vma=False),
+                *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes),
+            )
+
+        for impl in ("sort", "dense"):
+            before = bounded.total()
+            trace(8.0, impl)
+            assert gauge.get(what="moe_layer") == T
+            assert bounded.total() == before + 1
+            trace(1.5, impl)
+            assert gauge.get(what="moe_layer") == int(1.5 * T * 2 / E)
+            assert bounded.total() == before + 1
+            trace(4.0, impl)  # factor * k == E: T rows, by the factor
+            assert gauge.get(what="moe_layer") == T
+            assert bounded.total() == before + 1

@@ -224,6 +224,28 @@ class TestTraining:
             losses.append(float(metrics["ce"]))
         assert losses[-1] < losses[0] * 0.7, losses
 
+    @pytest.mark.parametrize("capacity_factor", [1.5, 2.0, 8.0])
+    def test_step_loss_matches_reference_any_factor(
+        self, devices, rng, capacity_factor
+    ):
+        """The train step and the unsharded oracle take their expert queue
+        length from ONE helper (ep_ops.expert_capacity), so on one device
+        and one microbatch (local tokens == global tokens) they agree at a
+        factor that drops (1.5), at capacity == tokens (2.0 = E/k) and
+        where the token bound decides (8.0)."""
+        mesh = make_mesh(MeshConfig(), devices[:1])
+        cfg = _cfg(capacity_factor=capacity_factor, n_microbatches=1)
+        params = init_params(jax.random.PRNGKey(5), cfg)
+        tokens, targets = _data(rng, cfg)
+        logits = reference_forward(params, tokens, cfg)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        want = float(jnp.mean(lse - tgt))
+        gp = shard_params(params, mesh, cfg)
+        train_step, init_opt = make_train_step(cfg, mesh)
+        _, _, metrics = jax.jit(train_step)(gp, init_opt(gp), tokens, targets)
+        np.testing.assert_allclose(float(metrics["ce"]), want, rtol=1e-5)
+
     def test_aux_loss_positive(self, devices, rng):
         mesh = make_mesh(MeshConfig(pp=1, dp=2, cp=2, tp=2), devices)
         cfg = _cfg(aux_loss_weight=0.01, z_loss_weight=1e-3)

@@ -32,7 +32,10 @@ Two implementations of the same contract:
 Token layout convention: ``E`` global experts, EP world ``W``, ``E_local=E/W``
 experts per member, per-member capacity ``C`` tokens per expert per source
 member. Dropped tokens (over capacity) contribute zero, matching
-drop-and-renormalize MoE training semantics.
+drop-and-renormalize MoE training semantics. ``C`` comes from
+:func:`expert_capacity`: ``capacity_factor`` times the balanced share, and
+never more than the source's own token count — top-k picks distinct experts
+per token, so a longer queue could not be filled by any routing.
 """
 
 from __future__ import annotations
@@ -517,6 +520,55 @@ def combine(
     return out
 
 
+def _capacity_by_factor(t: int, num_selected: int, num_experts: int,
+                        capacity_factor: float) -> int:
+    return int(capacity_factor * t * num_selected / num_experts)
+
+
+def expert_capacity(t: int, num_selected: int, num_experts: int,
+                    capacity_factor: float) -> int:
+    """Rows of one (source member, expert) queue for a source of ``t``
+    tokens: ``capacity_factor`` times the balanced share ``t*k/E``, bounded
+    by ``t``. The bound loses nothing: ``lax.top_k`` ids are distinct per
+    token (:func:`_gate_topk`), so one expert receives at most ``t`` rows
+    from one source whatever the routing, and ``pos < capacity`` keeps the
+    same assignments at any capacity >= ``t``. Below ``t`` (training
+    factors, ``capacity_factor * k < E``) the value is the factor's alone.
+    The one derivation for :func:`moe_ffn` and the unsharded oracle
+    (``models.flagship.reference_forward``). NOT for
+    ``ep.Buffer.capacity``: its callers hand in expert ids that may repeat
+    within a token, so one expert can be aimed at more than ``t`` times
+    there (``ep.ll`` bounds its pair capacity by its own lossless count)."""
+    return max(1, min(
+        _capacity_by_factor(t, num_selected, num_experts, capacity_factor), t
+    ))
+
+
+def _resolve_capacity(t: int, num_selected: int, num_experts: int,
+                      capacity_factor: float) -> int:
+    """:func:`expert_capacity` for one traced EP layer, recorded the way
+    :func:`resolve_chunks` records its depth (trace time, host side): the
+    resolved rows on the ``ep_expert_capacity`` gauge, and one tick of
+    ``ep_capacity_bounded_total`` when the token count, not
+    ``capacity_factor``, decided it (docs/OBSERVABILITY.md)."""
+    from uccl_tpu.obs import counters as _obsc
+
+    capacity = expert_capacity(t, num_selected, num_experts, capacity_factor)
+    _obsc.gauge(
+        "ep_expert_capacity",
+        "resolved per-expert queue rows of the last traced EP layer",
+    ).set(capacity, what="moe_layer")
+    bounded = _obsc.counter(
+        "ep_capacity_bounded_total",
+        "traced EP layers whose expert queues were bounded at the source's "
+        "token count instead of capacity_factor's share",
+    )
+    if _capacity_by_factor(t, num_selected, num_experts,
+                           capacity_factor) > t:
+        bounded.inc()
+    return capacity
+
+
 def resolve_chunks(n_chunks: int, wire: str, world: int, capacity: int,
                    e_local: int, hidden: int, itemsize: int,
                    wire_dtype=None) -> int:
@@ -703,6 +755,10 @@ def moe_ffn(
     or "ll" (packed low-latency path: grouped GEMMs over receive counts, no
     padded FLOPs — :mod:`uccl_tpu.ep.ll`; capacity_factor maps to its
     pair_capacity_factor bound).
+    capacity_factor: "sort" and "dense" queue :func:`expert_capacity` rows
+    per (source, expert): the factor's share of ``T*k/E``, never more than
+    ``T`` — an ample factor (``capacity_factor * k >= E``, serving) is
+    drop-free at exactly ``T`` rows, a training factor drops as before.
     wire: "lax" (XLA collectives) or "pallas" (device-initiated remote-DMA
     all-to-all, :mod:`uccl_tpu.ep.pallas_a2a`); for impl="ll" the value maps
     onto that path's wire form ("pallas" selects its dense-chunk layout on
@@ -722,7 +778,6 @@ def moe_ffn(
     t, h = x.shape
     e = router_logits.shape[-1]
     w = lax.axis_size(axis)
-    capacity = max(1, int(capacity_factor * t * num_selected / e))
     wire_dtype = resolve_wire_dtype(wire_fp8, wire_dtype)
     if impl == "ll":
         from uccl_tpu.ep.ll import ll_moe_ffn
@@ -735,6 +790,7 @@ def moe_ffn(
             wire_dtype=wire_dtype,
             n_chunks=n_chunks,
         )
+    capacity = _resolve_capacity(t, num_selected, e, capacity_factor)
     if impl == "sort":
         with jax.named_scope("moe.route"):
             rs = route_topk_sorted(router_logits, num_selected, capacity)

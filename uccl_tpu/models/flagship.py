@@ -555,11 +555,8 @@ def reference_forward(params, tokens, cfg: FlagshipConfig):
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         flat = h2.reshape(b * s, cfg.dim)
         logits = flat.astype(jnp.float32) @ lp["router"]
-        cap = max(
-            1,
-            int(
-                cfg.capacity_factor * flat.shape[0] * cfg.moe_topk / cfg.moe_experts
-            ),
+        cap = ep_ops.expert_capacity(
+            flat.shape[0], cfg.moe_topk, cfg.moe_experts, cfg.capacity_factor
         )
         r = ep_ops.route_topk(logits, cfg.moe_topk, cap)
         xe = jnp.einsum("tec,th->ech", r.dispatch_mask.astype(flat.dtype), flat)

@@ -236,10 +236,12 @@ def _forward_shard_slots(params, tokens, k_cache, v_cache, lengths, start,
     write-gated KV, per-slot attention masks) with the EP MoE FFN. Idle
     slots' dummy tokens do route through the experts — harmless: expert
     GEMM rows are independent and the ample serving capacity_factor keeps
-    the wire drop-free, so active rows are bit-identical to a batch
-    without the dummies. ``adapters``/``adapter_ids`` are the per-slot
-    fused LoRA tables (inference._lora_delta) — the attention projections
-    are dense-stack code, so the ONE fusion point serves both stacks."""
+    the wire drop-free (every expert queue holds all T rows of its source,
+    ``ep_ops.expert_capacity``, and not a row more), so active rows are
+    bit-identical to a batch without the dummies.
+    ``adapters``/``adapter_ids`` are the per-slot fused LoRA tables
+    (inference._lora_delta) — the attention projections are dense-stack
+    code, so the ONE fusion point serves both stacks."""
     cache = SlotKVCache(k_cache, v_cache, lengths)
     logits, cache = _forward_slots(
         params, tokens, cache, start, write_mask, cfg,
@@ -399,12 +401,14 @@ class MoEServer:
     def _check_drop_free(self):
         """The slot-serving oracle guarantee (bit-exact vs one-shot
         generate) requires the EP wire to be DROP-FREE for any routing:
-        per-expert capacity = floor(cf·T·topk/E) must cover the worst case
-        of all T tokens picking the same expert (topk experts are distinct
-        per token, so one expert receives at most T rows) — i.e.
-        cf·topk ≥ E. Otherwise idle-slot dummies and co-scheduled
-        neighbors could crowd a request's tokens past capacity and change
-        its output depending on who shares the batch."""
+        per-expert capacity = min(floor(cf·T·topk/E), T)
+        (``ep_ops.expert_capacity``) must cover the worst case of all T
+        tokens picking the same expert (topk experts are distinct per
+        token, so one expert receives at most T rows — which is also why
+        the queue stops at T) — i.e. cf·topk ≥ E. Otherwise idle-slot
+        dummies and co-scheduled neighbors could crowd a request's tokens
+        past capacity and change its output depending on who shares the
+        batch."""
         cfg = self.cfg
         if cfg.capacity_factor * cfg.moe_topk < cfg.moe_experts:
             raise ValueError(

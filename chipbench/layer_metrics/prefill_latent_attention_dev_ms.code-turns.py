@@ -1,0 +1,9 @@
+"""Device time of latent attention in one ``[slots, chunk]`` prefill
+program: scopes ``attn.*`` (with ``attn.latent_q`` and ``attn.latent_kv``)
+inside a ``uccl.wire.prefill`` span, median over the window's spans."""
+
+from chipbench import scopes_glm4 as sc
+
+
+def read(view):
+    return sc.scope_ms_in(view, sc.PREFILL, sc.LATENT_ATTENTION)

@@ -504,6 +504,9 @@ def ll_moe_ffn(
     renormalize: bool = True,
     n_chunks: int = 1,
     wire_dtype: Optional[str] = None,
+    gate: str = "softmax",
+    gate_bias=None,
+    routed_scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Full MoE layer on the low-latency path: route → packed dispatch →
     grouped GEMMs over counts → packed combine. Drop-free by default (the
@@ -516,7 +519,8 @@ def ll_moe_ffn(
     e = router_logits.shape[-1]
     with jax.named_scope("moe.route"):
         topk_vals, topk_idx, aux_loss, z_loss = _gate_topk(
-            router_logits, num_selected, renormalize
+            router_logits, num_selected, renormalize, gate, gate_bias,
+            routed_scale,
         )
     with jax.named_scope("moe.dispatch"):
         r = ll_dispatch(

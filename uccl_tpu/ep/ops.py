@@ -73,12 +73,36 @@ class Routing(NamedTuple):
     # demand is counts_raw; kept counts reflect drops)
 
 
-def _gate_topk(router_logits, num_selected: int, renormalize: bool):
-    """Shared gating math for both routing impls: softmax gates, z-loss,
-    (renormalized) top-k selection, GShard load-balance loss.
+GATES = ("softmax", "sigmoid_bias")
+
+
+def _gate_topk(router_logits, num_selected: int, renormalize: bool,
+               gate: str = "softmax", bias=None, scale: float = 1.0):
+    """THE gate, shared by every routing impl (``sort``, ``dense``, ``ll``
+    and the chunk-pipelined layer): scores, top-k choice, weights, losses.
+
+    ``softmax``: softmax gates, z-loss, (renormalized) top-k selection,
+    GShard load-balance loss. ``sigmoid_bias``: per-expert sigmoid scores in
+    float32; the k experts are CHOSEN by ``score + bias`` (``bias`` [E], the
+    auxiliary-loss-free balancing term) and WEIGHTED by the score alone,
+    renormalised over the chosen and scaled by ``scale`` — no auxiliary
+    losses (both zero). ``scale`` multiplies the weights of either gate.
     Returns (topk_vals [T,K], topk_idx [T,K], aux_loss, z_loss)."""
     e = router_logits.shape[-1]
     logits32 = router_logits.astype(jnp.float32)
+    if gate == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits32)  # [T, E]
+        choose = scores if bias is None else scores + bias.astype(jnp.float32)
+        _, topk_idx = lax.top_k(choose, num_selected)
+        topk_vals = jnp.take_along_axis(scores, topk_idx, axis=-1)
+        if renormalize:
+            topk_vals = topk_vals / (
+                jnp.sum(topk_vals, axis=-1, keepdims=True) + 1e-20
+            )
+        zero = jnp.zeros((), jnp.float32)
+        return topk_vals * scale, topk_idx, zero, zero
+    if gate != "softmax":
+        raise ValueError(f"unknown gate {gate!r} (want one of {GATES})")
     gates = jax.nn.softmax(logits32, axis=-1)  # [T, E]
     # z-loss stabilizes router logits; load-balance loss follows GShard.
     z = jax.nn.logsumexp(logits32, axis=-1)
@@ -89,6 +113,8 @@ def _gate_topk(router_logits, num_selected: int, renormalize: bool):
         topk_vals = topk_vals / jnp.maximum(
             jnp.sum(topk_vals, axis=-1, keepdims=True), 1e-9
         )
+    if scale != 1.0:
+        topk_vals = topk_vals * scale
 
     # GShard load-balance loss: E * mean(fraction routed) . mean(gate prob)
     me = jnp.mean(gates, axis=0)  # [E]
@@ -104,14 +130,19 @@ def route_topk(
     capacity: int,
     *,
     renormalize: bool = True,
+    gate: str = "softmax",
+    gate_bias=None,
+    routed_scale: float = 1.0,
 ) -> Routing:
     """Top-k gating with per-expert capacity and in-expert position assignment.
 
     router_logits: [T, E]. Returns masks/weights of shape [T, E, C].
+    ``gate``/``gate_bias``/``routed_scale``: :func:`_gate_topk`.
     """
     e = router_logits.shape[-1]
     topk_vals, topk_idx, aux_loss, z_loss = _gate_topk(
-        router_logits, num_selected, renormalize
+        router_logits, num_selected, renormalize, gate, gate_bias,
+        routed_scale,
     )
     dispatch, combine, counts_running = masks_from_topk(
         topk_idx, topk_vals, e, capacity
@@ -252,12 +283,16 @@ def route_topk_sorted(
     capacity: int,
     *,
     renormalize: bool = True,
+    gate: str = "softmax",
+    gate_bias=None,
+    routed_scale: float = 1.0,
 ) -> SortedRouting:
     """Top-k gating in sorted/ragged form — same math and losses as
     :func:`route_topk`, without materializing [T,E,C] masks."""
     e = router_logits.shape[-1]
     topk_vals, topk_idx, aux_loss, z_loss = _gate_topk(
-        router_logits, num_selected, renormalize
+        router_logits, num_selected, renormalize, gate, gate_bias,
+        routed_scale,
     )
     token_for_slot, slot, kept = sorted_from_topk(topk_idx, e, capacity)
     return SortedRouting(token_for_slot, slot, topk_vals, aux_loss, z_loss, kept)
@@ -746,6 +781,9 @@ def moe_ffn(
     wire: str = "lax",
     n_chunks: int = 1,
     wire_dtype=None,
+    gate: str = "softmax",
+    gate_bias=None,
+    routed_scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Full per-shard MoE layer: route → dispatch → SwiGLU experts → combine.
 
@@ -773,9 +811,13 @@ def moe_ffn(
     ``wire_fp8=True`` is the legacy spelling of "fp8"). Chunking composes
     bit-identically (blocks run along the hidden dim, untouched by the
     capacity split).
+    gate / gate_bias / routed_scale: the gate every impl routes by
+    (:func:`_gate_topk`): "softmax" (default) or "sigmoid_bias" with its
+    per-expert choice bias [E]; ``routed_scale`` multiplies the weights.
     Returns (out [T, H], aux_loss, z_loss).
     """
     t, h = x.shape
+    gating = dict(gate=gate, gate_bias=gate_bias, routed_scale=routed_scale)
     e = router_logits.shape[-1]
     w = lax.axis_size(axis)
     wire_dtype = resolve_wire_dtype(wire_fp8, wire_dtype)
@@ -789,11 +831,13 @@ def moe_ffn(
             wire="pallas" if wire == "pallas" else "auto",
             wire_dtype=wire_dtype,
             n_chunks=n_chunks,
+            **gating,
         )
     capacity = _resolve_capacity(t, num_selected, e, capacity_factor)
     if impl == "sort":
         with jax.named_scope("moe.route"):
-            rs = route_topk_sorted(router_logits, num_selected, capacity)
+            rs = route_topk_sorted(router_logits, num_selected, capacity,
+                                   **gating)
         n_chunks = resolve_chunks(
             n_chunks, wire, w, capacity, e // w, h,
             wire_itemsize(wire_fp8, h, x.dtype, wire_dtype=wire_dtype),
@@ -814,7 +858,7 @@ def moe_ffn(
         aux_loss, z_loss = rs.aux_loss, rs.z_loss
     elif impl == "dense":
         with jax.named_scope("moe.route"):
-            r = route_topk(router_logits, num_selected, capacity)
+            r = route_topk(router_logits, num_selected, capacity, **gating)
         with jax.named_scope("moe.dispatch"):
             xe = dispatch(x, r.dispatch_mask, axis, wire=wire,
                           wire_dtype=wire_dtype)

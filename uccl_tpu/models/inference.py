@@ -12,6 +12,7 @@ decode step hits the same compiled executable.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, NamedTuple, Tuple
 
 import jax
@@ -39,6 +40,28 @@ class KVCache(NamedTuple):
         )
 
 
+def _causal_mask(length, sq: int, smax: int):
+    """[Sq, Smax] (scalar ``length``) or [B, Sq, Smax] (per-slot [B]
+    ``length``) bool: queries at positions [length, length+Sq) attend at or
+    before their own position."""
+    kpos = jnp.arange(smax)
+    if jnp.ndim(length) == 0:
+        qpos = length + jnp.arange(sq)[:, None]  # [Sq, 1]
+        return kpos[None, :] <= qpos  # attend at or before own position
+    qpos = length[:, None] + jnp.arange(sq)[None, :]  # [B, Sq]
+    return kpos[None, None, :] <= qpos[:, :, None]  # [B, Sq, Smax]
+
+
+def _mask_scores(s, length):
+    """Causal mask over cached positions for scores ``s`` [B, H, Sq, Smax]
+    of queries at positions [length, length+Sq). ``length`` is a scalar or
+    [B] (see :func:`_attend_cached`)."""
+    mask = _causal_mask(length, *s.shape[-2:])
+    if jnp.ndim(length) == 0:
+        return jnp.where(mask[None, None], s, -1e30)
+    return jnp.where(mask[:, None], s, -1e30)
+
+
 def _attend_cached(q, k_cache, v_cache, length, cfg: DenseConfig):
     """q: [B, Sq, H, D] at positions [length, length+Sq); cache: [B, Smax, Hkv, D].
     Masked attention over the cache prefix + the new causal block.
@@ -48,8 +71,7 @@ def _attend_cached(q, k_cache, v_cache, length, cfg: DenseConfig):
     same, only its batch rank differs, so both paths produce bit-identical
     rows for equal per-row (length, prefix) — the serving engine's oracle
     guarantee rests on this."""
-    b, sq, h, d = q.shape
-    smax = k_cache.shape[1]
+    h, d = q.shape[2:]
     n_rep = h // cfg.n_kv_heads
     with jax.named_scope("attn.core"):
         kk = jnp.repeat(k_cache, n_rep, axis=2)
@@ -57,17 +79,167 @@ def _attend_cached(q, k_cache, v_cache, length, cfg: DenseConfig):
         s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
                        preferred_element_type=jnp.float32)
         s = s / jnp.sqrt(jnp.float32(d))
-        kpos = jnp.arange(smax)
-        if jnp.ndim(length) == 0:
-            qpos = length + jnp.arange(sq)[:, None]  # [Sq, 1]
-            mask = kpos[None, :] <= qpos  # attend at or before own position
-            s = jnp.where(mask[None, None], s, -1e30)
-        else:
-            qpos = length[:, None] + jnp.arange(sq)[None, :]  # [B, Sq]
-            mask = kpos[None, None, :] <= qpos[:, :, None]  # [B, Sq, Smax]
-            s = jnp.where(mask[:, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
+        p = jax.nn.softmax(_mask_scores(s, length), axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv)
+
+
+def _attend_latent(q_lat, q_rope, ckv_cache, kr_cache, length, scale):
+    """Latent (absorbed) attention over the cache: every head scores against
+    the SAME cached row, its 512-wide compressed part through the query
+    already multiplied into latent space and its one shared rotary key, and
+    sums the compressed rows themselves — a step reads 576 numbers a cached
+    position and never expands the pool into per-head keys and values.
+    q_lat: [B, Sq, H, R]; q_rope: [B, Sq, H, Dr]; caches [B, Smax, R] and
+    [B, Smax, Dr]; ``length`` as in :func:`_attend_cached`. Returns the
+    attended latent rows [B, Sq, H, R] (the caller applies W_uv)."""
+    b, sq, nh, r = q_lat.shape
+    smax = ckv_cache.shape[1]
+    with jax.named_scope("attn.core"):
+        # heads ride the query axis ([B, Sq*H, .]): the cache row is shared
+        # by every head, so scores and values are plain batched products
+        # with the cached positions minor — no transposed score layout
+        # (which cost the prefill program a 16,383-wide reduce-window a
+        # layer, 64 ms, on the chip: PERF.md section 6, PR 26)
+        s = jnp.einsum("bmc,bkc->bmk", q_lat.reshape(b, sq * nh, r),
+                       ckv_cache, preferred_element_type=jnp.float32)
+        s = s + jnp.einsum("bmr,bkr->bmk", q_rope.reshape(b, sq * nh, -1),
+                           kr_cache, preferred_element_type=jnp.float32)
+        # one mask row per query position, shared by its heads
+        mask = _causal_mask(length, sq, smax)[..., None, :]
+        s = jnp.where(mask, (s * jnp.float32(scale)).reshape(
+            b, sq, nh, smax), -1e30).reshape(b, sq * nh, smax)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bmk,bkc->bmc", p.astype(ckv_cache.dtype), ckv_cache)
+        return o.reshape(b, sq, nh, r)
+
+
+def kv_row_shapes(cfg):
+    """Per-position shapes of the two cache arrays of one layer, from the
+    model description: ``gqa`` keeps keys and values ``[Hkv, D]`` each;
+    ``mla`` keeps ONE latent row as its normalised compressed part
+    ``[kv_lora_rank]`` (in ``k``) and the shared rotary key
+    ``[qk_rope_dim]`` (in ``v``) — no value row at all."""
+    if getattr(cfg, "attn", "gqa") == "mla":
+        return (cfg.kv_lora_rank,), (cfg.qk_rope_dim,)
+    return (cfg.n_kv_heads, cfg.head_dim), (cfg.n_kv_heads, cfg.head_dim)
+
+
+def kv_wire_dims(cfg):
+    """(heads, width) of the two EQUAL arrays one layer's cached position
+    leaves a pool as (``export_rows``): gqa's own ``[Hkv, D]``; a latent
+    row's two halves, ``[1, (kv_lora_rank + qk_rope_dim) / 2]`` each."""
+    k_row, v_row = kv_row_shapes(cfg)
+    if k_row == v_row:
+        return k_row
+    return 1, (math.prod(k_row) + math.prod(v_row)) // 2
+
+
+def _gqa_attention(x, lp, k_cache, v_cache, positions, length, write, cfg,
+                   lora=None):
+    """One layer's grouped-query attention with its residual: project,
+    rotate, ``write`` the new rows into this layer's cache arrays, attend
+    over the cache. ``lora(h, target)`` adds the per-slot low-rank delta to
+    the query/value projections. Returns (x', k_cache', v_cache')."""
+    b, s, _ = x.shape
+    d = cfg.head_dim
+    with jax.named_scope("attn.qkv"):
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        q2 = h @ lp["wq"].astype(h.dtype)
+        v2 = h @ lp["wv"].astype(h.dtype)
+        if lora is not None:
+            q2 = q2 + lora(h, "wq")
+            v2 = v2 + lora(h, "wv")
+        q = q2.reshape(b, s, cfg.n_heads, d)
+        kk = (h @ lp["wk"].astype(h.dtype)).reshape(
+            b, s, cfg.n_kv_heads, d)
+        v = v2.reshape(b, s, cfg.n_kv_heads, d)
+        q = rope(q, positions, cfg.rope_theta)
+        kk = rope(kk, positions, cfg.rope_theta)
+    with jax.named_scope("attn.kv_write"):
+        k_cache = write(k_cache, kk)
+        v_cache = write(v_cache, v)
+    attn = _attend_cached(q, k_cache, v_cache, length, cfg)
+    with jax.named_scope("attn.out"):
+        x = x + attn.reshape(b, s, -1) @ lp["wo"].astype(attn.dtype)
+    return x, k_cache, v_cache
+
+
+def _mla_attention(x, lp, ckv_cache, kr_cache, positions, length, write,
+                   cfg, lora=None):
+    """One layer's multi-head latent attention with its residual, in the
+    absorbed form: queries through a low-rank bottleneck (``wq_a`` -> norm
+    -> ``wq_b``), keys and values through ONE shared one (``wkv_a`` -> norm)
+    whose output plus a single shared rotary key is all the cache holds.
+    ``W_kvb = [W_uk | W_uv]`` per head never touches the cache: W_uk is
+    multiplied into the query (``q_nope W_uk^T``), W_uv into the attended
+    latent rows — the same mathematics as expanding every cached row into
+    per-head keys and values, at 576 numbers read a cached position."""
+    if lora is not None:
+        raise ValueError("LoRA adapters target the gqa projections "
+                         "(wq/wv); latent attention has none")
+    b, s, _ = x.shape
+    nh, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+    with jax.named_scope("attn.latent_q"):
+        cq = rms_norm(h @ lp["wq_a"].astype(h.dtype), lp["q_a_norm"],
+                      cfg.norm_eps)
+    with jax.named_scope("attn.latent_kv"):
+        ckr = h @ lp["wkv_a"].astype(h.dtype)
+        ckv = rms_norm(ckr[..., :r], lp["kv_a_norm"], cfg.norm_eps)
+        # one rotary key shared by every head
+        kr = rope(ckr[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
+    with jax.named_scope("attn.qkv"):
+        q = (cq @ lp["wq_b"].astype(cq.dtype)).reshape(b, s, nh, dn + dr)
+        q_rope = rope(q[..., dn:], positions, cfg.rope_theta)
+        w_kvb = lp["wkv_b"].astype(q.dtype).reshape(r, nh, dn + dv)
+        q_lat = jnp.einsum("bshd,chd->bshc", q[..., :dn], w_kvb[..., :dn])
+    with jax.named_scope("attn.kv_write"):
+        ckv_cache = write(ckv_cache, ckv)
+        kr_cache = write(kr_cache, kr)
+    o_lat = _attend_latent(q_lat, q_rope, ckv_cache, kr_cache, length,
+                           1.0 / math.sqrt(dn + dr))
+    with jax.named_scope("attn.out"):
+        o = jnp.einsum("bshc,chd->bshd", o_lat, w_kvb[..., dn:])
+        x = x + o.reshape(b, s, nh * dv) @ lp["wo"].astype(o.dtype)
+    return x, ckv_cache, kr_cache
+
+
+def _attention_of(cfg):
+    """The attention kind of a model description: ``cfg.attn`` ("gqa" |
+    "mla"; descriptions without the field are gqa)."""
+    kind = getattr(cfg, "attn", "gqa")
+    if kind == "gqa":
+        return _gqa_attention
+    if kind == "mla":
+        return _mla_attention
+    raise ValueError(f"unknown attention kind {kind!r} (want 'gqa' or 'mla')")
+
+
+def _layer_params(params, i: int):
+    """Layer ``i``'s leaves. Layers come in stacked groups: the leading
+    ``dense_blocks`` (where the model has a dense-FFN prefix: their leading
+    dim is how many) and then ``blocks``."""
+    dense = params.get("dense_blocks")
+    if dense is not None:
+        n = jax.tree.leaves(dense)[0].shape[0]
+        if i < n:
+            return jax.tree.map(lambda a: a[i], dense)
+        i -= n
+    return jax.tree.map(lambda a: a[i], params["blocks"])
+
+
+def _dense_ffn(h2, lp):
+    act = jax.nn.silu(h2 @ lp["w_gate"].astype(h2.dtype)) * (
+        h2 @ lp["w_up"].astype(h2.dtype)
+    )
+    return act @ lp["w_down"].astype(act.dtype)
+
+
+def _head(x, params, cfg):
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return x.astype(jnp.float32) @ params["head"].astype(jnp.float32)
 
 
 def _forward_cached(
@@ -77,47 +249,29 @@ def _forward_cached(
 
     ``ffn(h2, layer_params) -> [B, S, H]`` overrides the dense SwiGLU block
     — the hook the MoE serving loop uses so the attention/KV-cache math
-    exists exactly once (uccl_tpu/models/moe_inference.py)."""
+    exists exactly once (uccl_tpu/models/moe_inference.py). The attention
+    kind comes from the model description (:func:`_attention_of`)."""
     b, s = tokens.shape
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(cache.k.dtype)
     positions = cache.length + jnp.arange(s)
+    attention = _attention_of(cfg)
+
+    def write(rows, new):
+        return lax.dynamic_update_slice(
+            rows, new, (0, cache.length) + (0,) * (new.ndim - 2))
+
     new_k, new_v = [], []
     for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], params["blocks"])
-        d = cfg.head_dim
-        with jax.named_scope("attn.qkv"):
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            q = (h @ lp["wq"].astype(h.dtype)).reshape(b, s, cfg.n_heads, d)
-            kk = (h @ lp["wk"].astype(h.dtype)).reshape(
-                b, s, cfg.n_kv_heads, d)
-            v = (h @ lp["wv"].astype(h.dtype)).reshape(
-                b, s, cfg.n_kv_heads, d)
-            q = rope(q, positions, cfg.rope_theta)
-            kk = rope(kk, positions, cfg.rope_theta)
-        with jax.named_scope("attn.kv_write"):
-            k_cache = lax.dynamic_update_slice(
-                cache.k[i], kk, (0, cache.length, 0, 0)
-            )
-            v_cache = lax.dynamic_update_slice(
-                cache.v[i], v, (0, cache.length, 0, 0)
-            )
+        lp = _layer_params(params, i)
+        x, k_cache, v_cache = attention(
+            x, lp, cache.k[i], cache.v[i], positions, cache.length, write,
+            cfg)
         new_k.append(k_cache)
         new_v.append(v_cache)
-        attn = _attend_cached(q, k_cache, v_cache, cache.length, cfg)
-        with jax.named_scope("attn.out"):
-            x = x + attn.reshape(b, s, -1) @ lp["wo"].astype(attn.dtype)
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if ffn is None:
-            act = jax.nn.silu(h2 @ lp["w_gate"].astype(h2.dtype)) * (
-                h2 @ lp["w_up"].astype(h2.dtype)
-            )
-            x = x + act @ lp["w_down"].astype(act.dtype)
-        else:
-            x = x + ffn(h2, lp)
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = x.astype(jnp.float32) @ params["head"]
+        x = x + (_dense_ffn(h2, lp) if ffn is None else ffn(h2, lp))
+    logits = _head(x, params, cfg)
     cache = KVCache(
         jnp.stack(new_k), jnp.stack(new_v), cache.length + s
     )
@@ -314,42 +468,26 @@ def _forward_slots(
     # the cache end (a bucket overhanging S_max) drop the same way
     pos_write = jnp.where(write_mask[:, None], positions, smax)
     bidx = jnp.arange(b)[:, None]
+    attention = _attention_of(cfg)
+
+    def write(rows, new):
+        return rows.at[bidx, pos_write].set(new, mode="drop")
+
     new_k, new_v = [], []
     for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], params["blocks"])
-        d = cfg.head_dim
-        with jax.named_scope("attn.qkv"):
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            q2 = h @ lp["wq"].astype(h.dtype)
-            v2 = h @ lp["wv"].astype(h.dtype)
-            if adapters is not None:
-                q2 = q2 + _lora_delta(h, adapters["wq"], adapter_ids, i)
-                v2 = v2 + _lora_delta(h, adapters["wv"], adapter_ids, i)
-            q = q2.reshape(b, s, cfg.n_heads, d)
-            kk = (h @ lp["wk"].astype(h.dtype)).reshape(
-                b, s, cfg.n_kv_heads, d)
-            v = v2.reshape(b, s, cfg.n_kv_heads, d)
-            q = rope(q, positions, cfg.rope_theta)
-            kk = rope(kk, positions, cfg.rope_theta)
-        with jax.named_scope("attn.kv_write"):
-            k_cache = cache.k[i].at[bidx, pos_write].set(kk, mode="drop")
-            v_cache = cache.v[i].at[bidx, pos_write].set(v, mode="drop")
+        lp = _layer_params(params, i)
+        lora = None
+        if adapters is not None:
+            def lora(h, target, i=i):
+                return _lora_delta(h, adapters[target], adapter_ids, i)
+        x, k_cache, v_cache = attention(
+            x, lp, cache.k[i], cache.v[i], positions, start, write, cfg,
+            lora=lora)
         new_k.append(k_cache)
         new_v.append(v_cache)
-        attn = _attend_cached(q, k_cache, v_cache, start, cfg)
-        with jax.named_scope("attn.out"):
-            x = x + attn.reshape(b, s, -1) @ lp["wo"].astype(attn.dtype)
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if ffn is None:
-            act = jax.nn.silu(h2 @ lp["w_gate"].astype(h2.dtype)) * (
-                h2 @ lp["w_up"].astype(h2.dtype)
-            )
-            x = x + act @ lp["w_down"].astype(act.dtype)
-        else:
-            x = x + ffn(h2, lp)
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = x.astype(jnp.float32) @ params["head"]
+        x = x + (_dense_ffn(h2, lp) if ffn is None else ffn(h2, lp))
+    logits = _head(x, params, cfg)
     return logits, SlotKVCache(
         jnp.stack(new_k), jnp.stack(new_v), cache.lengths
     )

@@ -37,8 +37,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from uccl_tpu.ep import ops as ep_ops
 from uccl_tpu.models.inference import (
-    KVCache, SlotKVCache, _forward_cached, _forward_slots,
-    greedy_acceptance, spec_advance,
+    KVCache, SlotKVCache, _dense_ffn, _forward_cached, _forward_slots,
+    greedy_acceptance, kv_row_shapes, spec_advance,
 )
 from uccl_tpu.models.sampling import (
     broadcast_params, sample_tokens, sample_window,
@@ -68,20 +68,115 @@ class MoEServeConfig:
     wire_dtype: Optional[str] = None  # None | "fp8" | "int8": block-scale
     # quantized EP wire payloads (shared ops.quant codec; one quantize
     # round trip of error per exchange — docs/QUANT_WIRE.md)
+    # -- block kinds: DATA of the one description MoEServer consumes. The
+    # defaults are the uniform block (Mixtral: gqa, every layer moe, softmax
+    # gate, no shared expert, float32); GLM-4.7-Flash / DeepSeek-style
+    # models are other values of the same fields (:meth:`from_hf`).
+    attn: str = "gqa"  # "gqa" | "mla" (latent attention; the widths below)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    first_k_dense: int = 0  # leading layers with a dense FFN, not experts
+    dense_ffn: int = 0  # their width
+    shared_ffn: int = 0  # width of the always-on shared expert (0 = none)
+    gate: str = "softmax"  # "softmax" | "sigmoid_bias" (ep.ops._gate_topk)
+    routed_scale: float = 1.0  # multiplies the routed experts' weights
+    param_dtype: str = "float32"  # storage dtype of the weight matrices
+    # (norms and the gate bias stay float32); activations and cache are
+    # float32 either way, so a bfloat16 matrix is upcast where it is used
+
+    def __post_init__(self):
+        if self.attn not in ("gqa", "mla"):
+            raise ValueError(f"attn {self.attn!r}: want 'gqa' or 'mla'")
+        if self.gate not in ep_ops.GATES:
+            raise ValueError(f"gate {self.gate!r}: want one of "
+                             f"{ep_ops.GATES}")
+        if not 0 <= self.first_k_dense < self.n_layers:
+            raise ValueError(
+                f"first_k_dense {self.first_k_dense} must leave at least "
+                f"one of the {self.n_layers} layers to the experts")
+        if self.first_k_dense and self.dense_ffn <= 0:
+            raise ValueError("first_k_dense needs dense_ffn, its width")
+        if self.attn == "mla":
+            widths = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
+                      self.qk_rope_dim, self.v_head_dim)
+            if min(widths) <= 0 or self.qk_rope_dim % 2 \
+                    or self.kv_lora_rank % 2:
+                raise ValueError(
+                    f"mla needs its five widths (q_lora_rank, kv_lora_rank, "
+                    f"qk_nope_dim, qk_rope_dim, v_head_dim), the rotary and "
+                    f"the latent one even; got {widths}")
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.first_k_dense
+
+    @classmethod
+    def from_hf(cls, hf: Dict[str, Any], **overrides) -> "MoEServeConfig":
+        """The description from a Hugging Face ``config.json`` dict —
+        ``mixtral`` (uniform gqa/softmax blocks) or the
+        ``deepseek_v3``/``glm4_moe_lite`` family (latent attention, leading
+        dense layers, sigmoid-bias gate, shared experts). ``overrides`` are
+        this class's own fields (capacity_factor, param_dtype ...)."""
+        heads = hf["num_attention_heads"]
+        kw: Dict[str, Any] = dict(
+            vocab=hf["vocab_size"], dim=hf["hidden_size"],
+            n_layers=hf["num_hidden_layers"], n_heads=heads,
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            moe_topk=hf["num_experts_per_tok"],
+        )
+        if "kv_lora_rank" in hf:
+            if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+                raise ValueError("group-limited routing (n_group > 1) is "
+                                 "not built")
+            if hf.get("rope_scaling") is not None:
+                raise ValueError("rope_scaling is not built")
+            shared = hf.get("n_shared_experts") or 0
+            kw.update(
+                attn="mla", q_lora_rank=hf["q_lora_rank"],
+                kv_lora_rank=hf["kv_lora_rank"],
+                qk_nope_dim=hf["qk_nope_head_dim"],
+                qk_rope_dim=hf["qk_rope_head_dim"],
+                v_head_dim=hf["v_head_dim"],
+                # gqa's widths: unused by mla, kept consistent for readers
+                n_kv_heads=heads,
+                head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+                moe_experts=hf["n_routed_experts"],
+                moe_ffn=hf["moe_intermediate_size"],
+                first_k_dense=hf.get("first_k_dense_replace", 0),
+                dense_ffn=hf["intermediate_size"],
+                shared_ffn=shared * hf["moe_intermediate_size"],
+                gate="sigmoid_bias",
+                routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+            )
+            if not hf.get("norm_topk_prob", True):
+                raise ValueError("norm_topk_prob false is not built")
+        else:
+            kw.update(
+                n_kv_heads=hf["num_key_value_heads"],
+                head_dim=hf.get("head_dim") or hf["hidden_size"] // heads,
+                moe_experts=hf["num_local_experts"],
+                moe_ffn=hf["intermediate_size"],
+            )
+        kw.update(overrides)
+        return cls(**kw)
 
 
 class MoEKVCache(NamedTuple):
-    k: jax.Array  # [W, L, B_loc, S_max, Hkv, D]
-    v: jax.Array
+    k: jax.Array  # [W, L, B_loc, S_max, *k_row] (inference.kv_row_shapes:
+    v: jax.Array  # gqa [Hkv, D] each; mla [kv_lora_rank] and [qk_rope_dim])
     length: jax.Array  # [W] int32
 
     @staticmethod
     def empty(cfg: MoEServeConfig, world: int, batch_local: int,
               max_seq: int, dtype=jnp.float32) -> "MoEKVCache":
-        shape = (world, cfg.n_layers, batch_local, max_seq,
-                 cfg.n_kv_heads, cfg.head_dim)
+        lead = (world, cfg.n_layers, batch_local, max_seq)
+        k_row, v_row = kv_row_shapes(cfg)
         return MoEKVCache(
-            jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+            jnp.zeros(lead + k_row, dtype), jnp.zeros(lead + v_row, dtype),
             jnp.zeros((world,), jnp.int32),
         )
 
@@ -90,17 +185,17 @@ class MoESlotCache(NamedTuple):
     """Slot-pool KV cache: one length PER SLOT (not per shard) — the
     continuous-batching engine admits/frees [w, b_loc] rows independently."""
 
-    k: jax.Array  # [W, L, B_loc, S_max, Hkv, D]
-    v: jax.Array
+    k: jax.Array  # [W, L, B_loc, S_max, *k_row] (inference.kv_row_shapes)
+    v: jax.Array  # [W, L, B_loc, S_max, *v_row]
     lengths: jax.Array  # [W, B_loc] int32
 
     @staticmethod
     def empty(cfg: MoEServeConfig, world: int, batch_local: int,
               max_seq: int, dtype=jnp.float32) -> "MoESlotCache":
-        shape = (world, cfg.n_layers, batch_local, max_seq,
-                 cfg.n_kv_heads, cfg.head_dim)
+        lead = (world, cfg.n_layers, batch_local, max_seq)
+        k_row, v_row = kv_row_shapes(cfg)
         return MoESlotCache(
-            jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+            jnp.zeros(lead + k_row, dtype), jnp.zeros(lead + v_row, dtype),
             jnp.zeros((world, batch_local), jnp.int32),
         )
 
@@ -120,12 +215,23 @@ class MoESlotCache(NamedTuple):
     def export_rows(self, slot: int, lo: int, hi: int):
         """Host copies of rows [lo, hi): (k, v) each [L, hi-lo, Hkv, D] —
         the same per-slot layout the dense cache exports, so the disagg
-        wire format is stack-independent."""
+        wire format is stack-independent. A latent pool's row (its two
+        arrays differ in width) leaves as the two halves of its 576 numbers,
+        ``[L, hi-lo, 1, 288]`` each (:func:`kv_wire_dims`): what moves rows
+        — the prefix cache, the tiers, the disaggregated wire — wants two
+        equal arrays and never looks inside them."""
         import numpy as np
 
         w, b = self._loc(slot)
-        return (np.asarray(self.k[w, :, b, lo:hi]),
-                np.asarray(self.v[w, :, b, lo:hi]))
+        k = np.asarray(self.k[w, :, b, lo:hi])
+        v = np.asarray(self.v[w, :, b, lo:hi])
+        if k.shape == v.shape:
+            return k, v
+        flat = np.concatenate([k.reshape(k.shape[:2] + (-1,)),
+                               v.reshape(v.shape[:2] + (-1,))], axis=-1)
+        half = flat.shape[-1] // 2
+        return (np.ascontiguousarray(flat[:, :, None, :half]),
+                np.ascontiguousarray(flat[:, :, None, half:]))
 
     def import_rows(self, slot: int, k_rows, v_rows, *,
                     length: int) -> "MoESlotCache":
@@ -137,6 +243,14 @@ class MoESlotCache(NamedTuple):
         k = np.array(self.k)
         v = np.array(self.v)
         lengths = np.array(self.lengths)
+        if k.shape[4:] != v.shape[4:]:  # the latent row's two wire halves
+            flat = np.concatenate(
+                [np.asarray(k_rows).reshape(k_rows.shape[:2] + (-1,)),
+                 np.asarray(v_rows).reshape(v_rows.shape[:2] + (-1,))],
+                axis=-1)
+            cut = int(np.prod(k.shape[4:]))
+            k_rows = flat[..., :cut].reshape(flat.shape[:2] + k.shape[4:])
+            v_rows = flat[..., cut:].reshape(flat.shape[:2] + v.shape[4:])
         k[w, :, b, :n] = np.asarray(k_rows, k.dtype)
         v[w, :, b, :n] = np.asarray(v_rows, v.dtype)
         lengths[w, b] = length
@@ -158,49 +272,125 @@ class MoESlotCache(NamedTuple):
                             jnp.asarray(lengths))
 
 
-def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
-    """Global parameter tree (experts carry the full [E, ...] axis)."""
-    k = jax.random.split(key, 12)
-    h, l, f, e = cfg.dim, cfg.n_layers, cfg.moe_ffn, cfg.moe_experts
+# Which draw a leaf comes from. The uniform block's leaves keep the twelve-
+# way split they always had (so a Mixtral-shaped model's weights are what
+# they were); every other leaf folds its own number into the key, and the
+# leading dense layers' group folds 64 more.
+_SPLIT_KEY = {"embed": 0, "wq": 1, "wk": 2, "wv": 3, "wo": 4, "router": 5,
+              "we_gate": 6, "we_up": 7, "we_down": 8, "head": 9}
+_FOLD_KEY = {"wq_a": 21, "wq_b": 22, "wkv_a": 23, "wkv_b": 24,
+             "ws_gate": 25, "ws_up": 26, "ws_down": 27, "router_bias": 28,
+             "w_gate": 29, "w_up": 30, "w_down": 31}
+_DENSE_GROUP_FOLD = 64
+ROUTER_BIAS_SCALE = 0.01  # seeded gate bias: choosing by score + bias and
+# weighing by the score alone are then told apart
+
+
+def _attn_shapes(cfg: MoEServeConfig):
+    """{leaf: (shape, fan-in)} of one layer's attention matrices, and its
+    norm leaves, for the description's attention kind."""
+    h = cfg.dim
+    if cfg.attn == "mla":
+        nh = cfg.n_heads
+        qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+        return {
+            "wq_a": ((h, cfg.q_lora_rank), h),
+            "wq_b": ((cfg.q_lora_rank, nh * qk), cfg.q_lora_rank),
+            "wkv_a": ((h, cfg.kv_lora_rank + cfg.qk_rope_dim), h),
+            "wkv_b": ((cfg.kv_lora_rank,
+                       nh * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                      cfg.kv_lora_rank),
+            "wo": ((nh * cfg.v_head_dim, h), nh * cfg.v_head_dim),
+        }, {"ln1": h, "ln2": h, "q_a_norm": cfg.q_lora_rank,
+            "kv_a_norm": cfg.kv_lora_rank}
     qd = cfg.n_heads * cfg.head_dim
     kvd = cfg.n_kv_heads * cfg.head_dim
-    s_in, s_f = 1.0 / math.sqrt(h), 1.0 / math.sqrt(f)
+    return {"wq": ((h, qd), h), "wk": ((h, kvd), h), "wv": ((h, kvd), h),
+            "wo": ((qd, h), qd)}, {"ln1": h, "ln2": h}
 
-    def rnd(kk, shape, scale):
-        return jax.random.normal(kk, shape, jnp.float32) * scale
 
-    return {
-        "embed": rnd(k[0], (cfg.vocab, h), 0.02),
-        "blocks": {
-            "ln1": jnp.ones((l, h), jnp.float32),
-            "ln2": jnp.ones((l, h), jnp.float32),
-            "wq": rnd(k[1], (l, h, qd), s_in),
-            "wk": rnd(k[2], (l, h, kvd), s_in),
-            "wv": rnd(k[3], (l, h, kvd), s_in),
-            "wo": rnd(k[4], (l, qd, h), 1.0 / math.sqrt(qd)),
-            "router": rnd(k[5], (l, h, e), s_in),
-            "we_gate": rnd(k[6], (l, e, h, f), s_in),
-            "we_up": rnd(k[7], (l, e, h, f), s_in),
-            "we_down": rnd(k[8], (l, e, f, h), s_f),
-        },
+def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
+    """Global parameter tree (experts carry the full [E, ...] axis). Layers
+    come in stacked groups: ``blocks`` [n_moe_layers, ...] and, where the
+    description has a dense prefix, ``dense_blocks`` [first_k_dense, ...].
+    Every matrix is drawn in float32 (embedding 0.02, others 1/sqrt(fan-in))
+    and stored in ``cfg.param_dtype``; norms are ones and the gate bias a
+    normal of scale 0.01, both float32."""
+    k = jax.random.split(key, 12)
+    h, f, e = cfg.dim, cfg.moe_ffn, cfg.moe_experts
+    dtype = jnp.dtype(cfg.param_dtype)
+
+    def rnd(name, shape, scale, group=0):
+        kk = k[_SPLIT_KEY[name]] if name in _SPLIT_KEY and not group else \
+            jax.random.fold_in(key, group + (_FOLD_KEY.get(name)
+                                             or _SPLIT_KEY[name]))
+        return (jax.random.normal(kk, shape, jnp.float32)
+                * scale).astype(dtype)
+
+    def group(n, ffn_shapes, fold):
+        mats, norms = _attn_shapes(cfg)
+        mats = {**mats, **ffn_shapes}
+        out = {name: jnp.ones((n, width), jnp.float32)
+               for name, width in norms.items()}
+        out.update({name: rnd(name, (n,) + shape, 1.0 / math.sqrt(fan), fold)
+                    for name, (shape, fan) in mats.items()})
+        return out
+
+    moe = {"router": ((h, e), h), "we_gate": ((e, h, f), h),
+           "we_up": ((e, h, f), h), "we_down": ((e, f, h), f)}
+    if cfg.shared_ffn:
+        fs = cfg.shared_ffn
+        moe.update({"ws_gate": ((h, fs), h), "ws_up": ((h, fs), h),
+                    "ws_down": ((fs, h), fs)})
+    params = {
+        "embed": rnd("embed", (cfg.vocab, h), 0.02),
+        "blocks": group(cfg.n_moe_layers, moe, 0),
         "final_norm": jnp.ones((h,), jnp.float32),
-        "head": rnd(k[9], (h, cfg.vocab), s_in),
+        "head": rnd("head", (h, cfg.vocab), 1.0 / math.sqrt(h)),
     }
+    if cfg.gate == "sigmoid_bias":
+        params["blocks"]["router_bias"] = jax.random.normal(
+            jax.random.fold_in(key, _FOLD_KEY["router_bias"]),
+            (cfg.n_moe_layers, e), jnp.float32) * ROUTER_BIAS_SCALE
+    if cfg.first_k_dense:
+        fd = cfg.dense_ffn
+        params["dense_blocks"] = group(
+            cfg.first_k_dense,
+            {"w_gate": ((h, fd), h), "w_up": ((h, fd), h),
+             "w_down": ((fd, h), fd)}, _DENSE_GROUP_FOLD)
+    return params
+
+
+# The router's product. The softmax gate's is at XLA's default, as it always
+# was. A sigmoid-bias model computes its router in float32 by definition, and
+# has to: top-4 of 64 sigmoid scores sit closer together than a product of
+# bfloat16-rounded operands resolves (13 % of served tokens moved on the
+# chip when it did not: PERF.md section 6, PR 26); 2048 x 64 a token is free.
+_ROUTER_PRECISION = {"softmax": None, "sigmoid_bias": lax.Precision.HIGHEST}
 
 
 def _moe_block(cfg: MoEServeConfig, impl: str):
-    """The EP MoE FFN as an :func:`inference._forward_cached`-style ``ffn``
-    hook: route over the EP axis (sorted path for prefill throughput,
-    packed LL for decode), experts being the LOCAL shard."""
+    """The FFN half of a layer as an :func:`inference._forward_cached`-style
+    ``ffn`` hook, by what the layer's leaves say it is: a dense-prefix layer
+    (no router) runs the dense SwiGLU; an expert layer routes over the EP
+    axis (sorted path for prefill throughput, packed LL for decode), experts
+    being the LOCAL shard, and adds the shared expert — once per token,
+    outside ``moe_ffn``, whatever the EP world."""
 
     def moe_block(h2, lp):
+        if "router" not in lp:
+            with jax.named_scope("ffn.dense"):
+                return _dense_ffn(h2, lp)
         b, sq, hd = h2.shape
         flat = h2.reshape(b * sq, hd)
         with jax.named_scope("moe.router"):
-            router_logits = flat.astype(jnp.float32) @ lp["router"]
+            router_logits = jnp.dot(
+                flat.astype(jnp.float32), lp["router"].astype(jnp.float32),
+                precision=_ROUTER_PRECISION[cfg.gate])
         out, _, _ = ep_ops.moe_ffn(
             flat, router_logits,
-            lp["we_gate"], lp["we_up"], lp["we_down"],
+            lp["we_gate"].astype(flat.dtype), lp["we_up"].astype(flat.dtype),
+            lp["we_down"].astype(flat.dtype),
             _AXIS,
             num_selected=cfg.moe_topk,
             capacity_factor=cfg.capacity_factor,
@@ -208,7 +398,15 @@ def _moe_block(cfg: MoEServeConfig, impl: str):
             wire=cfg.moe_wire,
             n_chunks=cfg.moe_chunks,
             wire_dtype=cfg.wire_dtype,
+            gate=cfg.gate,
+            gate_bias=lp.get("router_bias"),
+            routed_scale=cfg.routed_scale,
         )
+        if "ws_gate" in lp:
+            with jax.named_scope("moe.shared"):
+                out = out + _dense_ffn(
+                    flat, {"w_gate": lp["ws_gate"], "w_up": lp["ws_up"],
+                           "w_down": lp["ws_down"]})
         return out.reshape(b, sq, hd)
 
     return moe_block
@@ -251,23 +449,22 @@ def _forward_shard_slots(params, tokens, k_cache, v_cache, lengths, start,
     return logits, cache.k, cache.v
 
 
+_EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+
+
+def _is_expert_leaf(path) -> bool:
+    """A tree path of an EP-sharded leaf: ``blocks/we_*``."""
+    return path[-1].key in _EXPERT_LEAVES
+
+
 def _strip_shard(p):
     """Drop the per-shard leading dim shard_map hands each member:
     replicated leaves carry it LEADING ([1, ...] broadcast slice); expert
     leaves carry it at axis 1 ([L, 1, E_local, ...] — the sharded W axis
     of shard_params)."""
-    blocks = {}
-    for name, leaf in p["blocks"].items():
-        if name in ("we_gate", "we_up", "we_down"):
-            blocks[name] = leaf[:, 0]
-        else:
-            blocks[name] = leaf[0]
-    return {
-        "embed": p["embed"][0],
-        "blocks": blocks,
-        "final_norm": p["final_norm"][0],
-        "head": p["head"][0],
-    }
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf[:, 0] if _is_expert_leaf(path) else leaf[0],
+        p)
 
 
 class MoEServer:
@@ -301,53 +498,27 @@ class MoEServer:
         w = self.world
         e_local = self.cfg.moe_experts // w
 
-        def place(name, leaf):
-            if name in ("we_gate", "we_up", "we_down"):
+        def place(path, leaf):
+            if _is_expert_leaf(path):
                 l = leaf.shape[0]
                 return leaf.reshape((l, w, e_local) + leaf.shape[2:])
             return jnp.broadcast_to(leaf, (w,) + leaf.shape)
 
-        blocks = {
-            name: place(name, leaf)
-            for name, leaf in params["blocks"].items()
-        }
-        return {
-            "embed": jnp.broadcast_to(
-                params["embed"], (w,) + params["embed"].shape
-            ),
-            "blocks": blocks,
-            "final_norm": jnp.broadcast_to(
-                params["final_norm"], (w,) + params["final_norm"].shape
-            ),
-            "head": jnp.broadcast_to(
-                params["head"], (w,) + params["head"].shape
-            ),
-        }
+        return jax.tree_util.tree_map_with_path(place, params)
 
     def _fn(self, key, build):
         return self._fns.get(key, build)
 
     @staticmethod
-    def _param_specs():
-        # replicated leaves shard their broadcast leading [W] dim;
-        # expert leaves shard the [W] at axis 1 ([L, W, E_local, ...])
-        def block_spec(name):
-            if name in ("we_gate", "we_up", "we_down"):
-                return P(None, _AXIS)
-            return P(_AXIS)
+    def _param_specs(params):
+        """Partition specs of a placed tree, by its own structure:
+        replicated leaves shard their broadcast leading [W] dim; expert
+        leaves shard the [W] at axis 1 ([L, W, E_local, ...])."""
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: P(None, _AXIS) if _is_expert_leaf(path)
+            else P(_AXIS), params)
 
-        return {
-            "embed": P(_AXIS),
-            "blocks": {
-                name: block_spec(name)
-                for name in ("ln1", "ln2", "wq", "wk", "wv", "wo",
-                             "router", "we_gate", "we_up", "we_down")
-            },
-            "final_norm": P(_AXIS),
-            "head": P(_AXIS),
-        }
-
-    def _shard_mapped(self, f, n_in, n_out):
+    def _shard_mapped(self, f, n_in, n_out, params):
         """jit(shard_map(f)) with params first, then n_in P(dp) arrays.
         The compiled program is named after ``f`` (``jit_<f.__name__>`` on
         the profiler's ``XLA Modules`` line, and part of the persistent
@@ -355,7 +526,7 @@ class MoEServer:
         return jax.jit(
             shard_map(
                 f, mesh=self.mesh,
-                in_specs=(self._param_specs(),) + (P(_AXIS),) * n_in,
+                in_specs=(self._param_specs(params),) + (P(_AXIS),) * n_in,
                 out_specs=(P(_AXIS),) * n_out,
                 check_vma=False,
             )
@@ -372,7 +543,7 @@ class MoEServer:
 
         key = ("fwd", impl, tokens.shape, cache.k.shape)
         fn = self._fn(
-            key, lambda: self._shard_mapped(uccl_moe_forward, 4, 4))
+            key, lambda: self._shard_mapped(uccl_moe_forward, 4, 4, params))
         logits, nk, nv, nlen = fn(params, tokens, cache.k, cache.v,
                                   cache.length)
         return logits, MoEKVCache(nk, nv, nlen)
@@ -421,7 +592,16 @@ class MoEServer:
     def slot_cache(self, batch_local: int, max_seq: int) -> MoESlotCache:
         """The engine's fixed [W, B_loc, S_max] KV pool (per-slot lengths)."""
         self._check_drop_free()
-        return MoESlotCache.empty(self.cfg, self.world, batch_local, max_seq)
+        cache = MoESlotCache.empty(self.cfg, self.world, batch_local,
+                                   max_seq)
+        from uccl_tpu.obs import counters as _obsc
+
+        _obsc.gauge(
+            "serving_kv_row_bytes",
+            "bytes one cached position of one layer holds in the slot pool",
+        ).set(sum(math.prod(a.shape[4:]) * a.dtype.itemsize
+                  for a in (cache.k, cache.v)), kind=self.cfg.attn)
+        return cache
 
     @staticmethod
     def _extra_args(sampling, adapters, adapter_ids):
@@ -513,7 +693,7 @@ class MoEServer:
         key = ("prefill_slots", tokens.shape, cache.k.shape,
                sampled, adapted)
         fn = self._fn(key, lambda: self._shard_mapped(
-            uccl_moe_prefill_slots, 7 + len(extra), 4))
+            uccl_moe_prefill_slots, 7 + len(extra), 4, params))
         tok, nk, nv, nlen = fn(params, tokens, prompt_lens, new_mask,
                                start, cache.k, cache.v, cache.lengths,
                                *extra)
@@ -566,7 +746,7 @@ class MoEServer:
         key = ("verify_slots", impl, tokens.shape, cache.k.shape,
                sampled, adapted)
         fn = self._fn(key, lambda: self._shard_mapped(
-            uccl_moe_verify_slots, 5 + len(extra), 5))
+            uccl_moe_verify_slots, 5 + len(extra), 5, params))
         tok, n_acc, nk, nv, nlen = fn(params, tokens, active,
                                       cache.k, cache.v, cache.lengths,
                                       *extra)

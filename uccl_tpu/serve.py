@@ -170,7 +170,39 @@ def _timed_windows(run_full, run_one, batch, new_tokens, reps):
 def _params_dtype(params) -> str:
     import jax
 
-    return str(jax.tree.leaves(params)[0].dtype)
+    # the embedding's: the storage dtype of the matrices (a tree's first
+    # leaf may be a norm, which stays float32 under bfloat16 weights)
+    leaf = params["embed"] if "embed" in params else jax.tree.leaves(params)[0]
+    return str(leaf.dtype)
+
+
+def _moe_cfg(args):
+    """The MoE stack's model description: the published keys of a Hugging
+    Face ``config.json`` (``--model-config``: a ``mixtral``- or a
+    ``glm4_moe_lite``/``deepseek_v3``-shaped file — latent attention, a
+    dense prefix, sigmoid-bias gate, shared expert; weights stored in its
+    ``torch_dtype``), or else the hand-sized flags (the uniform block)."""
+    from uccl_tpu.models.moe_inference import MoEServeConfig
+
+    if not args.model_config:
+        return MoEServeConfig(
+            vocab=args.vocab, dim=args.dim, n_layers=args.layers,
+            n_heads=args.heads, n_kv_heads=args.kv_heads,
+            head_dim=args.dim // args.heads, moe_experts=args.experts,
+            moe_ffn=args.ffn,
+        )
+    if args.ckpt_dir:
+        raise SystemExit("--model-config serves seeded weights; it takes "
+                         "no --ckpt-dir")
+    with open(args.model_config) as f:
+        hf = json.load(f)
+    experts = hf.get("n_routed_experts") or hf["num_local_experts"]
+    return MoEServeConfig.from_hf(
+        hf,
+        # the slot engine needs a drop-free wire: factor * top-k >= experts
+        capacity_factor=max(8.0, experts / hf["num_experts_per_tok"]),
+        param_dtype=hf.get("torch_dtype", "float32"),
+    )
 
 
 def _moe_paths(cfg, impl, world, params):
@@ -182,7 +214,7 @@ def _moe_paths(cfg, impl, world, params):
 
     out = {"dtype": _params_dtype(params), "devices_used": world,
            "prefill_impl": "sort", "decode_impl": impl,
-           "moe_wire": cfg.moe_wire}
+           "moe_wire": cfg.moe_wire, "attn": cfg.attn, "gate": cfg.gate}
     if impl == "ll":
         out["ll_wire"] = "ragged" if wire_supports_ragged() else "dense"
     return out
@@ -328,12 +360,7 @@ def _serve_continuous(args, saved_cfg):
         )
         from uccl_tpu.parallel.mesh import MeshConfig, make_mesh
 
-        cfg = MoEServeConfig(
-            vocab=args.vocab, dim=args.dim, n_layers=args.layers,
-            n_heads=args.heads, n_kv_heads=args.kv_heads,
-            head_dim=args.dim // args.heads, moe_experts=args.experts,
-            moe_ffn=args.ffn,
-        )
+        cfg = _moe_cfg(args)
         n = len(jax.devices())
         world = args.dp or n
         if world > n:
@@ -665,6 +692,11 @@ def main(argv=None):
     ap.add_argument("--kv-heads", type=int, default=2)
     ap.add_argument("--ffn", type=int, default=128)
     ap.add_argument("--experts", type=int, default=8)
+    ap.add_argument("--model-config", default="",
+                    help="MoE stack: a Hugging Face config.json (mixtral or "
+                         "glm4_moe_lite/deepseek_v3 keys) to build the model "
+                         "description from, instead of the size flags; "
+                         "seeded weights in its torch_dtype")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--step", type=int, default=None,
                     help="checkpoint step (default: latest)")
@@ -804,12 +836,7 @@ def main(argv=None):
         obs.dump_from_args(args)
         return summary
 
-    cfg = MoEServeConfig(
-        vocab=args.vocab, dim=args.dim, n_layers=args.layers,
-        n_heads=args.heads, n_kv_heads=args.kv_heads,
-        head_dim=args.dim // args.heads, moe_experts=args.experts,
-        moe_ffn=args.ffn,
-    )
+    cfg = _moe_cfg(args)
     n = len(jax.devices())
     world = args.dp or n
     # fail the cheap flag checks in milliseconds, BEFORE any restore work

@@ -215,8 +215,13 @@ def _model_dims(backend) -> Dict[str, int]:
     cfg = getattr(backend, "cfg", None)
     if cfg is None:
         cfg = backend.server.cfg
-    return {"n_layers": cfg.n_layers, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.head_dim}
+    from uccl_tpu.models.inference import kv_wire_dims
+
+    # the two equal arrays a cached position leaves a pool as: gqa's own
+    # [Hkv, D]; the two halves of a latent row, [1, 288] each
+    heads, width = kv_wire_dims(cfg)
+    return {"n_layers": cfg.n_layers, "n_kv_heads": heads,
+            "head_dim": width}
 
 
 def wire_format_for(backend) -> KVWireFormat:

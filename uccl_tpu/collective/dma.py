@@ -18,7 +18,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.pallas import tpu as pltpu
 
 from uccl_tpu.utils import config as _config
 from uccl_tpu.utils import device as _device
@@ -51,7 +50,22 @@ MAX_INTERP_BYTES = _config.param(
     "bigger payloads fall back to the XLA lowering there",
 )
 
-MESH = pltpu.DeviceIdType.MESH
+
+def _pltpu():
+    """``jax.experimental.pallas.tpu``, imported when a kernel is built and
+    not with this module: the EP layer imports this module for its gates
+    and counters, and a process that only ever takes the lax wire (every
+    serving engine on one chip) should not pay most of a second of start-up
+    for kernels it never builds."""
+    from jax.experimental.pallas import tpu
+
+    return tpu
+
+
+def __getattr__(name):
+    if name == "MESH":  # the kernels' device addressing mode
+        return _pltpu().DeviceIdType.MESH
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 # Every transparent pallas-wire downgrade (chunked → unchunked → lax)
 # increments this counter with its site (`what`) and `reason` — benches and
@@ -241,11 +255,11 @@ def interp(interpret: bool):
     """Value for ``pl.pallas_call(interpret=...)``: the TPU interpreter
     (simulates remote DMAs, semaphores and barriers on host devices) or
     ``False`` for real Mosaic lowering."""
-    return pltpu.InterpretParams() if interpret else False
+    return _pltpu().InterpretParams() if interpret else False
 
 
 def compiler_params(collective_id: int = 0):
-    return pltpu.CompilerParams(
+    return _pltpu().CompilerParams(
         has_side_effects=True, collective_id=collective_id
     )
 
@@ -277,17 +291,17 @@ def mesh_id(axis, idx):
 def remote_kwargs(axis, idx) -> dict:
     """device_id kwargs for make_async_remote_copy / semaphore_signal:
     MESH coordinates, so kernels are sub-axis safe."""
-    return dict(device_id=mesh_id(axis, idx), device_id_type=MESH)
+    return dict(device_id=mesh_id(axis, idx),
+                device_id_type=_pltpu().DeviceIdType.MESH)
 
 
 def ring_barrier(axis, left, right):
     """Neighbor barrier: both ring neighbors' kernels are live (skew along
     the ring is then bounded transitively by the data dependencies)."""
+    pltpu = _pltpu()
     sem = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(sem, inc=1, device_id=mesh_id(axis, left),
-                           device_id_type=MESH)
-    pltpu.semaphore_signal(sem, inc=1, device_id=mesh_id(axis, right),
-                           device_id_type=MESH)
+    for peer in (left, right):
+        pltpu.semaphore_signal(sem, inc=1, **remote_kwargs(axis, peer))
     pltpu.semaphore_wait(sem, 2)
 
 
@@ -296,13 +310,12 @@ def all_barrier(axis, n: int):
     pattern needs this stronger form — its very first DMA may target ANY
     peer's buffers, so neighbor liveness (transitive, eventually) is not
     enough at the moment the DMA issues."""
+    pltpu = _pltpu()
     sem = pltpu.get_barrier_semaphore()
     r = lax.axis_index(axis)
     for i in range(1, n):
         pltpu.semaphore_signal(
-            sem, inc=1, device_id=mesh_id(axis, lax.rem(r + i, n)),
-            device_id_type=MESH,
-        )
+            sem, inc=1, **remote_kwargs(axis, lax.rem(r + i, n)))
     pltpu.semaphore_wait(sem, n - 1)
 
 

@@ -26,7 +26,7 @@ Surfaces:
   (return_recv_hook), and :class:`uccl_tpu.ep.Config` tuning hints.
 """
 
-from uccl_tpu.ep import ll, ops, pallas_a2a
+from uccl_tpu.ep import ll, ops
 from uccl_tpu.ep.buffer import Buffer, Config, EventOverlap, LowLatencyHandle
 from uccl_tpu.ep.cross_pod import CrossPodMoE
 from uccl_tpu.ep.elastic import ElasticBuffer, ElasticKVCache
@@ -46,3 +46,14 @@ __all__ = [
     "EngramTable",
     "mesh_fetch",
 ]
+
+
+def __getattr__(name):
+    # the kernel module (and Pallas with it: most of a second of import) is
+    # loaded when something names it, not with the package: the lax wire,
+    # which every one-chip serving engine takes, never does
+    if name == "pallas_a2a":
+        import importlib
+
+        return importlib.import_module("uccl_tpu.ep.pallas_a2a")
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

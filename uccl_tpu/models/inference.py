@@ -441,6 +441,30 @@ def _lora_delta(h, table, ids, layer):
     return jnp.einsum("bsr,bro->bso", jnp.einsum("bsh,bhr->bsr", h, al), bl)
 
 
+def gather_slots(cache: "SlotKVCache", slots) -> "SlotKVCache":
+    """The rows of ``slots`` ([R] int32) as an R-slot pool: what a compact
+    prefill program runs instead of the whole pool. An index past the pool
+    (a padding row) reads the last slot; :func:`scatter_slots` drops it."""
+    return SlotKVCache(
+        jnp.take(cache.k, slots, axis=1, mode="clip"),
+        jnp.take(cache.v, slots, axis=1, mode="clip"),
+        jnp.take(cache.lengths, slots, mode="clip"),
+    )
+
+
+def scatter_slots(cache: "SlotKVCache", rows: "SlotKVCache",
+                  slots) -> "SlotKVCache":
+    """``cache`` with ``rows`` written back at ``slots``; every slot not
+    named keeps its rows and length. A padding row names an index past the
+    pool and is dropped, so two rows never write one slot (the real rows of
+    a call name distinct slots)."""
+    return SlotKVCache(
+        cache.k.at[:, slots].set(rows.k, mode="drop"),
+        cache.v.at[:, slots].set(rows.v, mode="drop"),
+        cache.lengths.at[slots].set(rows.lengths, mode="drop"),
+    )
+
+
 def _forward_slots(
     params, tokens, cache: SlotKVCache, start, write_mask, cfg, ffn=None,
     adapters=None, adapter_ids=None,
@@ -496,7 +520,7 @@ def _forward_slots(
 def prefill_slots(
     params, tokens, prompt_lens, new_mask, cache: SlotKVCache,
     cfg: DenseConfig, start=None, sampling=None, adapters=None,
-    adapter_ids=None,
+    adapter_ids=None, slots=None,
 ) -> Tuple[jax.Array, SlotKVCache]:
     """Masked batched prefill of newly admitted slots — resumable.
 
@@ -521,9 +545,20 @@ def prefill_slots(
     the lockstep-keyed sample at output position ``pos0`` (the engine
     passes zeros: the first token is output index 0; ``temp <= 0`` rows
     stay greedy).
+
+    ``slots`` ([R] int32) makes the call COMPACT: every per-slot argument
+    and the returned token are [R], row r belonging to slot ``slots[r]``;
+    the program gathers those R slots' rows and lengths, runs the same
+    forward over R rows and scatters them back, so slots not named are
+    untouched and the work is R rows, not the pool's. Real rows name
+    distinct slots; a padding row (``new_mask`` false) names an index past
+    the pool and is dropped by the scatter.
     """
     if start is None:
         start = jnp.zeros_like(prompt_lens)
+    pool = cache
+    if slots is not None:
+        cache = gather_slots(pool, slots)
     logits, cache = _forward_slots(
         params, tokens, cache, start, new_mask, cfg,
         adapters=adapters, adapter_ids=adapter_ids,
@@ -544,7 +579,10 @@ def prefill_slots(
     lengths = jnp.where(
         new_mask, jnp.minimum(start + s, prompt_lens), cache.lengths
     )
-    return tok, SlotKVCache(cache.k, cache.v, lengths)
+    cache = SlotKVCache(cache.k, cache.v, lengths)
+    if slots is not None:
+        cache = scatter_slots(pool, cache, slots)
+    return tok, cache
 
 
 def verify_slots(

@@ -33,12 +33,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from jax import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from uccl_tpu.ep import ops as ep_ops
 from uccl_tpu.models.inference import (
     KVCache, SlotKVCache, _dense_ffn, _forward_cached, _forward_slots,
-    greedy_acceptance, kv_row_shapes, spec_advance,
+    gather_slots, greedy_acceptance, kv_row_shapes, scatter_slots,
+    spec_advance,
 )
 from uccl_tpu.models.sampling import (
     broadcast_params, sample_tokens, sample_window,
@@ -191,22 +192,33 @@ class MoESlotCache(NamedTuple):
 
     @staticmethod
     def empty(cfg: MoEServeConfig, world: int, batch_local: int,
-              max_seq: int, dtype=jnp.float32) -> "MoESlotCache":
+              max_seq: int, dtype=jnp.float32,
+              sharding=None) -> "MoESlotCache":
         lead = (world, cfg.n_layers, batch_local, max_seq)
         k_row, v_row = kv_row_shapes(cfg)
         return MoESlotCache(
-            jnp.zeros(lead + k_row, dtype), jnp.zeros(lead + v_row, dtype),
-            jnp.zeros((world, batch_local), jnp.int32),
+            jnp.zeros(lead + k_row, dtype, device=sharding),
+            jnp.zeros(lead + v_row, dtype, device=sharding),
+            jnp.zeros((world, batch_local), jnp.int32, device=sharding),
         )
 
     # -- slot KV export/import views (the disaggregation surface) ----------
     #
     # Mirrors inference.SlotKVCache: a flat slot id s maps to grid row
     # (w, b) = (s // B_loc, s % B_loc). Exports/imports go through host
-    # numpy round-trips — np.asarray gathers a sharded pool, and the next
-    # shard_mapped call re-shards the rebuilt arrays — which keeps the
-    # surface correct on any mesh at the cost of a pool copy per call
-    # (admission-rate work, not step-rate).
+    # numpy round-trips — np.asarray gathers a sharded pool, and the rebuilt
+    # arrays go back under the placement the pool had (``_placed_like``) —
+    # which keeps the surface correct on any mesh at the cost of a pool copy
+    # per call (admission-rate work, not step-rate).
+
+    def _placed_like(self, k, v, lengths) -> "MoESlotCache":
+        """Host arrays as a pool placed the way this one is. jit keys its
+        executables on its arguments' placement, so a pool handed back any
+        other way (``jnp.asarray``: uncommitted, one device) would make
+        every serving program trace, lower and load a second time."""
+        return MoESlotCache(*(
+            jax.device_put(new, old.sharding)
+            for new, old in zip((k, v, lengths), self)))
 
     def _loc(self, slot: int):
         b_loc = self.k.shape[2]
@@ -254,8 +266,7 @@ class MoESlotCache(NamedTuple):
         k[w, :, b, :n] = np.asarray(k_rows, k.dtype)
         v[w, :, b, :n] = np.asarray(v_rows, v.dtype)
         lengths[w, b] = length
-        return MoESlotCache(jnp.asarray(k), jnp.asarray(v),
-                            jnp.asarray(lengths))
+        return self._placed_like(k, v, lengths)
 
     def copy_prefix(self, dst: int, src: int, n: int) -> "MoESlotCache":
         import numpy as np
@@ -268,8 +279,7 @@ class MoESlotCache(NamedTuple):
         k[dw, :, db, :n] = k[sw, :, sb, :n]
         v[dw, :, db, :n] = v[sw, :, sb, :n]
         lengths[dw, db] = n
-        return MoESlotCache(jnp.asarray(k), jnp.asarray(v),
-                            jnp.asarray(lengths))
+        return self._placed_like(k, v, lengths)
 
 
 # Which draw a leaf comes from. The uniform block's leaves keep the twelve-
@@ -485,7 +495,11 @@ class MoEServer:
         # the shared LRU-bounded compiled-fn pattern (utils/lru.py): a
         # long-lived serving process sweeping shapes (prefill buckets,
         # several decode batch tiers, varying scan lengths) would
-        # otherwise retain a compiled executable per shape forever
+        # otherwise retain a compiled executable per shape forever. A
+        # chunked engine's steady set is four programs (the three prefill
+        # rungs [1 | 2 | B_loc, C] and its decode OR verify program), times
+        # the four sampled x adapted variants a fully featured server
+        # meets: 16 entries, so none of them evicts another
         self._fns = LRUFnCache(16)
 
     # -- parameter placement ------------------------------------------------
@@ -590,10 +604,18 @@ class MoEServer:
             )
 
     def slot_cache(self, batch_local: int, max_seq: int) -> MoESlotCache:
-        """The engine's fixed [W, B_loc, S_max] KV pool (per-slot lengths)."""
+        """The engine's fixed [W, B_loc, S_max] KV pool (per-slot lengths),
+        born as the slot programs return it: committed, under the sharding
+        their ``out_specs`` give (over one shard JAX hands ``P(dp)`` back
+        as ``P()``). jit keys its traces on that, so a pool of plain
+        ``jnp.zeros`` (uncommitted) made a program's second call — on the
+        pool the first gave back — trace, lower and load it all over
+        again: every serving program's start-up was paid twice."""
         self._check_drop_free()
-        cache = MoESlotCache.empty(self.cfg, self.world, batch_local,
-                                   max_seq)
+        cache = MoESlotCache.empty(
+            self.cfg, self.world, batch_local, max_seq,
+            sharding=NamedSharding(
+                self.mesh, P(_AXIS) if self.world > 1 else P()))
         from uccl_tpu.obs import counters as _obsc
 
         _obsc.gauge(
@@ -638,7 +660,7 @@ class MoEServer:
 
     def prefill_slots(self, params, tokens, prompt_lens, new_mask,
                       cache: MoESlotCache, start=None, sampling=None,
-                      adapters=None, adapter_ids=None):
+                      adapters=None, adapter_ids=None, slots=None):
         """Masked batched prefill of newly admitted slots (sorted EP path)
         — resumable, mirroring :func:`inference.prefill_slots`.
 
@@ -659,20 +681,35 @@ class MoEServer:
         lockstep-keyed sample instead of the argmax (mirrors
         :func:`inference.prefill_slots`). ``adapters``/``adapter_ids``
         fuse the per-slot LoRA delta (tables broadcast [W, ...],
-        ids gridded [W, B_loc])."""
+        ids gridded [W, B_loc]).
+
+        ``slots`` ([W, R] int32 local slot indices) makes the call COMPACT,
+        as in :func:`inference.prefill_slots`: every per-slot argument and
+        the returned token are [W, R]; each shard gathers its R rows of the
+        pool, runs the same forward over R rows (so ``expert_capacity``
+        sees R * S tokens and the queues shrink with R; the wire stays
+        drop-free) and scatters them back. Slots not named are untouched; a
+        padding row (``new_mask`` false) names an index past the pool."""
         self._check_drop_free()
         cfg = self.cfg
         s = tokens.shape[-1]
         if start is None:
             start = jnp.zeros_like(prompt_lens)
         sampled, adapted = sampling is not None, adapters is not None
+        compact = slots is not None
         extra = self._extra_args(sampling, adapters, adapter_ids)
+        if compact:
+            extra = [slots] + extra
 
         def uccl_moe_prefill_slots(p, tok, lens, mask, off, kc, vc, ln,
                                    *rest):
+            pool = rows = SlotKVCache(kc[0], vc[0], ln[0])
+            if compact:
+                idx, rest = rest[0][0], rest[1:]
+                rows = gather_slots(pool, idx)
             samp, adp, ids = self._split_extra(rest, sampled, adapted)
             logits, nk, nv = _forward_shard_slots(
-                _strip_shard(p), tok[0], kc[0], vc[0], ln[0],
+                _strip_shard(p), tok[0], rows.k, rows.v, rows.lengths,
                 off[0], mask[0], cfg, "sort",
                 adapters=adp, adapter_ids=ids,
             )
@@ -686,12 +723,15 @@ class MoEServer:
                 seeds, pos0, temp, top_p, top_k = samp
                 t = sample_tokens(seeds, pos0, last, temp, top_p, top_k)
             nlen = jnp.where(
-                mask[0], jnp.minimum(off[0] + s, lens[0]), ln[0]
+                mask[0], jnp.minimum(off[0] + s, lens[0]), rows.lengths
             )
+            if compact:
+                nk, nv, nlen = scatter_slots(
+                    pool, SlotKVCache(nk, nv, nlen), idx)
             return t[None], nk[None], nv[None], nlen[None]
 
         key = ("prefill_slots", tokens.shape, cache.k.shape,
-               sampled, adapted)
+               sampled, adapted, compact)
         fn = self._fn(key, lambda: self._shard_mapped(
             uccl_moe_prefill_slots, 7 + len(extra), 4, params))
         tok, nk, nv, nlen = fn(params, tokens, prompt_lens, new_mask,

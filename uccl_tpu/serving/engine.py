@@ -13,11 +13,13 @@ arriving prompt then stalls every in-flight decode for the full prefill —
 each admitted request advances a prefill cursor by ONE fixed-size chunk of
 C tokens per step, and the step still runs its single decode pass. A decode
 therefore never waits behind more than one chunk (the stall bound, tested),
-and the prefill program compiles ONCE at [n_slots, C] instead of once per
-pow2 bucket. ``step_tokens`` adds a per-step token budget (decode token =
-1, prefill chunk = C): admission is deferred while the step's committed
-spend would exceed it. ``prefill_chunk=None`` (default) is the PR 3
-whole-prompt path, unchanged.
+and the prefill program exists at ONE width C instead of once per pow2
+bucket — in three row counts, [1 | 2 | n_slots, C] (:func:`prefill_rung`):
+a chunk step sends the model only the rows of the slots that are
+prefilling, one, two or the pool. ``step_tokens`` adds a per-step token
+budget (decode token = 1, prefill chunk = C): admission is deferred while
+the step's committed spend would exceed it. ``prefill_chunk=None``
+(default) is the PR 3 whole-prompt path, unchanged.
 
 The engine is exact, not approximate: each request's emitted tokens are
 bit-identical to the one-shot ``generate`` oracle for the same prompt
@@ -70,6 +72,11 @@ _PREFILL_TOKENS = obs.counter(
     "serving_prefill_tokens_total",
     "prompt tokens per prefill path: kind=computed ran the model, "
     "kind=skipped were reused from the prefix cache (the auditable cut)",
+)
+_PREFILL_RUNG = obs.counter(
+    "serving_prefill_rung_total",
+    "chunked-prefill program calls per rung (labels: rows = the slot rows "
+    "the program ran: 1, 2 or the whole pool)",
 )
 _DROPPED = obs.counter(
     "serving_rejected_total",
@@ -167,13 +174,96 @@ def _bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
-class DenseBackend:
+def prefill_rung(n: int, n_slots: int) -> int:
+    """Rows of the chunked-prefill program for ``n >= 1`` prefilling slots
+    of a pool of ``n_slots``: one, two, or the whole pool. Three rungs and
+    not every power of two, because a rung's price is one more program
+    traced, lowered and loaded at start-up, which grows with the model's
+    depth (the layers are unrolled); most chunk steps carry one prefilling
+    slot and the next most two; and in a burst the program is bound by
+    reading the expert weights whatever its rows, so looping a small rung
+    would lose to the whole-pool program."""
+    return n if n <= min(2, n_slots) else n_slots
+
+
+def prefill_rungs(n_slots: int) -> tuple:
+    """Every value :func:`prefill_rung` takes over a pool of ``n_slots``
+    (deduplicated: a pool of one or two slots has fewer than three)."""
+    return tuple(sorted({prefill_rung(n, n_slots) for n in (1, 2, 3)}))
+
+
+class _PrefillRungs:
+    """What the two backends share of the chunked prefill's rungs:
+    ``prefill_rungs`` (the attribute the engine reads to size a chunked
+    call, :func:`prefill_rung`), the ``prefill`` entry in its two forms, and
+    building every rung with the first. The backend brings
+    ``_run_prefill`` (stage, launch, fetch of one program) and ``n_slots``.
+    """
+
+    def _init_rungs(self, rungs: tuple) -> None:
+        self.prefill_rungs = rungs
+        self._rungs_built = set()  # (chunk, sampled, adapted) kinds
+
+    def prefill(self, tokens: np.ndarray, lens: np.ndarray,
+                mask: np.ndarray,
+                start: Optional[np.ndarray] = None,
+                sampling=None, adapters=None,
+                slots: Optional[np.ndarray] = None) -> np.ndarray:
+        """One prefill program. Whole-pool form: every argument is
+        [n_slots, ...] and row s is slot s. Compact form (``slots`` [R]
+        given, a chunked call on a rung below the pool): every argument and
+        the returned tokens are [R, ...] and row r is slot ``slots[r]``."""
+        if start is None:
+            start = np.zeros(tokens.shape[0], np.int32)
+        else:  # a chunked call
+            self._build_other_rungs(*tokens.shape, sampling, adapters)
+        return self._run_prefill(tokens, lens, mask, start, sampling,
+                                 adapters, slots)
+
+    def _build_other_rungs(self, rows: int, chunk: int, sampling,
+                           adapters) -> None:
+        """All rungs are built when the first one is: before the first
+        chunked call of a (chunk, sampled, adapted) kind runs its own rung
+        (``rows``), run each OTHER rung once with an all-false mask on the
+        live pool — a no-op on its contents — so a later change of
+        occupancy finds its program traced, lowered and loaded. A warm-up
+        that only ever has one slot prefilling then leaves nothing to
+        compile in flight."""
+        key = (chunk, sampling is not None, adapters is not None)
+        if key in self._rungs_built:
+            return
+        self._rungs_built.add(key)
+        for r in self.prefill_rungs:
+            if r == rows:
+                continue
+            samp = adp = None
+            if sampling is not None:
+                samp = tuple(np.zeros(r, np.asarray(a).dtype)
+                             for a in sampling)
+            if adapters is not None:
+                adp = (adapters[0], np.zeros(r, np.int32))
+            self._run_prefill(
+                np.zeros((r, chunk), np.int32), np.ones(r, np.int32),
+                np.zeros(r, bool), np.zeros(r, np.int32), samp, adp,
+                # padding rows all: an index past the pool, dropped on the
+                # way back; the pool rung is the ungathered program
+                None if r == self.n_slots
+                else np.full(r, self.n_slots, np.int32))
+
+
+class DenseBackend(_PrefillRungs):
     """Slot-pool serving over the dense KV stack (models/inference.py).
 
     ``fns`` shares another backend's compiled-program cache: the jitted
     programs are pure in params/cache (nothing baked but shapes), so N
     replica backends of the same (cfg, n_slots, max_seq) can reuse ONE
-    compile set — a replica set costs one warmup, not N."""
+    compile set — a replica set costs one warmup, not N.
+
+    Its prefill runs at the three rungs (:class:`_PrefillRungs`). A chunked
+    engine's steady set in the ``LRUFnCache(16)`` is the compact and the
+    whole-pool prefill function and its decode OR verify one, times the four
+    sampled x adapted variants: 12 entries (jit keeps the two compact row
+    counts under one function)."""
 
     def __init__(self, params, cfg, *, n_slots: int, max_seq: int,
                  fns: Optional[LRUFnCache] = None):
@@ -186,10 +276,12 @@ class DenseBackend:
         self.n_slots = n_slots
         self.max_seq = max_seq
         self.cache = SlotKVCache.empty(cfg, n_slots, max_seq)
+        self._init_rungs(prefill_rungs(n_slots))
         self._fns = fns if fns is not None else LRUFnCache(16)
         self._jax = jax
 
-    def _prefill_fn(self, s: int, sampled: bool, adapted: bool):
+    def _prefill_fn(self, s: int, sampled: bool, adapted: bool,
+                    compact: bool = False):
         jax = self._jax
         cfg = self.cfg
 
@@ -198,17 +290,21 @@ class DenseBackend:
 
             def uccl_dense_prefill_slots(p, tok, lens, mask, off, kc, vc,
                                          ln, *rest):
+                slots = None
+                if compact:
+                    slots, rest = rest[0], rest[1:]
                 samp, adp, ids = _split_extra(rest, sampled, adapted)
                 t, cache = prefill_slots(
                     p, tok, lens, mask, SlotKVCache(kc, vc, ln), cfg,
                     start=off, sampling=samp, adapters=adp,
-                    adapter_ids=ids,
+                    adapter_ids=ids, slots=slots,
                 )
                 return t, cache.k, cache.v, cache.lengths
 
             return jax.jit(uccl_dense_prefill_slots)
 
-        return self._fns.get(("prefill", s, sampled, adapted), build)
+        return self._fns.get(("prefill", s, sampled, adapted, compact),
+                             build)
 
     def _decode_fn(self, sampled: bool, adapted: bool):
         jax = self._jax
@@ -250,18 +346,16 @@ class DenseBackend:
 
         return self._fns.get(("verify", s, sampled, adapted), build)
 
-    def prefill(self, tokens: np.ndarray, lens: np.ndarray,
-                mask: np.ndarray,
-                start: Optional[np.ndarray] = None,
-                sampling=None, adapters=None) -> np.ndarray:
+    def _run_prefill(self, tokens, lens, mask, start, sampling, adapters,
+                     slots) -> np.ndarray:
         from uccl_tpu.models.inference import SlotKVCache
 
         with obs.span("backend.stage", "wire"):
-            if start is None:
-                start = np.zeros(tokens.shape[0], np.int32)
             fn = self._prefill_fn(tokens.shape[1], sampling is not None,
-                                  adapters is not None)
+                                  adapters is not None, slots is not None)
             extra = _flat_extra(sampling, adapters)
+            if slots is not None:
+                extra = [slots] + extra
         with obs.span("backend.launch", "wire"):
             t, k, v, ln = fn(self.params, tokens, lens, mask, start,
                              self.cache.k, self.cache.v, self.cache.lengths,
@@ -318,11 +412,17 @@ class DenseBackend:
         self.cache = self.cache.copy_prefix(dst, src, n)
 
 
-class MoEBackend:
+class MoEBackend(_PrefillRungs):
     """Slot-pool serving over the EP-sharded MoE stack: slots are the
     [W, B_loc] rows of the server's cache (slot s ↔ shard s // B_loc, row
     s % B_loc); prefill routes through the sorted EP path, decode through
-    the packed LL path (the DeepEP decode regime) by default."""
+    the packed LL path (the DeepEP decode regime) by default.
+
+    On one shard its prefill runs at the three rungs
+    (:class:`_PrefillRungs`); over ``world > 1`` shards the backend declares
+    the whole-pool rung alone (a compact call there would have to be sized
+    by the fullest shard and padded per shard; no cell runs it, so it keeps
+    the program it had)."""
 
     def __init__(self, server, params, *, batch_local: int, max_seq: int,
                  decode_impl: str = "ll"):
@@ -334,13 +434,17 @@ class MoEBackend:
         self.max_seq = max_seq
         self.decode_impl = decode_impl
         self.cache = server.slot_cache(batch_local, max_seq)
+        self._init_rungs(prefill_rungs(self.n_slots) if self.world == 1
+                         else (self.n_slots,))
 
     def _grid(self, flat: np.ndarray, dtype) -> "np.ndarray":
+        """A flat per-row array on the [W, rows-per-shard] shard layout
+        (B_loc rows a shard, or a compact call's R on the one shard)."""
         import jax.numpy as jnp
 
+        flat = np.asarray(flat)
         return jnp.asarray(
-            np.asarray(flat).reshape((self.world, self.b_loc)
-                                     + flat.shape[1:]).astype(dtype)
+            flat.reshape((self.world, -1) + flat.shape[1:]).astype(dtype)
         )
 
     def _extra(self, sampling, adapters):
@@ -366,25 +470,23 @@ class MoEBackend:
             ids = self._grid(flat_ids, np.int32)
         return samp, adp, ids
 
-    def prefill(self, tokens: np.ndarray, lens: np.ndarray,
-                mask: np.ndarray,
-                start: Optional[np.ndarray] = None,
-                sampling=None, adapters=None) -> np.ndarray:
+    def _run_prefill(self, tokens, lens, mask, start, sampling, adapters,
+                     slots) -> np.ndarray:
         with obs.span("backend.stage", "wire"):
-            if start is None:
-                start = np.zeros(tokens.shape[0], np.int32)
             samp, adp, ids = self._extra(sampling, adapters)
             tokens = self._grid(tokens, np.int32)
             lens = self._grid(lens, np.int32)
             mask = self._grid(mask, bool)
             start = self._grid(start, np.int32)
+            if slots is not None:
+                slots = self._grid(slots, np.int32)
         with obs.span("backend.launch", "wire"):
             t, self.cache = self.server.prefill_slots(
                 self.params, tokens, lens, mask, self.cache, start=start,
-                sampling=samp, adapters=adp, adapter_ids=ids,
+                sampling=samp, adapters=adp, adapter_ids=ids, slots=slots,
             )
         with obs.span("backend.fetch", "wire"):
-            return np.asarray(t).reshape(self.n_slots)
+            return np.asarray(t).reshape(-1)
 
     def decode(self, tokens: np.ndarray, active: np.ndarray,
                sampling=None, adapters=None) -> np.ndarray:
@@ -510,8 +612,9 @@ class ServingEngine:
     """submit()/step()/drain() over a backend (Dense or MoE).
 
     ``prefill_chunk=C`` enables chunked prefill: admitted requests advance
-    their prefill cursor by one C-token chunk per step (one compiled
-    prefill program at [n_slots, C]) and in-flight decodes run every step —
+    their prefill cursor by one C-token chunk per step (one prefill program
+    at [R, C], R the rung of the slots prefilling: 1, 2 or n_slots) and
+    in-flight decodes run every step —
     no decode ever waits behind more than one chunk. ``step_tokens`` caps a
     step's committed token spend (decode slot = 1 token, or 1+k under
     speculation; prefill chunk = C) by deferring admission; it requires
@@ -1496,7 +1599,11 @@ class ServingEngine:
                             events: Optional[List[ChunkEvent]] = None,
                             ) -> None:
         """Advance every mid-prefill slot by one C-token chunk (ONE batched
-        call, one compiled program at [n_slots, C]). Rows whose cursor
+        call). The call carries R rows, the rung of :func:`prefill_rung` for
+        the slots that are prefilling: below the pool's size the rows are
+        COMPACT — row i is the i-th prefilling slot, named in ``slots``, and
+        the model runs those rows of the pool and no others; at the pool's
+        size row s is slot s, as ever. Rows whose cursor
         reaches the prompt end emit their first token and leave
         PARTIAL_PREFILL; other rows' returned tokens are garbage by the
         model contract and ignored here. ``events`` carries this step's
@@ -1505,23 +1612,43 @@ class ServingEngine:
         so a sink can export rows while slots still hold them."""
         c = self.prefill_chunk
         n = self.backend.n_slots
-        tokens = np.zeros((n, c), np.int32)
-        lens = np.ones(n, np.int32)  # 1 (not 0): the gather index
-        start = np.zeros(n, np.int32)  # clip stays in bounds on idle rows
-        mask = np.zeros(n, bool)
-        for slot, req in self._prefilling.items():
+        rows = list(self._prefilling.items())
+        r = prefill_rung(len(rows), n)
+        if r not in getattr(self.backend, "prefill_rungs", ()):
+            r = n  # the rung every backend has
+        compact = r < n
+        row_of = {slot: i if compact else slot
+                  for i, (slot, _) in enumerate(rows)}
+        tokens = np.zeros((r, c), np.int32)
+        lens = np.ones(r, np.int32)  # 1 (not 0): the gather index
+        start = np.zeros(r, np.int32)  # clip stays in bounds on idle rows
+        mask = np.zeros(r, bool)
+        for slot, req in rows:
+            row = row_of[slot]
             chunk = req.prompt[req.prefill_pos:req.prefill_pos + c]
-            tokens[slot, :chunk.size] = chunk
-            lens[slot] = req.prompt.size
-            start[slot] = req.prefill_pos
-            mask[slot] = True
+            tokens[row, :chunk.size] = chunk
+            lens[row] = req.prompt.size
+            start[row] = req.prefill_pos
+            mask[row] = True
+        kw = self._extra_kw(rows)
+        if compact:
+            # a padding row names no slot (an index past the pool); the
+            # per-slot extras travel with their rows (a padding row takes
+            # any slot's: it is masked); the adapter tables are not per-slot
+            slots = kw["slots"] = np.full(r, n, np.int32)
+            slots[:len(rows)] = [slot for slot, _ in rows]
+            at = np.minimum(slots, n - 1)
+            if "sampling" in kw:
+                kw["sampling"] = tuple(a[at] for a in kw["sampling"])
+            if "adapters" in kw:
+                tables, ids = kw["adapters"]
+                kw["adapters"] = (tables, ids[at])
+        _PREFILL_RUNG.inc(rows=r)
         tr = obs.get_tracer()
         ts0 = tr.now_us() if tr is not None else 0.0
         t0 = now()
-        rows = list(self._prefilling.items())
-        with obs.span("wire.prefill", "wire", n=len(rows), chunk=c):
-            tok = self.backend.prefill(tokens, lens, mask, start=start,
-                                       **self._extra_kw(rows))
+        with obs.span("wire.prefill", "wire", n=len(rows), chunk=c, rows=r):
+            tok = self.backend.prefill(tokens, lens, mask, start=start, **kw)
         self.metrics.on_prefill(now() - t0, len(self._prefilling),
                                 chunked=True)
         t_done = now()
@@ -1542,7 +1669,7 @@ class ServingEngine:
                 computed += req.prefill_pos - old
                 events.append(ChunkEvent(
                     req, slot, old, req.prefill_pos, done,
-                    int(tok[slot]) if done else None, False,
+                    int(tok[row_of[slot]]) if done else None, False,
                 ))
                 advanced.append((slot, req, done))
             _PREFILL_TOKENS.inc(computed, kind="computed")
@@ -1559,7 +1686,7 @@ class ServingEngine:
                     continue  # more chunks to go — next step
                 del self._prefilling[slot]
                 req.state = RequestState.ACTIVE
-                self._emit_first_token(slot, req, tok[slot], t_done,
+                self._emit_first_token(slot, req, tok[row_of[slot]], t_done,
                                        finished)
 
     def _decode(self, finished) -> None:

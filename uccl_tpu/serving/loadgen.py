@@ -170,8 +170,9 @@ def warm_engine(engine: ServingEngine, lens, max_seq: int,
     percentiles would report compile time, not serving time.
 
     Whole-prompt mode compiles one program per pow2 bucket (one
-    representative length each). Chunked mode has exactly ONE prefill
-    program — [n_slots, C] regardless of prompt length — so a single
+    representative length each). Chunked mode has ONE prefill width — C
+    regardless of prompt length, and a backend builds all its rungs
+    ([1 | 2 | n_slots, C]) on its first chunked call — so a single
     longest-length request covers it (and exercises the multi-chunk
     resume path while it's at it). Min 2 tokens either way — a 1-token
     warmup retires at prefill and would leave the decode program cold."""

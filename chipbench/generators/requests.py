@@ -3,9 +3,15 @@
 
 Every seed gets the SAME multiset of prompt lengths, output lengths and
 inter-arrival gaps — the distribution's own quantiles at the midpoints of
-``n`` equal-probability strata — and a seed-drawn order of each. So two
-seeds offer the same work in the same window and differ in who arrives
-beside whom; a run-to-run difference is then the system's, not the draw's.
+``n`` equal-probability strata — in a drawn order of each. Without
+``order_seed`` the order is drawn from the run's seed: two seeds offer the
+same work in the same window and differ in who arrives beside whom. Since
+PR 28 that alone changes the work (three prompts prefilling together run
+the pool-wide program at five times the price of one or two), so a cell's
+mix pins the order with ``order_seed``: every seed then offers the same
+lengths at the same times, one fixed schedule as a load generator replays
+it, and the seed draws the tokens (and the weights). A run-to-run
+difference is then the system's, not the draw's.
 
 A mix file (``chipbench/traffic/<mix>.json``) holds::
 
@@ -15,6 +21,8 @@ A mix file (``chipbench/traffic/<mix>.json``) holds::
      "prompt_len": {"dist": "lognormal", "median": 256, "sigma": 0.8,
                     "min": 32, "max": 2048},
      "output_len": {"dist": "uniform", "min": 8, "max": 32},
+     "order_seed": 576721147,           # optional: the order of lengths and
+                                        # gaps is drawn from this, not --seed
      "drain_s": 15,                     # cap on finishing what holds a slot
      "attempted": "due"}                # or "admitted" (a backlog)
 
@@ -72,11 +80,14 @@ def n_requests(mix: dict, seconds: float) -> int:
 def generate(mix: dict, seed: int, seconds: float, vocab: int) -> Traffic:
     """The window's requests. ``open_loop``: round(rate x seconds) requests
     whose gaps are the exponential distribution's stratum quantiles in a
-    seed-drawn order, scaled so the last falls due just inside the window (a
+    drawn order, scaled so the last falls due just inside the window (a
     Poisson stream's gaps without its sampling noise in their sum).
-    ``backlog``: ``requests`` requests all due at 0."""
+    ``backlog``: ``requests`` requests all due at 0. With ``order_seed`` in
+    the mix the three orders are what ``generate(mix, order_seed, ...)``
+    drew before the key existed, and the tokens come from the seed."""
     n = n_requests(mix, seconds)
-    rng = np.random.default_rng(seed)
+    pinned = "order_seed" in mix
+    rng = np.random.default_rng(mix["order_seed"] if pinned else seed)
     plens = rng.permutation(quantile_lengths(mix["prompt_len"], n))
     olens = rng.permutation(quantile_lengths(mix["output_len"], n))
     if mix["generator"] == "backlog":
@@ -89,5 +100,7 @@ def generate(mix: dict, seed: int, seconds: float, vocab: int) -> Traffic:
         span = seconds * (n - 0.5) / n
         due = np.concatenate([[0.0], np.cumsum(gaps)]) * (
             span / max(float(np.sum(gaps)), 1e-9))
+    if pinned:
+        rng = np.random.default_rng([seed, 0x70CE25])  # the tokens' own stream
     prompts = [rng.integers(0, vocab, int(l)).astype(np.int32) for l in plens]
     return Traffic(due_s=due, prompts=prompts, output_lens=olens)

@@ -1,6 +1,6 @@
-"""Device time of the expert GEMMs (scope ``moe.experts``) in one
-``[slots, chunk]`` prefill program: the operations that start inside a
-``uccl.wire.prefill`` span, median over the window's spans."""
+"""Device time of the expert GEMMs (scope ``moe.experts``) in one prefill
+program (``[1 | 2 | slots, chunk]`` since PR 28): the operations that start
+inside a ``uccl.wire.prefill`` span, median over the window's spans."""
 
 from chipbench import program_trace as pt
 

@@ -1,6 +1,7 @@
-"""Device-busy time of one ``[slots, chunk]`` prefill program (the
-operations that start inside the benchmark's span around
-``backend.prefill``), median."""
+"""Device-busy time of one prefill program (the operations that start
+inside the benchmark's span around ``backend.prefill``), median over the
+window's: since PR 28 the program over the prefilling slots' rows,
+``[1 | 2, chunk]``, and the pool's ``[slots, chunk]`` with three or more."""
 
 from chipbench.runners.serve import NAME_PREFILL
 from chipbench.stats import percentile

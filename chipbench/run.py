@@ -5,10 +5,11 @@
 A new process each time. Refuses (non-zero exit, no result line) without a
 TPU whose ``device_kind`` is in ``chipbench/peaks.json`` or with fewer
 chips than the cell asks for. The last line of standard output is one JSON
-object with exactly ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` (and ``breakdown`` when traced); everything else — sample
-counts, generator lateness, each number compared beside its limit — is on
-earlier lines.
+object with ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
+(and ``breakdown`` when traced) and, last, ``compared``: each number that
+decided ``correct`` with its limit, which are also the last lines of
+standard error; everything else — sample counts, generator lateness — is
+on earlier lines.
 
 Driven by data: a cell is an entry of ``workloads``; its configuration is
 ``configs[].file``, its traffic ``chipbench/traffic/<traffic>.json``, and
@@ -182,9 +183,13 @@ def run_cell(bench: dict, workload: str, cfg: dict, mix: dict, ctx: Ctx
                                "setup_phases_s")
         if k in record}))
     correct = True
+    compared = {}
     for name, value, limit in record["compared"]:
         ok = bool(value <= limit)
         correct = correct and ok
+        # plain numbers: a numpy integer does not go through json.dumps
+        compared[name] = {"value": getattr(value, "item", lambda: value)(),
+                          "limit": getattr(limit, "item", lambda: limit)()}
         log(f"chipbench: compare {name} value={value!r} limit={limit!r} "
             f"ok={ok}")
     dev = jax.devices()[0]
@@ -199,6 +204,7 @@ def run_cell(bench: dict, workload: str, cfg: dict, mix: dict, ctx: Ctx
             if value is not None:
                 result["metrics"][m["name"]] = {"value": value,
                                                 "unit": m["unit"]}
+        result["compared"] = compared
         return result
     from chipbench import trace_reduce as tr
 
@@ -217,6 +223,7 @@ def run_cell(bench: dict, workload: str, cfg: dict, mix: dict, ctx: Ctx
             "idle_gaps": tr.idle_gaps(view.ops(0), *view.window,
                                       view.host_spans, 10),
         }
+    result["compared"] = compared  # the line's last key
     return result
 
 
@@ -284,6 +291,10 @@ def main(argv=None) -> int:
               compile_counter=CompileCounter())
     result = run_cell(bench, cell["name"], cfg, mix, ctx)
     print(json.dumps(result), flush=True)
+    # each number compared beside its limit, as standard error's last lines
+    for name, c in result["compared"].items():
+        print(f"chipbench: compare {name} value={c['value']!r} "
+              f"limit={c['limit']!r}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -56,6 +56,7 @@ class SpannedBackend:
 class Served:
     """What the window saw of one request."""
     due_s: float
+    prompt_tokens: int  # known before the request is offered
     submit_s: float = float("nan")
     req: object = None
     stamps: List[float] = field(default_factory=list)  # one per output token
@@ -104,11 +105,11 @@ def build(cfg: dict, seed: int, rec):
 
 
 def warm(engine, chunk: int) -> None:
-    """prefill -> decode -> prefill -> decode: the one ``[slots, chunk]``
-    prefill program and the decode program, each also with the slot cache
-    as the other leaves it (the pool is born uncommitted and comes back
-    placed, which compiled a second time inside a measured window once —
-    PERF.md "Bring-up")."""
+    """prefill -> decode -> prefill -> decode: the prefill programs (every
+    rung on the engine's first chunked call, since PR 28) and the decode
+    program, each also with the slot cache as the other leaves it (a pool
+    born uncommitted came back placed, which compiled a second time inside
+    a measured window once — PERF.md "Bring-up")."""
     for _ in range(2):
         engine.submit(np.zeros(2 * chunk + 1, np.int32), max_new_tokens=3)
         engine.drain()
@@ -120,7 +121,8 @@ def drive(engine, traffic, seconds: float, drain_s: float, rec,
     """Offer the window's requests as they fall due, step the engine, stamp
     tokens; after the window, drain for at most ``drain_s``."""
     n = len(traffic.prompts)
-    served = [Served(due_s=float(d)) for d in traffic.due_s]
+    served = [Served(due_s=float(d), prompt_tokens=int(p.size))
+              for d, p in zip(traffic.due_s, traffic.prompts)]
     live: List[int] = []
     i = 0
     steps = 0
@@ -189,9 +191,23 @@ def reduce_window(served, seconds: float, attempted: str = "due",
     window, "admitted" those given a slot before it closed (a backlog is
     offered beyond capacity on purpose; what never got a slot was not tried).
     ``end_s`` is when the run stopped watching (the drain's end): a counted
-    request that had no first token by then waited at least that long."""
+    request that had no first token by then waited at least that long.
+    ``ttft_per_ktok_p50_ms`` is the median over those requests of TTFT per
+    1,000 prompt tokens: a prompt is prefilled chunk by chunk, so its TTFT
+    grows with its length, and the quotient is what a prefill step costs
+    whatever the chunk; the median does not follow the few requests that met
+    a rare slow step or waited for a slot (the mean does: ``ttft_mean_ms``).
+    ``itl_p90_ms`` is the 90th percentile of the gaps ``itl_p95_ms`` is the
+    95th of: where about one gap in twenty lies behind a slower kind of
+    step, the 95th sits on the edge between two kinds and the 90th inside
+    one, and the steps a busy host delays collect above the 95th before they
+    reach the 90th (PERF.md section 2); ``itl_p80_to_p99_ms`` keeps the upper
+    fifth of that distribution, point by point, for the log: where its edges
+    lie and how far down the delayed steps reach. ``requests`` keeps each
+    counted request's [prompt tokens, TTFT ms, 1 if it has a first token]
+    for the log: what PERF.md's table of arrangements was reckoned from."""
     end_s = seconds if end_s is None else end_s
-    ttft, gaps, late, qwait = [], [], [], []
+    ttft, requests, gaps, late, qwait = [], [], [], [], []
     prompt_tok = out_tok = finished = first_tokens = 0
     for sv in served:
         r = sv.req
@@ -206,6 +222,8 @@ def reduce_window(served, seconds: float, attempted: str = "due",
             # (or never offered: the window closed on a late generator): it
             # waited at least that long, and stays in the mean and the tail
             ttft.append(end_s - sv.due_s)
+        requests.append([sv.prompt_tokens, round(1e3 * ttft[-1], 3),
+                         int(bool(sv.stamps))])
         if r is None:
             continue
         late.append(sv.submit_s - sv.due_s)
@@ -219,15 +237,21 @@ def reduce_window(served, seconds: float, attempted: str = "due",
         out_tok += sum(1 for t in sv.stamps if t < seconds)
         finished += r.is_done() and r.finish_reason == "length"
     n = len(ttft)
+    gaps.sort()  # once, for the percentiles below
     return {
         "attempted": n, "failed": n - finished,
         "ttft_p50_ms": 1e3 * percentile(ttft, 50),
         "ttft_p75_ms": 1e3 * percentile(ttft, 75),
         "ttft_p90_ms": 1e3 * percentile(ttft, 90),
         "ttft_mean_ms": 1e3 * sum(ttft) / max(1, n),
+        "ttft_per_ktok_p50_ms": 1e3 * percentile(
+            [1e3 * t / r[0] for t, r in zip(ttft, requests)], 50),
         "itl_p50_ms": 1e3 * percentile(gaps, 50) if gaps else None,
+        "itl_p90_ms": 1e3 * percentile(gaps, 90) if gaps else None,
         "itl_p95_ms": 1e3 * percentile(gaps, 95) if gaps else None,
         "itl_mean_ms": 1e3 * sum(gaps) / len(gaps) if gaps else None,
+        "itl_p80_to_p99_ms": [round(1e3 * percentile(gaps, q), 3)
+                              for q in range(80, 100)] if gaps else None,
         "serve_tok_s": (prompt_tok + out_tok) / seconds,
         "queue_wait_p90_ms": (1e3 * percentile(qwait, 90)) if qwait else None,
         "n_ttft": n, "n_first_tokens": first_tokens, "n_itl": len(gaps),
@@ -235,6 +259,7 @@ def reduce_window(served, seconds: float, attempted: str = "due",
         "late_max_ms": 1e3 * max(late) if late else None,
         "prompt_tokens_in_window": prompt_tok,
         "output_tokens_in_window": out_tok,
+        "requests": requests,
     }
 
 

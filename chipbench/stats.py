@@ -22,11 +22,17 @@ def percentile(xs: Sequence[float], q: float) -> Optional[float]:
     return float(s[lo] * (1.0 - frac) + s[lo + 1] * frac)
 
 
-def spread(xs: Sequence[float]) -> Optional[float]:
-    """Distance between the first and third quartile as a share of the
-    median, by ``statistics.quantiles(xs, n=4)`` — the spread the bounds in
-    BENCHMARK.json are set from."""
+def range_spread(xs: Sequence[float]) -> Optional[float]:
+    """The spread of a set of runs as the driver's check reckons it (ledger,
+    PRs 28-29): the range of the runs over their median, the run farthest
+    from the median left out. A bound holds where this stays under half of
+    it, so the bounds in BENCHMARK.json are set from this one (until PR 30
+    from the distance between the quartiles, which reads about half of it
+    on six even runs and far more where one run is far off)."""
     if len(xs) < 2:
         return None
-    q1, med, q3 = statistics.quantiles(xs, n=4)
-    return (q3 - q1) / med if med else None
+    med = statistics.median(xs)
+    kept = sorted(xs, key=lambda x: abs(x - med))
+    if len(kept) > 2:
+        kept.pop()
+    return (max(kept) - min(kept)) / med if med else None

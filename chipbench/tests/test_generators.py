@@ -49,3 +49,39 @@ def test_backlog_is_all_due_at_zero():
     assert lens.min() >= 512 and lens.max() <= 3584
     assert t.output_lens.min() >= 8 and t.output_lens.max() <= 32
     assert (lens + t.output_lens).max() <= 4096
+
+
+def test_a_pinned_order_is_one_schedule_for_every_seed():
+    """``order_seed``: every seed offers the same lengths at the same times —
+    the arrangement ``--seed order_seed`` drew before the key existed — and
+    draws only the tokens."""
+    pinned = dict(MIX, order_seed=576721147)
+    was = gen.generate(MIX, 576721147, 30.0, 32000)
+    a = gen.generate(pinned, 1, 30.0, 32000)
+    b = gen.generate(pinned, 2**31 + 7, 30.0, 32000)
+    for t in (a, b):
+        assert np.array_equal(t.due_s, was.due_s)
+        assert np.array_equal(t.output_lens, was.output_lens)
+        assert [len(p) for p in t.prompts] == [len(p) for p in was.prompts]
+        assert all(p.dtype == np.int32 and 0 <= p.min() and p.max() < 32000
+                   for p in t.prompts)
+    assert not any(np.array_equal(p, q) for p, q in zip(a.prompts, b.prompts))
+    again = gen.generate(pinned, 1, 30.0, 32000)
+    assert all(np.array_equal(p, q) for p, q in zip(a.prompts, again.prompts))
+
+
+def test_the_cells_pin_their_order():
+    """Both serving mixes replay one schedule: a seed changes no length and
+    no arrival (PERF.md section 2 says why)."""
+    import json
+    import os
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for name in ("chat", "code-turns"):
+        with open(os.path.join(here, "traffic", name + ".json")) as f:
+            mix = json.load(f)
+        a = gen.generate(mix, 11, 51.0, 32000)
+        b = gen.generate(mix, 2**31 + 12, 51.0, 32000)
+        assert np.array_equal(a.due_s, b.due_s)
+        assert [len(p) for p in a.prompts] == [len(p) for p in b.prompts]
+        assert np.array_equal(a.output_lens, b.output_lens)

@@ -128,7 +128,7 @@ def test_new_scopes_are_read_and_a_scopeless_program_reads_none():
     b = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
     mine = [m["name"] for m in b["per_layer"]
             if m["name"].endswith(".code-turns")]
-    assert len(mine) == 18
+    assert len(mine) == 20
     for name in mine:
         if name.split(".")[0] in ("decode_step_dev_ms", "prefill_step_dev_ms",
                                   "decode_hbm_roofline_share",
@@ -172,8 +172,53 @@ def test_scope_rows_and_the_roofline_readers_on_hand_made_events(monkeypatch):
         "decode_latent_attention_roofline_share.code-turns").read(View)
     # 4096 rows x 576 x 4 B x 6 layers over 819 GB/s is 69 us of the 2 ms
     assert share == pytest.approx(100 * (4096 * 576 * 4 * 6 / 819e9) / 2e-3)
+    # a prefill span without ``rows`` (a program before PR 28) is the pool's
     mxu = R.load_reader("prefill_expert_mxu_share.code-turns").read(View)
     assert mxu == pytest.approx(
         100 * (5 * 1024 * 4 * 6 * 2048 * 1536 / 197e12) / 20e-3)
+    sc._scope_rows.cache_clear()
+    pt._window_ops.cache_clear()
+
+
+def test_expert_mxu_share_takes_each_programs_rows_from_its_span(monkeypatch):
+    """One-row, two-row and pool programs in one window: each quotient over
+    the rows its own span names, the median of the quotients (not the
+    median time over the pool's rows, which read 24 % where 3 % was true)."""
+    from chipbench import program_trace as pt
+    from chipbench import scopes_glm4 as sc
+
+    ms = 1e6
+    j = "jit(uccl_moe_prefill_slots)/"
+    programs = [(1, 8.0), (1, 8.2), (2, 8.5), (8, 41.6), (1, 8.1)]
+    spans, ops = [], []
+    for k, (rows, experts_ms) in enumerate(programs):
+        t = k * 100 * ms
+        spans.append((pt.PREFILL, t, 60 * ms,
+                      {"n": min(rows, 3), "chunk": "128", "rows": rows}))
+        ops += [("attn", t + 1 * ms, 4 * ms, j + "attn.core/dot_general:"),
+                ("experts", t + 5 * ms, experts_ms * ms,
+                 j + "moe.experts/ebf,efh->ebh/dot_general:")]
+    spans.append((pt.PREFILL, 500 * ms, 10 * ms, {"n": 1, "rows": 1}))  # empty
+    trace = pt.ProgramTrace(spans, [ops])
+    monkeypatch.setattr(pt, "load", lambda path: trace)
+    sc._scope_rows.cache_clear()
+    pt._window_ops.cache_clear()
+
+    class View:
+        record = {"trace_path": "hand-made"}
+        window = (0.0, 600 * ms)
+        cfg = published()
+        peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
+
+    def share(rows, experts_ms):
+        flops = 5 * rows * 128 * 4 * 6 * 2048 * 1536
+        return 100 * flops / 197e12 / (experts_ms / 1e3)
+
+    got = R.load_reader("prefill_expert_mxu_share.code-turns").read(View)
+    quotients = sorted(share(r, t) for r, t in programs)
+    assert got == pytest.approx(quotients[2])
+    assert got == pytest.approx(share(1, 8.0))  # 3.07 %: a one-row program
+    assert 2.9 < got < 3.2
+    assert share(8, 41.6) == pytest.approx(4.72, abs=0.01)  # PR 26's reading
     sc._scope_rows.cache_clear()
     pt._window_ops.cache_clear()

@@ -92,3 +92,28 @@ def test_recorded_chat_trace(chat):
     assert gaps[0][0] == "chipbench.backend_decode"
     assert abs(sum(g[1] for g in gaps) * 1e9 - ((t1 - t0) - busy)) < 1.0
     assert tr.top_ops(ops, 1)[0][0].startswith("%fusion.11 f32[8,32,4096]")
+
+
+def test_engine_host_time_counts_the_windows_steps_alone(chat):
+    """The trace runs on through the drain while the operations are cut to
+    the window: a step that starts past the window's end would count whole
+    as host time (PERF.md section 7, found in PR 24)."""
+    from chipbench import run as R
+
+    view = R.TraceView(chat, {}, {}, {}, {}, 1)
+    steps = [sp for sp in view.host_spans if sp[0] == "chipbench.engine_step"]
+    assert len(steps) == 10
+    # the measured window closes as the seventh step starts: three steps of
+    # the fixture (and the seventh) lie in the drain
+    view.window = (view.window[0], steps[6][1])
+    read = R.load_reader("engine_host_ms_per_step.chat").read
+    got = read(view)
+    inside = tr.busy_per_span(view.ops(0), steps[:6], "chipbench.engine_step")
+    assert got == pytest.approx(
+        sum(span - busy for busy, span, _ in inside) / 6 / 1e6)
+    assert 2.5 < got < 4.5  # 17.6 of 20.7 ms a decode step, 64.8 of 70.9
+    # with the drain's four steps counted whole it read nearly four times that
+    everything = tr.busy_per_span(view.ops(0), steps, "chipbench.engine_step")
+    assert sum(s - b for b, s, _ in everything) / 10 / 1e6 > 3 * got
+    view.window = None
+    assert read(view) is None

@@ -1,14 +1,16 @@
 """Readings a cell's ``correct`` limits are set from, on the chip:
 
-    python -m chipbench.tools.calibrate <workload> <seconds> <seed> [<seed> ...]
+    python -m chipbench.tools.calibrate [--controls=bf16,int8] <workload> <seconds> <seed> [<seed> ...]
 
 One process. For each seed the cell's runner makes a run with a window of
 <seconds> (the cell's own traffic at its own rate, then the drain) and
 holds what the timed path produced against the plain reference — and the
 same against the lower-precision control the configuration names (the
 reference computed with bfloat16 weights, KV and activations): one line per
-seed with the sound run's numbers and the control's. PERF.md records the
-table and the limits chosen from it. Not part of a benchmark run."""
+seed with the sound run's numbers and the control's (``--controls`` names
+others of ``reference.quantize_weights``' kinds, one after the other).
+PERF.md records the table and the limits chosen from it. Not part of a
+benchmark run."""
 
 import json
 import os
@@ -16,7 +18,7 @@ import sys
 import time
 
 
-def main(workload, seconds, *seeds):
+def main(workload, seconds, *seeds, controls=("bf16",)):
     from chipbench import run as R
 
     bench = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
@@ -29,7 +31,7 @@ def main(workload, seconds, *seeds):
         ctx = R.Ctx(cfg=cfg, mix=mix, seed=seed, seconds=float(seconds),
                     trace=False, t_start=time.time(), chips=cell["chips"],
                     peaks=peaks, trace_dir="", compile_counter=counter,
-                    controls=("bf16",))
+                    controls=tuple(controls))
         rec = runner.run(ctx)
         print("calibrate " + json.dumps({
             "seed": seed,
@@ -47,4 +49,8 @@ def main(workload, seconds, *seeds):
 
 
 if __name__ == "__main__":
-    main(*sys.argv[1:])
+    args = sys.argv[1:]
+    if args and args[0].startswith("--controls="):
+        main(*args[1:], controls=args[0].split("=", 1)[1].split(","))
+    else:
+        main(*args)

@@ -31,7 +31,10 @@ def main(workload, seconds, *rates):
     for k, arg in enumerate(rates):
         rate, _, seed = arg.partition(":")
         rate, seed = float(rate), int(seed) if seed else 1000 + k
-        traffic = gen.generate(dict(mix, rate_rps=rate), seed, seconds, vocab)
+        # a sweep asks every arrangement it tries: the order from ITS seed
+        m = dict(mix, rate_rps=rate)
+        m.pop("order_seed", None)
+        traffic = gen.generate(m, seed, seconds, vocab)
         rec.spans.clear()
         win = serve.drive(engine, traffic, seconds, 90.0, rec)
         served = win["served"]

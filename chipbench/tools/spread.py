@@ -4,15 +4,18 @@ are set from them:
     python -m chipbench.tools.spread <set1.jsonl> [<set2.jsonl> ...]
 
 Each file holds one result line (the last stdout line of a run) per run of
-one set. Prints, per metric: each set's median and spread (distance between
-the first and third quartile by ``statistics.quantiles(n=4)`` over the
-median), the wider spread, and five times it."""
+one set. Prints, per metric: each set's median and its spread by the driver's
+rule (range over median with the run farthest from the median left out:
+``stats.range_spread``); then the widest of them, twice it — the least bound
+under which the check can still tell a change (a bound holds where the runs
+spread by no more than half of it) — and eight times it, over which the
+check calls a bound too loose."""
 
 import json
 import statistics
 import sys
 
-from chipbench.stats import spread
+from chipbench.stats import range_spread
 
 
 def main(*paths):
@@ -32,12 +35,14 @@ def main(*paths):
             if name == "setup_s":
                 vals = vals[1:]  # a set's first run compiles: recorded apart
             if vals:  # a set made before the metric existed has none
-                rows.append((statistics.median(vals), spread(vals), vals))
+                rows.append((statistics.median(vals), range_spread(vals),
+                             vals))
         widest = max(r[1] for r in rows)
         print(f"{name}: " + "; ".join(
-            f"median {m:.6g} spread {100 * sp:.3f}%" for m, sp, _ in rows)
-            + f"; widest {100 * widest:.3f}% -> x5 = {500 * widest:.2f}%")
-        for _, _, vals in rows:
+            f"median {m:.6g} spread {100 * rs:.3f}%" for m, rs, _ in rows)
+            + f"; widest {100 * widest:.3f}% -> bound >= {200 * widest:.2f}%"
+            + f", <= {800 * widest:.2f}%")
+        for *_, vals in rows:
             print("    " + " ".join(f"{v:.6g}" for v in vals))
 
 

@@ -180,7 +180,9 @@ class TestWarmReplicaAdmission:
         spare = admit_warm_replica(
             r, proto, engine_kw={"prefill_chunk": 4})
         assert len(r.replicas) == 2
-        assert spare.backend._fns is proto._fns, "compiles must share"
+        assert spare.backend.programs is proto.programs, \
+            "compiles must share"
+        assert spare.backend.cache is not proto.cache
         # load the original so the spare wins the route
         eng0.submit(list(range(8)), max_new_tokens=4)
         prompt = np.arange(1, 7, dtype=np.int32)

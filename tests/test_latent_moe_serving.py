@@ -29,6 +29,7 @@ from uccl_tpu import obs
 from uccl_tpu.ep import ops as ep_ops
 from uccl_tpu.models import moe_inference as mi
 from uccl_tpu.models import reference_latent_moe as ref
+from uccl_tpu.models.inference import SlotKVCache, _forward_slots
 from uccl_tpu.models.moe_inference import (
     MoEServeConfig, MoEServer, MoESlotCache, init_params,
 )
@@ -75,10 +76,10 @@ def _slot_logits(srv, placed, tokens, cache, start, mask, impl="sort"):
     cfg = srv.cfg
 
     def f(p, tok, kc, vc, ln, off, m):
-        logits, nk, nv = mi._forward_shard_slots(
-            mi._strip_shard(p), tok[0], kc[0], vc[0], ln[0], off[0], m[0],
-            cfg, impl)
-        return logits[None], nk[None], nv[None]
+        logits, out = _forward_slots(
+            mi._strip_shard(p), tok[0], SlotKVCache(kc[0], vc[0], ln[0]),
+            off[0], m[0], cfg, ffn=mi._moe_block(cfg, impl))
+        return logits[None], out.k[None], out.v[None]
 
     fn = jax.jit(shard_map(
         f, mesh=srv.mesh,

@@ -26,6 +26,7 @@ from uccl_tpu.models.moe_inference import (
     MoEServeConfig, MoEServer, MoESlotCache, init_params,
 )
 from uccl_tpu.serving import DenseBackend, MoEBackend, ServingEngine
+from uccl_tpu.serving.backend import DensePrograms
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHUNK, SLOTS, MAX_SEQ = 8, 2, 64
@@ -141,9 +142,10 @@ def test_module_names_tell_the_programs_apart(profiled, devices):
                       head_dim=8, ffn=64)
     backend = DenseBackend(dense_init(jax.random.PRNGKey(1), cfg), cfg,
                            n_slots=2, max_seq=32)
-    names = {f.__name__ for f in (
-        backend._prefill_fn(8, False, False), backend._decode_fn(False, False),
-        backend._verify_fn(3, False, False))}
+    # the compiled callables behind the backend's three programs
+    assert isinstance(backend.programs, DensePrograms)
+    names = {backend.programs.compiled(kind, s, False, False).__name__
+             for kind, s in (("prefill", 8), ("decode", 1), ("verify", 3))}
     assert names == {"uccl_dense_prefill_slots", "uccl_dense_decode_slots",
                      "uccl_dense_verify_slots"}
 

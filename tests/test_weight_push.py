@@ -213,7 +213,7 @@ class TestConsumers:
         pub.publish("dense", jax.tree_util.tree_map(np.asarray, params))
         reps = replicate_backend(backend, 2, weights=pub.get("dense"))
         assert len(reps) == 2
-        assert reps[0]._fns is reps[1]._fns is backend._fns
+        assert reps[0].programs is reps[1].programs is backend.programs
         for a, b in zip(jax.tree_util.tree_leaves(reps[1].params),
                         jax.tree_util.tree_leaves(params)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -288,8 +288,8 @@ def admit_warm_spare(buf: ElasticBuffer, weights, *, prefix: str = "",
 def admit_warm_replica(router, prototype_backend, *, weights=None,
                        engine_kw: Optional[Dict] = None):
     """Elastic UP-scale: build a warm-spare serving replica off
-    ``prototype_backend`` (sharing its compiled-program caches — N
-    replicas cost one warmup, the ``serving.replicate_backend`` rule),
+    ``prototype_backend`` (``clone``: its own pool, the prototype's
+    compiled programs — the ``serving.replicate_backend`` rule),
     optionally serving a pushed weight snapshot
     (:class:`~uccl_tpu.p2p.weight_push.WeightSnapshot` — its wire bytes
     were counted at fetch), and :meth:`~uccl_tpu.serving.Router.attach`
@@ -298,13 +298,9 @@ def admit_warm_replica(router, prototype_backend, *, weights=None,
     the load-following control loop actuates. Returns the new
     ``ServingEngine`` (its stable replica id is on the router's
     ``attach`` instant)."""
-    from uccl_tpu.serving.engine import (
-        ServingEngine, _reweight_backend, replicate_backend,
-    )
+    from uccl_tpu.serving.engine import ServingEngine
 
-    backend = replicate_backend(prototype_backend, 2)[1]
-    if weights is not None:
-        backend = _reweight_backend(backend, weights)
-    eng = ServingEngine(backend, **(engine_kw or {}))
+    eng = ServingEngine(prototype_backend.clone(weights),
+                        **(engine_kw or {}))
     router.attach(eng)
     return eng

@@ -517,10 +517,40 @@ def _forward_slots(
     )
 
 
+def _flat_extra(sampling, adapters, adapter_ids) -> list:
+    """Flatten a slot program's optional sampled/adapted arguments into
+    positional jit args of fixed count: 5 per-slot sampling arrays, then 4
+    adapter tables + per-slot row ids. The compiled-program cache keys carry
+    the two presence flags, so the argmax/-adapter-free programs stay
+    byte-identical."""
+    extra = []
+    if sampling is not None:
+        extra.extend(sampling)
+    if adapters is not None:
+        extra.extend([adapters["wq"][0], adapters["wq"][1],
+                      adapters["wv"][0], adapters["wv"][1], adapter_ids])
+    return extra
+
+
+def _split_extra(rest, sampled: bool, adapted: bool):
+    """Inverse of :func:`_flat_extra` inside a jitted program: returns
+    (sampling tuple | None, adapter tables | None, adapter ids | None)."""
+    rest = list(rest)
+    samp = None
+    if sampled:
+        samp = tuple(rest[:5])
+        rest = rest[5:]
+    adp = ids = None
+    if adapted:
+        adp = {"wq": (rest[0], rest[1]), "wv": (rest[2], rest[3])}
+        ids = rest[4]
+    return samp, adp, ids
+
+
 def prefill_slots(
     params, tokens, prompt_lens, new_mask, cache: SlotKVCache,
     cfg: DenseConfig, start=None, sampling=None, adapters=None,
-    adapter_ids=None, slots=None,
+    adapter_ids=None, slots=None, ffn=None,
 ) -> Tuple[jax.Array, SlotKVCache]:
     """Masked batched prefill of newly admitted slots — resumable.
 
@@ -553,6 +583,10 @@ def prefill_slots(
     untouched and the work is R rows, not the pool's. Real rows name
     distinct slots; a padding row (``new_mask`` false) names an index past
     the pool and is dropped by the scatter.
+
+    This is the one statement of the program: the dense stack jits it as it
+    is, and the MoE stack runs it per shard with ``ffn`` the EP block
+    (``MoEServer.prefill_slots``; ``cfg`` is then its ``MoEServeConfig``).
     """
     if start is None:
         start = jnp.zeros_like(prompt_lens)
@@ -560,7 +594,7 @@ def prefill_slots(
     if slots is not None:
         cache = gather_slots(pool, slots)
     logits, cache = _forward_slots(
-        params, tokens, cache, start, new_mask, cfg,
+        params, tokens, cache, start, new_mask, cfg, ffn=ffn,
         adapters=adapters, adapter_ids=adapter_ids,
     )
     # each slot's last valid prompt position WITHIN this window; clipped so
@@ -587,7 +621,7 @@ def prefill_slots(
 
 def verify_slots(
     params, tokens, active, cache: SlotKVCache, cfg: DenseConfig,
-    sampling=None, adapters=None, adapter_ids=None,
+    sampling=None, adapters=None, adapter_ids=None, ffn=None,
 ) -> Tuple[jax.Array, jax.Array, SlotKVCache]:
     """Batched draft verification — the speculative-decoding primitive,
     generalizing :func:`decode_step_slots` from one token to a window.
@@ -623,9 +657,11 @@ def verify_slots(
     (docs/SERVING.md spells out the math).
 
     Returns (target tokens [B_slots, S], n_accepted [B_slots], cache').
+    The one statement of the program, as :func:`prefill_slots` is: ``ffn``
+    is the MoE stack's per-shard EP block.
     """
     logits, out = _forward_slots(
-        params, tokens, cache, cache.lengths, active, cfg,
+        params, tokens, cache, cache.lengths, active, cfg, ffn=ffn,
         adapters=adapters, adapter_ids=adapter_ids,
     )
     if sampling is None:
@@ -639,11 +675,9 @@ def verify_slots(
 
 
 def greedy_acceptance(tokens, tok):
-    """THE acceptance rule, shared by both stacks' verify primitives:
-    per-row count of the longest draft prefix (``tokens[:, 1:]``) matching
-    the window's own greedy argmaxes (``tok[:, :-1]``). Exactness hangs on
-    this one definition — a divergence between the dense and MoE stacks
-    would break their common oracle guarantee."""
+    """THE acceptance rule of :func:`verify_slots`: per-row count of the
+    longest draft prefix (``tokens[:, 1:]``) matching the window's own
+    targets (``tok[:, :-1]``)."""
     if tokens.shape[1] <= 1:
         return jnp.zeros((tokens.shape[0],), jnp.int32)
     match = (tokens[:, 1:] == tok[:, :-1]).astype(jnp.int32)

@@ -36,14 +36,12 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from uccl_tpu.ep import ops as ep_ops
+from uccl_tpu.models import inference
 from uccl_tpu.models.inference import (
-    KVCache, SlotKVCache, _dense_ffn, _forward_cached, _forward_slots,
-    gather_slots, greedy_acceptance, kv_row_shapes, scatter_slots,
-    spec_advance,
+    KVCache, SlotKVCache, _dense_ffn, _flat_extra, _forward_cached,
+    _split_extra, kv_row_shapes,
 )
-from uccl_tpu.models.sampling import (
-    broadcast_params, sample_tokens, sample_window,
-)
+from uccl_tpu.models.sampling import broadcast_params, sample_tokens
 from uccl_tpu.utils.lru import LRUFnCache
 
 _AXIS = "dp"  # the EP/serving axis of the mesh
@@ -436,29 +434,6 @@ def _forward_shard(params, tokens, k_cache, v_cache, length,
     return logits, cache.k, cache.v, cache.length
 
 
-def _forward_shard_slots(params, tokens, k_cache, v_cache, lengths, start,
-                         write_mask, cfg: MoEServeConfig, impl: str,
-                         adapters=None, adapter_ids=None):
-    """Per-shard masked slot forward (the continuous-batching primitive):
-    the dense slot-pool loop (inference._forward_slots — per-slot positions,
-    write-gated KV, per-slot attention masks) with the EP MoE FFN. Idle
-    slots' dummy tokens do route through the experts — harmless: expert
-    GEMM rows are independent and the ample serving capacity_factor keeps
-    the wire drop-free (every expert queue holds all T rows of its source,
-    ``ep_ops.expert_capacity``, and not a row more), so active rows are
-    bit-identical to a batch without the dummies.
-    ``adapters``/``adapter_ids`` are the per-slot fused LoRA tables
-    (inference._lora_delta) — the attention projections are dense-stack
-    code, so the ONE fusion point serves both stacks."""
-    cache = SlotKVCache(k_cache, v_cache, lengths)
-    logits, cache = _forward_slots(
-        params, tokens, cache, start, write_mask, cfg,
-        ffn=_moe_block(cfg, impl),
-        adapters=adapters, adapter_ids=adapter_ids,
-    )
-    return logits, cache.k, cache.v
-
-
 _EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 
 
@@ -495,11 +470,9 @@ class MoEServer:
         # the shared LRU-bounded compiled-fn pattern (utils/lru.py): a
         # long-lived serving process sweeping shapes (prefill buckets,
         # several decode batch tiers, varying scan lengths) would
-        # otherwise retain a compiled executable per shape forever. A
-        # chunked engine's steady set is four programs (the three prefill
-        # rungs [1 | 2 | B_loc, C] and its decode OR verify program), times
-        # the four sampled x adapted variants a fully featured server
-        # meets: 16 entries, so none of them evicts another
+        # otherwise retain a compiled executable per shape forever. 16
+        # holds a chunked engine's steady set (serving/backend.py's
+        # docstring sizes it)
         self._fns = LRUFnCache(16)
 
     # -- parameter placement ------------------------------------------------
@@ -625,110 +598,45 @@ class MoEServer:
                   for a in (cache.k, cache.v)), kind=self.cfg.attn)
         return cache
 
-    @staticmethod
-    def _extra_args(sampling, adapters, adapter_ids):
-        """Flatten the optional sampled/adapted arguments into the flat
-        P(dp)-sharded arg list ``_shard_mapped`` expects: 5 gridded
-        [W, B_loc] sampling arrays, then 4 broadcast [W, ...] adapter
-        tables + gridded adapter ids. The caller grids/broadcasts; the
-        shard fns strip the leading shard dim."""
-        extra = []
-        if sampling is not None:
-            extra.extend(sampling)
-        if adapters is not None:
-            extra.extend([adapters["wq"][0], adapters["wq"][1],
-                          adapters["wv"][0], adapters["wv"][1],
-                          adapter_ids])
-        return extra
-
-    @staticmethod
-    def _split_extra(rest, sampled: bool, adapted: bool):
-        """Inverse of :meth:`_extra_args` inside a shard fn (leading shard
-        dim stripped): returns (sampling tuple | None, adapters | None,
-        adapter_ids | None)."""
-        rest = list(rest)
-        samp = None
-        if sampled:
-            samp = tuple(r[0] for r in rest[:5])
-            rest = rest[5:]
-        adp = ids = None
-        if adapted:
-            adp = {"wq": (rest[0][0], rest[1][0]),
-                   "wv": (rest[2][0], rest[3][0])}
-            ids = rest[4][0]
-        return samp, adp, ids
-
     def prefill_slots(self, params, tokens, prompt_lens, new_mask,
                       cache: MoESlotCache, start=None, sampling=None,
                       adapters=None, adapter_ids=None, slots=None):
-        """Masked batched prefill of newly admitted slots (sorted EP path)
-        — resumable, mirroring :func:`inference.prefill_slots`.
+        """Masked batched prefill of newly admitted slots (sorted EP path):
+        :func:`inference.prefill_slots` — the one statement of the program,
+        read it there — run per shard with the EP block as its FFN.
 
-        tokens: [W, B_loc, S] right-padded prompt windows; prompt_lens (FULL
-        prompt lengths)/new_mask: [W, B_loc]; start: [W, B_loc] int32
-        per-slot offsets (None = zeros, the whole-prompt path). Row (w, b)
-        carries prompt positions [start, start+S): KV is written only there,
-        attention covers [0, start+S) — chunked prefill splits the same math
-        along the sequence axis (the drop-free EP wire keeps expert rows
-        independent), so resuming in chunks stays bit-exact. Slots outside
-        ``new_mask`` keep their KV rows and lengths — mid-decode neighbors
-        are untouched. Returns (greedy token [W, B_loc] — meaningful only
-        for rows whose window reaches the prompt end — and cache with
-        lengths set to min(start+S, prompt_lens) on admitted slots).
-
-        ``sampling``: per-slot gridded [W, B_loc] ``(seeds, pos0, temp,
-        top_p, top_k)`` arrays — the window-end token is then the
-        lockstep-keyed sample instead of the argmax (mirrors
-        :func:`inference.prefill_slots`). ``adapters``/``adapter_ids``
-        fuse the per-slot LoRA delta (tables broadcast [W, ...],
-        ids gridded [W, B_loc]).
-
-        ``slots`` ([W, R] int32 local slot indices) makes the call COMPACT,
-        as in :func:`inference.prefill_slots`: every per-slot argument and
-        the returned token are [W, R]; each shard gathers its R rows of the
-        pool, runs the same forward over R rows (so ``expert_capacity``
-        sees R * S tokens and the queues shrink with R; the wire stays
-        drop-free) and scatters them back. Slots not named are untouched; a
-        padding row (``new_mask`` false) names an index past the pool."""
+        Every per-slot argument carries the shard dimension in front:
+        tokens [W, B_loc, S]; prompt_lens / new_mask / start and each of
+        ``sampling``'s five arrays and ``adapter_ids`` [W, B_loc]; the
+        ``adapters`` tables broadcast [W, ...]; ``slots`` (a COMPACT call)
+        [W, R] local slot indices, every other per-slot argument and the
+        returned token then [W, R] — ``expert_capacity`` sees R * S tokens
+        and the queues shrink with R; the wire stays drop-free, which is
+        also what keeps chunked prefill bit-exact here (expert rows stay
+        independent). Returns (token [W, B_loc | R], cache')."""
         self._check_drop_free()
         cfg = self.cfg
-        s = tokens.shape[-1]
         if start is None:
             start = jnp.zeros_like(prompt_lens)
         sampled, adapted = sampling is not None, adapters is not None
         compact = slots is not None
-        extra = self._extra_args(sampling, adapters, adapter_ids)
+        extra = _flat_extra(sampling, adapters, adapter_ids)
         if compact:
             extra = [slots] + extra
 
         def uccl_moe_prefill_slots(p, tok, lens, mask, off, kc, vc, ln,
                                    *rest):
-            pool = rows = SlotKVCache(kc[0], vc[0], ln[0])
+            rest = [r[0] for r in rest]
+            idx = None
             if compact:
-                idx, rest = rest[0][0], rest[1:]
-                rows = gather_slots(pool, idx)
-            samp, adp, ids = self._split_extra(rest, sampled, adapted)
-            logits, nk, nv = _forward_shard_slots(
-                _strip_shard(p), tok[0], rows.k, rows.v, rows.lengths,
-                off[0], mask[0], cfg, "sort",
-                adapters=adp, adapter_ids=ids,
-            )
-            last_idx = jnp.clip(lens[0] - 1 - off[0], 0, s - 1)
-            last = jnp.take_along_axis(
-                logits, last_idx[:, None, None], axis=1
-            )[:, 0]
-            if samp is None:
-                t = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            else:
-                seeds, pos0, temp, top_p, top_k = samp
-                t = sample_tokens(seeds, pos0, last, temp, top_p, top_k)
-            nlen = jnp.where(
-                mask[0], jnp.minimum(off[0] + s, lens[0]), rows.lengths
-            )
-            if compact:
-                nk, nv, nlen = scatter_slots(
-                    pool, SlotKVCache(nk, nv, nlen), idx)
-            return t[None], nk[None], nv[None], nlen[None]
+                idx, rest = rest[0], rest[1:]
+            samp, adp, ids = _split_extra(rest, sampled, adapted)
+            t, out = inference.prefill_slots(
+                _strip_shard(p), tok[0], lens[0], mask[0],
+                SlotKVCache(kc[0], vc[0], ln[0]), cfg, start=off[0],
+                sampling=samp, adapters=adp, adapter_ids=ids, slots=idx,
+                ffn=_moe_block(cfg, "sort"))
+            return t[None], out.k[None], out.v[None], out.lengths[None]
 
         key = ("prefill_slots", tokens.shape, cache.k.shape,
                sampled, adapted, compact)
@@ -742,46 +650,29 @@ class MoEServer:
     def verify_slots(self, params, tokens, active, cache: MoESlotCache,
                      impl: str = "sort", sampling=None, adapters=None,
                      adapter_ids=None):
-        """Batched draft verification over the slot pool — the speculative-
-        decoding primitive, generalizing :meth:`decode_step_slots` from one
-        token to a window (mirrors :func:`inference.verify_slots`).
-
-        tokens: [W, B_loc, S] where column 0 is each slot's last committed
-        token and columns 1..S-1 its drafted continuation; active:
-        [W, B_loc] bool. Greedy acceptance = longest draft prefix matching
-        the window's own greedy argmaxes; active slots advance their length
-        by ``n_accepted + 1``; rejected-position KV is dead by the
-        chunked-prefill stale-KV argument (the next window re-writes it
-        before attending). Routes through the sorted EP path by default —
-        the multi-token regime, like prefill; the drop-free capacity check
-        keeps every routing exact regardless of window width. Returns
-        (target tokens [W, B_loc, S], n_accepted [W, B_loc], cache').
-
-        With ``sampling`` (gridded [W, B_loc] per-slot arrays), window
-        column j is sampled under the lockstep key for output position
-        ``pos0 + j`` — the same acceptance rule against sampled targets
-        is exact rejection sampling for deterministic drafters
-        (:func:`inference.verify_slots`, docs/SERVING.md)."""
+        """Batched draft verification over the slot pool:
+        :func:`inference.verify_slots` (the one statement of the program —
+        acceptance rule, cursor advance, the sampled case) run per shard
+        with the EP block as its FFN, the sorted path by default — the
+        multi-token regime, like prefill; the drop-free capacity check keeps
+        every routing exact whatever the window's width. tokens
+        [W, B_loc, S]; active, ``sampling``'s arrays and ``adapter_ids``
+        [W, B_loc]. Returns (target tokens [W, B_loc, S], n_accepted
+        [W, B_loc], cache')."""
         self._check_drop_free()
         cfg = self.cfg
         sampled, adapted = sampling is not None, adapters is not None
-        extra = self._extra_args(sampling, adapters, adapter_ids)
+        extra = _flat_extra(sampling, adapters, adapter_ids)
 
         def uccl_moe_verify_slots(p, tok, mask, kc, vc, ln, *rest):
-            samp, adp, ids = self._split_extra(rest, sampled, adapted)
-            logits, nk, nv = _forward_shard_slots(
-                _strip_shard(p), tok[0], kc[0], vc[0], ln[0],
-                ln[0], mask[0], cfg, impl,
-                adapters=adp, adapter_ids=ids,
-            )
-            if samp is None:
-                t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                seeds, pos0, temp, top_p, top_k = samp
-                t = sample_window(seeds, pos0, logits, temp, top_p, top_k)
-            n_acc = greedy_acceptance(tok[0], t)
-            nlen = spec_advance(ln[0], mask[0], n_acc)
-            return t[None], n_acc[None], nk[None], nv[None], nlen[None]
+            samp, adp, ids = _split_extra([r[0] for r in rest], sampled,
+                                          adapted)
+            t, n_acc, out = inference.verify_slots(
+                _strip_shard(p), tok[0], mask[0],
+                SlotKVCache(kc[0], vc[0], ln[0]), cfg, sampling=samp,
+                adapters=adp, adapter_ids=ids, ffn=_moe_block(cfg, impl))
+            return (t[None], n_acc[None], out.k[None], out.v[None],
+                    out.lengths[None])
 
         key = ("verify_slots", impl, tokens.shape, cache.k.shape,
                sampled, adapted)

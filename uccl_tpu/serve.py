@@ -327,11 +327,8 @@ def _serve_continuous(args, saved_cfg):
             print(f"serving {args.ckpt_dir}/step_{step} (dense)", flush=True)
         else:
             params = init_params(jax.random.PRNGKey(args.seed), dcfg)
-        backends = replicate_backend(
-            DenseBackend(params, dcfg, n_slots=args.slots,
-                         max_seq=max_seq),
-            args.replicas,
-        )
+        backend = DenseBackend(params, dcfg, n_slots=args.slots,
+                               max_seq=max_seq)
         vocab = dcfg.vocab
         # no mesh: the dense stack runs on one device whatever the host has
         paths = {"dtype": _params_dtype(params), "devices_used": 1}
@@ -386,12 +383,9 @@ def _serve_continuous(args, saved_cfg):
             print(f"serving {args.ckpt_dir}/step_{step}", flush=True)
         else:
             params = init_params(jax.random.PRNGKey(args.seed), cfg)
-        backends = replicate_backend(
-            MoEBackend(server, server.shard_params(params),
-                       batch_local=args.slots // world, max_seq=max_seq,
-                       decode_impl=impl),
-            args.replicas,
-        )
+        backend = MoEBackend(server, server.shard_params(params),
+                             batch_local=args.slots // world,
+                             max_seq=max_seq, decode_impl=impl)
         vocab = cfg.vocab
         paths = _moe_paths(cfg, impl, world, params)
 
@@ -433,7 +427,7 @@ def _serve_continuous(args, saved_cfg):
         spec_k=args.spec_k or None,
         priority_classes=args.priority_classes, preempt=preempt,
         adapters=store, tenant_fair=bool(args.tenants) or None,
-    ) for b in backends]
+    ) for b in replicate_backend(backend, args.replicas)]
     target = engines[0] if args.replicas == 1 else Router(engines)
 
     # synthetic workload (mixed prompt lengths, Poisson arrivals), compile

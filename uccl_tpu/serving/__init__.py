@@ -10,10 +10,10 @@ oracle.
 from uccl_tpu.serving.adapters import (  # noqa: F401
     AdapterStore, make_lora, materialize,
 )
-from uccl_tpu.serving.engine import (  # noqa: F401
-    ChunkEvent, DenseBackend, MoEBackend, ServingEngine,
-    replicate_backend,
+from uccl_tpu.serving.backend import (  # noqa: F401
+    DenseBackend, MoEBackend, replicate_backend,
 )
+from uccl_tpu.serving.engine import ChunkEvent, ServingEngine  # noqa: F401
 from uccl_tpu.serving.sampling import SamplingParams  # noqa: F401
 from uccl_tpu.serving.metrics import (  # noqa: F401
     ServingMetrics, percentile, percentiles_ms,

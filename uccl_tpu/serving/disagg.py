@@ -210,11 +210,8 @@ class KVWireFormat:
 
 
 def _model_dims(backend) -> Dict[str, int]:
-    """(n_layers, n_kv_heads, head_dim) of a serving backend — DenseBackend
-    carries its config, MoEBackend's lives on its server."""
-    cfg = getattr(backend, "cfg", None)
-    if cfg is None:
-        cfg = backend.server.cfg
+    """(n_layers, n_kv_heads, head_dim) of a serving backend's model."""
+    cfg = backend.cfg
     from uccl_tpu.models.inference import kv_wire_dims
 
     # the two equal arrays a cached position leaves a pool as: gqa's own

@@ -1,11 +1,14 @@
 """Continuous-batching serving engine over the KV slot pool.
 
-Orca/vLLM-shape iteration-level scheduling on the repo's serving stacks:
-``submit()`` queues a request, each ``step()`` (1) admits queue-head
-requests into free KV slots and batch-prefills exactly those slots (masked —
-mid-decode neighbors untouched), (2) runs ONE masked batched decode step
-over every active slot, (3) retires sequences on EOS or token budget and
-frees their slots for the next admission. ``drain()`` steps until idle.
+Orca/vLLM-shape iteration-level scheduling, and nothing of the model: the
+engine sees a slot backend (serving/backend.py — ``prefill`` / ``decode`` /
+``verify``, the slot-row shims, ``n_slots``, ``max_seq``, ``prefill_rungs``)
+and numpy arrays. ``submit()`` queues a request, each ``step()`` (1) admits
+queue-head requests into free KV slots and batch-prefills exactly those
+slots (masked — mid-decode neighbors untouched), (2) runs ONE masked batched
+decode step over every active slot, (3) retires sequences on EOS or token
+budget and frees their slots for the next admission. ``drain()`` steps until
+idle.
 
 **Chunked prefill** (``prefill_chunk=C``) bounds decode stalls: instead of
 prefilling a whole bucketed prompt before the step's decode pass — one long
@@ -19,14 +22,13 @@ a chunk step sends the model only the rows of the slots that are
 prefilling, one, two or the pool. ``step_tokens`` adds a per-step token
 budget (decode token = 1, prefill chunk = C): admission is deferred while
 the step's committed spend would exceed it. ``prefill_chunk=None``
-(default) is the PR 3 whole-prompt path, unchanged.
+(default) prefills a whole prompt in one call, padded to its pow2 bucket.
 
 The engine is exact, not approximate: each request's emitted tokens are
 bit-identical to the one-shot ``generate`` oracle for the same prompt
 (greedy decode over the same per-row math — chunked prefill is that math
-split along the sequence axis; tests/test_serving.py proves both modes on
-both stacks). Model programs are jitted once per shape via the same
-LRU-bounded ``_fns`` pattern the one-shot servers use.
+split along the sequence axis; tests/test_serving.py proves both modes
+over the dense and the MoE model).
 """
 
 from __future__ import annotations
@@ -37,6 +39,10 @@ from typing import Callable, List, Optional
 import numpy as np
 
 from uccl_tpu import obs
+from uccl_tpu.serving.backend import (  # noqa: F401 — re-exported: the
+    # backends lived in this module, and callers import them from here
+    DenseBackend, MoEBackend, prefill_rung, prefill_rungs, replicate_backend,
+)
 from uccl_tpu.serving.metrics import ServingMetrics
 from uccl_tpu.serving.request import Request, RequestState, now
 from uccl_tpu.serving.sampling import (
@@ -51,7 +57,6 @@ from uccl_tpu.serving.spec import (
     SPEC_ACCEPTED_LEN as _SPEC_ACCEPTED_LEN,
     SPEC_TOKENS as _SPEC_TOKENS,
 )
-from uccl_tpu.utils.lru import LRUFnCache
 
 # serving telemetry on the obs registry (docs/OBSERVABILITY.md): the
 # admission-rejection counter and slot-pool gauges are always live (dict
@@ -110,36 +115,6 @@ _TENANT_TOKS = obs.counter(
 )
 
 
-def _flat_extra(sampling, adapters) -> list:
-    """Flatten the optional sampled/adapted arguments into positional jit
-    args of fixed count: 5 per-slot sampling arrays, then 4 adapter tables
-    + per-slot row ids. The compiled-fn cache keys carry the two presence
-    flags, so the argmax/-adapter-free programs stay byte-identical."""
-    extra = []
-    if sampling is not None:
-        extra.extend(sampling)
-    if adapters is not None:
-        tables, ids = adapters
-        extra.extend([tables["wq"][0], tables["wq"][1],
-                      tables["wv"][0], tables["wv"][1], ids])
-    return extra
-
-
-def _split_extra(rest, sampled: bool, adapted: bool):
-    """Inverse of :func:`_flat_extra` inside a jitted run fn: returns
-    (sampling tuple | None, adapter tables | None, adapter ids | None)."""
-    rest = list(rest)
-    samp = None
-    if sampled:
-        samp = tuple(rest[:5])
-        rest = rest[5:]
-    adp = ids = None
-    if adapted:
-        adp = {"wq": (rest[0], rest[1]), "wv": (rest[2], rest[3])}
-        ids = rest[4]
-    return samp, adp, ids
-
-
 @dataclass
 class ChunkEvent:
     """One slot's KV rows [lo, hi) became valid during this engine step —
@@ -174,442 +149,8 @@ def _bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
-def prefill_rung(n: int, n_slots: int) -> int:
-    """Rows of the chunked-prefill program for ``n >= 1`` prefilling slots
-    of a pool of ``n_slots``: one, two, or the whole pool. Three rungs and
-    not every power of two, because a rung's price is one more program
-    traced, lowered and loaded at start-up, which grows with the model's
-    depth (the layers are unrolled); most chunk steps carry one prefilling
-    slot and the next most two; and in a burst the program is bound by
-    reading the expert weights whatever its rows, so looping a small rung
-    would lose to the whole-pool program."""
-    return n if n <= min(2, n_slots) else n_slots
-
-
-def prefill_rungs(n_slots: int) -> tuple:
-    """Every value :func:`prefill_rung` takes over a pool of ``n_slots``
-    (deduplicated: a pool of one or two slots has fewer than three)."""
-    return tuple(sorted({prefill_rung(n, n_slots) for n in (1, 2, 3)}))
-
-
-class _PrefillRungs:
-    """What the two backends share of the chunked prefill's rungs:
-    ``prefill_rungs`` (the attribute the engine reads to size a chunked
-    call, :func:`prefill_rung`), the ``prefill`` entry in its two forms, and
-    building every rung with the first. The backend brings
-    ``_run_prefill`` (stage, launch, fetch of one program) and ``n_slots``.
-    """
-
-    def _init_rungs(self, rungs: tuple) -> None:
-        self.prefill_rungs = rungs
-        self._rungs_built = set()  # (chunk, sampled, adapted) kinds
-
-    def prefill(self, tokens: np.ndarray, lens: np.ndarray,
-                mask: np.ndarray,
-                start: Optional[np.ndarray] = None,
-                sampling=None, adapters=None,
-                slots: Optional[np.ndarray] = None) -> np.ndarray:
-        """One prefill program. Whole-pool form: every argument is
-        [n_slots, ...] and row s is slot s. Compact form (``slots`` [R]
-        given, a chunked call on a rung below the pool): every argument and
-        the returned tokens are [R, ...] and row r is slot ``slots[r]``."""
-        if start is None:
-            start = np.zeros(tokens.shape[0], np.int32)
-        else:  # a chunked call
-            self._build_other_rungs(*tokens.shape, sampling, adapters)
-        return self._run_prefill(tokens, lens, mask, start, sampling,
-                                 adapters, slots)
-
-    def _build_other_rungs(self, rows: int, chunk: int, sampling,
-                           adapters) -> None:
-        """All rungs are built when the first one is: before the first
-        chunked call of a (chunk, sampled, adapted) kind runs its own rung
-        (``rows``), run each OTHER rung once with an all-false mask on the
-        live pool — a no-op on its contents — so a later change of
-        occupancy finds its program traced, lowered and loaded. A warm-up
-        that only ever has one slot prefilling then leaves nothing to
-        compile in flight."""
-        key = (chunk, sampling is not None, adapters is not None)
-        if key in self._rungs_built:
-            return
-        self._rungs_built.add(key)
-        for r in self.prefill_rungs:
-            if r == rows:
-                continue
-            samp = adp = None
-            if sampling is not None:
-                samp = tuple(np.zeros(r, np.asarray(a).dtype)
-                             for a in sampling)
-            if adapters is not None:
-                adp = (adapters[0], np.zeros(r, np.int32))
-            self._run_prefill(
-                np.zeros((r, chunk), np.int32), np.ones(r, np.int32),
-                np.zeros(r, bool), np.zeros(r, np.int32), samp, adp,
-                # padding rows all: an index past the pool, dropped on the
-                # way back; the pool rung is the ungathered program
-                None if r == self.n_slots
-                else np.full(r, self.n_slots, np.int32))
-
-
-class DenseBackend(_PrefillRungs):
-    """Slot-pool serving over the dense KV stack (models/inference.py).
-
-    ``fns`` shares another backend's compiled-program cache: the jitted
-    programs are pure in params/cache (nothing baked but shapes), so N
-    replica backends of the same (cfg, n_slots, max_seq) can reuse ONE
-    compile set — a replica set costs one warmup, not N.
-
-    Its prefill runs at the three rungs (:class:`_PrefillRungs`). A chunked
-    engine's steady set in the ``LRUFnCache(16)`` is the compact and the
-    whole-pool prefill function and its decode OR verify one, times the four
-    sampled x adapted variants: 12 entries (jit keeps the two compact row
-    counts under one function)."""
-
-    def __init__(self, params, cfg, *, n_slots: int, max_seq: int,
-                 fns: Optional[LRUFnCache] = None):
-        import jax
-
-        from uccl_tpu.models.inference import SlotKVCache
-
-        self.params = params
-        self.cfg = cfg
-        self.n_slots = n_slots
-        self.max_seq = max_seq
-        self.cache = SlotKVCache.empty(cfg, n_slots, max_seq)
-        self._init_rungs(prefill_rungs(n_slots))
-        self._fns = fns if fns is not None else LRUFnCache(16)
-        self._jax = jax
-
-    def _prefill_fn(self, s: int, sampled: bool, adapted: bool,
-                    compact: bool = False):
-        jax = self._jax
-        cfg = self.cfg
-
-        def build():
-            from uccl_tpu.models.inference import SlotKVCache, prefill_slots
-
-            def uccl_dense_prefill_slots(p, tok, lens, mask, off, kc, vc,
-                                         ln, *rest):
-                slots = None
-                if compact:
-                    slots, rest = rest[0], rest[1:]
-                samp, adp, ids = _split_extra(rest, sampled, adapted)
-                t, cache = prefill_slots(
-                    p, tok, lens, mask, SlotKVCache(kc, vc, ln), cfg,
-                    start=off, sampling=samp, adapters=adp,
-                    adapter_ids=ids, slots=slots,
-                )
-                return t, cache.k, cache.v, cache.lengths
-
-            return jax.jit(uccl_dense_prefill_slots)
-
-        return self._fns.get(("prefill", s, sampled, adapted, compact),
-                             build)
-
-    def _decode_fn(self, sampled: bool, adapted: bool):
-        jax = self._jax
-        cfg = self.cfg
-
-        def build():
-            from uccl_tpu.models.inference import (
-                SlotKVCache, decode_step_slots,
-            )
-
-            def uccl_dense_decode_slots(p, tok, mask, kc, vc, ln, *rest):
-                samp, adp, ids = _split_extra(rest, sampled, adapted)
-                t, cache = decode_step_slots(
-                    p, tok, mask, SlotKVCache(kc, vc, ln), cfg,
-                    sampling=samp, adapters=adp, adapter_ids=ids,
-                )
-                return t, cache.k, cache.v, cache.lengths
-
-            return jax.jit(uccl_dense_decode_slots)
-
-        return self._fns.get(("decode", sampled, adapted), build)
-
-    def _verify_fn(self, s: int, sampled: bool, adapted: bool):
-        jax = self._jax
-        cfg = self.cfg
-
-        def build():
-            from uccl_tpu.models.inference import SlotKVCache, verify_slots
-
-            def uccl_dense_verify_slots(p, tok, mask, kc, vc, ln, *rest):
-                samp, adp, ids = _split_extra(rest, sampled, adapted)
-                t, n_acc, cache = verify_slots(
-                    p, tok, mask, SlotKVCache(kc, vc, ln), cfg,
-                    sampling=samp, adapters=adp, adapter_ids=ids,
-                )
-                return t, n_acc, cache.k, cache.v, cache.lengths
-
-            return jax.jit(uccl_dense_verify_slots)
-
-        return self._fns.get(("verify", s, sampled, adapted), build)
-
-    def _run_prefill(self, tokens, lens, mask, start, sampling, adapters,
-                     slots) -> np.ndarray:
-        from uccl_tpu.models.inference import SlotKVCache
-
-        with obs.span("backend.stage", "wire"):
-            fn = self._prefill_fn(tokens.shape[1], sampling is not None,
-                                  adapters is not None, slots is not None)
-            extra = _flat_extra(sampling, adapters)
-            if slots is not None:
-                extra = [slots] + extra
-        with obs.span("backend.launch", "wire"):
-            t, k, v, ln = fn(self.params, tokens, lens, mask, start,
-                             self.cache.k, self.cache.v, self.cache.lengths,
-                             *extra)
-            self.cache = SlotKVCache(k, v, ln)
-        with obs.span("backend.fetch", "wire"):
-            return np.asarray(t)
-
-    def decode(self, tokens: np.ndarray, active: np.ndarray,
-               sampling=None, adapters=None) -> np.ndarray:
-        from uccl_tpu.models.inference import SlotKVCache
-
-        with obs.span("backend.stage", "wire"):
-            fn = self._decode_fn(sampling is not None, adapters is not None)
-            extra = _flat_extra(sampling, adapters)
-        with obs.span("backend.launch", "wire"):
-            t, k, v, ln = fn(self.params, tokens, active,
-                             self.cache.k, self.cache.v, self.cache.lengths,
-                             *extra)
-            self.cache = SlotKVCache(k, v, ln)
-        with obs.span("backend.fetch", "wire"):
-            return np.asarray(t)
-
-    def verify(self, tokens: np.ndarray, active: np.ndarray,
-               sampling=None, adapters=None):
-        """One batched [n_slots, k+1] draft-verify window (spec decode):
-        returns (target tokens [n_slots, k+1], n_accepted [n_slots]) —
-        greedy argmaxes, or lockstep-keyed samples under ``sampling``."""
-        from uccl_tpu.models.inference import SlotKVCache
-
-        with obs.span("backend.stage", "wire"):
-            fn = self._verify_fn(tokens.shape[1], sampling is not None,
-                                 adapters is not None)
-            extra = _flat_extra(sampling, adapters)
-        with obs.span("backend.launch", "wire"):
-            t, n_acc, k, v, ln = fn(self.params, tokens, active,
-                                    self.cache.k, self.cache.v,
-                                    self.cache.lengths, *extra)
-            self.cache = SlotKVCache(k, v, ln)
-        with obs.span("backend.fetch", "wire"):
-            return np.asarray(t), np.asarray(n_acc)
-
-    # slot KV movement (prefix-cache hits + the disagg p2p stream) — thin
-    # shims over the cache's export/import views (models/inference.py)
-    def export_slot_kv(self, slot: int, lo: int, hi: int):
-        return self.cache.export_rows(slot, lo, hi)
-
-    def import_slot_kv(self, slot: int, k_rows, v_rows, *,
-                       length: int) -> None:
-        self.cache = self.cache.import_rows(slot, k_rows, v_rows,
-                                            length=length)
-
-    def copy_slot_prefix(self, dst: int, src: int, n: int) -> None:
-        self.cache = self.cache.copy_prefix(dst, src, n)
-
-
-class MoEBackend(_PrefillRungs):
-    """Slot-pool serving over the EP-sharded MoE stack: slots are the
-    [W, B_loc] rows of the server's cache (slot s ↔ shard s // B_loc, row
-    s % B_loc); prefill routes through the sorted EP path, decode through
-    the packed LL path (the DeepEP decode regime) by default.
-
-    On one shard its prefill runs at the three rungs
-    (:class:`_PrefillRungs`); over ``world > 1`` shards the backend declares
-    the whole-pool rung alone (a compact call there would have to be sized
-    by the fullest shard and padded per shard; no cell runs it, so it keeps
-    the program it had)."""
-
-    def __init__(self, server, params, *, batch_local: int, max_seq: int,
-                 decode_impl: str = "ll"):
-        self.server = server
-        self.params = params
-        self.world = server.world
-        self.b_loc = batch_local
-        self.n_slots = self.world * batch_local
-        self.max_seq = max_seq
-        self.decode_impl = decode_impl
-        self.cache = server.slot_cache(batch_local, max_seq)
-        self._init_rungs(prefill_rungs(self.n_slots) if self.world == 1
-                         else (self.n_slots,))
-
-    def _grid(self, flat: np.ndarray, dtype) -> "np.ndarray":
-        """A flat per-row array on the [W, rows-per-shard] shard layout
-        (B_loc rows a shard, or a compact call's R on the one shard)."""
-        import jax.numpy as jnp
-
-        flat = np.asarray(flat)
-        return jnp.asarray(
-            flat.reshape((self.world, -1) + flat.shape[1:]).astype(dtype)
-        )
-
-    def _extra(self, sampling, adapters):
-        """Grid the flat per-slot sampled/adapted arguments onto the
-        [W, B_loc] shard layout: sampling arrays and adapter ids grid like
-        tokens; the stacked adapter tables broadcast a leading [W] dim
-        (every shard applies the same tables to its local rows)."""
-        import jax.numpy as jnp
-
-        samp = adp = ids = None
-        if sampling is not None:
-            seeds, pos0, temp, top_p, top_k = sampling
-            samp = (self._grid(seeds, np.int32),
-                    self._grid(pos0, np.int32),
-                    self._grid(temp, np.float32),
-                    self._grid(top_p, np.float32),
-                    self._grid(top_k, np.int32))
-        if adapters is not None:
-            tables, flat_ids = adapters
-            adp = {t: (jnp.broadcast_to(a, (self.world,) + a.shape),
-                       jnp.broadcast_to(b, (self.world,) + b.shape))
-                   for t, (a, b) in tables.items()}
-            ids = self._grid(flat_ids, np.int32)
-        return samp, adp, ids
-
-    def _run_prefill(self, tokens, lens, mask, start, sampling, adapters,
-                     slots) -> np.ndarray:
-        with obs.span("backend.stage", "wire"):
-            samp, adp, ids = self._extra(sampling, adapters)
-            tokens = self._grid(tokens, np.int32)
-            lens = self._grid(lens, np.int32)
-            mask = self._grid(mask, bool)
-            start = self._grid(start, np.int32)
-            if slots is not None:
-                slots = self._grid(slots, np.int32)
-        with obs.span("backend.launch", "wire"):
-            t, self.cache = self.server.prefill_slots(
-                self.params, tokens, lens, mask, self.cache, start=start,
-                sampling=samp, adapters=adp, adapter_ids=ids, slots=slots,
-            )
-        with obs.span("backend.fetch", "wire"):
-            return np.asarray(t).reshape(-1)
-
-    def decode(self, tokens: np.ndarray, active: np.ndarray,
-               sampling=None, adapters=None) -> np.ndarray:
-        with obs.span("backend.stage", "wire"):
-            samp, adp, ids = self._extra(sampling, adapters)
-            tokens = self._grid(tokens, np.int32)
-            active = self._grid(active, bool)
-        with obs.span("backend.launch", "wire"):
-            t, self.cache = self.server.decode_step_slots(
-                self.params, tokens, active, self.cache,
-                impl=self.decode_impl,
-                sampling=samp, adapters=adp, adapter_ids=ids,
-            )
-        with obs.span("backend.fetch", "wire"):
-            return np.asarray(t).reshape(self.n_slots)
-
-    def verify(self, tokens: np.ndarray, active: np.ndarray,
-               sampling=None, adapters=None):
-        """One batched [n_slots, k+1] draft-verify window (spec decode),
-        through the sorted EP path — the multi-token regime, like prefill.
-        Returns (target tokens [n_slots, k+1], n_accepted [n_slots])."""
-        s = tokens.shape[1]
-        with obs.span("backend.stage", "wire"):
-            samp, adp, ids = self._extra(sampling, adapters)
-            tokens = self._grid(tokens, np.int32)
-            active = self._grid(active, bool)
-        with obs.span("backend.launch", "wire"):
-            t, n_acc, self.cache = self.server.verify_slots(
-                self.params, tokens, active, self.cache,
-                sampling=samp, adapters=adp, adapter_ids=ids,
-            )
-        with obs.span("backend.fetch", "wire"):
-            return (np.asarray(t).reshape(self.n_slots, s),
-                    np.asarray(n_acc).reshape(self.n_slots))
-
-    # slot KV movement — MoESlotCache maps flat slot ids to its [W, B_loc]
-    # grid internally, so the engine-facing surface matches DenseBackend's
-    def export_slot_kv(self, slot: int, lo: int, hi: int):
-        return self.cache.export_rows(slot, lo, hi)
-
-    def import_slot_kv(self, slot: int, k_rows, v_rows, *,
-                       length: int) -> None:
-        self.cache = self.cache.import_rows(slot, k_rows, v_rows,
-                                            length=length)
-
-    def copy_slot_prefix(self, dst: int, src: int, n: int) -> None:
-        self.cache = self.cache.copy_prefix(dst, src, n)
-
-
-def replicate_backend(backend, n: int, weights=None) -> List:
-    """``n`` replica backends from one prototype — THE sharing rule for a
-    replica set (serve.py and serving_bench both build through here, so
-    it can't drift): every replica owns its KV pool, but dense replicas
-    share the prototype's compiled-program cache (the jitted fns are pure
-    in params/cache) and MoE replicas share its server (and therefore its
-    compiled programs) — N replicas cost one warmup.
-
-    ``weights``: a fetched weight-push snapshot
-    (:class:`uccl_tpu.p2p.weight_push.WeightSnapshot`) or a param pytree
-    — every replica INCLUDING the prototype serves these params instead
-    of the prototype's in-memory ones. This is the fleet spin-up path:
-    replicas import the published version off the p2p wire (its bytes
-    already counted on ``p2p_bytes_total{verb="weight_push"}``) rather
-    than cloning untracked host references. The tree structure must
-    match the prototype's params (same leaf paths/shapes) — mismatches
-    fail loudly before any replica serves a stale mix."""
-    if n < 1:
-        raise ValueError(f"need n >= 1 replicas, got {n}")
-    if weights is not None:
-        backend = _reweight_backend(backend, weights)
-    out = [backend]
-    for _ in range(1, n):
-        if isinstance(backend, MoEBackend):
-            out.append(MoEBackend(
-                backend.server, backend.params,
-                batch_local=backend.b_loc, max_seq=backend.max_seq,
-                decode_impl=backend.decode_impl,
-            ))
-        else:
-            out.append(DenseBackend(
-                backend.params, backend.cfg, n_slots=backend.n_slots,
-                max_seq=backend.max_seq, fns=backend._fns,
-            ))
-    return out
-
-
-def _reweight_backend(backend, weights):
-    """A same-shape backend serving ``weights`` (a WeightSnapshot or a
-    param pytree) — compiled-fn caches are reused (the jitted programs
-    are pure in params), so swapping a pushed version in costs zero new
-    compiles."""
-    import jax
-    import numpy as np
-
-    tree = weights.tree() if hasattr(weights, "tree") else weights
-    want, want_def = jax.tree_util.tree_flatten(backend.params)
-    got, got_def = jax.tree_util.tree_flatten(tree)
-    if want_def != got_def or len(want) != len(got):
-        raise ValueError(
-            f"pushed weight tree does not match the prototype's params "
-            f"(treedef {got_def} vs {want_def})"
-        )
-    for w, g in zip(want, got):
-        if tuple(np.shape(w)) != tuple(np.shape(g)):
-            raise ValueError(
-                f"pushed weight leaf shape {np.shape(g)} != prototype "
-                f"{np.shape(w)}"
-            )
-    params = jax.tree_util.tree_map(
-        lambda w, g: jax.numpy.asarray(g, dtype=w.dtype), backend.params,
-        tree,
-    )
-    if isinstance(backend, MoEBackend):
-        return MoEBackend(backend.server, params,
-                          batch_local=backend.b_loc,
-                          max_seq=backend.max_seq,
-                          decode_impl=backend.decode_impl)
-    return DenseBackend(params, backend.cfg, n_slots=backend.n_slots,
-                        max_seq=backend.max_seq, fns=backend._fns)
-
-
 class ServingEngine:
-    """submit()/step()/drain() over a backend (Dense or MoE).
+    """submit()/step()/drain() over a slot backend.
 
     ``prefill_chunk=C`` enables chunked prefill: admitted requests advance
     their prefill cursor by one C-token chunk per step (one prefill program
@@ -1614,6 +1155,8 @@ class ServingEngine:
         n = self.backend.n_slots
         rows = list(self._prefilling.items())
         r = prefill_rung(len(rows), n)
+        # getattr: a backend that declares no rungs (the tests' stubs,
+        # anything external) is called [n_slots, C] with no ``slots``
         if r not in getattr(self.backend, "prefill_rungs", ()):
             r = n  # the rung every backend has
         compact = r < n

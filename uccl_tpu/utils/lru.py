@@ -1,7 +1,8 @@
 """LRU-bounded compiled-function cache — THE ``_fns`` pattern.
 
-One implementation for every per-shape jit cache in the serving stacks
-(inference's generate cache, MoEServer._fns, the serving backends): a
+One implementation for every per-shape jit cache of the serving stack
+(inference's generate cache, MoEServer._fns, serving/backend.py's
+DensePrograms._fns — a backend itself holds none): a
 long-lived process sweeping shapes (batch buckets, growing scan lengths,
 several max_seq tiers) would otherwise retain a compiled executable per
 shape forever. A small cap comfortably covers a server's steady-state

@@ -7,7 +7,17 @@ nest ``chipbench/program_trace.py`` reads the device's idle time by);
 ``clone()`` and ``clone(params=tree)`` share the prototype's compiled programs,
 own their pool and serve the oracle's tokens without a new program; a tree
 that does not match is refused before it serves.
+
+The pool's ownership (ISSUE 32), over gqa and latent (mla) rows: a program
+CONSUMES the pool it is handed — donated, written in place, handed back as
+the same buffers — which ``serving_pool_in_place_total{program}`` counts and
+the compiled programs declare as input/output aliases; twins keep pools that
+survive each other's steps; the three slot-row shims read and write
+``backend.cache`` between donated steps bit-exactly.
 """
+
+import re
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +26,7 @@ import pytest
 
 from uccl_tpu import obs
 from uccl_tpu.serving import (
-    DenseBackend, MoEBackend, NGramDrafter, ServingEngine,
+    DenseBackend, MoEBackend, NGramDrafter, ServingEngine, replicate_backend,
 )
 from uccl_tpu.serving.backend import SlotBackend
 
@@ -24,6 +34,18 @@ MAX_SEQ = 32
 N_SLOTS = 4
 CHUNK = 4
 STACKS = ("dense", "moe")
+POOLS = STACKS + ("mla",)  # the pool's ownership: latent rows as well
+IN_PLACE = obs.counter("serving_pool_in_place_total")
+
+GQA = dict(vocab=64, dim=32, n_layers=1, n_heads=4, n_kv_heads=2, head_dim=8,
+           moe_experts=8, moe_topk=2, moe_ffn=64)
+# two layers (a dense one, an expert one), a cache row of 8 + 6 numbers
+MLA = dict(vocab=64, dim=40, n_layers=2, n_heads=3, rope_theta=1e4,
+           norm_eps=1e-5, moe_experts=8, moe_topk=2, moe_ffn=24,
+           capacity_factor=4.0, attn="mla", q_lora_rank=16, kv_lora_rank=8,
+           qk_nope_dim=5, qk_rope_dim=6, v_head_dim=7, n_kv_heads=3,
+           head_dim=11, first_k_dense=1, dense_ffn=36, shared_ffn=24,
+           gate="sigmoid_bias", routed_scale=1.8, param_dtype="bfloat16")
 
 
 def _dense():
@@ -43,16 +65,14 @@ def _dense():
     return backend, oracle, backend.programs._fns
 
 
-def _moe(devices):
+def _moe(devices, desc=GQA):
     from jax.sharding import Mesh
 
     from uccl_tpu.models.moe_inference import (
         MoEServeConfig, MoEServer, init_params,
     )
 
-    cfg = MoEServeConfig(vocab=64, dim=32, n_layers=1, n_heads=4,
-                         n_kv_heads=2, head_dim=8, moe_experts=8,
-                         moe_topk=2, moe_ffn=64)
+    cfg = MoEServeConfig(**desc)
     srv = MoEServer(cfg, Mesh(np.array(devices[:1]), ("dp",)))
     placed = srv.shard_params(init_params(jax.random.PRNGKey(0), cfg))
 
@@ -71,7 +91,8 @@ def stacks(devices):
     """{name: (prototype backend, oracle(params, prompt, n), the LRU its
     compiled programs live in)}, every program of a chunked engine and of a
     speculating one already built."""
-    out = {"dense": _dense(), "moe": _moe(devices)}
+    out = {"dense": _dense(), "moe": _moe(devices),
+           "mla": _moe(devices, MLA)}
     for backend, _, _ in out.values():
         for kw in ({}, {"spec_k": 2, "drafter": NGramDrafter()}):
             _serve(backend, [[1, 2, 3, 1, 2, 3, 1, 2, 3]], 4, **kw)
@@ -179,3 +200,186 @@ def test_clone_refuses_a_tree_that_does_not_match(stacks, stack, fault):
         bad["embed"] = np.zeros(bad["embed"].shape + (1,), np.float32)
     with pytest.raises(ValueError, match="pushed weight"):
         proto.clone(bad)
+
+
+# -- the pool's ownership (ISSUE 32) ----------------------------------------
+
+def _count_runs(backend):
+    """Count the backend's program calls by kind, from here on."""
+    calls, run = Counter(), backend._run
+
+    def counted(kind, *a, **kw):
+        calls[kind] += 1
+        return run(kind, *a, **kw)
+
+    backend._run = counted
+    return calls
+
+
+def _where(leaf):
+    """Where a pool leaf's one buffer lives."""
+    return leaf.addressable_shards[0].data.unsafe_buffer_pointer()
+
+
+def _call(backend, program):
+    """One direct call of ``program`` on ``backend``: slot 0 alone is
+    admitted / active."""
+    first = np.arange(N_SLOTS) == 0
+    toks = np.tile(np.arange(1, 1 + CHUNK, dtype=np.int32), (N_SLOTS, 1))
+    lens = np.full(N_SLOTS, 2 * CHUNK, np.int32)
+    start = np.zeros(N_SLOTS, np.int32)
+    if program == "prefill":  # whole prompts, whole pool
+        return backend.prefill(toks, np.full(N_SLOTS, CHUNK, np.int32), first)
+    if program == "prefill-chunk":  # a chunk, the pool's rung
+        return backend.prefill(toks, lens, first, start=start)
+    if program == "prefill-compact":  # a chunk, the one-row rung
+        return backend.prefill(toks[:1], lens[:1], first[:1], start=start[:1],
+                               slots=np.zeros(1, np.int32))
+    if program == "decode":
+        return backend.decode(toks[:, 0], first)
+    return backend.verify(toks[:, :3], first)
+
+
+@pytest.mark.parametrize("program", ["prefill", "prefill-chunk",
+                                     "prefill-compact", "decode", "verify"])
+@pytest.mark.parametrize("stack", POOLS)
+def test_a_program_consumes_the_pool_it_is_handed(stacks, stack, program):
+    backend = stacks[stack][0].clone()
+    calls = _count_runs(backend)
+    kind = program.split("-")[0]
+    handed = backend.cache
+    at = [_where(handed.k), _where(handed.v)]
+    before = IN_PLACE.get(program=kind)
+    _call(backend, program)
+    assert calls[kind] >= 1 and sum(calls.values()) == calls[kind]
+    assert all(leaf.is_deleted() for leaf in handed)
+    assert not any(leaf.is_deleted() for leaf in backend.cache)
+    # K and V come back as the buffers handed in: written in place, not
+    # copied (the few bytes of ``lengths`` the compiler may place anew)
+    assert [_where(backend.cache.k), _where(backend.cache.v)] == at
+    assert IN_PLACE.get(program=kind) - before == calls[kind]
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["decode", "verify"])
+@pytest.mark.parametrize("stack", POOLS)
+def test_the_counter_equals_the_calls_an_engine_makes(stacks, stack, spec):
+    """Every rung of the chunked prefill, decode (or verify) steps: the
+    counter moves by exactly the calls made, program by program."""
+    backend = stacks[stack][0].clone()
+    calls = _count_runs(backend)
+    before = {k: IN_PLACE.get(program=k)
+              for k in ("prefill", "decode", "verify")}
+    kw = {"spec_k": 2, "drafter": NGramDrafter()} if spec else {}
+    _serve(backend, [[1, 2, 3, 1, 2, 3, 1, 2, 3], [4, 5, 6, 4, 5],
+                     [7, 8, 9, 7, 8, 9, 7]], 4, **kw)
+    assert calls["prefill"] and calls["verify" if spec else "decode"]
+    for k, n in before.items():
+        assert IN_PLACE.get(program=k) - n == calls[k], k
+
+
+@pytest.mark.parametrize("stack", POOLS)
+def test_compiled_programs_alias_the_pool_to_their_output(stacks, stack,
+                                                          monkeypatch):
+    """What the compiler is told and what it accepted: the pool's three
+    leaves, and nothing else, are donated, and the compiled module carries
+    an input/output alias for each."""
+    proto, _, fns = stacks[stack]
+    backend = proto.clone()
+    seen, get = [], fns.get
+
+    def spy(key, build):
+        fn = get(key, build)
+
+        def call(*args):
+            seen.append((key, fn, jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a),
+                                               jnp.result_type(a)), args)))
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(fns, "get", spy)
+    for program in ("prefill", "prefill-chunk", "prefill-compact", "decode",
+                    "verify"):
+        _call(backend, program)
+    pool = sorted((a.shape, a.dtype) for a in backend.cache)
+    # prefill (whole-pool, compact), decode, verify: programs of their own
+    assert len({key for key, _, _ in seen}) >= 4
+    for key, fn, shapes in seen:
+        lowered = fn.lower(*shapes)
+        donated = [i for i in jax.tree_util.tree_leaves(lowered.args_info)
+                   if i.donated]
+        assert sorted((i.shape, i.dtype) for i in donated) == pool, key
+        header = lowered.compile().as_text().split("\n", 1)[0]
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", header)
+        assert aliases, (key, header[:200])
+        assert len(re.findall(r"\(\d+, \{\}", aliases.group(1))) == 3, key
+
+
+@pytest.mark.parametrize("stack", POOLS)
+def test_twins_keep_pools_that_survive_each_others_steps(stacks, stack):
+    a, b = replicate_backend(stacks[stack][0].clone(), 2)
+    c = a.clone()
+    assert a.programs is b.programs is c.programs
+    _call(a, "prefill")
+    rows = {id(x): _pool(x) for x in (a, b, c)}  # every pool still readable
+    for stepped in (a, b, c):
+        for program in ("prefill-chunk", "prefill-compact", "decode",
+                        "verify"):
+            _call(stepped, program)
+        for other in (a, b, c):
+            if other is stepped:
+                continue
+            for x, y in zip(rows[id(other)], _pool(other)):
+                np.testing.assert_array_equal(x, y)
+        rows[id(stepped)] = _pool(stepped)
+
+
+@pytest.mark.parametrize("stack", POOLS)
+def test_slot_rows_move_bit_exactly_between_donated_steps(stacks, stack):
+    """export -> (a step) -> import -> (a step) -> copy -> (a step) ->
+    export: the shims see ``backend.cache`` between steps, whichever buffers
+    it is by then."""
+    backend = stacks[stack][0].clone()
+    idle = np.zeros(N_SLOTS, bool)
+    _call(backend, "prefill")  # slot 0 holds CHUNK rows
+    k0, v0 = backend.export_slot_kv(0, 0, CHUNK)
+    assert np.abs(k0).sum() > 0
+
+    def step():  # consumes the pool; writes no row (nothing is active)
+        handed = backend.cache
+        backend.decode(np.zeros(N_SLOTS, np.int32), idle)
+        assert handed.k.is_deleted()
+
+    step()
+    backend.import_slot_kv(1, k0, v0, length=CHUNK)
+    step()
+    backend.copy_slot_prefix(2, 1, CHUNK)
+    step()
+    for slot in (0, 1, 2):
+        k, v = backend.export_slot_kv(slot, 0, CHUNK)
+        np.testing.assert_array_equal(k, k0)
+        np.testing.assert_array_equal(v, v0)
+    assert np.asarray(backend.cache.lengths).reshape(-1)[:3].tolist() == [
+        CHUNK] * 3
+
+
+@pytest.mark.parametrize("stack", POOLS)
+def test_a_program_that_fails_after_consuming_the_pool_says_so(stacks, stack):
+    backend = stacks[stack][0].clone()
+
+    class Broken:
+        def decode(self, params, tokens, active, pool, **kw):
+            for leaf in pool:
+                leaf.delete()
+            raise FloatingPointError("device fault")
+
+        def prefill(self, *a, **kw):
+            raise FloatingPointError("refused before it ran")
+
+    backend.programs = Broken()
+    with pytest.raises(FloatingPointError, match="refused"):  # pool intact
+        _call(backend, "prefill")
+    assert not backend.cache.k.is_deleted()
+    with pytest.raises(RuntimeError, match="consumed the slot pool") as e:
+        _call(backend, "decode")
+    assert isinstance(e.value.__cause__, FloatingPointError)

@@ -134,12 +134,15 @@ def kv_wire_dims(cfg):
     return 1, (math.prod(k_row) + math.prod(v_row)) // 2
 
 
-def _gqa_attention(x, lp, k_cache, v_cache, positions, length, write, cfg,
+def _gqa_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
                    lora=None):
     """One layer's grouped-query attention with its residual: project,
-    rotate, ``write`` the new rows into this layer's cache arrays, attend
-    over the cache. ``lora(h, target)`` adds the per-slot low-rank delta to
-    the query/value projections. Returns (x', k_cache', v_cache')."""
+    rotate, ``write`` the new rows into the layer-stacked cache arrays,
+    attend over this layer's rows of them. ``write(pool, new)`` returns the
+    updated stacked array and the layer's view of it ``[B, Smax, ...]``
+    (the callers own where a layer's rows live: :func:`_forward_cached`,
+    :func:`_forward_slots`). ``lora(h, target)`` adds the per-slot low-rank
+    delta to the query/value projections. Returns (x', k_pool', v_pool')."""
     b, s, _ = x.shape
     d = cfg.head_dim
     with jax.named_scope("attn.qkv"):
@@ -156,15 +159,15 @@ def _gqa_attention(x, lp, k_cache, v_cache, positions, length, write, cfg,
         q = rope(q, positions, cfg.rope_theta)
         kk = rope(kk, positions, cfg.rope_theta)
     with jax.named_scope("attn.kv_write"):
-        k_cache = write(k_cache, kk)
-        v_cache = write(v_cache, v)
+        k_pool, k_cache = write(k_pool, kk)
+        v_pool, v_cache = write(v_pool, v)
     attn = _attend_cached(q, k_cache, v_cache, length, cfg)
     with jax.named_scope("attn.out"):
         x = x + attn.reshape(b, s, -1) @ lp["wo"].astype(attn.dtype)
-    return x, k_cache, v_cache
+    return x, k_pool, v_pool
 
 
-def _mla_attention(x, lp, ckv_cache, kr_cache, positions, length, write,
+def _mla_attention(x, lp, ckv_pool, kr_pool, positions, length, write,
                    cfg, lora=None):
     """One layer's multi-head latent attention with its residual, in the
     absorbed form: queries through a low-rank bottleneck (``wq_a`` -> norm
@@ -195,14 +198,14 @@ def _mla_attention(x, lp, ckv_cache, kr_cache, positions, length, write,
         w_kvb = lp["wkv_b"].astype(q.dtype).reshape(r, nh, dn + dv)
         q_lat = jnp.einsum("bshd,chd->bshc", q[..., :dn], w_kvb[..., :dn])
     with jax.named_scope("attn.kv_write"):
-        ckv_cache = write(ckv_cache, ckv)
-        kr_cache = write(kr_cache, kr)
+        ckv_pool, ckv_cache = write(ckv_pool, ckv)
+        kr_pool, kr_cache = write(kr_pool, kr)
     o_lat = _attend_latent(q_lat, q_rope, ckv_cache, kr_cache, length,
                            1.0 / math.sqrt(dn + dr))
     with jax.named_scope("attn.out"):
         o = jnp.einsum("bshc,chd->bshd", o_lat, w_kvb[..., dn:])
         x = x + o.reshape(b, s, nh * dv) @ lp["wo"].astype(o.dtype)
-    return x, ckv_cache, kr_cache
+    return x, ckv_pool, kr_pool
 
 
 def _attention_of(cfg):
@@ -250,32 +253,29 @@ def _forward_cached(
     ``ffn(h2, layer_params) -> [B, S, H]`` overrides the dense SwiGLU block
     — the hook the MoE serving loop uses so the attention/KV-cache math
     exists exactly once (uccl_tpu/models/moe_inference.py). The attention
-    kind comes from the model description (:func:`_attention_of`)."""
+    kind comes from the model description (:func:`_attention_of`). The
+    layer-stacked cache arrays are written in place, as
+    :func:`_forward_slots` writes the pool's: carried through the layer
+    loop, one ``dynamic_update_slice`` a layer, attention over a slice."""
     b, s = tokens.shape
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(cache.k.dtype)
     positions = cache.length + jnp.arange(s)
     attention = _attention_of(cfg)
-
-    def write(rows, new):
-        return lax.dynamic_update_slice(
-            rows, new, (0, cache.length) + (0,) * (new.ndim - 2))
-
-    new_k, new_v = [], []
+    k, v = cache.k, cache.v
     for i in range(cfg.n_layers):
+        def write(pool, new, i=i):
+            pool = lax.dynamic_update_slice(
+                pool, new[None],
+                (i, 0, cache.length) + (0,) * (new.ndim - 2))
+            return pool, pool[i]
+
         lp = _layer_params(params, i)
-        x, k_cache, v_cache = attention(
-            x, lp, cache.k[i], cache.v[i], positions, cache.length, write,
-            cfg)
-        new_k.append(k_cache)
-        new_v.append(v_cache)
+        x, k, v = attention(x, lp, k, v, positions, cache.length, write, cfg)
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         x = x + (_dense_ffn(h2, lp) if ffn is None else ffn(h2, lp))
     logits = _head(x, params, cfg)
-    cache = KVCache(
-        jnp.stack(new_k), jnp.stack(new_v), cache.length + s
-    )
-    return logits, cache
+    return logits, KVCache(k, v, cache.length + s)
 
 
 def prefill(params, tokens, cfg: DenseConfig, max_seq: int) -> Tuple[jax.Array, KVCache]:
@@ -441,33 +441,9 @@ def _lora_delta(h, table, ids, layer):
     return jnp.einsum("bsr,bro->bso", jnp.einsum("bsh,bhr->bsr", h, al), bl)
 
 
-def gather_slots(cache: "SlotKVCache", slots) -> "SlotKVCache":
-    """The rows of ``slots`` ([R] int32) as an R-slot pool: what a compact
-    prefill program runs instead of the whole pool. An index past the pool
-    (a padding row) reads the last slot; :func:`scatter_slots` drops it."""
-    return SlotKVCache(
-        jnp.take(cache.k, slots, axis=1, mode="clip"),
-        jnp.take(cache.v, slots, axis=1, mode="clip"),
-        jnp.take(cache.lengths, slots, mode="clip"),
-    )
-
-
-def scatter_slots(cache: "SlotKVCache", rows: "SlotKVCache",
-                  slots) -> "SlotKVCache":
-    """``cache`` with ``rows`` written back at ``slots``; every slot not
-    named keeps its rows and length. A padding row names an index past the
-    pool and is dropped, so two rows never write one slot (the real rows of
-    a call name distinct slots)."""
-    return SlotKVCache(
-        cache.k.at[:, slots].set(rows.k, mode="drop"),
-        cache.v.at[:, slots].set(rows.v, mode="drop"),
-        cache.lengths.at[slots].set(rows.lengths, mode="drop"),
-    )
-
-
 def _forward_slots(
     params, tokens, cache: SlotKVCache, start, write_mask, cfg, ffn=None,
-    adapters=None, adapter_ids=None,
+    adapters=None, adapter_ids=None, slots=None,
 ) -> Tuple[jax.Array, SlotKVCache]:
     """Masked batched forward: tokens [B, S] at positions [start_b, start_b+S).
 
@@ -477,6 +453,21 @@ def _forward_slots(
     an idle slot's dummy token. Lengths are NOT advanced here; the callers
     own the per-slot length bookkeeping. ``ffn`` is the same dense-block
     override hook as :func:`_forward_cached` (the MoE serving loop uses it).
+
+    The pool is written IN PLACE: the layer-stacked ``[L, B_slots, S_max,
+    ...]`` arrays are carried through the layer loop, layer ``i``'s new rows
+    go in by one scatter, and attention reads layer ``i`` as a slice of the
+    array just written — nothing is sliced out, collected and re-stacked, so
+    a program that is handed its pool donated (every serving program is:
+    serving/backend.py) returns the buffers it was given with B x S rows a
+    layer changed.
+
+    ``slots`` ([R] int32, B == R) makes the rows COMPACT: row r is slot
+    ``slots[r]`` of the pool — its new rows are written there, and attention
+    reads that slot's rows as a slice of the layer (R slices side by side);
+    ``None`` is row b = slot b. A padding row names an index past the pool:
+    its write is dropped (it reads the last slot, to no effect). Real rows
+    name distinct slots.
 
     ``adapters`` = ``{"wq": (A, B), "wv": (A, B)}`` stacked LoRA tables +
     ``adapter_ids`` [B] fuse a per-slot low-rank delta onto the query and
@@ -491,30 +482,35 @@ def _forward_slots(
     # masked slots write at index smax → dropped by the scatter; rows beyond
     # the cache end (a bucket overhanging S_max) drop the same way
     pos_write = jnp.where(write_mask[:, None], positions, smax)
-    bidx = jnp.arange(b)[:, None]
+    bidx = (jnp.arange(b) if slots is None else slots)[:, None]
     attention = _attention_of(cfg)
-
-    def write(rows, new):
-        return rows.at[bidx, pos_write].set(new, mode="drop")
-
-    new_k, new_v = [], []
+    k, v = cache.k, cache.v
     for i in range(cfg.n_layers):
+        def write(pool, new, i=i):
+            pool = pool.at[i, bidx, pos_write].set(new, mode="drop")
+            if slots is None:
+                return pool, pool[i]
+            # a dynamic slice a row, straight from the stacked array (its
+            # start clamps into the pool): one row is read where it lies;
+            # a gather of whole slot rows cost the two-row program 1.6 ms
+            # a layer on the chip
+            zeros = (0,) * (pool.ndim - 2)
+            return pool, jnp.concatenate([
+                lax.dynamic_slice(pool, (i, slots[r]) + zeros,
+                                  (1, 1) + pool.shape[2:])[0]
+                for r in range(b)])
+
         lp = _layer_params(params, i)
         lora = None
         if adapters is not None:
             def lora(h, target, i=i):
                 return _lora_delta(h, adapters[target], adapter_ids, i)
-        x, k_cache, v_cache = attention(
-            x, lp, cache.k[i], cache.v[i], positions, start, write, cfg,
-            lora=lora)
-        new_k.append(k_cache)
-        new_v.append(v_cache)
+        x, k, v = attention(x, lp, k, v, positions, start, write, cfg,
+                            lora=lora)
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         x = x + (_dense_ffn(h2, lp) if ffn is None else ffn(h2, lp))
     logits = _head(x, params, cfg)
-    return logits, SlotKVCache(
-        jnp.stack(new_k), jnp.stack(new_v), cache.lengths
-    )
+    return logits, SlotKVCache(k, v, cache.lengths)
 
 
 def _flat_extra(sampling, adapters, adapter_ids) -> list:
@@ -578,11 +574,11 @@ def prefill_slots(
 
     ``slots`` ([R] int32) makes the call COMPACT: every per-slot argument
     and the returned token are [R], row r belonging to slot ``slots[r]``;
-    the program gathers those R slots' rows and lengths, runs the same
-    forward over R rows and scatters them back, so slots not named are
-    untouched and the work is R rows, not the pool's. Real rows name
-    distinct slots; a padding row (``new_mask`` false) names an index past
-    the pool and is dropped by the scatter.
+    the program runs the same forward over R rows, writes the chunk's new
+    rows at ``(slots[r], start_r)`` and attends over those R slots' rows
+    (:func:`_forward_slots`), so slots not named are untouched and the work
+    is R rows, not the pool's. Real rows name distinct slots; a padding row
+    (``new_mask`` false) names an index past the pool and is dropped.
 
     This is the one statement of the program: the dense stack jits it as it
     is, and the MoE stack runs it per shard with ``ffn`` the EP block
@@ -590,12 +586,9 @@ def prefill_slots(
     """
     if start is None:
         start = jnp.zeros_like(prompt_lens)
-    pool = cache
-    if slots is not None:
-        cache = gather_slots(pool, slots)
     logits, cache = _forward_slots(
         params, tokens, cache, start, new_mask, cfg, ffn=ffn,
-        adapters=adapters, adapter_ids=adapter_ids,
+        adapters=adapters, adapter_ids=adapter_ids, slots=slots,
     )
     # each slot's last valid prompt position WITHIN this window; clipped so
     # mid-prefill rows (prompt end beyond the window) gather in-bounds —
@@ -610,13 +603,13 @@ def prefill_slots(
     else:
         seeds, pos0, temp, top_p, top_k = sampling
         tok = sample_tokens(seeds, pos0, last, temp, top_p, top_k)
-    lengths = jnp.where(
-        new_mask, jnp.minimum(start + s, prompt_lens), cache.lengths
-    )
-    cache = SlotKVCache(cache.k, cache.v, lengths)
-    if slots is not None:
-        cache = scatter_slots(pool, cache, slots)
-    return tok, cache
+    # admitted rows stamp their slot's length; a row not admitted (or a
+    # padding row) names an index past the pool and is dropped
+    n_slots = cache.lengths.shape[0]
+    rows = jnp.arange(n_slots) if slots is None else slots
+    lengths = cache.lengths.at[jnp.where(new_mask, rows, n_slots)].set(
+        jnp.minimum(start + s, prompt_lens), mode="drop")
+    return tok, SlotKVCache(cache.k, cache.v, lengths)
 
 
 def verify_slots(

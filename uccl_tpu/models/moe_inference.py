@@ -505,18 +505,22 @@ class MoEServer:
             lambda path, _: P(None, _AXIS) if _is_expert_leaf(path)
             else P(_AXIS), params)
 
-    def _shard_mapped(self, f, n_in, n_out, params):
+    def _shard_mapped(self, f, n_in, n_out, params, donate=()):
         """jit(shard_map(f)) with params first, then n_in P(dp) arrays.
         The compiled program is named after ``f`` (``jit_<f.__name__>`` on
         the profiler's ``XLA Modules`` line, and part of the persistent
-        compile cache's key), so each closure carries a name of its own."""
+        compile cache's key), so each closure carries a name of its own.
+        ``donate``: positions of the arguments the program consumes (the
+        slot programs' pool: written in place, handed back as the same
+        buffers)."""
         return jax.jit(
             shard_map(
                 f, mesh=self.mesh,
                 in_specs=(self._param_specs(params),) + (P(_AXIS),) * n_in,
                 out_specs=(P(_AXIS),) * n_out,
                 check_vma=False,
-            )
+            ),
+            donate_argnums=donate,
         )
 
     def _forward(self, params, tokens, cache: MoEKVCache, impl: str):
@@ -613,7 +617,10 @@ class MoEServer:
         returned token then [W, R] — ``expert_capacity`` sees R * S tokens
         and the queues shrink with R; the wire stays drop-free, which is
         also what keeps chunked prefill bit-exact here (expert rows stay
-        independent). Returns (token [W, B_loc | R], cache')."""
+        independent). Returns (token [W, B_loc | R], cache'). ``cache`` is
+        CONSUMED: its arrays are donated to the program, written in place
+        and come back as ``cache'`` — keep the pool returned, never the one
+        passed."""
         self._check_drop_free()
         cfg = self.cfg
         if start is None:
@@ -641,7 +648,8 @@ class MoEServer:
         key = ("prefill_slots", tokens.shape, cache.k.shape,
                sampled, adapted, compact)
         fn = self._fn(key, lambda: self._shard_mapped(
-            uccl_moe_prefill_slots, 7 + len(extra), 4, params))
+            uccl_moe_prefill_slots, 7 + len(extra), 4, params,
+            donate=(5, 6, 7)))
         tok, nk, nv, nlen = fn(params, tokens, prompt_lens, new_mask,
                                start, cache.k, cache.v, cache.lengths,
                                *extra)
@@ -658,7 +666,8 @@ class MoEServer:
         every routing exact whatever the window's width. tokens
         [W, B_loc, S]; active, ``sampling``'s arrays and ``adapter_ids``
         [W, B_loc]. Returns (target tokens [W, B_loc, S], n_accepted
-        [W, B_loc], cache')."""
+        [W, B_loc], cache'); ``cache`` is consumed, as
+        :meth:`prefill_slots` consumes it."""
         self._check_drop_free()
         cfg = self.cfg
         sampled, adapted = sampling is not None, adapters is not None
@@ -677,7 +686,8 @@ class MoEServer:
         key = ("verify_slots", impl, tokens.shape, cache.k.shape,
                sampled, adapted)
         fn = self._fn(key, lambda: self._shard_mapped(
-            uccl_moe_verify_slots, 5 + len(extra), 5, params))
+            uccl_moe_verify_slots, 5 + len(extra), 5, params,
+            donate=(3, 4, 5)))
         tok, n_acc, nk, nv, nlen = fn(params, tokens, active,
                                       cache.k, cache.v, cache.lengths,
                                       *extra)

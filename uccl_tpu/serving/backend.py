@@ -39,6 +39,14 @@ from uccl_tpu.utils.lru import LRUFnCache
 
 _SAMPLING_DTYPES = (np.int32, np.int32, np.float32, np.float32, np.int32)
 
+_POOL_IN_PLACE = obs.counter(
+    "serving_pool_in_place_total",
+    "slot-program calls that consumed the pool they were handed (donated, "
+    "written in place, handed back as the same buffers; labels: program = "
+    "prefill | decode | verify) — equal to the calls made, or a program "
+    "copies its pool",
+)
+
 
 def prefill_rung(n: int, n_slots: int) -> int:
     """Rows of the chunked-prefill program for ``n >= 1`` prefilling slots
@@ -64,9 +72,11 @@ class Programs(NamedTuple):
     ...)``, ``decode(params, tokens, active, pool, ...)`` and ``verify(...)``
     as decode; each takes ``sampling=``, ``adapters=``, ``adapter_ids=``
     (prefill also ``slots=``) and returns its outputs, then the new pool.
-    They are pure in params and pool (nothing baked but shapes), so backends
-    of one shape share one of these — a replica set costs one warm-up, not
-    N."""
+    They are pure in params (nothing baked but shapes) and CONSUME the pool
+    handed in: it is donated, written in place and handed back as the same
+    buffers, so the caller drops every reference to the one it passed (the
+    backend's ``_run`` does, in the statement of the call). Backends of one
+    shape share one of these — a replica set costs one warm-up, not N."""
     prefill: Callable
     decode: Callable
     verify: Callable
@@ -110,9 +120,11 @@ class DensePrograms:
                     p, tok, mask, cache, cfg, sampling=samp, adapters=adp,
                     adapter_ids=ids)
 
+            # the pool (``cache``, by its position) is donated
             return jax.jit({"prefill": uccl_dense_prefill_slots,
                             "decode": uccl_dense_decode_slots,
-                            "verify": uccl_dense_verify_slots}[kind])
+                            "verify": uccl_dense_verify_slots}[kind],
+                           donate_argnums=5 if kind == "prefill" else 3)
 
         return self._fns.get((kind, s, sampled, adapted, compact), build)
 
@@ -168,11 +180,12 @@ class SlotBackend:
 
     def clone(self, params=None) -> "SlotBackend":
         """A same-shape backend with a pool of its own that shares this
-        one's compiled programs (they are pure in params and pool), so it
-        costs zero new compiles; serving ``params`` if given: a pytree (or a
-        weight-push snapshot's ``tree()``) of this backend's tree structure
-        and leaf shapes, cast leaf by leaf to the dtypes served now —
-        anything else is refused before it could serve a stale mix."""
+        one's compiled programs (they are pure in params and keep nothing of
+        a pool), so it costs zero new compiles; serving ``params`` if given:
+        a pytree (or a weight-push snapshot's ``tree()``) of this backend's
+        tree structure and leaf shapes, cast leaf by leaf to the dtypes
+        served now — anything else is refused before it could serve a stale
+        mix."""
         twin = copy.copy(self)
         twin.cache = self._new_pool()
         twin._rungs_built = set()
@@ -207,12 +220,16 @@ class SlotBackend:
             flat.reshape((self.world, -1) + flat.shape[1:]).astype(dtype)
         )
 
-    def _run(self, program, per_row, sampling, adapters, **rows_kw):
-        """One program call. ``per_row``: its (flat array, dtype) arguments
-        in order; ``rows_kw``: per-row int32 keyword arguments (None = not
-        passed). Returns every output but the pool, flat per row again.
-        The three spans are what ``chipbench/program_trace.py`` reads the
-        device's idle time by, nested inside the engine's ``wire.*``."""
+    def _run(self, kind, per_row, sampling, adapters, **rows_kw):
+        """One call of the program ``kind`` ("prefill" | "decode" |
+        "verify"). ``per_row``: its (flat array, dtype) arguments in order;
+        ``rows_kw``: per-row int32 keyword arguments (None = not passed).
+        Returns every output but the pool, flat per row again. The program
+        consumes the pool it is handed and ``self.cache`` becomes the one
+        it gives back, in one statement: nothing else may hold the pool
+        across a call. The three spans are what
+        ``chipbench/program_trace.py`` reads the device's idle time by,
+        nested inside the engine's ``wire.*``."""
         with obs.span("backend.stage", "wire"):
             kw = {}
             if sampling is not None:
@@ -233,7 +250,20 @@ class SlotBackend:
                 if a is not None:
                     kw[name] = self._lay(a, np.int32)
         with obs.span("backend.launch", "wire"):
-            *out, self.cache = program(self.params, *args, self.cache, **kw)
+            pool = self.cache
+            try:
+                *out, self.cache = getattr(self.programs, kind)(
+                    self.params, *args, pool, **kw)
+            except Exception as e:
+                if pool.k.is_deleted():
+                    raise RuntimeError(
+                        f"the {kind} program failed after it had consumed "
+                        f"the slot pool: this backend's cached rows are "
+                        f"gone and every request holding a slot with them"
+                    ) from e
+                raise
+            if pool.k.is_deleted():
+                _POOL_IN_PLACE.inc(program=kind)
         with obs.span("backend.fetch", "wire"):
             out = [np.asarray(o) for o in out]
             if self.world is not None:  # [W, rows / W, ...] -> [rows, ...]
@@ -259,7 +289,7 @@ class SlotBackend:
     def _run_prefill(self, tokens, lens, mask, start, sampling, adapters,
                      slots) -> np.ndarray:
         return self._run(
-            self.programs.prefill,
+            "prefill",
             [(tokens, np.int32), (lens, np.int32), (mask, bool)],
             sampling, adapters, start=start, slots=slots)[0]
 
@@ -296,7 +326,7 @@ class SlotBackend:
     def decode(self, tokens: np.ndarray, active: np.ndarray,
                sampling=None, adapters=None) -> np.ndarray:
         return self._run(
-            self.programs.decode,
+            "decode",
             [(tokens, np.int32), (active, bool)], sampling, adapters)[0]
 
     def verify(self, tokens: np.ndarray, active: np.ndarray,
@@ -305,7 +335,7 @@ class SlotBackend:
         returns (target tokens [n_slots, k+1], n_accepted [n_slots]) —
         greedy argmaxes, or lockstep-keyed samples under ``sampling``."""
         return tuple(self._run(
-            self.programs.verify,
+            "verify",
             [(tokens, np.int32), (active, bool)], sampling, adapters))
 
     # slot KV movement (prefix-cache hits + the disagg p2p stream) — thin

@@ -766,6 +766,63 @@ def _moe_ffn_sort_chunked(
         return jnp.einsum("tk,tkh->th", weights.astype(yk.dtype), yk)
 
 
+def _moe_ffn_held(x, router_logits, w_gate, w_up, w_down, axis, held: int,
+                  first: int, num_selected: int, capacity_factor: float,
+                  impl: str, gate: str, gate_bias, routed_scale: float):
+    """One member's share of an expert layer whose other experts live on
+    chips that are not here. The gate is the whole layer's: every token is
+    scored over all ``E`` experts, its ``k`` chosen and their weights
+    renormalised over all ``k``. Of the (token, choice) pairs, those that
+    land on the ``held`` experts ``[first, first + held)`` are queued and
+    computed — ``held`` queues, not ``E`` — and each weighted by its gate
+    weight; the rest are other members' work and add nothing here. The
+    result is this member's part of the layer's sum (the parts of all
+    ``E / held`` members add up to the layer: tests/test_hybrid_moe_serving).
+    No exchange, and nothing stands in for one.
+
+    Queues: ``capacity`` is :func:`expert_capacity` over the router's ``E``
+    — ``capacity_factor`` still counts in balanced shares ``T k / E`` of ONE
+    expert, whoever holds it — bounded by ``T``; drop-free (serving) is
+    ``capacity_factor * k >= E``, which gives every held queue ``T`` rows.
+    A pair for an absent expert is sent to a queue past the held ones
+    (id ``held``) that is never gathered, so it neither takes a held queue's
+    row nor counts as a drop."""
+    from uccl_tpu.obs import counters as _obsc
+
+    t, _ = x.shape
+    e = router_logits.shape[-1]
+    capacity = _resolve_capacity(t, num_selected, e, capacity_factor)
+    _obsc.gauge(
+        "ep_experts_held",
+        "experts resident on this member in the last traced EP layer that "
+        "held a share (their queues: ep_expert_capacity rows each)",
+    ).set(held, what="moe_layer")
+    with jax.named_scope("moe.route"):
+        vals, idx, aux_loss, z_loss = _gate_topk(
+            router_logits, num_selected, True, gate, gate_bias, routed_scale)
+        local = idx - first
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        if impl == "sort":
+            # held + 1 queues, the last the absent experts': its slots lie
+            # past the held buffer, so they gather nothing and return zero
+            tfs, slot, _ = sorted_from_topk(local, held + 1, capacity)
+            tfs = tfs[:held * capacity]
+        elif impl == "dense":
+            # an id past the held experts is an all-zero one-hot row
+            d_mask, c_weights, _ = masks_from_topk(local, vals, held,
+                                                   capacity)
+        else:
+            raise ValueError(f"unknown moe impl {impl!r} for a held share")
+    with jax.named_scope("moe.dispatch"):
+        xe = dispatch_sorted(x, tfs, held, capacity, axis) \
+            if impl == "sort" else dispatch(x, d_mask, axis)
+    ye = _expert_gemms(xe, w_gate, w_up, w_down)
+    with jax.named_scope("moe.combine"):
+        out = combine_sorted(ye, slot, vals, axis) if impl == "sort" \
+            else combine(ye, c_weights, axis)
+    return out.astype(x.dtype), aux_loss, z_loss
+
+
 def moe_ffn(
     x: jax.Array,
     router_logits: jax.Array,
@@ -784,6 +841,8 @@ def moe_ffn(
     gate: str = "softmax",
     gate_bias=None,
     routed_scale: float = 1.0,
+    experts_held: Optional[int] = None,
+    first_expert: int = 0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Full per-shard MoE layer: route → dispatch → SwiGLU experts → combine.
 
@@ -814,6 +873,11 @@ def moe_ffn(
     gate / gate_bias / routed_scale: the gate every impl routes by
     (:func:`_gate_topk`): "softmax" (default) or "sigmoid_bias" with its
     per-expert choice bias [E]; ``routed_scale`` multiplies the weights.
+    experts_held / first_expert: this member holds experts ``[first_expert,
+    first_expert + experts_held)`` of the ``E`` the router scores — ONE
+    member's share of a wider deployment (:func:`_moe_ffn_held`); the
+    weights are then ``[experts_held, ...]`` and the result the part of the
+    layer's sum its own experts give.
     Returns (out [T, H], aux_loss, z_loss).
     """
     t, h = x.shape
@@ -821,6 +885,15 @@ def moe_ffn(
     e = router_logits.shape[-1]
     w = lax.axis_size(axis)
     wire_dtype = resolve_wire_dtype(wire_fp8, wire_dtype)
+    if experts_held is not None and experts_held != e:
+        if w > 1 or impl == "ll":
+            raise ValueError(
+                f"a held share ({experts_held} of {e} experts) is one "
+                f"member's, computed without an exchange: EP world {w}, "
+                f"impl {impl!r} (want world 1, 'sort' or 'dense')")
+        return _moe_ffn_held(
+            x, router_logits, w_gate, w_up, w_down, axis, experts_held,
+            first_expert, num_selected, capacity_factor, impl, **gating)
     if impl == "ll":
         from uccl_tpu.ep.ll import ll_moe_ffn
 

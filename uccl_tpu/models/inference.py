@@ -12,6 +12,7 @@ decode step hits the same compiled executable.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, NamedTuple, Tuple
 
@@ -113,21 +114,80 @@ def _attend_latent(q_lat, q_rope, ckv_cache, kr_cache, length, scale):
         return o.reshape(b, sq, nh, r)
 
 
-def kv_row_shapes(cfg):
+def layer_kinds(cfg) -> Tuple[str, ...]:
+    """The per-layer attention kinds ("full" | "window") of a description
+    whose layers differ (``cfg.layer_kinds``); empty where every layer is
+    the same block (the dense stack, Mixtral, the latent family)."""
+    return tuple(getattr(cfg, "layer_kinds", ()) or ())
+
+
+def indexed_groups(names):
+    """``[(name, index among the equal names before it)]``: where layer i
+    lies in the stacked group its name names."""
+    seen: Dict[str, int] = {}
+    out = []
+    for name in names:
+        out.append((name, seen.get(name, 0)))
+        seen[name] = seen.get(name, 0) + 1
+    return out
+
+
+def cache_groups(cfg):
+    """Where each layer's cached rows live: ``[(group, index in group)]`` by
+    layer. Layers of one attention kind share one stacked cache array (a
+    GROUP: its own row shape and, in the slot pool, its own number of rows
+    a slot — all positions for "full", a ring for "window"). A description
+    without layer kinds has the one group ``None``: the plain stacked array."""
+    return indexed_groups(layer_kinds(cfg) or [None] * cfg.n_layers)
+
+
+def group_array(pool, group):
+    """A cache group's array of ``pool``: the plain stacked array (group
+    ``None``) or ``pool[group]``."""
+    return pool if group is None else pool[group]
+
+
+def _with_group(pool, group, new):
+    """``pool`` with the group's array replaced by ``new``."""
+    return new if group is None else {**pool, group: new}
+
+
+def kv_row_shapes(cfg, kind=None):
     """Per-position shapes of the two cache arrays of one layer, from the
     model description: ``gqa`` keeps keys and values ``[Hkv, D]`` each;
     ``mla`` keeps ONE latent row as its normalised compressed part
     ``[kv_lora_rank]`` (in ``k``) and the shared rotary key
-    ``[qk_rope_dim]`` (in ``v``) — no value row at all."""
+    ``[qk_rope_dim]`` (in ``v``) — no value row at all. A description with
+    layer kinds gives the row of ``kind``, FLAT: that kind's KV heads x
+    ``head_dim`` numbers of keys, KV heads x ``v_head_dim`` of values. Flat,
+    because the chip's default layout of ``[.., S, 4, 192]`` puts the
+    positions minor (no lane padding of 192), and a program that scatters
+    rows then copies the whole group in and out (2 x 2.5 ms a program on
+    the chip: PERF.md section 6, PR 36); ``[.., S, 768]`` is row-major as
+    it stands."""
     if getattr(cfg, "attn", "gqa") == "mla":
         return (cfg.kv_lora_rank,), (cfg.qk_rope_dim,)
+    if layer_kinds(cfg):
+        hkv = cfg.kv_heads(kind)
+        return (hkv * cfg.head_dim,), \
+            (hkv * (cfg.v_head_dim or cfg.head_dim),)
     return (cfg.n_kv_heads, cfg.head_dim), (cfg.n_kv_heads, cfg.head_dim)
+
+
+WINDOW_GROUPS_STAY = (
+    "a pool with window groups keeps a slot's last window - 1 positions of "
+    "its window layers in a ring and nothing older, so a slot's rows cannot "
+    "be handed to another slot, a tier or a peer as a prefix: ")
 
 
 def kv_wire_dims(cfg):
     """(heads, width) of the two EQUAL arrays one layer's cached position
     leaves a pool as (``export_rows``): gqa's own ``[Hkv, D]``; a latent
-    row's two halves, ``[1, (kv_lora_rank + qk_rope_dim) / 2]`` each."""
+    row's two halves, ``[1, (kv_lora_rank + qk_rope_dim) / 2]`` each. A
+    pool of cache groups (layer kinds) has no one row to put on a wire."""
+    if layer_kinds(cfg):
+        raise ValueError(WINDOW_GROUPS_STAY + "the disaggregated wire "
+                         "format describes one row shape for every layer")
     k_row, v_row = kv_row_shapes(cfg)
     if k_row == v_row:
         return k_row
@@ -208,9 +268,110 @@ def _mla_attention(x, lp, ckv_pool, kr_pool, positions, length, write,
     return x, ckv_pool, kr_pool
 
 
-def _attention_of(cfg):
-    """The attention kind of a model description: ``cfg.attn`` ("gqa" |
-    "mla"; descriptions without the field are gqa)."""
+# One batch row of scores at a time once all rows together pass this many
+# numbers (0.5 GB of float32): the pool-wide prefill rung over a 16,384-row
+# pool is [8, 64, 128, 16384] = 4.3 GB at once and 0.54 GB a row.
+_SCORES_AT_ONCE = 1 << 27
+
+
+def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
+                       lora=None, *, kind):
+    """One layer's grouped-query attention of a description with layer
+    kinds, with its residual — ONE function for "full" and "window" layers,
+    which differ in numbers the description gives by kind (KV heads, theta,
+    whether the softmax has a sink) and in the mask.
+
+    Queries are ``n_heads`` x ``head_dim``; keys ``Hkv`` x ``head_dim``,
+    values ``Hkv`` x ``v_head_dim`` scaled by ``value_scale``; the leading
+    ``rotary_dim`` numbers of each query and key head rotate. Query head j
+    reads KV head ``j // (n_heads / Hkv)``: the queries are reshaped
+    ``[B, Sq, Hkv, G, D]`` against the cache ``[B, K, Hkv, .]`` — the pool
+    is never repeated. Scores ``q.k / sqrt(head_dim)``.
+
+    The cache rows ``K`` of this layer's group are a RING: position ``q``
+    lives at row ``q % K``, so for a query at position ``p`` row ``r`` holds
+    position ``p - ((p - r) mod K)`` — the newest position at or before
+    ``p`` that maps to it (whatever was written there later, by a padded
+    chunk or a rejected draft, is a position past ``p`` and reads as one
+    ``K`` older: masked). A "full" layer's group has a row for every
+    position (``K = S_max``) and the formula reduces to ``q = r``, ``r <=
+    p``; a "window" layer sees ``max(0, p - window + 1) <= q <= p``. A kind
+    in ``cfg.sink`` adds one column to the softmax that holds the head's
+    learned logit ``lp["sink"]`` and carries no value:
+    ``P_k = exp(s_k) / (exp(sink) + sum_j exp(s_j))``.
+
+    ``write(pool, new)`` as in :func:`_gqa_attention`. Returns (x', k_pool',
+    v_pool')."""
+    if lora is not None:
+        raise ValueError("LoRA adapters target one (wq, wv) shape for every "
+                         "layer; layer kinds have their own")
+    b, s, _ = x.shape
+    nh, d = cfg.n_heads, cfg.head_dim
+    dv = cfg.v_head_dim or d
+    hkv = cfg.kv_heads(kind)
+    theta = cfg.theta(kind)
+    with jax.named_scope("attn.qkv." + kind):
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        q = (h @ lp["wq"].astype(h.dtype)).reshape(b, s, nh, d)
+        kk = (h @ lp["wk"].astype(h.dtype)).reshape(b, s, hkv, d)
+        v = (h @ lp["wv"].astype(h.dtype)).reshape(b, s, hkv, dv)
+        if cfg.value_scale != 1.0:
+            v = v * jnp.asarray(cfg.value_scale, v.dtype)
+        q = rope(q, positions, theta, cfg.rotary_dim)
+        kk = rope(kk, positions, theta, cfg.rotary_dim)
+    with jax.named_scope("attn.kv_write." + kind):
+        # rows are cached flat (:func:`kv_row_shapes`): [.., Hkv * D]
+        k_pool, k_cache = write(k_pool, kk.reshape(b, s, hkv * d))
+        v_pool, v_cache = write(v_pool, v.reshape(b, s, hkv * dv))
+    rows = k_cache.shape[1]
+    k_cache = k_cache.reshape(b, rows, hkv, d)
+    v_cache = v_cache.reshape(b, rows, hkv, dv)
+    qpos = positions if positions.ndim == 2 else positions[None]  # [B|1, Sq]
+    sink = None
+    if kind in cfg.sink:
+        sink = lp["sink"].astype(jnp.float32).reshape(hkv, nh // hkv, 1)
+
+    def core(qg, kc, vc, qp):
+        """[B', Sq, Hkv, G, D] queries over [B', K, Hkv, .] rows."""
+        sc = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kc,
+                        preferred_element_type=jnp.float32)
+        sc = sc / jnp.sqrt(jnp.float32(d))
+        held = qp[..., None] - jnp.mod(qp[..., None] - jnp.arange(rows), rows)
+        seen = held >= 0
+        if kind == "window":
+            seen = seen & (held > qp[..., None] - cfg.window)
+        sc = jnp.where(seen[:, None, None], sc, -1e30)
+        top = jnp.max(sc, axis=-1)
+        if sink is not None:
+            top = jnp.maximum(top, sink)
+        e = jnp.exp(sc - top[..., None])
+        den = jnp.sum(e, axis=-1)
+        if sink is not None:
+            den = den + jnp.exp(sink - top)
+        p = (e / den[..., None]).astype(vc.dtype)
+        return jnp.einsum("bhgqk,bkhd->bqhgd", p, vc)
+
+    with jax.named_scope("attn.core." + kind):
+        qg = q.reshape(b, s, hkv, nh // hkv, d)
+        qp = jnp.broadcast_to(qpos, (b, s))
+        if b > 1 and b * nh * s * rows > _SCORES_AT_ONCE:
+            attn = lax.map(
+                lambda a: core(*(t[None] for t in a))[0],
+                (qg, k_cache, v_cache, qp))
+        else:
+            attn = core(qg, k_cache, v_cache, qp)
+    with jax.named_scope("attn.out." + kind):
+        x = x + attn.reshape(b, s, nh * dv) @ lp["wo"].astype(attn.dtype)
+    return x, k_pool, v_pool
+
+
+def _attention_of(cfg, i: int = 0):
+    """The attention of layer ``i`` of a model description: ``cfg.attn``
+    ("gqa" | "mla"; descriptions without the field are gqa), and where the
+    description has layer kinds, the grouped function at layer ``i``'s."""
+    kinds = layer_kinds(cfg)
+    if kinds:
+        return functools.partial(_grouped_attention, kind=kinds[i])
     kind = getattr(cfg, "attn", "gqa")
     if kind == "gqa":
         return _gqa_attention
@@ -219,10 +380,14 @@ def _attention_of(cfg):
     raise ValueError(f"unknown attention kind {kind!r} (want 'gqa' or 'mla')")
 
 
-def _layer_params(params, i: int):
+def _layer_params(params, i: int, cfg=None):
     """Layer ``i``'s leaves. Layers come in stacked groups: the leading
     ``dense_blocks`` (where the model has a dense-FFN prefix: their leading
-    dim is how many) and then ``blocks``."""
+    dim is how many) and then ``blocks``; a description with layer kinds
+    (``cfg.param_groups()``) names each layer's group and its index there."""
+    if cfg is not None and layer_kinds(cfg):
+        group, j = cfg.param_groups()[i]
+        return jax.tree.map(lambda a: a[j], params[group])
     dense = params.get("dense_blocks")
     if dense is not None:
         n = jax.tree.leaves(dense)[0].shape[0]
@@ -256,22 +421,28 @@ def _forward_cached(
     kind comes from the model description (:func:`_attention_of`). The
     layer-stacked cache arrays are written in place, as
     :func:`_forward_slots` writes the pool's: carried through the layer
-    loop, one ``dynamic_update_slice`` a layer, attention over a slice."""
+    loop, one ``dynamic_update_slice`` a layer, attention over a slice.
+    Where the description has layer kinds, ``cache.k`` / ``cache.v`` are
+    ``{group: array}`` (:func:`cache_groups`), every group ``S_max`` rows
+    here: the one-shot path keeps a window layer's every position."""
     b, s = tokens.shape
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0).astype(cache.k.dtype)
-    positions = cache.length + jnp.arange(s)
-    attention = _attention_of(cfg)
     k, v = cache.k, cache.v
-    for i in range(cfg.n_layers):
-        def write(pool, new, i=i):
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(
+            jax.tree.leaves(k)[0].dtype)
+    positions = cache.length + jnp.arange(s)
+    for i, (group, gi) in enumerate(cache_groups(cfg)):
+        def write(pool, new, gi=gi):
             pool = lax.dynamic_update_slice(
                 pool, new[None],
-                (i, 0, cache.length) + (0,) * (new.ndim - 2))
-            return pool, pool[i]
+                (gi, 0, cache.length) + (0,) * (new.ndim - 2))
+            return pool, pool[gi]
 
-        lp = _layer_params(params, i)
-        x, k, v = attention(x, lp, k, v, positions, cache.length, write, cfg)
+        lp = _layer_params(params, i, cfg)
+        x, nk, nv = _attention_of(cfg, i)(
+            x, lp, group_array(k, group), group_array(v, group), positions,
+            cache.length, write, cfg)
+        k, v = _with_group(k, group, nk), _with_group(v, group, nv)
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         x = x + (_dense_ffn(h2, lp) if ffn is None else ffn(h2, lp))
     logits = _head(x, params, cfg)
@@ -460,7 +631,10 @@ def _forward_slots(
     array just written — nothing is sliced out, collected and re-stacked, so
     a program that is handed its pool donated (every serving program is:
     serving/backend.py) returns the buffers it was given with B x S rows a
-    layer changed.
+    layer changed. Where the description has layer kinds the pool is
+    ``{group: array}`` (:func:`cache_groups`): a "full" group ``[L_full,
+    B_slots, S_max, ...]`` and a "window" group ``[L_win, B_slots, ring,
+    ...]``, each carried and written the same way.
 
     ``slots`` ([R] int32, B == R) makes the rows COMPACT: row r is slot
     ``slots[r]`` of the pool — its new rows are written there, and attention
@@ -475,38 +649,64 @@ def _forward_slots(
     byte-identical to the pre-adapter form.
     """
     b, s = tokens.shape
-    smax = cache.k.shape[2]
+    k, v = cache.k, cache.v
+    groups = cache_groups(cfg)
+    # rows a slot has in the plain pool, or in the full group of one with
+    # cache groups: S_max (window groups are rings, written below)
+    flat = k if groups[0][0] is None else k.get("full")
+    smax = flat.shape[2] if flat is not None else 0
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0).astype(cache.k.dtype)
+        x = jnp.take(params["embed"], tokens, axis=0).astype(
+            jax.tree.leaves(k)[0].dtype)
     positions = start[:, None] + jnp.arange(s)[None, :]  # [B, S]
     # masked slots write at index smax → dropped by the scatter; rows beyond
     # the cache end (a bucket overhanging S_max) drop the same way
     pos_write = jnp.where(write_mask[:, None], positions, smax)
     bidx = (jnp.arange(b) if slots is None else slots)[:, None]
-    attention = _attention_of(cfg)
-    k, v = cache.k, cache.v
-    for i in range(cfg.n_layers):
-        def write(pool, new, i=i):
-            pool = pool.at[i, bidx, pos_write].set(new, mode="drop")
+    pos_ring = None
+    if any(group == "window" for group, _ in groups):
+        # A window layer's group is a ring of ``rows``: position p lives at
+        # row p % rows. THE invariant: rows >= window - 1 + S, so a write at
+        # p .. p+S-1 only overwrites positions <= p - window, which no query
+        # at or after p can see. That is what makes a chunk after a chunk, a
+        # right-padded last chunk and a verify window's rejected rows
+        # harmless here exactly as they are in a flat pool: what they leave
+        # behind lies past the slot's length and is rewritten before a query
+        # reaches it, and what they overwrote was already out of every later
+        # query's window.
+        rows = k["window"].shape[2]
+        if rows < cfg.window - 1 + s:
+            raise ValueError(
+                f"a window layer's ring of {rows} rows cannot take a write "
+                f"of {s} positions: it must hold window - 1 + the widest "
+                f"write = {cfg.window - 1 + s} (window_ring)")
+        pos_ring = jnp.where(write_mask[:, None], positions % rows, rows)
+    for i, (group, gi) in enumerate(groups):
+        pos = pos_ring if group == "window" else pos_write
+
+        def write(pool, new, gi=gi, pos=pos):
+            pool = pool.at[gi, bidx, pos].set(new, mode="drop")
             if slots is None:
-                return pool, pool[i]
+                return pool, pool[gi]
             # a dynamic slice a row, straight from the stacked array (its
             # start clamps into the pool): one row is read where it lies;
             # a gather of whole slot rows cost the two-row program 1.6 ms
             # a layer on the chip
             zeros = (0,) * (pool.ndim - 2)
             return pool, jnp.concatenate([
-                lax.dynamic_slice(pool, (i, slots[r]) + zeros,
+                lax.dynamic_slice(pool, (gi, slots[r]) + zeros,
                                   (1, 1) + pool.shape[2:])[0]
                 for r in range(b)])
 
-        lp = _layer_params(params, i)
+        lp = _layer_params(params, i, cfg)
         lora = None
         if adapters is not None:
             def lora(h, target, i=i):
                 return _lora_delta(h, adapters[target], adapter_ids, i)
-        x, k, v = attention(x, lp, k, v, positions, start, write, cfg,
-                            lora=lora)
+        x, nk, nv = _attention_of(cfg, i)(
+            x, lp, group_array(k, group), group_array(v, group), positions,
+            start, write, cfg, lora=lora)
+        k, v = _with_group(k, group, nk), _with_group(v, group, nv)
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         x = x + (_dense_ffn(h2, lp) if ffn is None else ffn(h2, lp))
     logits = _head(x, params, cfg)

@@ -20,16 +20,23 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 def rope(
-    x: jax.Array, positions: jax.Array, theta: float = 10000.0
+    x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+    rotary_dim: int = 0,
 ) -> jax.Array:
     """Rotary position embedding, split-half (Llama) convention.
 
     x: [B, S, H, D]; positions: [S] absolute positions (callers under sequence
     sharding pass ``cp_index * S_local + arange(S_local)``), or [B, S]
     per-sequence positions (the slot-pool serving path, where every slot sits
-    at its own decode offset).
+    at its own decode offset). ``rotary_dim`` (0 = all of D) rotates the
+    leading ``rotary_dim`` numbers of each head — split-half within them —
+    and leaves the rest as they are (a partial rotary factor).
     """
     d = x.shape[-1]
+    if 0 < rotary_dim < d:
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], positions, theta), x[..., rotary_dim:]],
+            axis=-1)
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # [(B,) S, half]

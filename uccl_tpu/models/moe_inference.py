@@ -25,6 +25,7 @@ W-shard mesh generate identical tokens — sharding is semantics-free.
 from __future__ import annotations
 
 import math
+from collections import Counter
 from dataclasses import dataclass
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -85,10 +86,52 @@ class MoEServeConfig:
     param_dtype: str = "float32"  # storage dtype of the weight matrices
     # (norms and the gate bias stay float32); activations and cache are
     # float32 either way, so a bfloat16 matrix is upcast where it is used
+    # -- layer kinds: gqa layers that differ by layer (MiMo-V2-Flash: window
+    # and full attention side by side). Empty = every layer the one block.
+    layer_kinds: Tuple[str, ...] = ()  # per layer: "full" | "window"
+    window: int = 0  # a window layer's query at p sees [p - window + 1, p]
+    window_kv_heads: int = 0  # the window kind's KV heads (n_kv_heads: full)
+    window_rope_theta: float = 0.0  # the window kind's theta (rope_theta: full)
+    window_ring: int = 0  # rows a slot keeps of a window layer (0: 2 x window)
+    rotary_dim: int = 0  # leading numbers of a head that rotate (0: all)
+    value_scale: float = 1.0  # multiplies the values before attention
+    sink: Tuple[str, ...] = ()  # kinds whose softmax has a learned sink column
+    # (with layer kinds, gqa's values are v_head_dim wide: 0 = head_dim)
+    # -- this member's share of a wider deployment: the router keeps its
+    # moe_experts outputs, experts [first_expert, first_expert + held) live
+    # here and only their part of the layer's sum is computed (ep.ops.moe_ffn)
+    experts_held: int = 0  # 0 = all moe_experts
+    first_expert: int = 0
 
     def __post_init__(self):
         if self.attn not in ("gqa", "mla"):
             raise ValueError(f"attn {self.attn!r}: want 'gqa' or 'mla'")
+        kinds = self.layer_kinds
+        if kinds:
+            if self.attn != "gqa" or len(kinds) != self.n_layers \
+                    or set(kinds) - {"full", "window"}:
+                raise ValueError(
+                    f"layer_kinds names 'full' or 'window' for each of the "
+                    f"{self.n_layers} gqa layers; got {kinds} ({self.attn})")
+            if "window" in kinds and (self.window < 1
+                                      or self.window_kv_heads < 1):
+                raise ValueError("window layers need window and "
+                                 "window_kv_heads")
+            if set(self.sink) - {"full", "window"} or self.rotary_dim % 2:
+                raise ValueError("sink names layer kinds; rotary_dim is even")
+            if "window" in kinds and self.ring < 2 * self.window - 1:
+                raise ValueError(
+                    f"window_ring {self.ring} must hold window - 1 + a write "
+                    f"of at least a window: {2 * self.window - 1}")
+        elif self.window or self.sink or self.rotary_dim \
+                or self.value_scale != 1.0 \
+                or (self.attn == "gqa" and self.v_head_dim):
+            raise ValueError("window, sink, rotary_dim, value_scale and a "
+                             "gqa v_head_dim belong to layer_kinds")
+        if not 0 <= self.first_expert <= self.moe_experts - self.n_held:
+            raise ValueError(
+                f"experts [{self.first_expert}, {self.first_expert} + "
+                f"{self.n_held}) are not among the {self.moe_experts} routed")
         if self.gate not in ep_ops.GATES:
             raise ValueError(f"gate {self.gate!r}: want one of "
                              f"{ep_ops.GATES}")
@@ -112,22 +155,118 @@ class MoEServeConfig:
     def n_moe_layers(self) -> int:
         return self.n_layers - self.first_k_dense
 
+    @property
+    def n_held(self) -> int:
+        """Experts resident here: the queues and the expert leaves."""
+        return self.experts_held or self.moe_experts
+
+    @property
+    def ring(self) -> int:
+        """Rows a slot keeps of each window layer (``MoESlotCache``): at
+        least ``window - 1 + the widest write`` (a prefill chunk, a verify
+        window), checked where the write's width is known
+        (``inference._forward_slots``, ``ServingEngine``)."""
+        return self.window_ring or 2 * self.window
+
+    def kv_heads(self, kind: str) -> int:
+        return self.window_kv_heads if kind == "window" else self.n_kv_heads
+
+    def theta(self, kind: str) -> float:
+        return self.window_rope_theta if kind == "window" \
+            else self.rope_theta
+
+    def param_groups(self):
+        """``[(group, index in group)]`` by layer: layers are stacked by
+        (FFN kind, attention kind). The groups the uniform descriptions
+        have keep their names (``dense_blocks``, ``blocks``: a "full" layer
+        is the block they always were); a window layer's group is
+        ``window_blocks`` / ``dense_window_blocks``."""
+        kinds = self.layer_kinds or ("full",) * self.n_layers
+        return inference.indexed_groups(
+            ("dense_" if i < self.first_k_dense else "")
+            + ("window_" if kinds[i] == "window" else "") + "blocks"
+            for i in range(self.n_layers))
+
     @classmethod
     def from_hf(cls, hf: Dict[str, Any], **overrides) -> "MoEServeConfig":
         """The description from a Hugging Face ``config.json`` dict —
-        ``mixtral`` (uniform gqa/softmax blocks) or the
+        ``mixtral`` (uniform gqa/softmax blocks), the
         ``deepseek_v3``/``glm4_moe_lite`` family (latent attention, leading
-        dense layers, sigmoid-bias gate, shared experts). ``overrides`` are
-        this class's own fields (capacity_factor, param_dtype ...)."""
+        dense layers, sigmoid-bias gate, shared experts) or ``mimo_v2_flash``
+        (keyed on ``hybrid_layer_pattern``: window and full gqa layers with
+        their own KV heads and thetas, a partial rotary factor, scaled
+        values, a sink in the window softmax, sigmoid-bias experts; the
+        first ``num_hidden_layers`` entries of its two per-layer lists are
+        read). A file that states a member's share gives ``n_routed_experts``
+        as the experts held and ``router_experts`` as the router's width
+        (``first_expert``: the first held). ``overrides`` are this class's
+        own fields (capacity_factor, param_dtype ...)."""
         heads = hf["num_attention_heads"]
+        n_layers = hf["num_hidden_layers"]
         kw: Dict[str, Any] = dict(
             vocab=hf["vocab_size"], dim=hf["hidden_size"],
-            n_layers=hf["num_hidden_layers"], n_heads=heads,
+            n_layers=n_layers, n_heads=heads,
             rope_theta=float(hf.get("rope_theta", 10000.0)),
-            norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            norm_eps=float(hf.get("rms_norm_eps")
+                           or hf.get("layernorm_epsilon") or 1e-6),
             moe_topk=hf["num_experts_per_tok"],
         )
-        if "kv_lora_rank" in hf:
+        if "hybrid_layer_pattern" in hf:
+            pattern = list(hf["hybrid_layer_pattern"])[:n_layers]
+            freq = list(hf["moe_layer_freq"])[:n_layers]
+            if len(pattern) < n_layers or len(freq) < n_layers:
+                raise ValueError(
+                    f"hybrid_layer_pattern / moe_layer_freq name fewer than "
+                    f"num_hidden_layers ({n_layers}) layers")
+            dense = next((i for i, m in enumerate(freq) if m), n_layers)
+            if not all(freq[dense:]):
+                raise ValueError("a dense FFN after the first expert layer "
+                                 "(moe_layer_freq) is not built")
+            if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+                raise ValueError("group-limited routing (n_group > 1) is "
+                                 "not built")
+            if hf.get("scoring_func", "sigmoid") != "sigmoid" \
+                    or not hf.get("norm_topk_prob", True):
+                raise ValueError("the gate built is sigmoid scores, "
+                                 "renormalised over the chosen")
+            if hf.get("n_shared_experts") or hf.get("attention_bias"):
+                raise ValueError("shared experts / attention biases beside "
+                                 "layer kinds are not built")
+            same = (("swa_num_attention_heads", heads),
+                    ("swa_head_dim", hf["head_dim"]),
+                    ("swa_v_head_dim", hf["v_head_dim"]),
+                    ("sliding_window_size", hf["sliding_window"]))
+            for key, want in same:
+                if hf.get(key, want) != want:
+                    raise ValueError(f"{key} {hf[key]} != {want}: window "
+                                     f"layers with their own query heads or "
+                                     f"head sizes are not built")
+            routed = hf.get("router_experts", hf["n_routed_experts"])
+            held = hf["n_routed_experts"]
+            kw.update(
+                n_kv_heads=hf["num_key_value_heads"],
+                head_dim=hf["head_dim"], v_head_dim=hf["v_head_dim"],
+                layer_kinds=tuple("window" if p else "full"
+                                  for p in pattern),
+                window=hf["sliding_window"],
+                window_kv_heads=hf["swa_num_key_value_heads"],
+                window_rope_theta=float(hf["swa_rope_theta"]),
+                rotary_dim=int(hf.get("partial_rotary_factor", 1.0)
+                               * hf["head_dim"]) // 2 * 2,
+                value_scale=float(hf.get("attention_value_scale") or 1.0),
+                sink=tuple(kind for kind, key in (
+                    ("full", "add_full_attention_sink_bias"),
+                    ("window", "add_swa_attention_sink_bias"))
+                    if hf.get(key)),
+                moe_experts=routed,
+                experts_held=held if held != routed else 0,
+                first_expert=hf.get("first_expert", 0),
+                moe_ffn=hf["moe_intermediate_size"],
+                first_k_dense=dense, dense_ffn=hf["intermediate_size"],
+                gate="sigmoid_bias",
+                routed_scale=float(hf.get("routed_scaling_factor") or 1.0),
+            )
+        elif "kv_lora_rank" in hf:
             if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
                 raise ValueError("group-limited routing (n_group > 1) is "
                                  "not built")
@@ -164,25 +303,50 @@ class MoEServeConfig:
         return cls(**kw)
 
 
+def _cache_arrays(cfg: MoEServeConfig, world: int, batch_local: int,
+                  rows: Dict[Optional[str], int], dtype, sharding=None):
+    """The (k, v) of a cache: one ``[W, L, B_loc, rows, *row]`` array each,
+    or — where the description has layer kinds — ``{group: array}`` with a
+    group's own layer count, ``rows[group]`` and row shape
+    (``inference.cache_groups`` / ``kv_row_shapes``)."""
+    layers = Counter(group for group, _ in inference.cache_groups(cfg))
+    out = []
+    for which in (0, 1):
+        arrays = {
+            group: jnp.zeros(
+                (world, n, batch_local, rows[group])
+                + kv_row_shapes(cfg, group)[which], dtype, device=sharding)
+            for group, n in layers.items()}
+        out.append(arrays[None] if None in arrays else arrays)
+    return out
+
+
 class MoEKVCache(NamedTuple):
     k: jax.Array  # [W, L, B_loc, S_max, *k_row] (inference.kv_row_shapes:
-    v: jax.Array  # gqa [Hkv, D] each; mla [kv_lora_rank] and [qk_rope_dim])
+    v: jax.Array  # gqa [Hkv, D] each; mla [kv_lora_rank] and [qk_rope_dim]);
+    # with layer kinds {group: [W, L_group, B_loc, S_max, *row of the kind]}
     length: jax.Array  # [W] int32
 
     @staticmethod
     def empty(cfg: MoEServeConfig, world: int, batch_local: int,
               max_seq: int, dtype=jnp.float32) -> "MoEKVCache":
-        lead = (world, cfg.n_layers, batch_local, max_seq)
-        k_row, v_row = kv_row_shapes(cfg)
-        return MoEKVCache(
-            jnp.zeros(lead + k_row, dtype), jnp.zeros(lead + v_row, dtype),
-            jnp.zeros((world,), jnp.int32),
-        )
+        k, v = _cache_arrays(cfg, world, batch_local,
+                             {None: max_seq, "full": max_seq,
+                              "window": max_seq}, dtype)
+        return MoEKVCache(k, v, jnp.zeros((world,), jnp.int32))
 
 
 class MoESlotCache(NamedTuple):
     """Slot-pool KV cache: one length PER SLOT (not per shard) — the
-    continuous-batching engine admits/frees [w, b_loc] rows independently."""
+    continuous-batching engine admits/frees [w, b_loc] rows independently.
+
+    Where the description has layer kinds the pool is cache GROUPS by
+    attention kind, ``k`` and ``v`` each ``{"full": [W, L_full, B_loc,
+    S_max, Hkv * .], "window": [W, L_win, B_loc, ring, Hkv_win * .]}``: a
+    full layer keeps every position of a slot, a window layer a ring of
+    ``cfg.ring`` rows (position p at row p % ring). Rows of such a pool
+    cannot be exported, imported or copied between slots: the three views
+    below raise (``inference.WINDOW_GROUPS_STAY``)."""
 
     k: jax.Array  # [W, L, B_loc, S_max, *k_row] (inference.kv_row_shapes)
     v: jax.Array  # [W, L, B_loc, S_max, *v_row]
@@ -192,13 +356,15 @@ class MoESlotCache(NamedTuple):
     def empty(cfg: MoEServeConfig, world: int, batch_local: int,
               max_seq: int, dtype=jnp.float32,
               sharding=None) -> "MoESlotCache":
-        lead = (world, cfg.n_layers, batch_local, max_seq)
-        k_row, v_row = kv_row_shapes(cfg)
+        k, v = _cache_arrays(cfg, world, batch_local,
+                             {None: max_seq, "full": max_seq,
+                              "window": cfg.ring}, dtype, sharding)
         return MoESlotCache(
-            jnp.zeros(lead + k_row, dtype, device=sharding),
-            jnp.zeros(lead + v_row, dtype, device=sharding),
-            jnp.zeros((world, batch_local), jnp.int32, device=sharding),
-        )
+            k, v, jnp.zeros((world, batch_local), jnp.int32, device=sharding))
+
+    def _one_group(self, what: str) -> None:
+        if isinstance(self.k, dict):
+            raise ValueError(inference.WINDOW_GROUPS_STAY + what)
 
     # -- slot KV export/import views (the disaggregation surface) ----------
     #
@@ -219,7 +385,7 @@ class MoESlotCache(NamedTuple):
             for new, old in zip((k, v, lengths), self)))
 
     def _loc(self, slot: int):
-        b_loc = self.k.shape[2]
+        b_loc = self.lengths.shape[1]
         return slot // b_loc, slot % b_loc
 
     def export_rows(self, slot: int, lo: int, hi: int):
@@ -232,6 +398,7 @@ class MoESlotCache(NamedTuple):
         equal arrays and never looks inside them."""
         import numpy as np
 
+        self._one_group("export_rows has no one array a layer to hand out")
         w, b = self._loc(slot)
         k = np.asarray(self.k[w, :, b, lo:hi])
         v = np.asarray(self.v[w, :, b, lo:hi])
@@ -247,6 +414,9 @@ class MoESlotCache(NamedTuple):
                     length: int) -> "MoESlotCache":
         import numpy as np
 
+        self._one_group("import_rows would need a prefix's last window - 1 "
+                        "positions of every window layer, which no exporter "
+                        "keeps")
         w, b = self._loc(slot)
         n = k_rows.shape[1]
         # np.array (not asarray): device gathers come back read-only
@@ -269,6 +439,8 @@ class MoESlotCache(NamedTuple):
     def copy_prefix(self, dst: int, src: int, n: int) -> "MoESlotCache":
         import numpy as np
 
+        self._one_group("copy_prefix finds the donor's ring holding its "
+                        "newest positions, not the prefix's last window - 1")
         dw, db = self._loc(dst)
         sw, sb = self._loc(src)
         k = np.array(self.k)
@@ -288,15 +460,23 @@ _SPLIT_KEY = {"embed": 0, "wq": 1, "wk": 2, "wv": 3, "wo": 4, "router": 5,
               "we_gate": 6, "we_up": 7, "we_down": 8, "head": 9}
 _FOLD_KEY = {"wq_a": 21, "wq_b": 22, "wkv_a": 23, "wkv_b": 24,
              "ws_gate": 25, "ws_up": 26, "ws_down": 27, "router_bias": 28,
-             "w_gate": 29, "w_up": 30, "w_down": 31}
+             "w_gate": 29, "w_up": 30, "w_down": 31, "sink": 32}
 _DENSE_GROUP_FOLD = 64
+# a group's fold: the groups that always were keep theirs (so the uniform
+# descriptions' weights are what they were); a window group folds 128 more
+_GROUP_FOLD = {"blocks": 0, "dense_blocks": _DENSE_GROUP_FOLD,
+               "window_blocks": 128,
+               "dense_window_blocks": 128 + _DENSE_GROUP_FOLD}
+SINK_SCALE = 1.0  # seeded sink logits: as large as the scores they sit
+# beside, so that a softmax without its sink column is told apart
 ROUTER_BIAS_SCALE = 0.01  # seeded gate bias: choosing by score + bias and
 # weighing by the score alone are then told apart
 
 
-def _attn_shapes(cfg: MoEServeConfig):
+def _attn_shapes(cfg: MoEServeConfig, kind: str = "full"):
     """{leaf: (shape, fan-in)} of one layer's attention matrices, and its
-    norm leaves, for the description's attention kind."""
+    norm leaves, for the description's attention kind (``kind``: the
+    layer's, where the description has layer kinds)."""
     h = cfg.dim
     if cfg.attn == "mla":
         nh = cfg.n_heads
@@ -312,20 +492,27 @@ def _attn_shapes(cfg: MoEServeConfig):
         }, {"ln1": h, "ln2": h, "q_a_norm": cfg.q_lora_rank,
             "kv_a_norm": cfg.kv_lora_rank}
     qd = cfg.n_heads * cfg.head_dim
-    kvd = cfg.n_kv_heads * cfg.head_dim
-    return {"wq": ((h, qd), h), "wk": ((h, kvd), h), "wv": ((h, kvd), h),
-            "wo": ((qd, h), qd)}, {"ln1": h, "ln2": h}
+    hkv = cfg.kv_heads(kind)
+    vd = cfg.v_head_dim or cfg.head_dim  # gqa's is set with layer kinds only
+    od = cfg.n_heads * vd
+    return {"wq": ((h, qd), h), "wk": ((h, hkv * cfg.head_dim), h),
+            "wv": ((h, hkv * vd), h), "wo": ((od, h), od)}, \
+        {"ln1": h, "ln2": h}
 
 
 def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
-    """Global parameter tree (experts carry the full [E, ...] axis). Layers
-    come in stacked groups: ``blocks`` [n_moe_layers, ...] and, where the
-    description has a dense prefix, ``dense_blocks`` [first_k_dense, ...].
+    """Global parameter tree (the expert leaves carry the ``[n_held, ...]``
+    axis: all the routed experts, or this member's share). Layers come in
+    stacked groups by (FFN kind, attention kind) — ``cfg.param_groups()``:
+    ``blocks`` [n_moe_layers, ...] and, where the description has a dense
+    prefix, ``dense_blocks`` [first_k_dense, ...]; with layer kinds the
+    window layers' ``window_blocks`` / ``dense_window_blocks`` beside them.
     Every matrix is drawn in float32 (embedding 0.02, others 1/sqrt(fan-in))
-    and stored in ``cfg.param_dtype``; norms are ones and the gate bias a
-    normal of scale 0.01, both float32."""
+    and stored in ``cfg.param_dtype``; norms are ones, the gate bias a
+    normal of scale 0.01 and a sink kind's per-head logit a normal of scale
+    1.0, all float32."""
     k = jax.random.split(key, 12)
-    h, f, e = cfg.dim, cfg.moe_ffn, cfg.moe_experts
+    h, f, e = cfg.dim, cfg.moe_ffn, cfg.n_held
     dtype = jnp.dtype(cfg.param_dtype)
 
     def rnd(name, shape, scale, group=0):
@@ -335,37 +522,43 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
         return (jax.random.normal(kk, shape, jnp.float32)
                 * scale).astype(dtype)
 
-    def group(n, ffn_shapes, fold):
-        mats, norms = _attn_shapes(cfg)
-        mats = {**mats, **ffn_shapes}
-        out = {name: jnp.ones((n, width), jnp.float32)
-               for name, width in norms.items()}
-        out.update({name: rnd(name, (n,) + shape, 1.0 / math.sqrt(fan), fold)
-                    for name, (shape, fan) in mats.items()})
-        return out
-
-    moe = {"router": ((h, e), h), "we_gate": ((e, h, f), h),
+    moe = {"router": ((h, cfg.moe_experts), h), "we_gate": ((e, h, f), h),
            "we_up": ((e, h, f), h), "we_down": ((e, f, h), f)}
     if cfg.shared_ffn:
         fs = cfg.shared_ffn
         moe.update({"ws_gate": ((h, fs), h), "ws_up": ((h, fs), h),
                     "ws_down": ((fs, h), fs)})
+    fd = cfg.dense_ffn
+    dense = {"w_gate": ((h, fd), h), "w_up": ((h, fd), h),
+             "w_down": ((fd, h), fd)}
+
+    def group(name, n):
+        kind = "window" if "window_" in name else "full"
+        fold = _GROUP_FOLD[name]
+        mats, norms = _attn_shapes(cfg, kind)
+        is_dense = name.startswith("dense_")
+        mats = {**mats, **(dense if is_dense else moe)}
+        out = {leaf: jnp.ones((n, width), jnp.float32)
+               for leaf, width in norms.items()}
+        out.update({leaf: rnd(leaf, (n,) + shape, 1.0 / math.sqrt(fan), fold)
+                    for leaf, (shape, fan) in mats.items()})
+        if cfg.gate == "sigmoid_bias" and not is_dense:
+            out["router_bias"] = jax.random.normal(
+                jax.random.fold_in(key, fold + _FOLD_KEY["router_bias"]),
+                (n, cfg.moe_experts), jnp.float32) * ROUTER_BIAS_SCALE
+        if kind in cfg.sink:
+            out["sink"] = jax.random.normal(
+                jax.random.fold_in(key, fold + _FOLD_KEY["sink"]),
+                (n, cfg.n_heads), jnp.float32) * SINK_SCALE
+        return out
+
+    sizes = Counter(name for name, _ in cfg.param_groups())
     params = {
         "embed": rnd("embed", (cfg.vocab, h), 0.02),
-        "blocks": group(cfg.n_moe_layers, moe, 0),
         "final_norm": jnp.ones((h,), jnp.float32),
         "head": rnd("head", (h, cfg.vocab), 1.0 / math.sqrt(h)),
     }
-    if cfg.gate == "sigmoid_bias":
-        params["blocks"]["router_bias"] = jax.random.normal(
-            jax.random.fold_in(key, _FOLD_KEY["router_bias"]),
-            (cfg.n_moe_layers, e), jnp.float32) * ROUTER_BIAS_SCALE
-    if cfg.first_k_dense:
-        fd = cfg.dense_ffn
-        params["dense_blocks"] = group(
-            cfg.first_k_dense,
-            {"w_gate": ((h, fd), h), "w_up": ((h, fd), h),
-             "w_down": ((fd, h), fd)}, _DENSE_GROUP_FOLD)
+    params.update({name: group(name, n) for name, n in sizes.items()})
     return params
 
 
@@ -409,6 +602,8 @@ def _moe_block(cfg: MoEServeConfig, impl: str):
             gate=cfg.gate,
             gate_bias=lp.get("router_bias"),
             routed_scale=cfg.routed_scale,
+            experts_held=cfg.experts_held or None,
+            first_expert=cfg.first_expert,
         )
         if "ws_gate" in lp:
             with jax.named_scope("moe.shared"):
@@ -442,6 +637,22 @@ def _is_expert_leaf(path) -> bool:
     return path[-1].key in _EXPERT_LEAVES
 
 
+def _member(tree):
+    """A member's slice of arrays shard_map hands in with the [1, ...] shard
+    dimension in front (an array, or a pool's ``{group: array}``)."""
+    return jax.tree.map(lambda a: a[0], tree)
+
+
+def _lead(tree):
+    """The inverse: the shard dimension back in front."""
+    return jax.tree.map(lambda a: a[None], tree)
+
+
+def _shapes(*trees) -> tuple:
+    """The leaf shapes of caches' arrays: a compiled program's key."""
+    return tuple(a.shape for a in jax.tree.leaves(trees))
+
+
 def _strip_shard(p):
     """Drop the per-shard leading dim shard_map hands each member:
     replicated leaves carry it LEADING ([1, ...] broadcast slice); expert
@@ -467,6 +678,12 @@ class MoEServer:
                 f"the dp world {self.world} must divide moe_experts "
                 f"{cfg.moe_experts}"
             )
+        if cfg.experts_held and self.world > 1:
+            raise ValueError(
+                f"experts_held {cfg.experts_held} of {cfg.moe_experts} is "
+                f"ONE member's share of a wider deployment; over a dp world "
+                f"of {self.world} give each member its own description, or "
+                f"all the experts to the world")
         # the shared LRU-bounded compiled-fn pattern (utils/lru.py): a
         # long-lived serving process sweeping shapes (prefill buckets,
         # several decode batch tiers, varying scan lengths) would
@@ -483,7 +700,7 @@ class MoEServer:
         per forward) so each decode step feeds the SAME arrays through the
         jit boundary instead of re-tiling params every token."""
         w = self.world
-        e_local = self.cfg.moe_experts // w
+        e_local = self.cfg.n_held // w
 
         def place(path, leaf):
             if _is_expert_leaf(path):
@@ -528,11 +745,12 @@ class MoEServer:
 
         def uccl_moe_forward(p, tok, kc, vc, ln):
             logits, nk, nv, nlen = _forward_shard(
-                _strip_shard(p), tok[0], kc[0], vc[0], ln[0], cfg, impl
+                _strip_shard(p), tok[0], _member(kc), _member(vc), ln[0],
+                cfg, impl
             )
-            return logits[None], nk[None], nv[None], nlen[None]
+            return logits[None], _lead(nk), _lead(nv), nlen[None]
 
-        key = ("fwd", impl, tokens.shape, cache.k.shape)
+        key = ("fwd", impl, tokens.shape, _shapes(cache.k, cache.v))
         fn = self._fn(
             key, lambda: self._shard_mapped(uccl_moe_forward, 4, 4, params))
         logits, nk, nv, nlen = fn(params, tokens, cache.k, cache.v,
@@ -595,11 +813,22 @@ class MoEServer:
                 self.mesh, P(_AXIS) if self.world > 1 else P()))
         from uccl_tpu.obs import counters as _obsc
 
-        _obsc.gauge(
+        row_bytes = _obsc.gauge(
             "serving_kv_row_bytes",
-            "bytes one cached position of one layer holds in the slot pool",
-        ).set(sum(math.prod(a.shape[4:]) * a.dtype.itemsize
-                  for a in (cache.k, cache.v)), kind=self.cfg.attn)
+            "bytes one cached position of one layer holds in the slot pool "
+            "(label kind: the attention kind, or the layer kind of a pool "
+            "with cache groups)")
+        pool_bytes = _obsc.gauge(
+            "serving_kv_pool_bytes",
+            "bytes of the slot pool's arrays by cache group (label group: "
+            "full | window, or the attention kind of a pool with one)")
+        for group in {g for g, _ in inference.cache_groups(self.cfg)}:
+            pair = [inference.group_array(a, group)
+                    for a in (cache.k, cache.v)]
+            label = group or self.cfg.attn
+            row_bytes.set(sum(math.prod(a.shape[4:]) * a.dtype.itemsize
+                              for a in pair), kind=label)
+            pool_bytes.set(sum(a.nbytes for a in pair), group=label)
         return cache
 
     def prefill_slots(self, params, tokens, prompt_lens, new_mask,
@@ -640,12 +869,12 @@ class MoEServer:
             samp, adp, ids = _split_extra(rest, sampled, adapted)
             t, out = inference.prefill_slots(
                 _strip_shard(p), tok[0], lens[0], mask[0],
-                SlotKVCache(kc[0], vc[0], ln[0]), cfg, start=off[0],
-                sampling=samp, adapters=adp, adapter_ids=ids, slots=idx,
-                ffn=_moe_block(cfg, "sort"))
-            return t[None], out.k[None], out.v[None], out.lengths[None]
+                SlotKVCache(_member(kc), _member(vc), ln[0]), cfg,
+                start=off[0], sampling=samp, adapters=adp, adapter_ids=ids,
+                slots=idx, ffn=_moe_block(cfg, "sort"))
+            return t[None], _lead(out.k), _lead(out.v), out.lengths[None]
 
-        key = ("prefill_slots", tokens.shape, cache.k.shape,
+        key = ("prefill_slots", tokens.shape, _shapes(cache.k, cache.v),
                sampled, adapted, compact)
         fn = self._fn(key, lambda: self._shard_mapped(
             uccl_moe_prefill_slots, 7 + len(extra), 4, params,
@@ -678,13 +907,14 @@ class MoEServer:
                                           adapted)
             t, n_acc, out = inference.verify_slots(
                 _strip_shard(p), tok[0], mask[0],
-                SlotKVCache(kc[0], vc[0], ln[0]), cfg, sampling=samp,
-                adapters=adp, adapter_ids=ids, ffn=_moe_block(cfg, impl))
-            return (t[None], n_acc[None], out.k[None], out.v[None],
+                SlotKVCache(_member(kc), _member(vc), ln[0]), cfg,
+                sampling=samp, adapters=adp, adapter_ids=ids,
+                ffn=_moe_block(cfg, impl))
+            return (t[None], n_acc[None], _lead(out.k), _lead(out.v),
                     out.lengths[None])
 
-        key = ("verify_slots", impl, tokens.shape, cache.k.shape,
-               sampled, adapted)
+        key = ("verify_slots", impl, tokens.shape,
+               _shapes(cache.k, cache.v), sampled, adapted)
         fn = self._fn(key, lambda: self._shard_mapped(
             uccl_moe_verify_slots, 5 + len(extra), 5, params,
             donate=(3, 4, 5)))
@@ -733,7 +963,8 @@ class MoEServer:
         logits, cache = self.prefill(params, prompt, max_seq)
         if sampling is None:
             tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            key = ("gen", impl, new_tokens, tok0.shape, cache.k.shape)
+            key = ("gen", impl, new_tokens, tok0.shape,
+                   _shapes(cache.k, cache.v))
 
             def build():
                 def uccl_moe_generate(p, tok, kc, vc, ln):
@@ -756,7 +987,8 @@ class MoEServer:
             fn = self._fn(key, build)
             return fn(params, tok0, cache.k, cache.v, cache.length)
 
-        key = ("gen_sampled", impl, new_tokens, logits.shape, cache.k.shape)
+        key = ("gen_sampled", impl, new_tokens, logits.shape,
+               _shapes(cache.k, cache.v))
 
         def build():
             def uccl_moe_generate_sampled(p, lg0, kc, vc, ln, seed, temp,

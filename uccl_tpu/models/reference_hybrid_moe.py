@@ -1,0 +1,120 @@
+"""Plain reference forward for the serving stack's descriptions with LAYER
+KINDS (``MoEServeConfig.layer_kinds``: the MiMo-V2-Flash block — window and
+full grouped-query attention layers with their own KV head counts and
+thetas, keys wider than values, a partial rotary factor, scaled values, a
+learned sink in the window softmax; a leading dense layer, then sigmoid-bias
+experts of which this member may hold a share). What tier-1 holds the
+program to.
+
+Straightforward ``jax.numpy`` in float32 at
+``default_matmul_precision("highest")``: a loop over the query heads with a
+``[T, T]`` banded mask and the sink as one more column, a loop over the
+experts, no cache, no ring, no batching, no capacity (norm, SwiGLU and gate
+are ``reference_latent_moe``'s). It shares no routing, attention, norm or
+rotary code with the program (``models/inference.py``,
+``ep/ops.py``): only the parameter tree ``init_params`` draws and the
+description's fields. Given the same share: the expert leaves it is handed
+are the ``experts_held`` experts from ``first_expert``, the sum runs over
+those alone, and ``embed`` / ``head`` are the vocabulary's slice.
+
+    h    = RMSNorm(x, ln1);  Hkv, theta by the layer's kind
+    q_j  = (h Wq)_j [192];  k_g = (h Wk)_g [192];  v_g = a (h Wv)_g [128]
+    q_j, k_g: the leading rotary_dim numbers rotated (split-half), theta
+    s_j(t,u) = q_j(t).k_{j // (H/Hkv)}(u) / sqrt(192)
+    full:    P_j(t,.) = softmax over u <= t
+    window:  P_j(t,u) = exp(s_j(t,u)) / (exp(sink_j) + sum_u' exp(s_j(t,u')))
+             over t - window < u <= t  (the sink's own column is dropped)
+    x    = x + concat_j(P_j v_{j // (H/Hkv)}) Wo
+    dense layers:   x = x + W_down(silu(h2 W_gate) * (h2 W_up))
+    expert layers:  s = sigmoid(h2 W_r) [E];  chosen = top-k of (s + b)
+                    w = scale * s[chosen] / (sum s[chosen] + 1e-20)
+                    x = x + sum over chosen j that are HELD of w_j E_j(h2)
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+# the other plain reference's norm, SwiGLU and sigmoid-bias gate: the same
+# equations, and as free of the program's code
+from uccl_tpu.models.reference_latent_moe import (
+    _f32, _norm, _swiglu, gate_weights,
+)
+
+
+def _rotate(x, pos, theta, rot):
+    """Split-half rotary embedding of the leading ``rot`` numbers of x
+    [T, D] at positions [T]; the rest untouched."""
+    half = rot // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[:, None] * inv
+    a, b = x[:, :half], x[:, half:rot]
+    return jnp.concatenate([a * jnp.cos(ang) - b * jnp.sin(ang),
+                            b * jnp.cos(ang) + a * jnp.sin(ang),
+                            x[:, rot:]], axis=-1)
+
+
+def attention(x, lp, cfg, kind: str):
+    """One layer's attention half on one sequence [T, H], head by head."""
+    t = x.shape[0]
+    nh, d = cfg.n_heads, cfg.head_dim
+    dv = cfg.v_head_dim or d
+    window = kind == "window"
+    hkv = cfg.window_kv_heads if window else cfg.n_kv_heads
+    theta = cfg.window_rope_theta if window else cfg.rope_theta
+    rot = cfg.rotary_dim or d
+    pos = jnp.arange(t)
+    h = _norm(x, lp["ln1"], cfg.norm_eps)
+    q = (h @ lp["wq"]).reshape(t, nh, d)
+    k = (h @ lp["wk"]).reshape(t, hkv, d)
+    v = (h @ lp["wv"]).reshape(t, hkv, dv) * cfg.value_scale
+    seen = pos[None, :] <= pos[:, None]
+    if window:
+        seen = seen & (pos[None, :] > pos[:, None] - cfg.window)
+    heads = []
+    for j in range(nh):
+        g = j // (nh // hkv)
+        s = _rotate(q[:, j], pos, theta, rot) \
+            @ _rotate(k[:, g], pos, theta, rot).T / math.sqrt(d)
+        s = jnp.where(seen, s, -jnp.inf)
+        if kind in cfg.sink:
+            s = jnp.concatenate(
+                [s, jnp.full((t, 1), lp["sink"][j])], axis=-1)
+        p = jax.nn.softmax(s, axis=-1)[:, :t]
+        heads.append(p @ v[:, g])
+    return x + jnp.concatenate(heads, axis=-1) @ lp["wo"]
+
+
+def expert_layer_sum(h2, lp, cfg, first: int = None, held: int = None):
+    """The routed experts' weighted sum for rows ``h2`` [T, H], over the
+    experts ``[first, first + held)`` whose leaves ``lp`` carries (the
+    description's own share by default)."""
+    first = cfg.first_expert if first is None else first
+    held = cfg.n_held if held is None else held
+    w = gate_weights(h2, lp["router"], lp["router_bias"], cfg.moe_topk,
+                     cfg.routed_scale)
+    out = jnp.zeros_like(h2)
+    for j in range(held):
+        out = out + w[:, first + j, None] * _swiglu(
+            h2, lp["we_gate"][j], lp["we_up"][j], lp["we_down"][j])
+    return out
+
+
+def forward_logits(params, tokens, cfg):
+    """Logits [T, V] (float32) of one token sequence [T]: the description's
+    forward, every layer of its kind."""
+    with jax.default_matmul_precision("highest"):
+        p = _f32(params)
+        x = p["embed"][jnp.asarray(tokens)]
+        for i, (group, j) in enumerate(cfg.param_groups()):
+            lp = jax.tree.map(lambda a: a[j], p[group])
+            x = attention(x, lp, cfg, cfg.layer_kinds[i])
+            h2 = _norm(x, lp["ln2"], cfg.norm_eps)
+            if "router" in lp:
+                x = x + expert_layer_sum(h2, lp, cfg)
+            else:
+                x = x + _swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
+        return _norm(x, p["final_norm"], cfg.norm_eps) @ p["head"]

@@ -32,6 +32,7 @@ CI serving smoke tier:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -178,10 +179,12 @@ def _params_dtype(params) -> str:
 
 def _moe_cfg(args):
     """The MoE stack's model description: the published keys of a Hugging
-    Face ``config.json`` (``--model-config``: a ``mixtral``- or a
-    ``glm4_moe_lite``/``deepseek_v3``-shaped file — latent attention, a
-    dense prefix, sigmoid-bias gate, shared expert; weights stored in its
-    ``torch_dtype``), or else the hand-sized flags (the uniform block)."""
+    Face ``config.json`` (``--model-config``: a ``mixtral``-, a
+    ``glm4_moe_lite``/``deepseek_v3``- — latent attention, a dense prefix,
+    sigmoid-bias gate, shared expert — or a ``mimo_v2_flash``-shaped file —
+    window and full attention layers with their own cache groups, a held
+    share of the experts; weights stored in its ``torch_dtype``), or else
+    the hand-sized flags (the uniform block)."""
     from uccl_tpu.models.moe_inference import MoEServeConfig
 
     if not args.model_config:
@@ -196,13 +199,21 @@ def _moe_cfg(args):
                          "no --ckpt-dir")
     with open(args.model_config) as f:
         hf = json.load(f)
-    experts = hf.get("n_routed_experts") or hf["num_local_experts"]
-    return MoEServeConfig.from_hf(
+    experts = hf.get("router_experts") or hf.get("n_routed_experts") \
+        or hf["num_local_experts"]
+    cfg = MoEServeConfig.from_hf(
         hf,
         # the slot engine needs a drop-free wire: factor * top-k >= experts
+        # (the ROUTED experts, whatever share of them is held here)
         capacity_factor=max(8.0, experts / hf["num_experts_per_tok"]),
         param_dtype=hf.get("torch_dtype", "float32"),
     )
+    if "window" in cfg.layer_kinds:
+        # a window layer's ring holds window - 1 + the widest write
+        widest = max(args.prefill_chunk, args.spec_k + 1)
+        cfg = dataclasses.replace(cfg, window_ring=max(
+            cfg.ring, cfg.window - 1 + widest))
+    return cfg
 
 
 def _moe_paths(cfg, impl, world, params):

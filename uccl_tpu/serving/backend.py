@@ -48,6 +48,12 @@ _POOL_IN_PLACE = obs.counter(
 )
 
 
+def _consumed(pool) -> bool:
+    """Whether a program took every array of the pool it was handed (the
+    (k, v) pair, or the pair of each cache group)."""
+    return all(a.is_deleted() for a in jax.tree.leaves((pool.k, pool.v)))
+
+
 def prefill_rung(n: int, n_slots: int) -> int:
     """Rows of the chunked-prefill program for ``n >= 1`` prefilling slots
     of a pool of ``n_slots``: one, two, or the whole pool. Three rungs and
@@ -255,14 +261,14 @@ class SlotBackend:
                 *out, self.cache = getattr(self.programs, kind)(
                     self.params, *args, pool, **kw)
             except Exception as e:
-                if pool.k.is_deleted():
+                if _consumed(pool):
                     raise RuntimeError(
                         f"the {kind} program failed after it had consumed "
                         f"the slot pool: this backend's cached rows are "
                         f"gone and every request holding a slot with them"
                     ) from e
                 raise
-            if pool.k.is_deleted():
+            if _consumed(pool):
                 _POOL_IN_PLACE.inc(program=kind)
         with obs.span("backend.fetch", "wire"):
             out = [np.asarray(o) for o in out]

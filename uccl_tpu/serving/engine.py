@@ -149,6 +149,35 @@ def _bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
+def _check_window_groups(backend, prefill_chunk, spec_k, prefix_cache,
+                         kv_tiers) -> None:
+    """What a pool with window groups (a model description whose
+    ``layer_kinds`` name "window" layers) cannot do yet, refused before a
+    request could meet it; and the ring against the widest write."""
+    cfg = getattr(backend, "cfg", None)
+    if "window" not in (getattr(cfg, "layer_kinds", ()) or ()):
+        return
+    from uccl_tpu.models.inference import WINDOW_GROUPS_STAY
+
+    if kv_tiers is not None:
+        raise ValueError(WINDOW_GROUPS_STAY + "kv_tiers demotes and "
+                         "promotes exported rows")
+    if prefix_cache is not None:
+        raise ValueError(WINDOW_GROUPS_STAY + "prefix_cache copies a "
+                         "donor's rows, whose ring no longer holds the "
+                         "prefix's last window - 1 positions")
+    if prefill_chunk is None:
+        raise ValueError(
+            "a pool with window groups requires prefill_chunk: a whole "
+            "prompt in one write would wrap its window layers' ring")
+    widest = max(prefill_chunk, (spec_k or 0) + 1)
+    if cfg.ring < cfg.window - 1 + widest:
+        raise ValueError(
+            f"the window layers' ring of {cfg.ring} rows must hold window - "
+            f"1 + the widest write ({cfg.window} - 1 + {widest}): raise "
+            f"window_ring or lower prefill_chunk / spec_k")
+
+
 class ServingEngine:
     """submit()/step()/drain() over a slot backend.
 
@@ -266,6 +295,8 @@ class ServingEngine:
                     "chunk boundaries and resumes via the chunked "
                     "start-offset program"
                 )
+        _check_window_groups(backend, prefill_chunk, spec_k, prefix_cache,
+                             kv_tiers)
         self.backend = backend
         self.spec_k = spec_k
         self.drafter = drafter

@@ -67,21 +67,49 @@ def _attend_cached(q, k_cache, v_cache, length, cfg: DenseConfig):
     """q: [B, Sq, H, D] at positions [length, length+Sq); cache: [B, Smax, Hkv, D].
     Masked attention over the cache prefix + the new causal block.
 
+    Query head ``j`` reads KV head ``j // G`` (``G = H / Hkv``): the queries
+    are grouped ``[B, Sq, Hkv, G, D]`` and contracted against the cache AS
+    IT LIES in the pool — no KV head is repeated, and the program holds no
+    array of ``G`` times the cache's size (a repeat writes and reads back
+    ``G`` x the pool: 7.4 of the 15.9 ms of Mixtral's ``[16, 1]`` decode
+    program over a ``[16, 4096, 8, 128]`` float32 layer on a v5e). On the
+    chip each contraction compiles to ONE fusion whose operand is the pool's
+    own array, read once at 83-90 % of the HBM's bandwidth (0.36-0.40 ms for
+    the 0.27 GB; all of ``attn.*`` 0.80 ms, the program 9.29), for every
+    ``Sq`` alike: decode, chunks and verify windows share the form (PERF.md
+    section 6, PR 37). At ``G = 1`` (multi-head attention) the group axis
+    has length one and the program is the ungrouped one.
+
+    ``Sq = 1`` asks for float32-accurate products. Ungrouped, a single-row
+    product never reached the MXU (the compiler keeps it on the VPU in
+    float32, whatever precision is asked); with ``G`` rows a KV head it
+    does, and at the default precision its operands would be rounded to
+    bfloat16. That flips greedy tokens at near-ties often enough to put the
+    99th-percentile gap to a full-float32 reference at 0.17 of a limit of
+    0.25 in one of 16 seeds (under 0.02 in 12 of 12 with this), so decode
+    keeps the precision it had; wider ``Sq`` were MXU products before and
+    stay at the default.
+
     ``length`` is a scalar (one shared prefix — the one-shot path) or [B]
     per-sequence prefixes (the slot-pool serving path): the mask math is the
     same, only its batch rank differs, so both paths produce bit-identical
     rows for equal per-row (length, prefix) — the serving engine's oracle
     guarantee rests on this."""
-    h, d = q.shape[2:]
-    n_rep = h // cfg.n_kv_heads
+    b, sq, h, d = q.shape
+    hkv = cfg.n_kv_heads
+    precision = lax.Precision.HIGHEST if sq == 1 else None
     with jax.named_scope("attn.core"):
-        kk = jnp.repeat(k_cache, n_rep, axis=2)
-        vv = jnp.repeat(v_cache, n_rep, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
+        qg = q.reshape(b, sq, hkv, h // hkv, d)
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_cache, precision=precision,
                        preferred_element_type=jnp.float32)
         s = s / jnp.sqrt(jnp.float32(d))
-        p = jax.nn.softmax(_mask_scores(s, length), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv)
+        # [B, Hkv, G, Sq, K] is [B, H, Sq, K] with head j = (j // G, j % G)
+        p = jax.nn.softmax(
+            _mask_scores(s.reshape(b, h, sq, -1), length), axis=-1)
+        o = jnp.einsum("bhgqk,bkhd->bqhgd",
+                       p.reshape(s.shape).astype(v_cache.dtype), v_cache,
+                       precision=precision)
+        return o.reshape(b, sq, h, d)
 
 
 def _attend_latent(q_lat, q_rope, ckv_cache, kr_cache, length, scale):

@@ -101,7 +101,9 @@ def _tokens(n, seed=0):
 def _slot_logits(srv, placed, tokens, cache, start, mask, impl="sort",
                  slots=None):
     """Logits [B, S, V] and the new cache of one masked slot forward —
-    what prefill_slots / verify_slots reduce to tokens."""
+    what prefill_slots / verify_slots reduce to tokens. One jitted function
+    a (server, impl, compact or not): a step at a shape met before does not
+    compile again."""
     cfg = srv.cfg
 
     def f(p, tok, kc, vc, ln, off, m, *idx):
@@ -113,10 +115,15 @@ def _slot_logits(srv, placed, tokens, cache, start, mask, impl="sort",
         return logits[None], mi._lead(out.k), mi._lead(out.v)
 
     extra = [] if slots is None else [jnp.asarray(slots, jnp.int32)[None]]
-    fn = jax.jit(shard_map(
-        f, mesh=srv.mesh,
-        in_specs=(srv._param_specs(placed),) + (P("dp"),) * (6 + len(extra)),
-        out_specs=(P("dp"),) * 3, check_vma=False))
+    fns = srv.__dict__.setdefault("_slot_logits_fns", {})
+    key = (impl, len(extra))
+    if key not in fns:
+        fns[key] = jax.jit(shard_map(
+            f, mesh=srv.mesh,
+            in_specs=(srv._param_specs(placed),)
+            + (P("dp"),) * (6 + len(extra)),
+            out_specs=(P("dp"),) * 3, check_vma=False))
+    fn = fns[key]
     logits, nk, nv = fn(placed, jnp.asarray(tokens)[None], cache.k, cache.v,
                         cache.lengths, jnp.asarray(start, jnp.int32)[None],
                         jnp.asarray(mask)[None], *extra)
@@ -215,8 +222,11 @@ def test_from_hf_reads_the_published_keys():
         MoEServeConfig(window=8)
     with pytest.raises(ValueError, match="for each of the"):
         MoEServeConfig(n_layers=2, layer_kinds=("full",))
-    with pytest.raises(ValueError, match="window_ring"):
-        MoEServeConfig(**dict(HYBRID, window_ring=12))
+    # the ring's floor is a window's rows (window - 1 + the widest write is
+    # asked where the write is known: tests/test_afmoe_serving.py)
+    MoEServeConfig(**dict(HYBRID, window_ring=12))
+    with pytest.raises(ValueError, match="must hold a window's rows"):
+        MoEServeConfig(**dict(HYBRID, window_ring=7))
     with pytest.raises(ValueError, match="not among"):
         MoEServeConfig(**dict(HYBRID, first_expert=14))
 
@@ -386,9 +396,11 @@ def test_row_at_a_time_attention_is_all_rows_at_once(model, monkeypatch):
     on = np.ones(2, bool)
     at_once, _ = _slot_logits(srv, placed, both, cache, [0, 0], on)
     monkeypatch.setattr(inference, "_SCORES_AT_ONCE", 1)
+    srv._slot_logits_fns.clear()  # trace again, under the new threshold
     by_row, _ = _slot_logits(srv, placed, both, srv.slot_cache(2, MAX_SEQ),
                              [0, 0], on)
     np.testing.assert_allclose(by_row, at_once, atol=PATH_TOL)
+    srv._slot_logits_fns.clear()
 
 
 def test_engine_served_tokens_are_generates(model):
@@ -652,3 +664,14 @@ def test_hybrid_programs_carry_their_scopes(hybrid_program_text, program,
                                             scope):
     assert f"/{scope}/" in hybrid_program_text[program], (
         f"{scope} is in no op_name of the compiled {program} program")
+
+
+@pytest.mark.parametrize("scope", ("attn.gate", "ffn.post_norm",
+                                   "moe.shared"))
+@pytest.mark.parametrize("program", ("decode", "prefill"))
+def test_hybrid_programs_carry_nothing_of_another_block(
+        hybrid_program_text, program, scope):
+    """What another description's layers carry (a gated output, a closing
+    norm, a shared expert: tests/test_afmoe_serving.py) is read from a
+    layer's own leaves and costs this description no operation."""
+    assert f"/{scope}" not in hybrid_program_text[program]

@@ -328,6 +328,13 @@ def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
     learned logit ``lp["sink"]`` and carries no value:
     ``P_k = exp(s_k) / (exp(sink) + sum_j exp(s_j))``.
 
+    What else a description's layers may carry is read from the layer's own
+    leaves and costs a layer without them nothing: ``q_norm`` / ``k_norm``
+    (each query and key head RMS-normed before the rotation), ``wg`` (the
+    heads' output times ``sigmoid(h wg)`` before the output projection) and
+    ``ln1_post`` (the branch's output normed before it joins the residual).
+    A kind in ``cfg.unrotated`` carries no rotary embedding.
+
     ``write(pool, new)`` as in :func:`_gqa_attention`. Returns (x', k_pool',
     v_pool')."""
     if lora is not None:
@@ -345,8 +352,12 @@ def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
         v = (h @ lp["wv"].astype(h.dtype)).reshape(b, s, hkv, dv)
         if cfg.value_scale != 1.0:
             v = v * jnp.asarray(cfg.value_scale, v.dtype)
-        q = rope(q, positions, theta, cfg.rotary_dim)
-        kk = rope(kk, positions, theta, cfg.rotary_dim)
+        if "q_norm" in lp:
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            kk = rms_norm(kk, lp["k_norm"], cfg.norm_eps)
+        if kind not in cfg.unrotated:
+            q = rope(q, positions, theta, cfg.rotary_dim)
+            kk = rope(kk, positions, theta, cfg.rotary_dim)
     with jax.named_scope("attn.kv_write." + kind):
         # rows are cached flat (:func:`kv_row_shapes`): [.., Hkv * D]
         k_pool, k_cache = write(k_pool, kk.reshape(b, s, hkv * d))
@@ -388,8 +399,15 @@ def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
                 (qg, k_cache, v_cache, qp))
         else:
             attn = core(qg, k_cache, v_cache, qp)
+    if "wg" in lp:
+        with jax.named_scope("attn.gate." + kind):
+            gate = jax.nn.sigmoid(h @ lp["wg"].astype(h.dtype))
+            attn = attn * gate.reshape(b, s, hkv, nh // hkv, dv)
     with jax.named_scope("attn.out." + kind):
-        x = x + attn.reshape(b, s, nh * dv) @ lp["wo"].astype(attn.dtype)
+        out = attn.reshape(b, s, nh * dv) @ lp["wo"].astype(attn.dtype)
+        if "ln1_post" in lp:
+            out = rms_norm(out, lp["ln1_post"], cfg.norm_eps)
+        x = x + out
     return x, k_pool, v_pool
 
 
@@ -432,6 +450,27 @@ def _dense_ffn(h2, lp):
     return act @ lp["w_down"].astype(act.dtype)
 
 
+def _embed(params, tokens, cfg, dtype):
+    """The embedding's rows of ``tokens`` in the activations' ``dtype``,
+    times the description's ``embed_scale`` where it has one."""
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(dtype)
+        scale = getattr(cfg, "embed_scale", 1.0)
+        return x if scale == 1.0 else x * jnp.asarray(scale, dtype)
+
+
+def _ffn_half(x, lp, cfg, ffn):
+    """The FFN half of a layer with its residual: ``ffn(h2, lp)`` (the dense
+    SwiGLU without one) of the normed input; a layer with ``ln2_post`` norms
+    the branch's output before it joins the residual."""
+    h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
+    out = _dense_ffn(h2, lp) if ffn is None else ffn(h2, lp)
+    if "ln2_post" in lp:
+        with jax.named_scope("ffn.post_norm"):
+            out = rms_norm(out, lp["ln2_post"], cfg.norm_eps)
+    return x + out
+
+
 def _head(x, params, cfg):
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -455,9 +494,7 @@ def _forward_cached(
     here: the one-shot path keeps a window layer's every position."""
     b, s = tokens.shape
     k, v = cache.k, cache.v
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0).astype(
-            jax.tree.leaves(k)[0].dtype)
+    x = _embed(params, tokens, cfg, jax.tree.leaves(k)[0].dtype)
     positions = cache.length + jnp.arange(s)
     for i, (group, gi) in enumerate(cache_groups(cfg)):
         def write(pool, new, gi=gi):
@@ -471,8 +508,7 @@ def _forward_cached(
             x, lp, group_array(k, group), group_array(v, group), positions,
             cache.length, write, cfg)
         k, v = _with_group(k, group, nk), _with_group(v, group, nv)
-        h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        x = x + (_dense_ffn(h2, lp) if ffn is None else ffn(h2, lp))
+        x = _ffn_half(x, lp, cfg, ffn)
     logits = _head(x, params, cfg)
     return logits, KVCache(k, v, cache.length + s)
 
@@ -683,9 +719,7 @@ def _forward_slots(
     # cache groups: S_max (window groups are rings, written below)
     flat = k if groups[0][0] is None else k.get("full")
     smax = flat.shape[2] if flat is not None else 0
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0).astype(
-            jax.tree.leaves(k)[0].dtype)
+    x = _embed(params, tokens, cfg, jax.tree.leaves(k)[0].dtype)
     positions = start[:, None] + jnp.arange(s)[None, :]  # [B, S]
     # masked slots write at index smax → dropped by the scatter; rows beyond
     # the cache end (a bucket overhanging S_max) drop the same way
@@ -735,8 +769,7 @@ def _forward_slots(
             x, lp, group_array(k, group), group_array(v, group), positions,
             start, write, cfg, lora=lora)
         k, v = _with_group(k, group, nk), _with_group(v, group, nv)
-        h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        x = x + (_dense_ffn(h2, lp) if ffn is None else ffn(h2, lp))
+        x = _ffn_half(x, lp, cfg, ffn)
     logits = _head(x, params, cfg)
     return logits, SlotKVCache(k, v, cache.lengths)
 

@@ -97,6 +97,17 @@ class MoEServeConfig:
     value_scale: float = 1.0  # multiplies the values before attention
     sink: Tuple[str, ...] = ()  # kinds whose softmax has a learned sink column
     # (with layer kinds, gqa's values are v_head_dim wide: 0 = head_dim)
+    unrotated: Tuple[str, ...] = ()  # kinds whose queries and keys carry no
+    # rotary embedding (Trinity's full layers); every other kind rotates
+    qk_norm: bool = False  # each query and key head RMS-normed (leaves
+    # q_norm / k_norm [head_dim]) before the rotation
+    attn_gate: bool = False  # attention output times sigmoid(h wg), head by
+    # head, before the output projection (leaf wg, as wide as wo is tall)
+    post_norms: bool = False  # sandwich norms: each branch's OUTPUT is
+    # normed before it joins the residual (leaves ln1_post / ln2_post)
+    embed_scale: float = 1.0  # multiplies the embedding's output
+    norm_gain_scale: float = 0.0  # init_params draws a layer's norm gains as
+    # 1 + this x a seeded normal (0: ones), so that a gain left out shows
     # -- this member's share of a wider deployment: the router keeps its
     # moe_experts outputs, experts [first_expert, first_expert + held) live
     # here and only their part of the layer's sum is computed (ep.ops.moe_ffn)
@@ -119,15 +130,22 @@ class MoEServeConfig:
                                  "window_kv_heads")
             if set(self.sink) - {"full", "window"} or self.rotary_dim % 2:
                 raise ValueError("sink names layer kinds; rotary_dim is even")
-            if "window" in kinds and self.ring < 2 * self.window - 1:
+            if set(self.unrotated) - {"full", "window"}:
+                raise ValueError("unrotated names layer kinds")
+            if "window" in kinds and self.ring < self.window:
                 raise ValueError(
-                    f"window_ring {self.ring} must hold window - 1 + a write "
-                    f"of at least a window: {2 * self.window - 1}")
+                    f"window_ring {self.ring} must hold a window's rows "
+                    f"({self.window}): a query reads its last {self.window} "
+                    f"positions from the ring (window - 1 + the widest write "
+                    f"is asked where that width is known: the slot forward "
+                    f"and ServingEngine)")
         elif self.window or self.sink or self.rotary_dim \
-                or self.value_scale != 1.0 \
+                or self.value_scale != 1.0 or self.unrotated \
+                or self.qk_norm or self.attn_gate or self.post_norms \
                 or (self.attn == "gqa" and self.v_head_dim):
-            raise ValueError("window, sink, rotary_dim, value_scale and a "
-                             "gqa v_head_dim belong to layer_kinds")
+            raise ValueError("window, sink, rotary_dim, value_scale, "
+                             "unrotated, qk_norm, attn_gate, post_norms and "
+                             "a gqa v_head_dim belong to layer_kinds")
         if not 0 <= self.first_expert <= self.moe_experts - self.n_held:
             raise ValueError(
                 f"experts [{self.first_expert}, {self.first_expert} + "
@@ -197,10 +215,17 @@ class MoEServeConfig:
         their own KV heads and thetas, a partial rotary factor, scaled
         values, a sink in the window softmax, sigmoid-bias experts; the
         first ``num_hidden_layers`` entries of its two per-layer lists are
-        read). A file that states a member's share gives ``n_routed_experts``
-        as the experts held and ``router_experts`` as the router's width
+        read) or ``afmoe`` (Trinity; keyed on ``layer_types``:
+        ``sliding_attention`` | ``full_attention`` layers with one KV head
+        count and one theta, rotary on the window layers only, QK-norm, a
+        gated output, sandwich norms, a scaled embedding,
+        ``num_dense_layers`` leading dense layers, then sigmoid-bias experts
+        beside ``num_shared_experts`` shared ones; ``layer_types`` is read
+        up to ``num_hidden_layers``). A file that states a member's share
+        gives ``n_routed_experts`` (``afmoe``: ``num_experts``) as the
+        experts held and ``router_experts`` as the router's width
         (``first_expert``: the first held). ``overrides`` are this class's
-        own fields (capacity_factor, param_dtype ...)."""
+        own fields (capacity_factor, param_dtype, window_ring ...)."""
         heads = hf["num_attention_heads"]
         n_layers = hf["num_hidden_layers"]
         kw: Dict[str, Any] = dict(
@@ -211,7 +236,54 @@ class MoEServeConfig:
                            or hf.get("layernorm_epsilon") or 1e-6),
             moe_topk=hf["num_experts_per_tok"],
         )
-        if "hybrid_layer_pattern" in hf:
+        if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+            raise ValueError("group-limited routing (n_group > 1) is not "
+                             "built")
+        if "layer_types" in hf:
+            types = list(hf["layer_types"])[:n_layers]
+            kind_of = {"sliding_attention": "window",
+                       "full_attention": "full"}
+            unknown = sorted(set(types) - set(kind_of))
+            if unknown or len(types) < n_layers:
+                raise ValueError(
+                    f"layer_types names 'sliding_attention' or "
+                    f"'full_attention' for each of num_hidden_layers "
+                    f"({n_layers}) layers; got {len(types)} entries, "
+                    f"unknown {unknown}")
+            if hf.get("score_func", "sigmoid") != "sigmoid":
+                raise ValueError(f"score_func {hf['score_func']!r}: the "
+                                 f"gate built here is 'sigmoid'")
+            if not hf.get("route_norm", True):
+                raise ValueError("route_norm false (weights not renormalised "
+                                 "over the chosen) is not built")
+            if hf.get("rope_scaling") is not None:
+                raise ValueError("rope_scaling is not built")
+            routed = hf.get("router_experts", hf["num_experts"])
+            held = hf["num_experts"]
+            kw.update(
+                n_kv_heads=hf["num_key_value_heads"],
+                window_kv_heads=hf["num_key_value_heads"],
+                window_rope_theta=kw["rope_theta"],
+                head_dim=hf.get("head_dim") or hf["hidden_size"] // heads,
+                layer_kinds=tuple(kind_of[t] for t in types),
+                window=hf["sliding_window"],
+                unrotated=("full",), qk_norm=True, attn_gate=True,
+                post_norms=True,
+                embed_scale=math.sqrt(hf["hidden_size"])
+                if hf.get("mup_enabled") else 1.0,
+                norm_gain_scale=NORM_GAIN_SCALE,
+                moe_experts=routed,
+                experts_held=held if held != routed else 0,
+                first_expert=hf.get("first_expert", 0),
+                moe_ffn=hf["moe_intermediate_size"],
+                first_k_dense=hf.get("num_dense_layers", 0),
+                dense_ffn=hf["intermediate_size"],
+                shared_ffn=(hf.get("num_shared_experts") or 0)
+                * hf["moe_intermediate_size"],
+                gate="sigmoid_bias",
+                routed_scale=float(hf.get("route_scale") or 1.0),
+            )
+        elif "hybrid_layer_pattern" in hf:
             pattern = list(hf["hybrid_layer_pattern"])[:n_layers]
             freq = list(hf["moe_layer_freq"])[:n_layers]
             if len(pattern) < n_layers or len(freq) < n_layers:
@@ -222,9 +294,6 @@ class MoEServeConfig:
             if not all(freq[dense:]):
                 raise ValueError("a dense FFN after the first expert layer "
                                  "(moe_layer_freq) is not built")
-            if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
-                raise ValueError("group-limited routing (n_group > 1) is "
-                                 "not built")
             if hf.get("scoring_func", "sigmoid") != "sigmoid" \
                     or not hf.get("norm_topk_prob", True):
                 raise ValueError("the gate built is sigmoid scores, "
@@ -267,9 +336,6 @@ class MoEServeConfig:
                 routed_scale=float(hf.get("routed_scaling_factor") or 1.0),
             )
         elif "kv_lora_rank" in hf:
-            if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
-                raise ValueError("group-limited routing (n_group > 1) is "
-                                 "not built")
             if hf.get("rope_scaling") is not None:
                 raise ValueError("rope_scaling is not built")
             shared = hf.get("n_shared_experts") or 0
@@ -460,7 +526,9 @@ _SPLIT_KEY = {"embed": 0, "wq": 1, "wk": 2, "wv": 3, "wo": 4, "router": 5,
               "we_gate": 6, "we_up": 7, "we_down": 8, "head": 9}
 _FOLD_KEY = {"wq_a": 21, "wq_b": 22, "wkv_a": 23, "wkv_b": 24,
              "ws_gate": 25, "ws_up": 26, "ws_down": 27, "router_bias": 28,
-             "w_gate": 29, "w_up": 30, "w_down": 31, "sink": 32}
+             "w_gate": 29, "w_up": 30, "w_down": 31, "sink": 32, "wg": 33,
+             "q_norm": 34, "k_norm": 35, "ln1_post": 36, "ln2_post": 37,
+             "ln1": 38, "ln2": 39}
 _DENSE_GROUP_FOLD = 64
 # a group's fold: the groups that always were keep theirs (so the uniform
 # descriptions' weights are what they were); a window group folds 128 more
@@ -471,6 +539,9 @@ SINK_SCALE = 1.0  # seeded sink logits: as large as the scores they sit
 # beside, so that a softmax without its sink column is told apart
 ROUTER_BIAS_SCALE = 0.01  # seeded gate bias: choosing by score + bias and
 # weighing by the score alone are then told apart
+NORM_GAIN_SCALE = 0.1  # seeded norm gains (``from_hf``'s afmoe branch asks
+# for them): 1 + 0.1 x a normal, so that a norm whose gain is left out, or a
+# branch normed once where the model norms it twice, is told apart
 
 
 def _attn_shapes(cfg: MoEServeConfig, kind: str = "full"):
@@ -495,9 +566,16 @@ def _attn_shapes(cfg: MoEServeConfig, kind: str = "full"):
     hkv = cfg.kv_heads(kind)
     vd = cfg.v_head_dim or cfg.head_dim  # gqa's is set with layer kinds only
     od = cfg.n_heads * vd
-    return {"wq": ((h, qd), h), "wk": ((h, hkv * cfg.head_dim), h),
-            "wv": ((h, hkv * vd), h), "wo": ((od, h), od)}, \
-        {"ln1": h, "ln2": h}
+    mats = {"wq": ((h, qd), h), "wk": ((h, hkv * cfg.head_dim), h),
+            "wv": ((h, hkv * vd), h), "wo": ((od, h), od)}
+    norms = {"ln1": h, "ln2": h}
+    if cfg.attn_gate:
+        mats["wg"] = ((h, od), h)
+    if cfg.qk_norm:
+        norms.update(q_norm=cfg.head_dim, k_norm=cfg.head_dim)
+    if cfg.post_norms:
+        norms.update(ln1_post=h, ln2_post=h)
+    return mats, norms
 
 
 def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
@@ -508,9 +586,10 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
     prefix, ``dense_blocks`` [first_k_dense, ...]; with layer kinds the
     window layers' ``window_blocks`` / ``dense_window_blocks`` beside them.
     Every matrix is drawn in float32 (embedding 0.02, others 1/sqrt(fan-in))
-    and stored in ``cfg.param_dtype``; norms are ones, the gate bias a
-    normal of scale 0.01 and a sink kind's per-head logit a normal of scale
-    1.0, all float32."""
+    and stored in ``cfg.param_dtype``; a layer's norm gains are ones (or,
+    where ``cfg.norm_gain_scale`` asks, 1 + that x a normal), the gate bias
+    a normal of scale 0.01 and a sink kind's per-head logit a normal of
+    scale 1.0, all float32."""
     k = jax.random.split(key, 12)
     h, f, e = cfg.dim, cfg.moe_ffn, cfg.n_held
     dtype = jnp.dtype(cfg.param_dtype)
@@ -538,8 +617,14 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
         mats, norms = _attn_shapes(cfg, kind)
         is_dense = name.startswith("dense_")
         mats = {**mats, **(dense if is_dense else moe)}
-        out = {leaf: jnp.ones((n, width), jnp.float32)
-               for leaf, width in norms.items()}
+        def gain(leaf, width):
+            if not cfg.norm_gain_scale:
+                return jnp.ones((n, width), jnp.float32)
+            return 1.0 + cfg.norm_gain_scale * jax.random.normal(
+                jax.random.fold_in(key, fold + _FOLD_KEY[leaf]), (n, width),
+                jnp.float32)
+
+        out = {leaf: gain(leaf, width) for leaf, width in norms.items()}
         out.update({leaf: rnd(leaf, (n,) + shape, 1.0 / math.sqrt(fan), fold)
                     for leaf, (shape, fan) in mats.items()})
         if cfg.gate == "sigmoid_bias" and not is_dense:
@@ -829,6 +914,12 @@ class MoEServer:
             row_bytes.set(sum(math.prod(a.shape[4:]) * a.dtype.itemsize
                               for a in pair), kind=label)
             pool_bytes.set(sum(a.nbytes for a in pair), group=label)
+            if group == "window":
+                _obsc.gauge(
+                    "serving_kv_ring_rows",
+                    "rows a slot keeps of each window layer in the slot "
+                    "pool: the ring (window - 1 + the widest write, or more)"
+                ).set(pair[0].shape[3])
         return cache
 
     def prefill_slots(self, params, tokens, prompt_lens, new_mask,

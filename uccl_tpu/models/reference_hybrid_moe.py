@@ -3,8 +3,11 @@ KINDS (``MoEServeConfig.layer_kinds``: the MiMo-V2-Flash block — window and
 full grouped-query attention layers with their own KV head counts and
 thetas, keys wider than values, a partial rotary factor, scaled values, a
 learned sink in the window softmax; a leading dense layer, then sigmoid-bias
-experts of which this member may hold a share). What tier-1 holds the
-program to.
+experts of which this member may hold a share — and the Trinity (``afmoe``)
+block: one KV head count, rotary on the window layers only, each query and
+key head RMS-normed, the heads' output gated, each branch's output normed
+before the residual, the embedding scaled, a shared expert beside the held
+share). What tier-1 holds the program to.
 
 Straightforward ``jax.numpy`` in float32 at
 ``default_matmul_precision("highest")``: a loop over the query heads with a
@@ -29,6 +32,16 @@ those alone, and ``embed`` / ``head`` are the vocabulary's slice.
     expert layers:  s = sigmoid(h2 W_r) [E];  chosen = top-k of (s + b)
                     w = scale * s[chosen] / (sum s[chosen] + 1e-20)
                     x = x + sum over chosen j that are HELD of w_j E_j(h2)
+
+and, where the description says so (the Trinity block):
+
+    x0   = sqrt(H) E[token]                                   (embed_scale)
+    q_j <- RMSNorm(q_j, q_norm [D]);  k_g <- RMSNorm(k_g, k_norm [D]), then
+           rotated in window layers and NOT in full ones  (qk_norm, unrotated)
+    o_j  = P_j v_g * sigmoid((h Wg)_j)                          (attn_gate)
+    x    = x + RMSNorm(concat_j(o_j) Wo, ln1_post)             (post_norms)
+    x    = x + RMSNorm(F(h2), ln2_post),  F the dense SwiGLU or
+           E_shared(h2) + sum over chosen held j of w_j E_j(h2)
 """
 
 from __future__ import annotations
@@ -74,18 +87,32 @@ def attention(x, lp, cfg, kind: str):
     seen = pos[None, :] <= pos[:, None]
     if window:
         seen = seen & (pos[None, :] > pos[:, None] - cfg.window)
+
+    def placed(y, gain):
+        """One head's queries or keys [T, D]: normed where the description
+        norms them, then rotated where this layer's kind rotates."""
+        if cfg.qk_norm:
+            y = _norm(y, lp[gain], cfg.norm_eps)
+        return y if kind in cfg.unrotated else _rotate(y, pos, theta, rot)
+
     heads = []
     for j in range(nh):
         g = j // (nh // hkv)
-        s = _rotate(q[:, j], pos, theta, rot) \
-            @ _rotate(k[:, g], pos, theta, rot).T / math.sqrt(d)
+        s = placed(q[:, j], "q_norm") @ placed(k[:, g], "k_norm").T \
+            / math.sqrt(d)
         s = jnp.where(seen, s, -jnp.inf)
         if kind in cfg.sink:
             s = jnp.concatenate(
                 [s, jnp.full((t, 1), lp["sink"][j])], axis=-1)
         p = jax.nn.softmax(s, axis=-1)[:, :t]
-        heads.append(p @ v[:, g])
-    return x + jnp.concatenate(heads, axis=-1) @ lp["wo"]
+        o = p @ v[:, g]
+        if cfg.attn_gate:
+            o = o * jax.nn.sigmoid(h @ lp["wg"][:, j * dv:(j + 1) * dv])
+        heads.append(o)
+    out = jnp.concatenate(heads, axis=-1) @ lp["wo"]
+    if cfg.post_norms:
+        out = _norm(out, lp["ln1_post"], cfg.norm_eps)
+    return x + out
 
 
 def expert_layer_sum(h2, lp, cfg, first: int = None, held: int = None):
@@ -108,13 +135,19 @@ def forward_logits(params, tokens, cfg):
     forward, every layer of its kind."""
     with jax.default_matmul_precision("highest"):
         p = _f32(params)
-        x = p["embed"][jnp.asarray(tokens)]
+        x = p["embed"][jnp.asarray(tokens)] * cfg.embed_scale
         for i, (group, j) in enumerate(cfg.param_groups()):
             lp = jax.tree.map(lambda a: a[j], p[group])
             x = attention(x, lp, cfg, cfg.layer_kinds[i])
             h2 = _norm(x, lp["ln2"], cfg.norm_eps)
             if "router" in lp:
-                x = x + expert_layer_sum(h2, lp, cfg)
+                out = expert_layer_sum(h2, lp, cfg)
+                if cfg.shared_ffn:  # every member computes it: once a token
+                    out = out + _swiglu(h2, lp["ws_gate"], lp["ws_up"],
+                                        lp["ws_down"])
             else:
-                x = x + _swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
+                out = _swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
+            if cfg.post_norms:
+                out = _norm(out, lp["ln2_post"], cfg.norm_eps)
+            x = x + out
         return _norm(x, p["final_norm"], cfg.norm_eps) @ p["head"]

@@ -181,10 +181,12 @@ def _moe_cfg(args):
     """The MoE stack's model description: the published keys of a Hugging
     Face ``config.json`` (``--model-config``: a ``mixtral``-, a
     ``glm4_moe_lite``/``deepseek_v3``- — latent attention, a dense prefix,
-    sigmoid-bias gate, shared expert — or a ``mimo_v2_flash``-shaped file —
+    sigmoid-bias gate, shared expert —, a ``mimo_v2_flash``-shaped file —
     window and full attention layers with their own cache groups, a held
-    share of the experts; weights stored in its ``torch_dtype``), or else
-    the hand-sized flags (the uniform block)."""
+    share of the experts — or an ``afmoe``-shaped one — gated attention
+    with QK-norm, rotary on the window layers only, sandwich norms, a shared
+    expert beside a held share; weights stored in its ``torch_dtype``), or
+    else the hand-sized flags (the uniform block)."""
     from uccl_tpu.models.moe_inference import MoEServeConfig
 
     if not args.model_config:
@@ -199,8 +201,9 @@ def _moe_cfg(args):
                          "no --ckpt-dir")
     with open(args.model_config) as f:
         hf = json.load(f)
-    experts = hf.get("router_experts") or hf.get("n_routed_experts") \
-        or hf["num_local_experts"]
+    experts = next(hf[k] for k in ("router_experts", "n_routed_experts",
+                                   "num_experts", "num_local_experts")
+                   if hf.get(k))
     cfg = MoEServeConfig.from_hf(
         hf,
         # the slot engine needs a drop-free wire: factor * top-k >= experts

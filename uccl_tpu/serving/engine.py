@@ -133,11 +133,18 @@ class ChunkEvent:
     reused: bool
 
 
-def _kv_rows(decoding) -> int:
-    """Cached rows the decoding slots attend over (prompt + generated so
-    far): what a decode step's attention must read, from the engine's own
-    state — the argument a roofline reader needs on the wire span."""
-    return sum(int(r.prompt.size) + r.n_generated for r in decoding.values())
+def _kv_rows(decoding, window: int = 0) -> dict:
+    """Cached rows the decoding slots attend over, from the engine's own
+    state — the arguments a roofline reader needs on the wire span.
+    ``kv_rows``: prompt + generated so far, summed over the slots (what a
+    layer that keeps every position must read); with window layers
+    (``window`` > 0) also ``window_rows``: ``min(length, window)`` a slot,
+    what a window layer's queries can see."""
+    lens = [int(r.prompt.size) + r.n_generated for r in decoding.values()]
+    rows = {"kv_rows": sum(lens)}
+    if window:
+        rows["window_rows"] = sum(min(n, window) for n in lens)
+    return rows
 
 
 def _bucket(n: int, cap: int) -> int:
@@ -150,13 +157,14 @@ def _bucket(n: int, cap: int) -> int:
 
 
 def _check_window_groups(backend, prefill_chunk, spec_k, prefix_cache,
-                         kv_tiers) -> None:
+                         kv_tiers) -> int:
     """What a pool with window groups (a model description whose
     ``layer_kinds`` name "window" layers) cannot do yet, refused before a
-    request could meet it; and the ring against the widest write."""
+    request could meet it; and the ring against the widest write. Returns
+    the window (0 for a pool without window groups)."""
     cfg = getattr(backend, "cfg", None)
     if "window" not in (getattr(cfg, "layer_kinds", ()) or ()):
-        return
+        return 0
     from uccl_tpu.models.inference import WINDOW_GROUPS_STAY
 
     if kv_tiers is not None:
@@ -176,6 +184,7 @@ def _check_window_groups(backend, prefill_chunk, spec_k, prefix_cache,
             f"the window layers' ring of {cfg.ring} rows must hold window - "
             f"1 + the widest write ({cfg.window} - 1 + {widest}): raise "
             f"window_ring or lower prefill_chunk / spec_k")
+    return cfg.window
 
 
 class ServingEngine:
@@ -295,8 +304,8 @@ class ServingEngine:
                     "chunk boundaries and resumes via the chunked "
                     "start-offset program"
                 )
-        _check_window_groups(backend, prefill_chunk, spec_k, prefix_cache,
-                             kv_tiers)
+        self._window = _check_window_groups(
+            backend, prefill_chunk, spec_k, prefix_cache, kv_tiers)
         self.backend = backend
         self.spec_k = spec_k
         self.drafter = drafter
@@ -1277,7 +1286,7 @@ class ServingEngine:
         rows = list(decoding.items())
         t0 = now()
         with obs.span("wire.decode", "wire", n=len(decoding),
-                      kv_rows=_kv_rows(decoding)):
+                      **_kv_rows(decoding, self._window)):
             tok = self.backend.decode(self._last_tok.copy(), active,
                                       **self._extra_kw(rows, pos0))
         self.metrics.on_decode_step(now() - t0, len(decoding),
@@ -1319,7 +1328,7 @@ class ServingEngine:
         # the device window only — the host commit loop below is
         # engine.retire's (same placement as _decode's wire.decode)
         with obs.span("wire.verify", "wire", n=len(decoding), k=k,
-                      kv_rows=_kv_rows(decoding)):
+                      **_kv_rows(decoding, self._window)):
             tok, n_acc = self.backend.verify(tokens, active,
                                              **self._extra_kw(rows, pos0))
         dt = now() - t0

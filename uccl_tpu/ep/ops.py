@@ -685,7 +685,17 @@ def _expert_gemms(xe, w_gate, w_up, w_down):
     expert_gemm_probe.py — but in the fused model context the end-to-end
     gain was <1%, and the unrolled dots lose their `e` batch dim, which
     silently drags every expert GEMM into the remat="dots" saved set and
-    OOMs the documented-working B=32 dots config.)"""
+    OOMs the documented-working B=32 dots config.)
+
+    Who keeps this batched form, which reads every expert's weights
+    whatever the routing: the trainer (``models/flagship.py``; the tags
+    above), the chunk-pipelined layer, every prefill program (a chunk of
+    64-128 rows x top-k reaches the experts held or nearly, so there is
+    nothing to skip), the one-shot ``MoEServer.generate`` / ``decode_step``,
+    any layer over an exchange (``world > 1``) and the ``ll`` / ``dense``
+    forms. Only a caller that names the rows that count (``moe_ffn(...,
+    rows=)``: the slot pool's decode and verify programs) gets
+    :func:`_expert_gemms_reached` in its place."""
     with jax.named_scope("moe.experts"):
         xe = checkpoint_name(xe, _XE)
         h_gate = checkpoint_name(
@@ -694,6 +704,63 @@ def _expert_gemms(xe, w_gate, w_up, w_down):
         act = jax.nn.silu(h_gate) * h_up
         return checkpoint_name(
             jnp.einsum("ebf,efh->ebh", act, w_down), _YE)
+
+
+def _expert_gemms_reached(xe, w_gate, w_up, w_down, layer, counts):
+    """:func:`_expert_gemms` over the experts whose queue holds a row, and
+    over no other expert's weights: ``counts`` [E] are the queues' kept
+    rows; the experts with ``counts > 0`` come first in ``ids`` and a loop
+    of ``n_reached`` trips (traced: the routing sets it every step) takes
+    expert ``ids[i]`` — its queue ``[1, C, H]`` out of ``xe``, its three
+    matrices out of the leaves WHERE THEY LIE, the same three products and
+    SwiGLU, written into a zero ``ye [E, C, H]`` at its place. Same
+    contraction, dtype and precision a row as the batched einsum, so a
+    row's result does not change with who else is computed; an expert
+    nobody reached keeps its zero rows, which no slot gathers.
+
+    The leaves come in as stored and are cast AFTER the slice: a cast of
+    a whole leaf in front of the loop is a loop-invariant copy of every
+    expert in the activations' dtype. ``layer`` (an int, or None) says the
+    leaves are a group's stack ``[L, E, ...]`` and this is layer ``layer``
+    of it: the slice is taken out of the stack inside the body, because a
+    ``stack[layer]`` in front of the loop is an operand of the ``while``
+    and the compiler materialises it (1.2 GB a layer at GLM's widths in
+    the rehearsal compile). Returns (ye, n_reached int32)."""
+    reached = counts > 0
+    n_reached = jnp.sum(reached, dtype=jnp.int32)
+    ids = jnp.argsort(~reached, stable=True).astype(jnp.int32)
+
+    def expert(w, e, shape=None):
+        """``shape`` of expert ``e``'s matrix from its corner (all of it:
+        ``[1, H, F]``), where it lies in the leaf."""
+        shape = (1,) + (w.shape[-2:] if shape is None else shape)
+        if layer is None:
+            return lax.dynamic_slice(w, (e, 0, 0), shape).astype(xe.dtype)
+        return lax.dynamic_slice(
+            w, (layer, e, 0, 0), (1,) + shape)[0].astype(xe.dtype)
+
+    def body(i, ye):
+        e = lax.dynamic_index_in_dim(ids, i, keepdims=False)
+        x1 = lax.dynamic_slice_in_dim(xe, e, 1, 0)
+        h_gate = jnp.einsum("ebh,ehf->ebf", x1, expert(w_gate, e))
+        h_up = jnp.einsum("ebh,ehf->ebf", x1, expert(w_up, e))
+        act = jax.nn.silu(h_gate) * h_up
+        y1 = jnp.einsum("ebf,efh->ebh", act, expert(w_down, e))
+        # One number of each leaf enters the result in the activations'
+        # precision, times zero. Without it the TPU compiler sees float32
+        # leaves whose every reader is a product at the MXU's default
+        # precision, and converts the WHOLE leaves to bfloat16 in front of
+        # the loop, every step (Mixtral's: 5.6 GB read, 2.8 GB of
+        # temporaries written, in the rehearsal compile); with it the
+        # products read the float32 expert where it lies and convert in
+        # the fusion, as the batched einsum's do.
+        keep = sum(expert(w, e, (1, 1)) for w in (w_gate, w_up, w_down))
+        y1 = y1 + keep * jnp.zeros((), xe.dtype)
+        return lax.dynamic_update_slice_in_dim(ye, y1, e, 0)
+
+    with jax.named_scope("moe.experts"):
+        return lax.fori_loop(0, n_reached, body, jnp.zeros_like(xe)), \
+            n_reached
 
 
 def _moe_ffn_sort_chunked(
@@ -768,7 +835,8 @@ def _moe_ffn_sort_chunked(
 
 def _moe_ffn_held(x, router_logits, w_gate, w_up, w_down, axis, held: int,
                   first: int, num_selected: int, capacity_factor: float,
-                  impl: str, gate: str, gate_bias, routed_scale: float):
+                  impl: str, gate: str, gate_bias, routed_scale: float,
+                  rows=None, layer=None):
     """One member's share of an expert layer whose other experts live on
     chips that are not here. The gate is the whole layer's: every token is
     scored over all ``E`` experts, its ``k`` chosen and their weights
@@ -786,26 +854,37 @@ def _moe_ffn_held(x, router_logits, w_gate, w_up, w_down, axis, held: int,
     ``capacity_factor * k >= E``, which gives every held queue ``T`` rows.
     A pair for an absent expert is sent to a queue past the held ones
     (id ``held``) that is never gathered, so it neither takes a held queue's
-    row nor counts as a drop."""
+    row nor counts as a drop.
+
+    ``rows`` ([T] bool, ``impl`` "sort"; :func:`moe_ffn`): the rows that
+    count. A pair of a row that does not goes where a pair for an
+    absent expert goes, and the GEMMs run over the held experts that a row
+    which counts reached (:func:`_expert_gemms_reached`; ``layer`` as
+    there, the leaves as stored); the result then carries ``n_reached``.
+    ``held`` may be all ``E`` here: the whole layer on one member."""
     from uccl_tpu.obs import counters as _obsc
 
     t, _ = x.shape
     e = router_logits.shape[-1]
     capacity = _resolve_capacity(t, num_selected, e, capacity_factor)
-    _obsc.gauge(
-        "ep_experts_held",
-        "experts resident on this member in the last traced EP layer that "
-        "held a share (their queues: ep_expert_capacity rows each)",
-    ).set(held, what="moe_layer")
+    if held != e:
+        _obsc.gauge(
+            "ep_experts_held",
+            "experts resident on this member in the last traced EP layer "
+            "that held a share (their queues: ep_expert_capacity rows each)",
+        ).set(held, what="moe_layer")
     with jax.named_scope("moe.route"):
         vals, idx, aux_loss, z_loss = _gate_topk(
             router_logits, num_selected, True, gate, gate_bias, routed_scale)
         local = idx - first
-        local = jnp.where((local >= 0) & (local < held), local, held)
+        here = (local >= 0) & (local < held)
+        if rows is not None:
+            here = here & rows[:, None]
+        local = jnp.where(here, local, held)
         if impl == "sort":
             # held + 1 queues, the last the absent experts': its slots lie
             # past the held buffer, so they gather nothing and return zero
-            tfs, slot, _ = sorted_from_topk(local, held + 1, capacity)
+            tfs, slot, kept = sorted_from_topk(local, held + 1, capacity)
             tfs = tfs[:held * capacity]
         elif impl == "dense":
             # an id past the held experts is an all-zero one-hot row
@@ -816,11 +895,18 @@ def _moe_ffn_held(x, router_logits, w_gate, w_up, w_down, axis, held: int,
     with jax.named_scope("moe.dispatch"):
         xe = dispatch_sorted(x, tfs, held, capacity, axis) \
             if impl == "sort" else dispatch(x, d_mask, axis)
-    ye = _expert_gemms(xe, w_gate, w_up, w_down)
+    if rows is None:
+        ye = _expert_gemms(xe, w_gate, w_up, w_down)
+    else:
+        ye, n_reached = _expert_gemms_reached(xe, w_gate, w_up, w_down,
+                                              layer, kept[:held])
     with jax.named_scope("moe.combine"):
         out = combine_sorted(ye, slot, vals, axis) if impl == "sort" \
             else combine(ye, c_weights, axis)
-    return out.astype(x.dtype), aux_loss, z_loss
+    out = out.astype(x.dtype)
+    if rows is None:
+        return out, aux_loss, z_loss
+    return out, aux_loss, z_loss, n_reached
 
 
 def moe_ffn(
@@ -843,7 +929,9 @@ def moe_ffn(
     routed_scale: float = 1.0,
     experts_held: Optional[int] = None,
     first_expert: int = 0,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    rows: Optional[jax.Array] = None,
+    layer: Optional[int] = None,
+) -> Tuple[jax.Array, ...]:
     """Full per-shard MoE layer: route → dispatch → SwiGLU experts → combine.
 
     x: [T, H]; router_logits: [T, E]; expert weights are the *local* shard:
@@ -878,13 +966,43 @@ def moe_ffn(
     member's share of a wider deployment (:func:`_moe_ffn_held`); the
     weights are then ``[experts_held, ...]`` and the result the part of the
     layer's sum its own experts give.
-    Returns (out [T, H], aux_loss, z_loss).
+    rows / layer: ``rows`` [T] bool names the rows that COUNT — what the
+    slot pool's decode and verify programs hand down on one shard: a
+    decoding slot's rows, and not the dummy token of an idle slot, whose
+    result the caller discards. EP world 1 and impl "sort" only (the whole
+    layer or a held share; anything else is refused, as a held share over
+    an exchange is). A (token, choice) pair of a row that does not count
+    goes to a queue past the last one, never gathered and no drop, and the
+    GEMMs loop over the experts a row which counts reached, reading no
+    other expert's weights (:func:`_expert_gemms_reached`) — one algorithm
+    whose trip count the routing sets every step; at full reach it reads
+    what the batched einsum reads. A row that counts keeps its queue, its
+    weights and its arithmetic; the others' output rows are not meaningful.
+    The weights then come AS STORED — the loop slices an expert out and
+    casts the slice — and ``layer`` (an int) says they are a stack ``[L,
+    E_local, ...]`` of which this is layer ``layer`` (None: the layer's own
+    ``[E_local, ...]``). The layer then also returns ``n_reached`` (int32,
+    traced): how many experts' weights it read. ``rows`` absent (the
+    trainer, every prefill program, the one-shot paths, any layer over an
+    exchange, "ll" and "dense"): the batched layer as it always was.
+    Returns (out [T, H], aux_loss, z_loss), and ``n_reached`` after them
+    where ``rows`` was given.
     """
     t, h = x.shape
     gating = dict(gate=gate, gate_bias=gate_bias, routed_scale=routed_scale)
     e = router_logits.shape[-1]
     w = lax.axis_size(axis)
     wire_dtype = resolve_wire_dtype(wire_fp8, wire_dtype)
+    if rows is not None:
+        if w > 1 or impl != "sort":
+            raise ValueError(
+                f"rows that count are one member's, looped over without an "
+                f"exchange: EP world {w}, impl {impl!r} (want world 1, "
+                f"'sort'); hand no rows and the batched layer runs")
+        return _moe_ffn_held(
+            x, router_logits, w_gate, w_up, w_down, axis,
+            experts_held or e, first_expert, num_selected, capacity_factor,
+            impl, **gating, rows=rows, layer=layer)
     if experts_held is not None and experts_held != e:
         if w > 1 or impl == "ll":
             raise ValueError(

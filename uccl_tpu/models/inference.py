@@ -426,21 +426,28 @@ def _attention_of(cfg, i: int = 0):
     raise ValueError(f"unknown attention kind {kind!r} (want 'gqa' or 'mla')")
 
 
-def _layer_params(params, i: int, cfg=None):
-    """Layer ``i``'s leaves. Layers come in stacked groups: the leading
+def _layer_place(params, i: int, cfg=None):
+    """Where layer ``i``'s leaves lie: (its group's stacked leaves, its
+    index there). Layers come in stacked groups: the leading
     ``dense_blocks`` (where the model has a dense-FFN prefix: their leading
     dim is how many) and then ``blocks``; a description with layer kinds
     (``cfg.param_groups()``) names each layer's group and its index there."""
     if cfg is not None and layer_kinds(cfg):
         group, j = cfg.param_groups()[i]
-        return jax.tree.map(lambda a: a[j], params[group])
+        return params[group], j
     dense = params.get("dense_blocks")
     if dense is not None:
         n = jax.tree.leaves(dense)[0].shape[0]
         if i < n:
-            return jax.tree.map(lambda a: a[i], dense)
+            return dense, i
         i -= n
-    return jax.tree.map(lambda a: a[i], params["blocks"])
+    return params["blocks"], i
+
+
+def _layer_params(params, i: int, cfg=None):
+    """Layer ``i``'s leaves, sliced out of their group's stack."""
+    group, j = _layer_place(params, i, cfg)
+    return jax.tree.map(lambda a: a[j], group)
 
 
 def _dense_ffn(h2, lp):
@@ -459,12 +466,13 @@ def _embed(params, tokens, cfg, dtype):
         return x if scale == 1.0 else x * jnp.asarray(scale, dtype)
 
 
-def _ffn_half(x, lp, cfg, ffn):
+def _ffn_half(x, lp, cfg, ffn, **slot_kw):
     """The FFN half of a layer with its residual: ``ffn(h2, lp)`` (the dense
     SwiGLU without one) of the normed input; a layer with ``ln2_post`` norms
-    the branch's output before it joins the residual."""
+    the branch's output before it joins the residual. ``slot_kw``: what the
+    slot loop hands the hook beside them (:func:`_forward_slots`)."""
     h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    out = _dense_ffn(h2, lp) if ffn is None else ffn(h2, lp)
+    out = _dense_ffn(h2, lp) if ffn is None else ffn(h2, lp, **slot_kw)
     if "ln2_post" in lp:
         with jax.named_scope("ffn.post_norm"):
             out = rms_norm(out, lp["ln2_post"], cfg.norm_eps)
@@ -687,7 +695,13 @@ def _forward_slots(
     dropped), so mid-decode neighbors are never corrupted by a prefill or by
     an idle slot's dummy token. Lengths are NOT advanced here; the callers
     own the per-slot length bookkeeping. ``ffn`` is the same dense-block
-    override hook as :func:`_forward_cached` (the MoE serving loop uses it).
+    override hook as :func:`_forward_cached` (the MoE serving loop uses it),
+    handed two things more here, ``ffn(h2, lp, rows=, place=)``: ``rows`` =
+    ``write_mask``, the rows whose results the caller keeps (an idle slot's
+    dummy token is not one), and ``place`` = (the layer's group of stacked
+    leaves, its index there), what ``lp`` was sliced from, for a block that
+    reads a leaf where it lies. A block may ignore both; the dense SwiGLU
+    (``ffn=None``) does.
 
     The pool is written IN PLACE: the layer-stacked ``[L, B_slots, S_max,
     ...]`` arrays are carried through the layer loop, layer ``i``'s new rows
@@ -769,7 +783,8 @@ def _forward_slots(
             x, lp, group_array(k, group), group_array(v, group), positions,
             start, write, cfg, lora=lora)
         k, v = _with_group(k, group, nk), _with_group(v, group, nv)
-        x = _ffn_half(x, lp, cfg, ffn)
+        x = _ffn_half(x, lp, cfg, ffn, rows=write_mask,
+                      place=_layer_place(params, i, cfg))
     logits = _head(x, params, cfg)
     return logits, SlotKVCache(k, v, cache.lengths)
 

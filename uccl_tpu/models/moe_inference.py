@@ -31,6 +31,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from jax import shard_map
@@ -655,15 +656,23 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
 _ROUTER_PRECISION = {"softmax": None, "sigmoid_bias": lax.Precision.HIGHEST}
 
 
-def _moe_block(cfg: MoEServeConfig, impl: str):
+def _moe_block(cfg: MoEServeConfig, impl: str, reads: Optional[list] = None):
     """The FFN half of a layer as an :func:`inference._forward_cached`-style
     ``ffn`` hook, by what the layer's leaves say it is: a dense-prefix layer
     (no router) runs the dense SwiGLU; an expert layer routes over the EP
     axis (sorted path for prefill throughput, packed LL for decode), experts
     being the LOCAL shard, and adds the shared expert — once per token,
-    outside ``moe_ffn``, whatever the EP world."""
+    outside ``moe_ffn``, whatever the EP world.
 
-    def moe_block(h2, lp):
+    ``reads`` (a list, the decode / verify program's): the block then takes
+    what the slot loop hands it (``rows`` [B], ``place``:
+    :func:`inference._forward_slots`) down to ``moe_ffn(..., rows=,
+    layer=)`` — the expert leaves as stored, not yet sliced or cast — and
+    appends each expert layer's ``n_reached`` to it, for the program to sum
+    and return. Without it (every prefill program, the one-shot paths) the
+    block ignores both and is what it always was."""
+
+    def moe_block(h2, lp, rows=None, place=None):
         if "router" not in lp:
             with jax.named_scope("ffn.dense"):
                 return _dense_ffn(h2, lp)
@@ -673,10 +682,15 @@ def _moe_block(cfg: MoEServeConfig, impl: str):
             router_logits = jnp.dot(
                 flat.astype(jnp.float32), lp["router"].astype(jnp.float32),
                 precision=_ROUTER_PRECISION[cfg.gate])
-        out, _, _ = ep_ops.moe_ffn(
-            flat, router_logits,
-            lp["we_gate"].astype(flat.dtype), lp["we_up"].astype(flat.dtype),
-            lp["we_down"].astype(flat.dtype),
+        if reads is None:
+            experts = [lp[n].astype(flat.dtype) for n in _EXPERT_LEAVES]
+            loop_kw = {}
+        else:
+            group, layer = place
+            experts = [group[n] for n in _EXPERT_LEAVES]
+            loop_kw = dict(rows=jnp.repeat(rows, sq), layer=layer)
+        out, *rest = ep_ops.moe_ffn(
+            flat, router_logits, *experts,
             _AXIS,
             num_selected=cfg.moe_topk,
             capacity_factor=cfg.capacity_factor,
@@ -689,7 +703,10 @@ def _moe_block(cfg: MoEServeConfig, impl: str):
             routed_scale=cfg.routed_scale,
             experts_held=cfg.experts_held or None,
             first_expert=cfg.first_expert,
+            **loop_kw,
         )
+        if reads is not None:
+            reads.append(rest[-1])  # (aux_loss, z_loss, n_reached)
         if "ws_gate" in lp:
             with jax.named_scope("moe.shared"):
                 out = out + _dense_ffn(
@@ -986,33 +1003,45 @@ class MoEServer:
         every routing exact whatever the window's width. tokens
         [W, B_loc, S]; active, ``sampling``'s arrays and ``adapter_ids``
         [W, B_loc]. Returns (target tokens [W, B_loc, S], n_accepted
-        [W, B_loc], cache'); ``cache`` is consumed, as
-        :meth:`prefill_slots` consumes it."""
+        [W, B_loc], experts read [W], cache'); ``cache`` is consumed, as
+        :meth:`prefill_slots` consumes it. Experts read: how many experts'
+        weights each member's expert GEMMs read in the step, summed over
+        its expert layers, of the ``n_held / W`` x ``n_moe_layers`` it
+        holds. On one shard with ``impl`` "sort" the ``active`` rows are
+        handed down as the rows that count (``ep.ops.moe_ffn(..., rows=)``)
+        and the program reads, and counts, the experts they reached;
+        otherwise the program is what it always was and reads them all."""
         self._check_drop_free()
         cfg = self.cfg
         sampled, adapted = sampling is not None, adapters is not None
         extra = _flat_extra(sampling, adapters, adapter_ids)
+        counted = self.world == 1 and impl == "sort"
 
         def uccl_moe_verify_slots(p, tok, mask, kc, vc, ln, *rest):
             samp, adp, ids = _split_extra([r[0] for r in rest], sampled,
                                           adapted)
+            reads = [] if counted else None
             t, n_acc, out = inference.verify_slots(
                 _strip_shard(p), tok[0], mask[0],
                 SlotKVCache(_member(kc), _member(vc), ln[0]), cfg,
                 sampling=samp, adapters=adp, adapter_ids=ids,
-                ffn=_moe_block(cfg, impl))
-            return (t[None], n_acc[None], _lead(out.k), _lead(out.v),
+                ffn=_moe_block(cfg, impl, reads))
+            read = [sum(reads)[None]] if counted else []
+            return (t[None], n_acc[None], *read, _lead(out.k), _lead(out.v),
                     out.lengths[None])
 
         key = ("verify_slots", impl, tokens.shape,
                _shapes(cache.k, cache.v), sampled, adapted)
         fn = self._fn(key, lambda: self._shard_mapped(
-            uccl_moe_verify_slots, 5 + len(extra), 5, params,
+            uccl_moe_verify_slots, 5 + len(extra), 5 + counted, params,
             donate=(3, 4, 5)))
-        tok, n_acc, nk, nv, nlen = fn(params, tokens, active,
-                                      cache.k, cache.v, cache.lengths,
-                                      *extra)
-        return tok, n_acc, MoESlotCache(nk, nv, nlen)
+        *out, nk, nv, nlen = fn(params, tokens, active, cache.k, cache.v,
+                                cache.lengths, *extra)
+        if not counted:  # the batched GEMMs: every expert, whatever the rows
+            out.append(np.full(
+                self.world, cfg.n_held // self.world * cfg.n_moe_layers,
+                np.int32))
+        return (*out, MoESlotCache(nk, nv, nlen))
 
     def decode_step_slots(self, params, token, active, cache: MoESlotCache,
                           impl: str = "ll", sampling=None, adapters=None,
@@ -1021,13 +1050,11 @@ class MoEServer:
         path by default) — the S=1 case of :meth:`verify_slots`.
         token/active: [W, B_loc]; inactive slots neither write KV nor
         advance their length. Returns (next greedy-or-sampled token
-        [W, B_loc], cache')."""
-        tok, _, cache = self.verify_slots(params, token[..., None], active,
-                                          cache, impl=impl,
-                                          sampling=sampling,
-                                          adapters=adapters,
-                                          adapter_ids=adapter_ids)
-        return tok[..., 0], cache
+        [W, B_loc], experts read [W], cache')."""
+        tok, _, read, cache = self.verify_slots(
+            params, token[..., None], active, cache, impl=impl,
+            sampling=sampling, adapters=adapters, adapter_ids=adapter_ids)
+        return tok[..., 0], read, cache
 
     def generate(self, params, prompt, new_tokens: int, max_seq: int,
                  impl: str = "ll", sampling=None):

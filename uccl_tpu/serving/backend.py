@@ -48,6 +48,21 @@ _POOL_IN_PLACE = obs.counter(
 )
 
 
+_EXPERTS_READ = obs.counter(
+    "ep_experts_read_total",
+    "experts whose weights the decode / verify programs' expert GEMMs read, "
+    "summed over expert layers, members and steps (the program's own "
+    "output, fetched with its tokens)",
+)
+_EXPERTS_HELD = obs.counter(
+    "ep_experts_held_total",
+    "experts held x expert layers, summed over the same steps: what the "
+    "programs would read if they skipped nothing — read / held is 1.0 where "
+    "the GEMMs ran over every expert, and the reached share where they loop "
+    "over the experts the decoding rows reached",
+)
+
+
 def _consumed(pool) -> bool:
     """Whether a program took every array of the pool it was handed (the
     (k, v) pair, or the pair of each cache group)."""
@@ -168,11 +183,14 @@ class SlotBackend:
     laid out for the programs — ``None``: as it is, numpy straight into jit;
     ``W``: on the device as ``[W, rows / W]``, adapter tables broadcast
     ``[W, ...]``; ``rungs`` are the row counts the chunked prefill may be
-    called at (:func:`prefill_rung`)."""
+    called at (:func:`prefill_rung`); ``experts_held`` is experts held x
+    expert layers where the decode and verify programs return, last before
+    the pool, how many experts' weights the step read (``[W]`` int32; the MoE
+    stack's do), and 0 where they return no such count."""
 
     def __init__(self, params, cfg, programs: Programs, new_pool: Callable, *,
                  n_slots: int, max_seq: int, rungs: tuple,
-                 world: Optional[int] = None):
+                 world: Optional[int] = None, experts_held: int = 0):
         self.params = params
         self.cfg = cfg
         self.programs = programs
@@ -180,6 +198,7 @@ class SlotBackend:
         self.max_seq = max_seq
         self.prefill_rungs = rungs
         self.world = world
+        self.experts_held = experts_held
         self._new_pool = new_pool
         self.cache = new_pool()
         self._rungs_built = set()  # (chunk, sampled, adapted) kinds
@@ -274,7 +293,21 @@ class SlotBackend:
             out = [np.asarray(o) for o in out]
             if self.world is not None:  # [W, rows / W, ...] -> [rows, ...]
                 out = [o.reshape((-1,) + o.shape[2:]) for o in out]
-            return out
+        if self.experts_held and kind != "prefill":
+            self._count_experts(int(out.pop().sum()))
+        return out
+
+    def _count_experts(self, read: int) -> None:
+        """One decode / verify step's experts read, on the two counters and
+        as the arguments of an ``ep.experts`` span inside the step's
+        ``wire.decode`` / ``wire.verify``, after the fetch that brought the
+        count (a span, empty, because a span's arguments are fixed when it
+        opens and an instant does not reach a profiler session)."""
+        _EXPERTS_READ.inc(read)
+        _EXPERTS_HELD.inc(self.experts_held)
+        with obs.span("ep.experts", "wire", experts_read=read,
+                      experts_held=self.experts_held):
+            pass
 
     def prefill(self, tokens: np.ndarray, lens: np.ndarray,
                 mask: np.ndarray,
@@ -396,7 +429,8 @@ class MoEBackend(SlotBackend):
             partial(server.slot_cache, batch_local, max_seq),
             n_slots=n_slots, max_seq=max_seq, world=server.world,
             rungs=(prefill_rungs(n_slots) if server.world == 1
-                   else (n_slots,)))
+                   else (n_slots,)),
+            experts_held=server.cfg.n_held * server.cfg.n_moe_layers)
 
 
 def replicate_backend(backend, n: int, weights=None) -> List:

@@ -137,7 +137,7 @@ from __future__ import annotations
 
 import json
 import sys
-from collections import Counter, defaultdict
+from collections import defaultdict
 
 
 def fail(msg: str) -> None:
@@ -153,22 +153,15 @@ def check_trace(path: str) -> None:
         fail(f"{path}: no traceEvents")
     tracks = {e["tid"]: e["args"]["name"] for e in evs
               if e.get("name") == "thread_name"}
-    b, e_ = Counter(), Counter()
     by_track = defaultdict(list)
     for ev in evs:
-        if ev["ph"] == "B":
-            b[ev["tid"]] += 1
-        elif ev["ph"] == "E":
-            e_[ev["tid"]] += 1
-        elif ev["ph"] == "X" and ev.get("dur", 0) < 0:
+        if ev["ph"] == "X" and ev.get("dur", 0) < 0:
             fail(f"{path}: X event {ev['name']!r} with negative dur")
-        if ev["ph"] in "XBEi":
+        if ev["ph"] in "Xi":
             track = tracks.get(ev["tid"])
             if track is None:
                 fail(f"{path}: event on unnamed tid {ev['tid']}")
             by_track[track].append(ev)
-    if b != e_:
-        fail(f"{path}: unbalanced B/E events ({dict(b)} vs {dict(e_)})")
 
     complete = 0
     for track, track_evs in by_track.items():

@@ -33,7 +33,7 @@ import argparse
 import json
 import os
 import sys
-from collections import Counter, defaultdict
+from collections import defaultdict
 from typing import Dict, List
 
 # the cross-process causal chain (BEGIN <= GRANT <= FINAL in stream
@@ -141,24 +141,17 @@ def validate_merged(merged: Dict) -> Dict:
         m["pid"] for m in merged["otherData"].get("merged_from", ())
         if not m.get("anchored", True)
     }
-    b, e = Counter(), Counter()
     flows: Dict[str, Dict] = defaultdict(lambda: {"s": [], "f": []})
     by_trace: Dict[str, List[Dict]] = defaultdict(list)
     for ev in evs:
         ph = ev.get("ph")
         if ph == "X" and ev.get("dur", 0) < 0:
             fail(f"X event {ev['name']!r} with negative dur after merge")
-        if ph == "B":
-            b[(ev["pid"], ev["tid"])] += 1
-        elif ph == "E":
-            e[(ev["pid"], ev["tid"])] += 1
-        elif ph in ("s", "f"):
+        if ph in ("s", "f"):
             flows[str(ev.get("id"))][ph].append(ev)
         tid = (ev.get("args") or {}).get("trace_id")
         if tid:
             by_trace[tid].append(ev)
-    if b != e:
-        fail(f"unbalanced B/E after merge ({dict(b)} vs {dict(e)})")
     for fid, sf in flows.items():
         if sf["f"] and not sf["s"]:
             fail(f"flow id {fid}: finish without a start — the s/f pair "

@@ -8,7 +8,7 @@ through a real shard_map) shares the suite's virtual mesh.
 
 import json
 import threading
-from collections import Counter, defaultdict
+from collections import defaultdict
 
 import numpy as np
 import pytest
@@ -90,8 +90,8 @@ class TestTracer:
         assert obs.get_tracer() is None
         with obs.span("nothing", track="x", a=1):
             obs.instant("also-nothing")
-        obs.begin("b")
-        obs.end("b")
+        with obs.span("b"):
+            obs.mark("m", track="x", k=1)
         assert obs.get_tracer() is None  # still off, nothing recorded
 
     def test_span_and_clear(self, tracer):
@@ -104,22 +104,17 @@ class TestTracer:
         assert len(tracer) == 0
 
 
-def _phase_counts(trace):
-    """{track: [event names in ts order]} + B/E balance per tid."""
+def _by_track(trace):
+    """{track: [span and instant events in ts order]}."""
     tracks = {e["tid"]: e["args"]["name"] for e in trace["traceEvents"]
               if e.get("name") == "thread_name"}
     by_track = defaultdict(list)
-    b, e_ = Counter(), Counter()
     for ev in trace["traceEvents"]:
-        if ev["ph"] in "XBEi":
+        if ev["ph"] in "Xi":
             by_track[tracks[ev["tid"]]].append(ev)
-        if ev["ph"] == "B":
-            b[ev["tid"]] += 1
-        elif ev["ph"] == "E":
-            e_[ev["tid"]] += 1
     for evs in by_track.values():
         evs.sort(key=lambda ev: ev["ts"])
-    return by_track, b, e_
+    return by_track
 
 
 class TestHistograms:
@@ -239,27 +234,22 @@ class TestHistograms:
 
 class TestChromeTrace:
     def test_valid_json_balanced_and_nonnegative(self, tracer):
-        obs.begin("open-span", track="manual")
-        obs.instant("tick", track="manual")
-        obs.end("open-span", track="manual")
-        obs.begin("left-open", track="manual")  # exporter must close it
+        with obs.span("outer", track="manual", k=1):
+            obs.instant("tick", track="manual")
+            obs.mark("marked", track="manual", rid=3)
         with obs.span("x", track="other"):
             pass
         from uccl_tpu.obs import chrome_trace
 
         trace = json.loads(chrome_trace.dumps())
         assert isinstance(trace["traceEvents"], list)
-        _, b, e_ = _phase_counts(trace)
-        assert b == e_  # every B has a matching E
-        assert all(ev.get("dur", 0) >= 0 for ev in trace["traceEvents"]
+        # complete spans and instants only: nothing a viewer must pair up
+        assert {ev["ph"] for ev in trace["traceEvents"]} == {"M", "X", "i"}
+        assert all(ev["dur"] >= 0 for ev in trace["traceEvents"]
                    if ev["ph"] == "X")
-
-    def test_orphan_end_dropped(self, tracer):
-        obs.end("never-began", track="t")
-        obs.instant("i", track="t")
-        trace = obs.to_chrome_trace()
-        _, b, e_ = _phase_counts(trace)
-        assert b == e_ == Counter()
+        manual = _by_track(trace)["manual"]
+        assert [ev["name"] for ev in manual] == ["outer", "tick", "marked"]
+        assert manual[2]["args"] == {"rid": 3} and manual[2]["s"] == "t"
 
     def test_flow_events_and_clock_metadata(self, tracer):
         fid = obs.flow_id("deadbeefcafe0123")
@@ -302,8 +292,7 @@ class TestRequestLifecycle:
     def test_lifecycle_complete_whole_prompt(self, tracer):
         _, reqs = self._run()
         trace = obs.to_chrome_trace()
-        by_track, b, e_ = _phase_counts(trace)
-        assert b == e_
+        by_track = _by_track(trace)
         for r in reqs:
             names = [ev["name"] for ev in by_track[r.track]]
             # the full lifecycle, in timeline order, on the request's row
@@ -321,7 +310,7 @@ class TestRequestLifecycle:
     def test_lifecycle_complete_chunked(self, tracer):
         _, reqs = self._run(prefill_chunk=2)
         trace = obs.to_chrome_trace()
-        by_track, _, _ = _phase_counts(trace)
+        by_track = _by_track(trace)
         for r in reqs:
             names = [ev["name"] for ev in by_track[r.track]]
             chunks = names.count("prefill_chunk")
@@ -468,6 +457,31 @@ class TestPrometheusExport:
         assert "uccl_serving_completed 3" in lines
         assert any(line.startswith('uccl_serving_ttft_ms{q="p50"} ')
                    for line in lines)
+
+    def test_per_step_series_are_bounded_and_their_totals_exact(
+            self, monkeypatch):
+        """A server steps for as long as it lives: the two per-step series
+        keep the last STEP_SAMPLES; the slowest step and the decode wall
+        time (``decode_tok_s``) are running values and stay exact."""
+        from uccl_tpu.serving import metrics as sm
+
+        monkeypatch.setattr(sm, "STEP_SAMPLES", 8)
+        short, long_ = sm.ServingMetrics(), sm.ServingMetrics()
+        for m, n in ((short, 6), (long_, 20)):
+            m.on_step(0.5)  # the slowest step is the first: it falls off
+            for i in range(n):
+                m.on_step(0.01 * (i % 4 + 1))
+                m.on_decode_step(0.01 * (i % 4 + 1), 2)
+        assert len(short.step_s) == 7 and len(long_.step_s) == 8
+        assert len(long_.decode_step_s) == 8
+        a, b = short.snapshot(), long_.snapshot()
+        # under the bound: what a list of every sample gave
+        assert a["step_ms"] == sm.percentiles_ms(
+            [0.5] + [0.01 * (i % 4 + 1) for i in range(6)])
+        assert a["max_step_ms"] == b["max_step_ms"] == 500.0
+        assert a["decode_tok_s"] == round(12 / (0.01 * (10 + 1 + 2)), 1)
+        assert b["decode_tok_s"] == round(40 / (0.1 * 5), 1)
+        assert b["step_ms"]["p50"] == 25.0  # the last eight: 1..4 twice
 
     def test_stats_registry_mirrors_into_obs(self):
         from uccl_tpu.utils import stats

@@ -7,7 +7,12 @@
   imports without JAX;
 * the ring, when on, holds the same spans (plus the exit-time arguments);
 * the device programs carry their ``jax.named_scope``s into the compiled
-  text, and each jitted serving program has a module name of its own.
+  text, and each jitted serving program has a module name of its own;
+* (ISSUE 40) a step's ``wire.prefill`` and ``wire.decode`` share its
+  number, and a request's ``admit`` and ``first_token`` reach the session
+  as empty ``uccl.<mark>`` annotations with the request's ``rid``
+  (``obs.mark``: what a reader of the trace consumes, nothing else), the
+  ring keeping the instants it had.
 """
 
 import glob
@@ -37,9 +42,17 @@ ENGINE_SPANS = ("engine.step", "engine.admit", "wire.prefill", "wire.decode",
 SPAN_ARGS = {
     "engine.step": {"queued", "active", "prefilling", "decoding"},
     "engine.admit": {"queued"},
-    "wire.prefill": {"n", "chunk"},
-    "wire.decode": {"n", "kv_rows"},
+    "wire.prefill": {"step", "n", "chunk"},
+    "wire.decode": {"step", "n", "kv_rows"},
 }
+# a request's lifecycle in the ring, in order, with what each instant
+# carries; MARKS are the two bridged to the profiler, with the ``rid`` a
+# reader pairs them by
+LIFECYCLE = {"submit": {"rid", "prompt_len", "max_new_tokens"},
+             "admit": {"rid", "slot"},
+             "first_token": {"rid", "ttft_ms"},
+             "finish": {"reason", "tokens"}}
+MARKS = ("admit", "first_token")
 PARENT = {"engine.admit": "engine.step", "engine.retire": "engine.step",
           "wire.prefill": "engine.step", "wire.decode": "engine.step",
           "backend.stage": "wire.", "backend.launch": "wire.",
@@ -131,6 +144,67 @@ def test_span_reaches_the_profiler_nested_and_with_its_arguments(
         assert len(mine) == len(wires)
 
 
+def test_a_steps_calls_share_its_number(profiled):
+    steps = sorted((e for e in profiled if e[0] == "uccl.engine.step"),
+                   key=lambda e: e[1])
+    wires = [e for e in profiled if e[0].startswith("uccl.wire.")]
+    assert {e[0] for e in wires} == {"uccl.wire.prefill", "uccl.wire.decode"}
+    numbers = []  # each engine.step's: what its calls carry, all the same
+    for _, t0, t1, _, _ in steps:
+        mine = {e[3]["step"] for e in wires if t0 <= e[1] and e[2] <= t1}
+        assert len(mine) == 1, "a step's calls carry one number"
+        numbers.extend(mine)
+    # the engine counts its steps: back-to-back steps differ by one
+    assert numbers == list(range(numbers[0], numbers[0] + len(steps)))
+    # a chunk step that also decodes: two calls, one number
+    both = [n for n in numbers
+            if {e[0] for e in wires if e[3]["step"] == n}
+            == {"uccl.wire.prefill", "uccl.wire.decode"}]
+    assert both
+
+
+@pytest.mark.parametrize("mark", MARKS)
+def test_request_mark_reaches_the_profiler_with_its_rid(profiled, mark):
+    mine = [e for e in profiled if e[0] == "uccl." + mark]
+    # the session served the engine's third and fourth request, once each
+    assert sorted(e[3]["rid"] for e in mine) == [2, 3]
+    for _, t0, t1, args, _ in mine:
+        assert LIFECYCLE[mark] <= set(args)
+        assert t1 - t0 < 1e6  # empty: a mark covers no time (under a ms)
+
+
+@pytest.mark.parametrize("instant", sorted(set(LIFECYCLE) - set(MARKS))
+                         + ["prefill_chunk"])
+def test_what_no_reader_consumes_stays_out_of_the_profiler(profiled, instant):
+    assert not [e for e in profiled if e[0] == "uccl." + instant]
+
+
+def test_a_requests_wait_holds_its_prefill_calls(profiled):
+    for rid in (2, 3):
+        admit, first = sorted((e for e in profiled if e[0][5:] in MARKS
+                               and e[3].get("rid") == rid),
+                              key=lambda e: e[1])
+        assert (admit[0], first[0]) == ("uccl.admit", "uccl.first_token")
+        # the wait a reader sums device time over: it holds the request's
+        # prefill calls (12 and 5 tokens at a chunk of 8: two and one)
+        calls = [e for e in profiled if e[0] == "uccl.wire.prefill"
+                 and admit[1] <= e[1] and e[2] <= first[2]]
+        assert len(calls) >= (2 if rid == 2 else 1)
+
+
+def test_the_experts_count_arrives_as_before(profiled):
+    counts = [e for e in profiled if e[0] == "uccl.ep.experts"]
+    decodes = [e for e in profiled if e[0] == "uccl.wire.decode"]
+    assert len(counts) == len(decodes) > 0
+    for _, t0, t1, args, _ in counts:
+        assert set(args) == {"experts_read", "experts_held"}
+        assert 0 < args["experts_read"] <= args["experts_held"] == 8 * 2
+        fetch_ends = [e[2] for e in profiled if e[0] == "uccl.backend.fetch"]
+        (wire,) = [e for e in decodes if e[1] <= t0 and t1 <= e[2]]
+        # after the fetch that brought it, still inside the wire span
+        assert any(wire[1] <= f <= t0 for f in fetch_ends)
+
+
 def test_module_names_tell_the_programs_apart(profiled, devices):
     modules = {e[4] for e in profiled if e[4]}
     assert {"jit_uccl_moe_prefill_slots",
@@ -160,11 +234,22 @@ def test_nothing_is_recorded_with_no_session_and_the_ring_off(moe):
 
 
 def test_obs_imports_without_jax():
-    code = ("import sys\n"
+    code = ("import sys, tracemalloc\n"
             "from uccl_tpu import obs\n"
+            "from uccl_tpu.obs import tracer\n"
             "with obs.span('engine.step', 'engine', queued=1) as sp:\n"
             "    sp.add(finished=0)\n"
             "assert obs.span('a') is obs.span('b')  # the cached no-op\n"
+            "obs.mark('admit', 'req-0', rid=0, slot=1)  # binds what it can\n"
+            "tracemalloc.start()\n"
+            "a = tracemalloc.take_snapshot()\n"
+            "for _ in range(1000):\n"
+            "    obs.mark('admit', 'req-0', rid=0, slot=1)\n"
+            "b = tracemalloc.take_snapshot()\n"
+            "held = [d for d in b.compare_to(a, 'filename')\n"
+            "        if d.traceback[0].filename == tracer.__file__]\n"
+            "assert not held, held  # the ring off, no JAX: nothing kept\n"
+            "assert obs.get_tracer() is None\n"
             "assert 'jax' not in sys.modules, 'obs pulled JAX in'\n")
     subprocess.run([sys.executable, "-c", code], check=True, cwd=ROOT,
                    timeout=120)
@@ -196,11 +281,24 @@ def test_ring_holds_the_same_spans_with_exit_arguments(moe):
                    and e.ts_us + e.dur_us <= w.ts_us + w.dur_us + 1e-3
                    for n in ("wire.prefill", "wire.decode")
                    for w in by_name[n])
-    # the per-request lifecycle is untouched
+    # the per-request lifecycle is untouched: the instants it had, on the
+    # request's own row, admit and first_token now with the request's rid
     for r in reqs:
-        names = [e.name for e in evs if e.track == r.track]
+        mine = [e for e in evs if e.track == r.track]
+        names = [e.name for e in mine]
         assert names[0] == "submit" and names[-1] == "finish"
         assert "first_token" in names and "prefill_chunk" in names
+        marks = [e for e in mine if e.name in LIFECYCLE]
+        assert [e.name for e in marks] == list(LIFECYCLE)
+        assert all(e.ph == "i" and e.args.get("rid", r.rid) == r.rid
+                   and LIFECYCLE[e.name] <= set(e.args) for e in marks)
+    counts = [e for e in evs if e.name == "ep.experts"]
+    assert len(counts) == len(by_name["wire.decode"])
+    assert all(e.ph == "i" and e.track == "wire" for e in counts)
+    # and each step's calls carry its number, as in the profiler's trace
+    numbers = sorted({e.args["step"] for n in ("wire.prefill", "wire.decode")
+                      for e in by_name[n]})
+    assert numbers == list(range(numbers[0], numbers[0] + len(steps)))
 
 
 # -- scope names in the compiled programs -----------------------------------

@@ -30,10 +30,12 @@ Instrumentation idiom::
 
 Nothing is recorded (one bool check) until ``obs.enable_tracing()`` /
 ``--trace-out`` turns the tracer on; counters are always live (they are
-just dict adds). Independently of that switch, every ``obs.span`` is also
-written into any open ``jax.profiler`` session as ``uccl.<name>`` — on the
-profiler's clock, beside the device's operations (obs/tracer.py; about a
-microsecond a span when no session is open, nothing in a process without
+just dict adds). Independently of that switch, every ``obs.span`` and every
+``obs.mark`` (an instant a reader wants beside the device's operations: a
+request's ``admit`` and ``first_token``) is also written into any open
+``jax.profiler`` session
+as ``uccl.<name>`` — on the profiler's clock (obs/tracer.py; about a
+microsecond each when no session is open, nothing in a process without
 JAX).
 """
 
@@ -46,8 +48,8 @@ from uccl_tpu.obs.context import (  # noqa: F401
     TraceContext, estimate_clock_offset, flow_id, new_context,
 )
 from uccl_tpu.obs.tracer import (  # noqa: F401
-    Event, Tracer, begin, complete, end, flow_end, flow_start, get_tracer,
-    instant, set_clock_offset, span,
+    Event, Tracer, complete, flow_end, flow_start, get_tracer, instant,
+    mark, set_clock_offset, span,
 )
 from uccl_tpu.obs.tracer import enable as enable_tracing  # noqa: F401
 from uccl_tpu.obs.tracer import disable as disable_tracing  # noqa: F401
@@ -81,7 +83,7 @@ __all__ = [
     "Registry", "counter", "gauge", "histogram", "histogram_quantile",
     "bucket_width", "log_buckets", "DEFAULT_LATENCY_BUCKETS",
     "sanitize_name", "escape_label_value", "Event", "Tracer",
-    "begin", "complete", "end", "get_tracer", "instant", "span",
+    "complete", "get_tracer", "instant", "mark", "span",
     "flow_start", "flow_end", "set_clock_offset",
     "TraceContext", "new_context", "flow_id", "estimate_clock_offset",
     "enable_tracing", "disable_tracing", "tracing_enabled",

@@ -1,7 +1,7 @@
 """Chrome-trace / Perfetto JSON export of a :class:`~uccl_tpu.obs.tracer.Tracer`.
 
 Emits the Trace Event Format's JSON object form (``{"traceEvents": [...]}``)
-with ``B``/``E``/``X``/``i`` phase events plus ``M`` metadata naming the
+with ``X``/``i`` phase events plus ``M`` metadata naming the
 process and one thread row per tracer track — so ``ui.perfetto.dev`` (or
 ``chrome://tracing``) opens the file directly and shows each request,
 the engine loop, and the wire as its own labeled row.
@@ -11,9 +11,6 @@ Format notes (the parts tools are strict about):
 * timestamps (``ts``) and durations (``dur``) are microseconds;
 * ``X`` events must carry a non-negative ``dur``;
 * ``i`` (instant) events carry a scope ``s`` ("t" = thread-scoped);
-* every ``B`` should be closed by an ``E`` on the same pid/tid —
-  :func:`to_chrome_trace` closes any still-open ``B`` at the trace's end
-  timestamp rather than emitting an unbalanced file;
 * flow events (``s``/``f``) carry ``cat`` + ``id`` (the s/f pair binds by
   both), and the finish end binds to its enclosing slice (``bp: "e"``).
 
@@ -64,13 +61,8 @@ def to_chrome_trace(tracer: Optional[Tracer] = None, *,
             })
         return t
 
-    # track open B stacks per tid so the emitted file is always balanced
-    open_b: Dict[int, List[str]] = {}
-    end_ts = 0.0
     for ev in events:
-        t = tid(ev.track)
-        end_ts = max(end_ts, ev.ts_us + (ev.dur_us if ev.ph == "X" else 0.0))
-        rec = {"name": ev.name, "ph": ev.ph, "pid": PID, "tid": t,
+        rec = {"name": ev.name, "ph": ev.ph, "pid": PID, "tid": tid(ev.track),
                "ts": round(ev.ts_us, 3)}
         if ev.ph == "X":
             rec["dur"] = round(max(0.0, ev.dur_us), 3)
@@ -81,21 +73,9 @@ def to_chrome_trace(tracer: Optional[Tracer] = None, *,
             rec["id"] = ev.fid
             if ev.ph == "f":
                 rec["bp"] = "e"  # bind to the enclosing slice
-        elif ev.ph == "B":
-            open_b.setdefault(t, []).append(ev.name)
-        elif ev.ph == "E":
-            stack = open_b.get(t)
-            if not stack:
-                continue  # E whose B fell off the ring: drop, stay balanced
-            stack.pop()
         if ev.args:
             rec["args"] = dict(ev.args)
         out.append(rec)
-    # close any B still open (e.g. a span in flight at dump time)
-    for t, stack in open_b.items():
-        for name in reversed(stack):
-            out.append({"name": name, "ph": "E", "pid": PID, "tid": t,
-                        "ts": round(end_ts, 3)})
 
     trace = {
         "traceEvents": out,

@@ -13,9 +13,9 @@ steps, wire windows — with the properties the NPKit design proves out:
   nothing else shared.
 * **near-zero cost when disabled**: the module-level helpers check one
   bool; no allocation in the ring, no lock, no timestamp read. A
-  :func:`span` additionally enters a ``jax.profiler.TraceAnnotation``
-  (about a microsecond when no profiler session is open) once JAX is in
-  the process.
+  :func:`span` or a :func:`mark` additionally enters a
+  ``jax.profiler.TraceAnnotation`` (about a microsecond when no profiler
+  session is open) once JAX is in the process.
 * **monotonic timestamps**: ``time.perf_counter`` relative to the tracer's
   epoch, in microseconds (the Chrome-trace unit), so spans from different
   threads land on one consistent timeline.
@@ -28,8 +28,17 @@ enabled. That is how a profiled run attributes device idle time to what the
 host was doing (chipbench's ``program_trace.py`` reads them back). This
 module never imports JAX: the annotation class is bound the first time a
 span is opened in a process that already has ``jax`` in ``sys.modules``
-(no JAX, no profiler session to write into). After-the-fact records
-(:meth:`Tracer.complete`, instants, flows) stay ring-only.
+(no JAX, no profiler session to write into). :func:`mark` bridges a
+point in time the same way: the ring's instant and an EMPTY annotation
+``"uccl." + name`` that carries the arguments (an instant has no place in a
+profiler session; an empty span has). It is for an event that a reader of
+the profiler's trace consumes — today a request's ``admit`` and
+``first_token`` (the wait between them is split by what the device did)
+and a step's ``ep.experts`` count. Everything else (:func:`instant`: a
+request's other lifecycle events, the p2p, disagg and KV-tier loops, where
+a microsecond counts and nobody asks what the device did meanwhile;
+after-the-fact records: :meth:`Tracer.complete`, flows) stays ring-only
+until a reader wants it on the device's clock.
 
 Tracks: every event carries a ``track`` label — the Chrome-trace exporter
 maps each distinct label to a tid row. ``track=None`` means "this thread's
@@ -38,11 +47,11 @@ never interleave on one row; instrumentation that owns a logical timeline
 (a request, the engine loop, the wire) passes an explicit label instead.
 
 Event phases follow the Chrome-trace vocabulary: ``X`` (complete span with
-a duration — what :func:`span`/:meth:`Tracer.complete` emit), ``B``/``E``
-(open/close pairs for spans that cross call boundaries), ``i`` (instant),
-and ``s``/``f`` flow start/finish pairs (:meth:`Tracer.flow`) whose shared
-``fid`` binds two spans — possibly in DIFFERENT processes' traces, once
-merged by ``scripts/trace_merge.py`` — into one Perfetto arrow.
+a duration — what :func:`span`/:meth:`Tracer.complete` emit), ``i``
+(instant — :func:`instant`/:func:`mark`), and ``s``/``f`` flow
+start/finish pairs (:meth:`Tracer.flow`) whose shared ``fid`` binds two
+spans — possibly in DIFFERENT processes' traces, once merged by
+``scripts/trace_merge.py`` — into one Perfetto arrow.
 
 Fleet clocks: each tracer records ``wall_epoch_us`` (the wall-clock time of
 its monotonic ts 0) at construction, and :meth:`set_clock_offset` stores
@@ -74,7 +83,7 @@ _EVENTS_DROPPED = _counter(
 
 __all__ = [
     "Event", "Tracer", "enable", "disable", "enabled", "get_tracer",
-    "span", "instant", "begin", "end", "complete",
+    "span", "instant", "mark", "complete",
     "flow_start", "flow_end", "set_clock_offset",
 ]
 
@@ -86,7 +95,7 @@ class Event(NamedTuple):
     ``ph in ("s", "f")``."""
 
     name: str
-    ph: str  # "X" | "B" | "E" | "i" | "s" | "f"
+    ph: str  # "X" | "i" | "s" | "f"
     ts_us: float
     dur_us: float
     track: str
@@ -158,14 +167,6 @@ class Tracer:
                 **args) -> None:
         self._record(Event(name, "i", self.now_us(), 0.0,
                            self._track(track), args or None))
-
-    def begin(self, name: str, track: Optional[str] = None, **args) -> None:
-        self._record(Event(name, "B", self.now_us(), 0.0,
-                           self._track(track), args or None))
-
-    def end(self, name: str, track: Optional[str] = None) -> None:
-        self._record(Event(name, "E", self.now_us(), 0.0,
-                           self._track(track), None))
 
     def complete(self, name: str, ts_us: float, dur_us: float,
                  track: Optional[str] = None, **args) -> None:
@@ -322,16 +323,19 @@ def instant(name: str, track: Optional[str] = None, **args) -> None:
         t.instant(name, track, **args)
 
 
-def begin(name: str, track: Optional[str] = None, **args) -> None:
+def mark(name: str, track: Optional[str] = None, **args) -> None:
+    """A point in time with arguments: the ring's instant when tracing is
+    on and, in any open ``jax.profiler`` session, an empty annotation
+    ``"uccl." + name`` carrying ``args`` (see the module docstring for
+    which events are marks and which stay :func:`instant`). Allocates
+    nothing in a process with the ring off and no JAX."""
     t = _tracer
     if t is not None:
-        t.begin(name, track, **args)
-
-
-def end(name: str, track: Optional[str] = None) -> None:
-    t = _tracer
-    if t is not None:
-        t.end(name, track)
+        t.instant(name, track, **args)
+    ann = _annotation_cls()
+    if ann is not None:
+        with ann("uccl." + name, **args):
+            pass
 
 
 def complete(name: str, ts_us: float, dur_us: float,

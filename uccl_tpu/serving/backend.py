@@ -299,15 +299,13 @@ class SlotBackend:
 
     def _count_experts(self, read: int) -> None:
         """One decode / verify step's experts read, on the two counters and
-        as the arguments of an ``ep.experts`` span inside the step's
+        as the arguments of an ``ep.experts`` mark inside the step's
         ``wire.decode`` / ``wire.verify``, after the fetch that brought the
-        count (a span, empty, because a span's arguments are fixed when it
-        opens and an instant does not reach a profiler session)."""
+        count."""
         _EXPERTS_READ.inc(read)
         _EXPERTS_HELD.inc(self.experts_held)
-        with obs.span("ep.experts", "wire", experts_read=read,
-                      experts_held=self.experts_held):
-            pass
+        obs.mark("ep.experts", "wire", experts_read=read,
+                 experts_held=self.experts_held)
 
     def prefill(self, tokens: np.ndarray, lens: np.ndarray,
                 mask: np.ndarray,

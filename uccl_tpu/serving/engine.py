@@ -340,6 +340,10 @@ class ServingEngine:
         self.dead = False  # killed (chaos / failure injection): step() raises
         self._last_tok = np.zeros(backend.n_slots, np.int32)
         self._next_rid = 0
+        # steps taken, on a step's wire.prefill and wire.decode spans as
+        # ``step``: what tells a reader which calls are one step's and
+        # which steps are back to back
+        self._steps = 0
         if step_stall_s is not None and step_stall_s <= 0:
             raise ValueError(
                 f"step_stall_s must be > 0, got {step_stall_s}"
@@ -625,6 +629,7 @@ class ServingEngine:
                 "recover its requests via Router health handling"
             )
         t0 = now()
+        self._steps += 1
         finished: List[Request] = []
         # spans nest on this one thread (engine.step > engine.admit |
         # wire.* > backend.* | engine.retire): the innermost one covering an
@@ -785,9 +790,14 @@ class ServingEngine:
                                              False, None, True))
             self._by_slot[slot] = req
             self._prefilling[slot] = req
-            self.metrics.on_admit(req)
-            obs.instant("admit", track=req.track, slot=slot)
+            self._mark_admit(slot, req)
         return events
+
+    def _mark_admit(self, slot: int, req: Request) -> None:
+        """A request has its slot (either admission path): the metric and
+        the mark a reader pairs with ``first_token`` by ``rid``."""
+        self.metrics.on_admit(req)
+        obs.mark("admit", track=req.track, rid=req.rid, slot=slot)
 
     def _make_room(self) -> bool:
         """Admission's last resort when no slot is free: evict the LRU
@@ -1147,15 +1157,14 @@ class ServingEngine:
             lens[slot] = req.prompt.size
             mask[slot] = True
             self._stamp_admit(slot, req)
-            self.metrics.on_admit(req)
-            obs.instant("admit", track=req.track, slot=slot)
+            self._mark_admit(slot, req)
         _PREFILL_TOKENS.inc(sum(int(r.prompt.size) for _, r in newly),
                             kind="computed")
         tr = obs.get_tracer()
         ts0 = tr.now_us() if tr is not None else 0.0
         t0 = now()
-        with obs.span("wire.prefill", "wire", n=len(newly),
-                      bucket=s_bucket):
+        with obs.span("wire.prefill", "wire", step=self._steps,
+                      n=len(newly), bucket=s_bucket):
             tok = self.backend.prefill(tokens, lens, mask,
                                        **self._extra_kw(newly))
         self.metrics.on_prefill(now() - t0, len(newly))
@@ -1230,7 +1239,8 @@ class ServingEngine:
         tr = obs.get_tracer()
         ts0 = tr.now_us() if tr is not None else 0.0
         t0 = now()
-        with obs.span("wire.prefill", "wire", n=len(rows), chunk=c, rows=r):
+        with obs.span("wire.prefill", "wire", step=self._steps,
+                      n=len(rows), chunk=c, rows=r):
             tok = self.backend.prefill(tokens, lens, mask, start=start, **kw)
         self.metrics.on_prefill(now() - t0, len(self._prefilling),
                                 chunked=True)
@@ -1285,8 +1295,8 @@ class ServingEngine:
             pos0[slot] = req.n_generated  # this step's output index
         rows = list(decoding.items())
         t0 = now()
-        with obs.span("wire.decode", "wire", n=len(decoding),
-                      **_kv_rows(decoding, self._window)):
+        with obs.span("wire.decode", "wire", step=self._steps,
+                      n=len(decoding), **_kv_rows(decoding, self._window)):
             tok = self.backend.decode(self._last_tok.copy(), active,
                                       **self._extra_kw(rows, pos0))
         self.metrics.on_decode_step(now() - t0, len(decoding),
@@ -1386,9 +1396,8 @@ class ServingEngine:
         req.out_tokens.append(int(tok_val))
         req.t_first_token = t
         self.metrics.on_first_token(req)
-        obs.instant("first_token", track=req.track,
-                    ttft_ms=round(req.ttft * 1e3, 3),
-                    trace_id=req.trace_id)
+        obs.mark("first_token", track=req.track, rid=req.rid,
+                 ttft_ms=round(req.ttft * 1e3, 3), trace_id=req.trace_id)
         self._maybe_retire(slot, req, t, finished)
 
     def _maybe_retire(self, slot: int, req: Request, t: float,

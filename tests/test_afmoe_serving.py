@@ -34,13 +34,15 @@ import pytest
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from test_hybrid_moe_serving import _layer, _pool_rows, _slot_logits
+from test_hybrid_moe_serving import (
+    _layer, _lowered_programs, _pool_rows, _slot_logits,
+)
 from uccl_tpu import obs
 from uccl_tpu.models import inference
 from uccl_tpu.models import moe_inference as mi
 from uccl_tpu.models import reference_hybrid_moe as ref
 from uccl_tpu.models.moe_inference import (
-    MoEServeConfig, MoEServer, MoESlotCache, init_params,
+    MoEServeConfig, MoEServer, init_params,
 )
 from uccl_tpu.serving import MoEBackend, ServingEngine
 
@@ -567,25 +569,8 @@ AFMOE_SCOPES = tuple(
 @pytest.fixture(scope="module")
 def program_text(model):
     cfg, params, srv, placed = model
-    cache = srv.slot_cache(2, MAX_SEQ)
-
-    def decode(p, tok, act, k, v, ln):
-        return srv.decode_step_slots(p, tok, act, MoESlotCache(k, v, ln),
-                                     impl="sort")
-
-    def prefill(p, tok, lens, mask, k, v, ln):
-        return srv.prefill_slots(p, tok, lens, mask, MoESlotCache(k, v, ln))
-
-    act = jnp.ones((1, 2), bool)
-    return {
-        "decode": jax.jit(decode).lower(
-            placed, jnp.ones((1, 2), jnp.int32), act, *cache
-        ).compile().as_text(),
-        "prefill": jax.jit(prefill).lower(
-            placed, jnp.ones((1, 2, 4), jnp.int32),
-            jnp.full((1, 2), 4, jnp.int32), act, *cache
-        ).compile().as_text(),
-    }
+    return {name: low.compile().as_text()
+            for name, low in _lowered_programs(srv, placed).items()}
 
 
 @pytest.mark.parametrize("scope", AFMOE_SCOPES)

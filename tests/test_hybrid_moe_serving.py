@@ -634,9 +634,9 @@ HYBRID_SCOPES = tuple(
     "moe.experts", "moe.combine", "head")
 
 
-@pytest.fixture(scope="module")
-def hybrid_program_text(model):
-    cfg, params, srv, placed = model
+def _lowered_programs(srv, placed, chunk=4):
+    """The server's own decode and pool-wide prefill programs over a pool of
+    two slots, lowered: ``{"decode" | "prefill": jax.stages.Lowered}``."""
     cache = srv.slot_cache(2, MAX_SEQ)
 
     def decode(p, tok, act, k, v, ln):
@@ -649,13 +649,18 @@ def hybrid_program_text(model):
     act = jnp.ones((1, 2), bool)
     return {
         "decode": jax.jit(decode).lower(
-            placed, jnp.ones((1, 2), jnp.int32), act, *cache
-        ).compile().as_text(),
+            placed, jnp.ones((1, 2), jnp.int32), act, *cache),
         "prefill": jax.jit(prefill).lower(
-            placed, jnp.ones((1, 2, 4), jnp.int32),
-            jnp.full((1, 2), 4, jnp.int32), act, *cache
-        ).compile().as_text(),
+            placed, jnp.ones((1, 2, chunk), jnp.int32),
+            jnp.full((1, 2), chunk, jnp.int32), act, *cache),
     }
+
+
+@pytest.fixture(scope="module")
+def hybrid_program_text(model):
+    cfg, params, srv, placed = model
+    return {name: low.compile().as_text()
+            for name, low in _lowered_programs(srv, placed).items()}
 
 
 @pytest.mark.parametrize("scope", HYBRID_SCOPES)

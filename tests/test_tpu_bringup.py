@@ -1,6 +1,7 @@
 """What guards the chip path from the CPU: the Pallas kernels cross-lowered
-for the TPU, chip_smoke.py's phases at a tiny size on a CPU mesh, and its
-refusal to run without a chip.
+for the TPU, chip_smoke.py's phases at a tiny size on a CPU mesh, its
+refusal to run without a chip, and a serving program COMPILED for a v5e that
+is described and not attached.
 
 Cross-lowering (``lower(lowering_platforms=("tpu",))``) is the free
 pre-flight before chip time: tracing, block specs, scratch shapes, semaphore
@@ -8,6 +9,14 @@ plumbing and the Pallas→Mosaic-MLIR stage all run here, and the kernels must
 come out as ``tpu_custom_call``s. What only a chip can say — Mosaic's
 backend compile (VMEM limit, tiling) and execution — is chip_smoke.py's and
 the four-chip session's (PERF.md).
+
+The TPU's compiler is installed here and compiles for a described chip
+(``jax.experimental.topologies``; the ``v5e`` fixture): what it makes of a
+program — which operations stand at entry level, how many bytes of
+temporaries it needs — is read from the compiled program, no chip time. The
+fixture is this file's alone and is asked for by name: a process keeps the
+TPU's library once it has loaded it, so only the worker that runs this file
+does. Nothing runs there: a time is the chip's to say.
 
 The tiny size for chip_smoke's phases is chosen HERE, explicitly — the
 script has no small mode and never picks a size from the absence of a chip.
@@ -17,6 +26,7 @@ Those two tests run it in a subprocess: its entry points read
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -25,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from uccl_tpu.collective import pallas_ccl
 from uccl_tpu.ep import pallas_a2a
@@ -138,3 +148,97 @@ def test_plain_run_without_a_chip_exits_nonzero():
     assert r.returncode != 0
     assert "platform: cpu" in r.stdout
     assert '"ok"' not in r.stdout  # no result line
+
+
+# -- compiled for a described v5e ---------------------------------------------
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip's mesh, or a skip that says why there is none.
+    libtpu wants the slice's type and its workers' names from the
+    environment where there is no metadata server to ask."""
+    from jax.experimental import topologies
+
+    with pytest.MonkeyPatch.context() as env:
+        for name, value in (("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                            ("TPU_WORKER_HOSTNAMES", "localhost"),
+                            ("TPU_LOG_DIR", "disabled")):
+            if name not in os.environ:
+                env.setenv(name, value)
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu, or it is another process's
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield Mesh(np.array(topo.devices[:1]), ("dp",))
+
+
+def _entry_results(compiled_text: str):
+    """(name, opcode, dtype, elements) of every instruction of a compiled
+    program's ENTRY computation."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", compiled_text,
+                      re.S | re.M).group(1)
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m:
+            name, dtype, dims, opcode = m.groups()
+            yield name, opcode, dtype, int(np.prod(
+                [int(d) for d in dims.split(",") if d]))
+
+
+def test_decode_program_reads_stacked_cache_groups_where_they_lie(v5e):
+    """The decode program of a description with two full and two window
+    layers (8 slots of 16,384 rows, keys 4 x 192 and values 4 x 128 wide,
+    rings of 256 rows 8 heads wide), compiled for the v5e: beside the
+    in-place writes of the pool, no entry-level operation slices or copies a
+    whole layer's rows, and the temporaries stay under ONE layer's smallest
+    full group in bfloat16. The grouped contraction over a layer sliced out
+    of a stacked group of two or more made both: a ``slice`` and a ``copy``
+    ``bf16[1, 8, rows, W]`` a layer for keys and for values, 407 MB of
+    temporaries at this size (PERF.md section 6, PR 41)."""
+    from uccl_tpu.models.moe_inference import (
+        MoEServeConfig, MoEServer, MoESlotCache, init_params,
+    )
+
+    cfg = MoEServeConfig(
+        vocab=1024, dim=512, n_layers=4, n_heads=16, n_kv_heads=4,
+        head_dim=192, v_head_dim=128, rope_theta=5e6, norm_eps=1e-5,
+        moe_experts=4, moe_topk=2, moe_ffn=256, capacity_factor=2.0,
+        layer_kinds=("full", "window", "full", "window"), window=128,
+        window_kv_heads=8, window_rope_theta=1e4, rotary_dim=64,
+        value_scale=0.707, sink=("window",), gate="sigmoid_bias",
+        param_dtype="bfloat16")
+    slots, max_seq = 8, 16384
+    srv = MoEServer(cfg, v5e)
+    chip = NamedSharding(v5e, P())
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=chip), tree)
+
+    placed = described(jax.eval_shape(
+        lambda key: srv.shard_params(init_params(key, cfg)),
+        jax.random.PRNGKey(0)))
+    pool = described(jax.eval_shape(
+        lambda: MoESlotCache.empty(cfg, 1, slots, max_seq)))
+
+    def decode(p, tok, act, k, v, ln):
+        return srv.decode_step_slots(p, tok, act, MoESlotCache(k, v, ln),
+                                     impl="sort")
+
+    compiled = jax.jit(decode, donate_argnums=(3, 4, 5)).lower(
+        placed, jax.ShapeDtypeStruct((1, slots), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((1, slots), jnp.bool_, sharding=chip),
+        *pool).compile()
+    # [W, L, B, rows, width] leaves: one layer's rows of the smallest group
+    layer = min(int(np.prod(a.shape[2:]))
+                for a in jax.tree.leaves((pool.k, pool.v)))
+    moved = [(name, opcode, dtype, n)
+             for name, opcode, dtype, n in _entry_results(compiled.as_text())
+             if n >= layer and (opcode in ("slice", "copy")
+                                or re.search("slice.*fusion", name))]
+    assert not moved, moved
+    full_v = slots * max_seq * cfg.kv_heads("full") * cfg.v_head_dim
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < 2 * full_v, (temporaries, 2 * full_v)

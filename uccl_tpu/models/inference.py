@@ -192,7 +192,11 @@ def kv_row_shapes(cfg, kind=None):
     positions minor (no lane padding of 192), and a program that scatters
     rows then copies the whole group in and out (2 x 2.5 ms a program on
     the chip: PERF.md section 6, PR 36); ``[.., S, 768]`` is row-major as
-    it stands."""
+    it stands. The decode program also CONTRACTS the row flat
+    (:func:`_grouped_attention`): viewed ``[.., S, Hkv, D]``, a layer
+    sliced out of a stacked group of two or more is wanted positions-minor
+    again, and the compiler makes a bfloat16 slice and a copy of the whole
+    layer for it every step (PERF.md section 6, PR 41)."""
     if getattr(cfg, "attn", "gqa") == "mla":
         return (cfg.kv_lora_rank,), (cfg.qk_rope_dim,)
     if layer_kinds(cfg):
@@ -316,6 +320,30 @@ def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
     ``[B, Sq, Hkv, G, D]`` against the cache ``[B, K, Hkv, .]`` — the pool
     is never repeated. Scores ``q.k / sqrt(head_dim)``.
 
+    One query a row (``Sq == 1``: the decode program, a verify window of
+    one, a one-shot decode step) contracts the rows FLAT instead, ``[B, K,
+    Hkv * D]`` as ``write`` hands them over: head (h, g)'s query stands in
+    KV head h's ``D`` columns of a ``Hkv * D``-wide row of zeros, scores are
+    ``bmc,bkc->bmk`` over all ``n_heads`` rows, values ``bmk,bkc->bmc``
+    against ``[B, K, Hkv * Dv]``, and each head keeps its own ``Dv`` columns
+    (what :func:`_attend_latent` does with its one shared row). The
+    products with zeros are exact, so mask, sink, softmax and the operands'
+    rounding are the grouped form's. Why: on the chip the view ``[B, K, Hkv,
+    D]`` of a layer sliced out of a stacked group of two or more layers is
+    wanted in another layout (positions minor), and the compiler
+    materialises it — a ``slice`` and a ``copy`` ``bf16[1, B, K, Hkv * D]``
+    of the WHOLE layer, keys and values, every step: 5.06 of the 6.30 ms of
+    MiMo-V2-Flash's two full layers and 3.19 of the 4.86 ms of Trinity's
+    four rings (ledger, PR 40), whatever the row's width, a dynamic index or
+    float32 products (PERF.md section 6, PR 41). Flat, the program reads the
+    rows once where they lie, no temporaries: 2.12 and 2.54 ms on the chip
+    (my chip runs, PR 41). It costs ``Hkv`` times the multiply-adds: 43
+    GFLOP a step (0.2 ms of the MXU's peak) beside a 1.34 GB read (1.79 ms,
+    92 % of the HBM's bandwidth) there — hidden; at a chunk's ``Sq`` = 128
+    it is 128 times that and not hidden, and a compact prefill program's
+    copy is of one slot's rows only, so every wider call keeps the grouped
+    form. The rule is the call's shape; nothing sets it.
+
     The cache rows ``K`` of this layer's group are a RING: position ``q``
     lives at row ``q % K``, so for a query at position ``p`` row ``r`` holds
     position ``p - ((p - r) mod K)`` — the newest position at or before
@@ -363,17 +391,29 @@ def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
         k_pool, k_cache = write(k_pool, kk.reshape(b, s, hkv * d))
         v_pool, v_cache = write(v_pool, v.reshape(b, s, hkv * dv))
     rows = k_cache.shape[1]
-    k_cache = k_cache.reshape(b, rows, hkv, d)
-    v_cache = v_cache.reshape(b, rows, hkv, dv)
+    flat = s == 1
+    if not flat:
+        k_cache = k_cache.reshape(b, rows, hkv, d)
+        v_cache = v_cache.reshape(b, rows, hkv, dv)
     qpos = positions if positions.ndim == 2 else positions[None]  # [B|1, Sq]
     sink = None
     if kind in cfg.sink:
         sink = lp["sink"].astype(jnp.float32).reshape(hkv, nh // hkv, 1)
 
     def core(qg, kc, vc, qp):
-        """[B', Sq, Hkv, G, D] queries over [B', K, Hkv, .] rows."""
-        sc = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kc,
-                        preferred_element_type=jnp.float32)
+        """[B', Sq, Hkv, G, D] queries over the layer's rows: [B', K, Hkv,
+        .], or where ``flat`` [B', K, Hkv * .] as they lie."""
+        if flat:
+            # [Hkv, 1, Hkv, 1]: KV head h' is query head (h, g)'s own; its
+            # query stands in that head's columns of a flat row
+            own = jnp.eye(hkv, dtype=bool)[:, None, :, None]
+            qf = jnp.where(own, qg[:, 0, :, :, None], 0)
+            sc = jnp.einsum("bmc,bkc->bmk", qf.reshape(-1, nh, hkv * d), kc,
+                            preferred_element_type=jnp.float32)
+            sc = sc.reshape(-1, hkv, nh // hkv, 1, rows)
+        else:
+            sc = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kc,
+                            preferred_element_type=jnp.float32)
         sc = sc / jnp.sqrt(jnp.float32(d))
         held = qp[..., None] - jnp.mod(qp[..., None] - jnp.arange(rows), rows)
         seen = held >= 0
@@ -388,7 +428,12 @@ def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
         if sink is not None:
             den = den + jnp.exp(sink - top)
         p = (e / den[..., None]).astype(vc.dtype)
-        return jnp.einsum("bhgqk,bkhd->bqhgd", p, vc)
+        if not flat:
+            return jnp.einsum("bhgqk,bkhd->bqhgd", p, vc)
+        # every head against the whole flat row; it keeps its own columns
+        o = jnp.einsum("bmk,bkc->bmc", p.reshape(-1, nh, rows), vc)
+        o = o.reshape(-1, hkv, nh // hkv, hkv, dv)
+        return jnp.sum(jnp.where(own, o, 0), axis=3)[:, None]
 
     with jax.named_scope("attn.core." + kind):
         qg = q.reshape(b, s, hkv, nh // hkv, d)

@@ -377,32 +377,9 @@ def test_engine_served_tokens_are_generates(model):
 
 # -- the ring's floor --------------------------------------------------------
 
-@pytest.mark.parametrize("engine_kw", [
-    dict(prefill_chunk=5), dict(prefill_chunk=2, spec_k=4)],
-    ids=["chunk_of_5", "verify_window_of_5"])
-@pytest.mark.parametrize("ring", [12, 11])
-def test_a_ring_holds_window_less_one_and_the_widest_write(
-        model, devices, ring, engine_kw):
-    """Window 8 and a widest write of 5 — a prefill chunk, or a verify
-    window of 4 drafts and the committed token: a ring of 8 - 1 + 5 = 12 rows
-    serves ``generate``'s tokens exactly, through several wraps; one row
-    fewer is refused where the write's width is known, with the reason."""
-    cfg, params, _, _ = model
-    srv = _server(devices, dataclasses.replace(cfg, window_ring=ring))
-    placed = srv.shard_params(params)
-    if ring == 12:
-        _serves_generates_tokens(srv, placed, ((9, 30), (23, 26)),
-                                 **engine_kw)
-        return
-    backend = MoEBackend(srv, placed, batch_local=2, max_seq=MAX_SEQ,
-                         decode_impl="sort")
-    with pytest.raises(ValueError, match=r"must hold window - 1 \+ the "
-                                         r"widest write \(8 - 1 \+ 5\)"):
-        ServingEngine(backend, **engine_kw)
-    with pytest.raises(ValueError, match="cannot take a write of 5"):
-        _slot_logits(srv, placed, np.zeros((2, 5), np.int32),
-                     srv.slot_cache(2, MAX_SEQ), [0, 0], np.ones(2, bool))
-
+# (reach - 1 + the widest write, for window and conv rings alike:
+# tests/test_lfm2_serving.py::
+# test_a_ring_holds_its_reach_less_one_and_the_widest_write)
 
 def test_a_ring_under_a_window_is_refused_at_construction(model):
     cfg = model[0]

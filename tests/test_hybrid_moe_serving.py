@@ -576,7 +576,8 @@ def test_the_shares_of_all_holders_add_up_to_the_uncut_layer(devices):
                experts_held=held, first_expert=0)
 
 
-# -- what a pool with window groups cannot do yet -----------------------------
+# -- what a pool with ring groups cannot do yet (window rings here; conv
+# rings: tests/test_lfm2_serving.py) -----------------------------
 
 @pytest.mark.parametrize("what", ["prefix_cache", "kv_tiers", "export_rows",
                                   "import_rows", "copy_prefix", "disagg",
@@ -602,7 +603,7 @@ def test_window_groups_refuse_what_they_cannot_do(model, what):
         "disagg": lambda: wire_format_for(backend),
     }
     if what in calls:
-        with pytest.raises(ValueError, match="window groups"):
+        with pytest.raises(ValueError, match="ring groups"):
             calls[what]()
     elif what == "whole_prompt":
         with pytest.raises(ValueError, match="requires prefill_chunk"):

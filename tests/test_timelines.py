@@ -356,9 +356,14 @@ def test_each_new_metric_is_in_all_four_cells():
     for m in entries:
         assert m["layer"] == "serving engine"
         assert m["source"] == "program_span" and len(m["workloads"]) == 1
+    # the four cells the benchmark had when these readings were added; a
+    # later cell has what the benchmark's cap of 128 per-layer metrics left
+    # room for (PERF.md section 3)
+    four = {m["workloads"][0] for m in entries}
+    assert len(four) == 4 and four <= cells
     for name in EXPECTED:
         assert {m["workloads"][0] for m in entries
-                if m["name"].rsplit(".", 1)[0] == name} == cells
+                if m["name"].rsplit(".", 1)[0] == name} == four
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in _new_entries()])

@@ -143,9 +143,10 @@ def _attend_latent(q_lat, q_rope, ckv_cache, kr_cache, length, scale):
 
 
 def layer_kinds(cfg) -> Tuple[str, ...]:
-    """The per-layer attention kinds ("full" | "window") of a description
-    whose layers differ (``cfg.layer_kinds``); empty where every layer is
-    the same block (the dense stack, Mixtral, the latent family)."""
+    """The per-layer operator kinds ("full" | "window" attention, "conv":
+    a gated short convolution) of a description whose layers differ
+    (``cfg.layer_kinds``); empty where every layer is the same block (the
+    dense stack, Mixtral, the latent family)."""
     return tuple(getattr(cfg, "layer_kinds", ()) or ())
 
 
@@ -164,20 +165,38 @@ def cache_groups(cfg):
     """Where each layer's cached rows live: ``[(group, index in group)]`` by
     layer. Layers of one attention kind share one stacked cache array (a
     GROUP: its own row shape and, in the slot pool, its own number of rows
-    a slot — all positions for "full", a ring for "window"). A description
-    without layer kinds has the one group ``None``: the plain stacked array."""
+    a slot — all positions for "full", a ring for "window" and "conv":
+    :data:`RING_GROUPS`). A description without layer kinds has the one
+    group ``None``: the plain stacked array."""
     return indexed_groups(layer_kinds(cfg) or [None] * cfg.n_layers)
 
 
 def group_array(pool, group):
     """A cache group's array of ``pool``: the plain stacked array (group
-    ``None``) or ``pool[group]``."""
-    return pool if group is None else pool[group]
+    ``None``) or ``pool[group]`` — ``None`` where the group keeps no such
+    array (a conv group has no value rows: :func:`kv_row_shapes`)."""
+    return pool if group is None else pool.get(group)
 
 
 def _with_group(pool, group, new):
-    """``pool`` with the group's array replaced by ``new``."""
+    """``pool`` with the group's array replaced by ``new`` (``None``: the
+    group keeps no such array, and ``pool`` is what it was)."""
+    if new is None:
+        return pool
     return new if group is None else {**pool, group: new}
+
+
+# The cache groups a slot keeps as a RING by position (position p at row
+# p % rows), each with the name of what ``cfg.reach(group)`` counts: a
+# window layer's query at p reads positions (p - window, p], a conv layer's
+# filter (p - taps, p]. THE invariant, one for both: rows >= reach - 1 + S
+# for every write of S positions, so a write at p .. p+S-1 only overwrites
+# positions <= p - reach, which nothing at or after p reads. That is what
+# makes a chunk after a chunk, a right-padded last chunk and a verify
+# window's rejected rows harmless in a ring exactly as they are in a flat
+# pool: what they leave behind lies past the slot's length and is rewritten
+# before anything reads it, and what they overwrote was already out of reach.
+RING_GROUPS = {"window": "window", "conv": "taps"}
 
 
 def kv_row_shapes(cfg, kind=None):
@@ -196,9 +215,13 @@ def kv_row_shapes(cfg, kind=None):
     (:func:`_grouped_attention`): viewed ``[.., S, Hkv, D]``, a layer
     sliced out of a stacked group of two or more is wanted positions-minor
     again, and the compiler makes a bfloat16 slice and a copy of the whole
-    layer for it every step (PERF.md section 6, PR 41)."""
+    layer for it every step (PERF.md section 6, PR 41). A "conv" layer's
+    position is ONE row, the ``dim`` numbers of its gated input ``y = b *
+    u`` (:func:`_short_conv`), and it keeps no second array: ``None``."""
     if getattr(cfg, "attn", "gqa") == "mla":
         return (cfg.kv_lora_rank,), (cfg.qk_rope_dim,)
+    if kind == "conv":
+        return (cfg.dim,), None
     if layer_kinds(cfg):
         hkv = cfg.kv_heads(kind)
         return (hkv * cfg.head_dim,), \
@@ -206,10 +229,11 @@ def kv_row_shapes(cfg, kind=None):
     return (cfg.n_kv_heads, cfg.head_dim), (cfg.n_kv_heads, cfg.head_dim)
 
 
-WINDOW_GROUPS_STAY = (
-    "a pool with window groups keeps a slot's last window - 1 positions of "
-    "its window layers in a ring and nothing older, so a slot's rows cannot "
-    "be handed to another slot, a tier or a peer as a prefix: ")
+RING_GROUPS_STAY = (
+    "a pool with ring groups keeps a slot's last reach - 1 positions of its "
+    "window and conv layers (window - 1, taps - 1) in a ring and nothing "
+    "older, so a slot's rows cannot be handed to another slot, a tier or a "
+    "peer as a prefix: ")
 
 
 def kv_wire_dims(cfg):
@@ -218,7 +242,7 @@ def kv_wire_dims(cfg):
     row's two halves, ``[1, (kv_lora_rank + qk_rope_dim) / 2]`` each. A
     pool of cache groups (layer kinds) has no one row to put on a wire."""
     if layer_kinds(cfg):
-        raise ValueError(WINDOW_GROUPS_STAY + "the disaggregated wire "
+        raise ValueError(RING_GROUPS_STAY + "the disaggregated wire "
                          "format describes one row shape for every layer")
     k_row, v_row = kv_row_shapes(cfg)
     if k_row == v_row:
@@ -456,12 +480,66 @@ def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
     return x, k_pool, v_pool
 
 
+def _short_conv(x, lp, k_pool, v_pool, positions, length, write, cfg,
+                lora=None):
+    """One layer's gated short convolution with its residual — the operator
+    of a "conv" layer, with the signature the layer loops call an attention
+    by. ``[b | c | u] = h w_in`` (three ``dim``-wide parts of the normed
+    input's projection), ``y = b * u``, a causal depthwise filter of
+    ``cfg.conv_taps`` taps over ``y`` along the positions (``w_conv [dim,
+    taps]``, the last tap on the position itself), ``out = (c * z) w_out``.
+    No scores, no softmax, no value row: what a slot keeps of the layer is
+    ``y`` at its last ``taps - 1`` positions.
+
+    It keeps them BY POSITION, as attention keeps its rows: ``write`` puts
+    the call's ``y`` rows into the layer's group (position p at row ``p %
+    rows``: a ring in the slot pool, ``max_seq`` rows on the one-shot path)
+    and the call's first position reads the ``taps - 1`` positions before it
+    from there; the positions of the call itself are read from ``y`` as it
+    was just computed, which is what the ring now holds of them. So a chunk
+    after a chunk, a padded last chunk and a verify window's rejected rows
+    leave the state exact by the ring's invariant (:data:`RING_GROUPS`):
+    rollback is the cursor, and no program hands back a snapshot. A tap
+    that reaches before position 0 reads ZERO whatever the ring holds (a
+    slot's previous occupant left rows there): from ``positions``, not from
+    a scrub at admission. Returns (x', k_pool', v_pool as given: None)."""
+    if lora is not None:
+        raise ValueError("LoRA adapters target the attention projections "
+                         "(wq/wv); a conv layer has none")
+    b, s, _ = x.shape
+    taps = cfg.conv_taps
+    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+    with jax.named_scope("conv.in_proj"):
+        gate_b, gate_c, u = jnp.split(h @ lp["w_in"].astype(h.dtype), 3,
+                                      axis=-1)
+        y = gate_b * u
+    with jax.named_scope("conv.state"):
+        k_pool, ring = write(k_pool, y)
+        rows = ring.shape[1]
+        first = jnp.broadcast_to(positions[..., :1], (b, 1))
+        back = first - jnp.arange(taps - 1, 0, -1)  # [B, taps - 1] positions
+        before = jnp.take_along_axis(ring, (back % rows)[..., None], axis=1)
+        before = jnp.where((back >= 0)[..., None], before, 0)
+        ys = jnp.concatenate([before.astype(y.dtype), y], axis=1)
+    with jax.named_scope("conv.mix"):
+        w = lp["w_conv"].astype(y.dtype)
+        z = sum(w[:, j] * ys[:, j:j + s] for j in range(taps))
+        out = gate_c * z
+    with jax.named_scope("conv.out_proj"):
+        x = x + out @ lp["w_out"].astype(out.dtype)
+    return x, k_pool, v_pool
+
+
 def _attention_of(cfg, i: int = 0):
-    """The attention of layer ``i`` of a model description: ``cfg.attn``
-    ("gqa" | "mla"; descriptions without the field are gqa), and where the
-    description has layer kinds, the grouped function at layer ``i``'s."""
+    """The operator of layer ``i`` of a model description — an attention, or
+    where the layer's kind says so a short convolution: ``cfg.attn`` ("gqa"
+    | "mla"; descriptions without the field are gqa), and where the
+    description has layer kinds, :func:`_short_conv` for a "conv" layer and
+    the grouped attention at layer ``i``'s kind for every other."""
     kinds = layer_kinds(cfg)
     if kinds:
+        if kinds[i] == "conv":
+            return _short_conv
         return functools.partial(_grouped_attention, kind=kinds[i])
     kind = getattr(cfg, "attn", "gqa")
     if kind == "gqa":
@@ -525,8 +603,14 @@ def _ffn_half(x, lp, cfg, ffn, **slot_kw):
 
 
 def _head(x, params, cfg):
+    """The output norm and the logits. A tree without a ``head`` leaf is a
+    model whose head is TIED to its embedding: the rows are contracted
+    with ``embed`` as it lies (no transposed copy is kept)."""
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if "head" not in params:
+            return jnp.einsum("...h,vh->...v", x.astype(jnp.float32),
+                              params["embed"].astype(jnp.float32))
         return x.astype(jnp.float32) @ params["head"].astype(jnp.float32)
 
 
@@ -756,8 +840,9 @@ def _forward_slots(
     serving/backend.py) returns the buffers it was given with B x S rows a
     layer changed. Where the description has layer kinds the pool is
     ``{group: array}`` (:func:`cache_groups`): a "full" group ``[L_full,
-    B_slots, S_max, ...]`` and a "window" group ``[L_win, B_slots, ring,
-    ...]``, each carried and written the same way.
+    B_slots, S_max, ...]`` and the ring groups (:data:`RING_GROUPS`: "window"
+    ``[L_win, B_slots, ring, ...]``, "conv" ``[L_conv, B_slots, ring,
+    dim]`` in ``k`` alone), each carried and written the same way.
 
     ``slots`` ([R] int32, B == R) makes the rows COMPACT: row r is slot
     ``slots[r]`` of the pool — its new rows are written there, and attention
@@ -775,7 +860,7 @@ def _forward_slots(
     k, v = cache.k, cache.v
     groups = cache_groups(cfg)
     # rows a slot has in the plain pool, or in the full group of one with
-    # cache groups: S_max (window groups are rings, written below)
+    # cache groups: S_max (ring groups are written below)
     flat = k if groups[0][0] is None else k.get("full")
     smax = flat.shape[2] if flat is not None else 0
     x = _embed(params, tokens, cfg, jax.tree.leaves(k)[0].dtype)
@@ -784,26 +869,24 @@ def _forward_slots(
     # the cache end (a bucket overhanging S_max) drop the same way
     pos_write = jnp.where(write_mask[:, None], positions, smax)
     bidx = (jnp.arange(b) if slots is None else slots)[:, None]
-    pos_ring = None
-    if any(group == "window" for group, _ in groups):
-        # A window layer's group is a ring of ``rows``: position p lives at
-        # row p % rows. THE invariant: rows >= window - 1 + S, so a write at
-        # p .. p+S-1 only overwrites positions <= p - window, which no query
-        # at or after p can see. That is what makes a chunk after a chunk, a
-        # right-padded last chunk and a verify window's rejected rows
-        # harmless here exactly as they are in a flat pool: what they leave
-        # behind lies past the slot's length and is rewritten before a query
-        # reaches it, and what they overwrote was already out of every later
-        # query's window.
-        rows = k["window"].shape[2]
-        if rows < cfg.window - 1 + s:
+    pos_of = {None: pos_write, "full": pos_write}
+    for group in RING_GROUPS:
+        if not any(g == group for g, _ in groups):
+            continue
+        # a ring of ``rows``: position p lives at row p % rows, and the
+        # invariant (RING_GROUPS) is asked of each ring group by its own
+        # rows and reach
+        rows, reach = k[group].shape[2], cfg.reach(group)
+        if rows < reach - 1 + s:
             raise ValueError(
-                f"a window layer's ring of {rows} rows cannot take a write "
-                f"of {s} positions: it must hold window - 1 + the widest "
-                f"write = {cfg.window - 1 + s} (window_ring)")
-        pos_ring = jnp.where(write_mask[:, None], positions % rows, rows)
+                f"a {group} layer's ring of {rows} rows cannot take a write "
+                f"of {s} positions: it must hold {RING_GROUPS[group]} - 1 + "
+                f"the widest "
+                f"write = {reach - 1 + s} ({group}_ring), or the write "
+                f"would overwrite positions still to be read")
+        pos_of[group] = jnp.where(write_mask[:, None], positions % rows, rows)
     for i, (group, gi) in enumerate(groups):
-        pos = pos_ring if group == "window" else pos_write
+        pos = pos_of[group]
 
         def write(pool, new, gi=gi, pos=pos):
             pool = pool.at[gi, bidx, pos].set(new, mode="drop")

@@ -88,8 +88,10 @@ class MoEServeConfig:
     # (norms and the gate bias stay float32); activations and cache are
     # float32 either way, so a bfloat16 matrix is upcast where it is used
     # -- layer kinds: gqa layers that differ by layer (MiMo-V2-Flash: window
-    # and full attention side by side). Empty = every layer the one block.
-    layer_kinds: Tuple[str, ...] = ()  # per layer: "full" | "window"
+    # and full attention side by side), and layers that do not attend
+    # (LFM2: gated short convolutions between full attention layers).
+    # Empty = every layer the one block.
+    layer_kinds: Tuple[str, ...] = ()  # per layer: "full" | "window" | "conv"
     window: int = 0  # a window layer's query at p sees [p - window + 1, p]
     window_kv_heads: int = 0  # the window kind's KV heads (n_kv_heads: full)
     window_rope_theta: float = 0.0  # the window kind's theta (rope_theta: full)
@@ -109,6 +111,9 @@ class MoEServeConfig:
     embed_scale: float = 1.0  # multiplies the embedding's output
     norm_gain_scale: float = 0.0  # init_params draws a layer's norm gains as
     # 1 + this x a seeded normal (0: ones), so that a gain left out shows
+    conv_taps: int = 0  # a conv layer's filter: position p reads (p - taps, p]
+    conv_ring: int = 0  # rows a slot keeps of a conv layer (0: 2 x conv_taps)
+    tie_head: bool = False  # the head is the embedding: no ``head`` leaf
     # -- this member's share of a wider deployment: the router keeps its
     # moe_experts outputs, experts [first_expert, first_expert + held) live
     # here and only their part of the layer's sum is computed (ep.ops.moe_ffn)
@@ -121,32 +126,44 @@ class MoEServeConfig:
         kinds = self.layer_kinds
         if kinds:
             if self.attn != "gqa" or len(kinds) != self.n_layers \
-                    or set(kinds) - {"full", "window"}:
+                    or set(kinds) - {"full", "window", "conv"}:
                 raise ValueError(
-                    f"layer_kinds names 'full' or 'window' for each of the "
-                    f"{self.n_layers} gqa layers; got {kinds} ({self.attn})")
+                    f"layer_kinds names 'full', 'window' or 'conv' for each "
+                    f"of the {self.n_layers} gqa layers; got {kinds} "
+                    f"({self.attn})")
             if "window" in kinds and (self.window < 1
                                       or self.window_kv_heads < 1):
                 raise ValueError("window layers need window and "
                                  "window_kv_heads")
+            if ("conv" in kinds) != (self.conv_taps > 0) \
+                    or self.conv_taps == 1:
+                raise ValueError(
+                    f"conv layers need conv_taps >= 2, and conv_taps belongs "
+                    f"to them; got {self.conv_taps} with {kinds}")
             if set(self.sink) - {"full", "window"} or self.rotary_dim % 2:
                 raise ValueError("sink names layer kinds; rotary_dim is even")
             if set(self.unrotated) - {"full", "window"}:
                 raise ValueError("unrotated names layer kinds")
-            if "window" in kinds and self.ring < self.window:
-                raise ValueError(
-                    f"window_ring {self.ring} must hold a window's rows "
-                    f"({self.window}): a query reads its last {self.window} "
-                    f"positions from the ring (window - 1 + the widest write "
-                    f"is asked where that width is known: the slot forward "
-                    f"and ServingEngine)")
+            for group, holds in (("window", "a window's rows"),
+                                 ("conv", "a filter's taps")):
+                if group in kinds and self.ring_rows(group) \
+                        < self.reach(group):
+                    raise ValueError(
+                        f"{group}_ring {self.ring_rows(group)} must hold "
+                        f"{holds} ({self.reach(group)}): what is read at "
+                        f"position p are the ring's last {self.reach(group)} "
+                        f"positions, p among them ({holds.split()[-1]} - 1 + "
+                        f"the widest write is asked where that width is "
+                        f"known: the slot forward and ServingEngine)")
         elif self.window or self.sink or self.rotary_dim \
                 or self.value_scale != 1.0 or self.unrotated \
                 or self.qk_norm or self.attn_gate or self.post_norms \
+                or self.conv_taps or self.conv_ring \
                 or (self.attn == "gqa" and self.v_head_dim):
             raise ValueError("window, sink, rotary_dim, value_scale, "
-                             "unrotated, qk_norm, attn_gate, post_norms and "
-                             "a gqa v_head_dim belong to layer_kinds")
+                             "unrotated, qk_norm, attn_gate, post_norms, "
+                             "conv_taps, conv_ring and a gqa v_head_dim "
+                             "belong to layer_kinds")
         if not 0 <= self.first_expert <= self.moe_experts - self.n_held:
             raise ValueError(
                 f"experts [{self.first_expert}, {self.first_expert} + "
@@ -187,6 +204,20 @@ class MoEServeConfig:
         (``inference._forward_slots``, ``ServingEngine``)."""
         return self.window_ring or 2 * self.window
 
+    def ring_rows(self, group: str) -> int:
+        """Rows a slot keeps of each layer of a ring group
+        (``inference.RING_GROUPS``): :attr:`ring` for "window"; for "conv"
+        ``conv_ring``, or twice the taps."""
+        return self.ring if group == "window" \
+            else self.conv_ring or 2 * self.conv_taps
+
+    def reach(self, group: str) -> int:
+        """Positions a layer of a ring group reads back from its ring, the
+        newest among them: a window layer's query at p sees (p - window, p],
+        a conv layer's filter (p - conv_taps, p]. A ring holds ``reach - 1 +
+        the widest write`` rows or more."""
+        return self.window if group == "window" else self.conv_taps
+
     def kv_heads(self, kind: str) -> int:
         return self.window_kv_heads if kind == "window" else self.n_kv_heads
 
@@ -199,11 +230,12 @@ class MoEServeConfig:
         (FFN kind, attention kind). The groups the uniform descriptions
         have keep their names (``dense_blocks``, ``blocks``: a "full" layer
         is the block they always were); a window layer's group is
-        ``window_blocks`` / ``dense_window_blocks``."""
+        ``window_blocks`` / ``dense_window_blocks``, a conv layer's
+        ``conv_blocks`` / ``dense_conv_blocks``."""
         kinds = self.layer_kinds or ("full",) * self.n_layers
         return inference.indexed_groups(
             ("dense_" if i < self.first_k_dense else "")
-            + ("window_" if kinds[i] == "window" else "") + "blocks"
+            + ("" if kinds[i] == "full" else kinds[i] + "_") + "blocks"
             for i in range(self.n_layers))
 
     @classmethod
@@ -222,7 +254,13 @@ class MoEServeConfig:
         gated output, sandwich norms, a scaled embedding,
         ``num_dense_layers`` leading dense layers, then sigmoid-bias experts
         beside ``num_shared_experts`` shared ones; ``layer_types`` is read
-        up to ``num_hidden_layers``). A file that states a member's share
+        up to ``num_hidden_layers``) or ``lfm2_moe`` (LFM2-24B-A2B; keyed on
+        ``conv_L_cache``: ``conv`` | ``full_attention`` layers by
+        ``layer_types``, a gated short convolution of ``conv_L_cache`` taps
+        whose state is a third cache group, QK-norm on a uniform gqa,
+        ``num_dense_layers`` leading dense layers, then sigmoid-bias experts
+        with no shared one, a head tied to the embedding). A file that
+        states a member's share
         gives ``n_routed_experts`` (``afmoe``: ``num_experts``) as the
         experts held and ``router_experts`` as the router's width
         (``first_expert``: the first held). ``overrides`` are this class's
@@ -240,17 +278,48 @@ class MoEServeConfig:
         if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
             raise ValueError("group-limited routing (n_group > 1) is not "
                              "built")
-        if "layer_types" in hf:
-            types = list(hf["layer_types"])[:n_layers]
-            kind_of = {"sliding_attention": "window",
-                       "full_attention": "full"}
-            unknown = sorted(set(types) - set(kind_of))
-            if unknown or len(types) < n_layers:
-                raise ValueError(
-                    f"layer_types names 'sliding_attention' or "
-                    f"'full_attention' for each of num_hidden_layers "
-                    f"({n_layers}) layers; got {len(types)} entries, "
-                    f"unknown {unknown}")
+        if "conv_L_cache" in hf:
+            kinds = _layer_kinds(hf, n_layers, {"conv": "conv",
+                                                "full_attention": "full"})
+            if hf.get("conv_bias"):
+                raise ValueError("conv_bias true (a bias on the conv "
+                                 "layers' projections) is not built")
+            if not hf.get("use_expert_bias", True):
+                raise ValueError("use_expert_bias false (experts chosen by "
+                                 "the score alone) is not built")
+            if not hf.get("norm_topk_prob", True):
+                raise ValueError("norm_topk_prob false (weights not "
+                                 "renormalised over the chosen) is not built")
+            rope_params = hf.get("rope_parameters") or {}
+            if rope_params.get("rope_type", "default") != "default":
+                raise ValueError(f"rope_type {rope_params['rope_type']!r}: "
+                                 f"the rotation built is 'default'")
+            routed = hf.get("router_experts", hf["num_experts"])
+            held = hf["num_experts"]
+            kw.update(
+                rope_theta=float(rope_params.get("rope_theta",
+                                                 kw["rope_theta"])),
+                norm_eps=float(hf.get("norm_eps") or kw["norm_eps"]),
+                n_kv_heads=hf["num_key_value_heads"],
+                head_dim=hf.get("head_dim") or hf["hidden_size"] // heads,
+                layer_kinds=kinds,
+                conv_taps=hf["conv_L_cache"],
+                qk_norm=True, norm_gain_scale=NORM_GAIN_SCALE,
+                tie_head=bool(hf.get("tie_word_embeddings",
+                                     hf.get("tie_embedding", True))),
+                moe_experts=routed,
+                experts_held=held if held != routed else 0,
+                first_expert=hf.get("first_expert", 0),
+                moe_ffn=hf["moe_intermediate_size"],
+                first_k_dense=hf.get("num_dense_layers", 0),
+                dense_ffn=hf["intermediate_size"],
+                gate="sigmoid_bias",
+                routed_scale=float(hf.get("routed_scaling_factor") or 1.0),
+            )
+        elif "layer_types" in hf:
+            kinds = _layer_kinds(hf, n_layers,
+                                 {"sliding_attention": "window",
+                                  "full_attention": "full"})
             if hf.get("score_func", "sigmoid") != "sigmoid":
                 raise ValueError(f"score_func {hf['score_func']!r}: the "
                                  f"gate built here is 'sigmoid'")
@@ -266,7 +335,7 @@ class MoEServeConfig:
                 window_kv_heads=hf["num_key_value_heads"],
                 window_rope_theta=kw["rope_theta"],
                 head_dim=hf.get("head_dim") or hf["hidden_size"] // heads,
-                layer_kinds=tuple(kind_of[t] for t in types),
+                layer_kinds=kinds,
                 window=hf["sliding_window"],
                 unrotated=("full",), qk_norm=True, attn_gate=True,
                 post_norms=True,
@@ -370,12 +439,28 @@ class MoEServeConfig:
         return cls(**kw)
 
 
+def _layer_kinds(hf: Dict[str, Any], n_layers: int,
+                 kind_of: Dict[str, str]) -> Tuple[str, ...]:
+    """The first ``n_layers`` entries of a file's ``layer_types`` as this
+    module's layer kinds; an entry ``kind_of`` does not name, or too few
+    entries, raise by name."""
+    types = list(hf["layer_types"])[:n_layers]
+    unknown = sorted(set(types) - set(kind_of))
+    if unknown or len(types) < n_layers:
+        raise ValueError(
+            f"layer_types names {' or '.join(map(repr, kind_of))} for each "
+            f"of num_hidden_layers ({n_layers}) layers; got {len(types)} "
+            f"entries, unknown {unknown}")
+    return tuple(kind_of[t] for t in types)
+
+
 def _cache_arrays(cfg: MoEServeConfig, world: int, batch_local: int,
                   rows: Dict[Optional[str], int], dtype, sharding=None):
     """The (k, v) of a cache: one ``[W, L, B_loc, rows, *row]`` array each,
     or — where the description has layer kinds — ``{group: array}`` with a
     group's own layer count, ``rows[group]`` and row shape
-    (``inference.cache_groups`` / ``kv_row_shapes``)."""
+    (``inference.cache_groups`` / ``kv_row_shapes``; a "conv" group is in
+    ``k`` alone)."""
     layers = Counter(group for group, _ in inference.cache_groups(cfg))
     out = []
     for which in (0, 1):
@@ -383,7 +468,9 @@ def _cache_arrays(cfg: MoEServeConfig, world: int, batch_local: int,
             group: jnp.zeros(
                 (world, n, batch_local, rows[group])
                 + kv_row_shapes(cfg, group)[which], dtype, device=sharding)
-            for group, n in layers.items()}
+            for group, n in layers.items()
+            # a conv group keeps one array: no value rows
+            if kv_row_shapes(cfg, group)[which] is not None}
         out.append(arrays[None] if None in arrays else arrays)
     return out
 
@@ -399,7 +486,7 @@ class MoEKVCache(NamedTuple):
               max_seq: int, dtype=jnp.float32) -> "MoEKVCache":
         k, v = _cache_arrays(cfg, world, batch_local,
                              {None: max_seq, "full": max_seq,
-                              "window": max_seq}, dtype)
+                              "window": max_seq, "conv": max_seq}, dtype)
         return MoEKVCache(k, v, jnp.zeros((world,), jnp.int32))
 
 
@@ -408,12 +495,13 @@ class MoESlotCache(NamedTuple):
     continuous-batching engine admits/frees [w, b_loc] rows independently.
 
     Where the description has layer kinds the pool is cache GROUPS by
-    attention kind, ``k`` and ``v`` each ``{"full": [W, L_full, B_loc,
-    S_max, Hkv * .], "window": [W, L_win, B_loc, ring, Hkv_win * .]}``: a
-    full layer keeps every position of a slot, a window layer a ring of
-    ``cfg.ring`` rows (position p at row p % ring). Rows of such a pool
-    cannot be exported, imported or copied between slots: the three views
-    below raise (``inference.WINDOW_GROUPS_STAY``)."""
+    layer kind, ``k`` and ``v`` each ``{"full": [W, L_full, B_loc, S_max,
+    Hkv * .], "window": [W, L_win, B_loc, ring, Hkv_win * .]}`` and ``k``
+    alone ``"conv": [W, L_conv, B_loc, ring, dim]``: a full layer keeps
+    every position of a slot, a window or conv layer a ring of
+    ``cfg.ring_rows(group)`` rows (position p at row p % ring). Rows of a
+    pool with ring groups cannot be exported, imported or copied between
+    slots: the three views below raise (``inference.RING_GROUPS_STAY``)."""
 
     k: jax.Array  # [W, L, B_loc, S_max, *k_row] (inference.kv_row_shapes)
     v: jax.Array  # [W, L, B_loc, S_max, *v_row]
@@ -425,13 +513,15 @@ class MoESlotCache(NamedTuple):
               sharding=None) -> "MoESlotCache":
         k, v = _cache_arrays(cfg, world, batch_local,
                              {None: max_seq, "full": max_seq,
-                              "window": cfg.ring}, dtype, sharding)
+                              **{g: cfg.ring_rows(g)
+                                 for g in inference.RING_GROUPS}},
+                             dtype, sharding)
         return MoESlotCache(
             k, v, jnp.zeros((world, batch_local), jnp.int32, device=sharding))
 
     def _one_group(self, what: str) -> None:
         if isinstance(self.k, dict):
-            raise ValueError(inference.WINDOW_GROUPS_STAY + what)
+            raise ValueError(inference.RING_GROUPS_STAY + what)
 
     # -- slot KV export/import views (the disaggregation surface) ----------
     #
@@ -481,8 +571,8 @@ class MoESlotCache(NamedTuple):
                     length: int) -> "MoESlotCache":
         import numpy as np
 
-        self._one_group("import_rows would need a prefix's last window - 1 "
-                        "positions of every window layer, which no exporter "
+        self._one_group("import_rows would need a prefix's last reach - 1 "
+                        "positions of every ring layer, which no exporter "
                         "keeps")
         w, b = self._loc(slot)
         n = k_rows.shape[1]
@@ -507,7 +597,7 @@ class MoESlotCache(NamedTuple):
         import numpy as np
 
         self._one_group("copy_prefix finds the donor's ring holding its "
-                        "newest positions, not the prefix's last window - 1")
+                        "newest positions, not the prefix's last reach - 1")
         dw, db = self._loc(dst)
         sw, sb = self._loc(src)
         k = np.array(self.k)
@@ -529,13 +619,16 @@ _FOLD_KEY = {"wq_a": 21, "wq_b": 22, "wkv_a": 23, "wkv_b": 24,
              "ws_gate": 25, "ws_up": 26, "ws_down": 27, "router_bias": 28,
              "w_gate": 29, "w_up": 30, "w_down": 31, "sink": 32, "wg": 33,
              "q_norm": 34, "k_norm": 35, "ln1_post": 36, "ln2_post": 37,
-             "ln1": 38, "ln2": 39}
+             "ln1": 38, "ln2": 39, "w_in": 40, "w_conv": 41, "w_out": 42}
 _DENSE_GROUP_FOLD = 64
 # a group's fold: the groups that always were keep theirs (so the uniform
-# descriptions' weights are what they were); a window group folds 128 more
+# descriptions' weights are what they were); a window group folds 128 more,
+# a conv group 256
 _GROUP_FOLD = {"blocks": 0, "dense_blocks": _DENSE_GROUP_FOLD,
                "window_blocks": 128,
-               "dense_window_blocks": 128 + _DENSE_GROUP_FOLD}
+               "dense_window_blocks": 128 + _DENSE_GROUP_FOLD,
+               "conv_blocks": 256,
+               "dense_conv_blocks": 256 + _DENSE_GROUP_FOLD}
 SINK_SCALE = 1.0  # seeded sink logits: as large as the scores they sit
 # beside, so that a softmax without its sink column is told apart
 ROUTER_BIAS_SCALE = 0.01  # seeded gate bias: choosing by score + bias and
@@ -548,8 +641,15 @@ NORM_GAIN_SCALE = 0.1  # seeded norm gains (``from_hf``'s afmoe branch asks
 def _attn_shapes(cfg: MoEServeConfig, kind: str = "full"):
     """{leaf: (shape, fan-in)} of one layer's attention matrices, and its
     norm leaves, for the description's attention kind (``kind``: the
-    layer's, where the description has layer kinds)."""
+    layer's, where the description has layer kinds). A "conv" layer's are
+    its short convolution's: ``w_in`` (the three gates' projection),
+    ``w_out`` and the filter ``w_conv`` ``[dim, taps]``, drawn at
+    1/sqrt(taps) so that a tap left out or the taps reversed shows."""
     h = cfg.dim
+    if kind == "conv":
+        return {"w_in": ((h, 3 * h), h), "w_out": ((h, h), h),
+                "w_conv": ((h, cfg.conv_taps), cfg.conv_taps)}, \
+            {"ln1": h, "ln2": h}
     if cfg.attn == "mla":
         nh = cfg.n_heads
         qk = cfg.qk_nope_dim + cfg.qk_rope_dim
@@ -585,7 +685,8 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
     stacked groups by (FFN kind, attention kind) — ``cfg.param_groups()``:
     ``blocks`` [n_moe_layers, ...] and, where the description has a dense
     prefix, ``dense_blocks`` [first_k_dense, ...]; with layer kinds the
-    window layers' ``window_blocks`` / ``dense_window_blocks`` beside them.
+    window layers' ``window_blocks`` / ``dense_window_blocks`` and the conv
+    layers' ``conv_blocks`` / ``dense_conv_blocks`` beside them.
     Every matrix is drawn in float32 (embedding 0.02, others 1/sqrt(fan-in))
     and stored in ``cfg.param_dtype``; a layer's norm gains are ones (or,
     where ``cfg.norm_gain_scale`` asks, 1 + that x a normal), the gate bias
@@ -613,7 +714,8 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
              "w_down": ((fd, h), fd)}
 
     def group(name, n):
-        kind = "window" if "window_" in name else "full"
+        kind = next((k for k in ("window", "conv") if k + "_" in name),
+                    "full")
         fold = _GROUP_FOLD[name]
         mats, norms = _attn_shapes(cfg, kind)
         is_dense = name.startswith("dense_")
@@ -642,8 +744,9 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
     params = {
         "embed": rnd("embed", (cfg.vocab, h), 0.02),
         "final_norm": jnp.ones((h,), jnp.float32),
-        "head": rnd("head", (h, cfg.vocab), 1.0 / math.sqrt(h)),
     }
+    if not cfg.tie_head:  # a tied head is the embedding: no leaf of its own
+        params["head"] = rnd("head", (h, cfg.vocab), 1.0 / math.sqrt(h))
     params.update({name: group(name, n) for name, n in sizes.items()})
     return params
 
@@ -923,20 +1026,24 @@ class MoEServer:
         pool_bytes = _obsc.gauge(
             "serving_kv_pool_bytes",
             "bytes of the slot pool's arrays by cache group (label group: "
-            "full | window, or the attention kind of a pool with one)")
+            "full | window | conv, or the attention kind of a pool with one)")
         for group in {g for g, _ in inference.cache_groups(self.cfg)}:
-            pair = [inference.group_array(a, group)
-                    for a in (cache.k, cache.v)]
+            arrays = [a for a in (inference.group_array(pool, group)
+                                  for pool in (cache.k, cache.v))
+                      if a is not None]  # a conv group keeps one
             label = group or self.cfg.attn
             row_bytes.set(sum(math.prod(a.shape[4:]) * a.dtype.itemsize
-                              for a in pair), kind=label)
-            pool_bytes.set(sum(a.nbytes for a in pair), group=label)
-            if group == "window":
+                              for a in arrays), kind=label)
+            pool_bytes.set(sum(a.nbytes for a in arrays), group=label)
+            if group in inference.RING_GROUPS:
                 _obsc.gauge(
                     "serving_kv_ring_rows",
                     "rows a slot keeps of each window layer in the slot "
-                    "pool: the ring (window - 1 + the widest write, or more)"
-                ).set(pair[0].shape[3])
+                    "pool: the ring (window - 1 + the widest write, or "
+                    "more); under the label group=conv, of each conv layer "
+                    "(taps - 1 + the widest write, or more)"
+                ).set(arrays[0].shape[3],
+                      **({} if group == "window" else {"group": group}))
         return cache
 
     def prefill_slots(self, params, tokens, prompt_lens, new_mask,

@@ -7,7 +7,9 @@ experts of which this member may hold a share — and the Trinity (``afmoe``)
 block: one KV head count, rotary on the window layers only, each query and
 key head RMS-normed, the heads' output gated, each branch's output normed
 before the residual, the embedding scaled, a shared expert beside the held
-share). What tier-1 holds the program to.
+share — and the LFM2 (``lfm2_moe``) block: gated short convolutions in
+the "conv" layers between full attention layers with QK-norm, no shared
+expert, the head tied to the embedding). What tier-1 holds the program to.
 
 Straightforward ``jax.numpy`` in float32 at
 ``default_matmul_precision("highest")``: a loop over the query heads with a
@@ -42,6 +44,14 @@ and, where the description says so (the Trinity block):
     x    = x + RMSNorm(concat_j(o_j) Wo, ln1_post)             (post_norms)
     x    = x + RMSNorm(F(h2), ln2_post),  F the dense SwiGLU or
            E_shared(h2) + sum over chosen held j of w_j E_j(h2)
+
+and in a "conv" layer (the LFM2 block), in place of the attention half:
+
+    [b | c | u] = h W_in  (three H-wide parts);   y(t) = b(t) * u(t)
+    z(t) = sum_{j < taps} w[:, j] * y(t - (taps - 1) + j),  y(t < 0) = 0
+    x    = x + (c * z) W_out                                   (conv_taps)
+
+and where the head is tied (``tie_head``):  logits = RMSNorm(x) E^T
 """
 
 from __future__ import annotations
@@ -115,6 +125,21 @@ def attention(x, lp, cfg, kind: str):
     return x + out
 
 
+def short_conv(x, lp, cfg):
+    """One conv layer's operator half on one sequence [T, H]: the filter as
+    an explicit loop over the taps on ``y`` padded with ``taps - 1`` rows of
+    zeros on the left."""
+    t = x.shape[0]
+    taps = cfg.conv_taps
+    h = _norm(x, lp["ln1"], cfg.norm_eps)
+    b, c, u = jnp.split(h @ lp["w_in"], 3, axis=-1)
+    y = jnp.concatenate([jnp.zeros((taps - 1, x.shape[1]), x.dtype), b * u])
+    z = jnp.zeros_like(x)
+    for j in range(taps):
+        z = z + lp["w_conv"][:, j] * y[j:j + t]
+    return x + (c * z) @ lp["w_out"]
+
+
 def expert_layer_sum(h2, lp, cfg, first: int = None, held: int = None):
     """The routed experts' weighted sum for rows ``h2`` [T, H], over the
     experts ``[first, first + held)`` whose leaves ``lp`` carries (the
@@ -138,7 +163,9 @@ def forward_logits(params, tokens, cfg):
         x = p["embed"][jnp.asarray(tokens)] * cfg.embed_scale
         for i, (group, j) in enumerate(cfg.param_groups()):
             lp = jax.tree.map(lambda a: a[j], p[group])
-            x = attention(x, lp, cfg, cfg.layer_kinds[i])
+            kind = cfg.layer_kinds[i]
+            x = short_conv(x, lp, cfg) if kind == "conv" \
+                else attention(x, lp, cfg, kind)
             h2 = _norm(x, lp["ln2"], cfg.norm_eps)
             if "router" in lp:
                 out = expert_layer_sum(h2, lp, cfg)
@@ -150,4 +177,5 @@ def forward_logits(params, tokens, cfg):
             if cfg.post_norms:
                 out = _norm(out, lp["ln2_post"], cfg.norm_eps)
             x = x + out
-        return _norm(x, p["final_norm"], cfg.norm_eps) @ p["head"]
+        head = p["embed"].T if cfg.tie_head else p["head"]
+        return _norm(x, p["final_norm"], cfg.norm_eps) @ head

@@ -185,8 +185,11 @@ def _moe_cfg(args):
     window and full attention layers with their own cache groups, a held
     share of the experts — or an ``afmoe``-shaped one — gated attention
     with QK-norm, rotary on the window layers only, sandwich norms, a shared
-    expert beside a held share; weights stored in its ``torch_dtype``), or
-    else the hand-sized flags (the uniform block)."""
+    expert beside a held share — or an ``lfm2_moe``-shaped one — gated
+    short-convolution layers between full attention layers, their state a
+    third cache group, a head tied to the embedding; weights stored in its
+    ``torch_dtype``), or else the hand-sized flags (the uniform block)."""
+    from uccl_tpu.models.inference import RING_GROUPS
     from uccl_tpu.models.moe_inference import MoEServeConfig
 
     if not args.model_config:
@@ -211,11 +214,13 @@ def _moe_cfg(args):
         capacity_factor=max(8.0, experts / hf["num_experts_per_tok"]),
         param_dtype=hf.get("torch_dtype", "float32"),
     )
-    if "window" in cfg.layer_kinds:
-        # a window layer's ring holds window - 1 + the widest write
-        widest = max(args.prefill_chunk, args.spec_k + 1)
-        cfg = dataclasses.replace(cfg, window_ring=max(
-            cfg.ring, cfg.window - 1 + widest))
+    # a ring group's rows hold reach - 1 + the widest write (window - 1 +,
+    # taps - 1 +)
+    widest = max(args.prefill_chunk, args.spec_k + 1)
+    for group in RING_GROUPS:
+        if group in cfg.layer_kinds:
+            cfg = dataclasses.replace(cfg, **{group + "_ring": max(
+                cfg.ring_rows(group), cfg.reach(group) - 1 + widest)})
     return cfg
 
 

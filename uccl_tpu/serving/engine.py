@@ -156,35 +156,50 @@ def _bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
-def _check_window_groups(backend, prefill_chunk, spec_k, prefix_cache,
-                         kv_tiers) -> int:
-    """What a pool with window groups (a model description whose
-    ``layer_kinds`` name "window" layers) cannot do yet, refused before a
-    request could meet it; and the ring against the widest write. Returns
-    the window (0 for a pool without window groups)."""
+def _check_ring_groups(backend, prefill_chunk, spec_k, prefix_cache,
+                       kv_tiers, preempt, adapters) -> int:
+    """What a pool with ring groups (a model description whose
+    ``layer_kinds`` name "window" or "conv" layers: ``inference.
+    RING_GROUPS``) cannot do yet, refused before a request could meet it;
+    and each ring against the widest write. Returns the window (0 for a
+    pool without window groups)."""
     cfg = getattr(backend, "cfg", None)
-    if "window" not in (getattr(cfg, "layer_kinds", ()) or ()):
-        return 0
-    from uccl_tpu.models.inference import WINDOW_GROUPS_STAY
+    kinds = getattr(cfg, "layer_kinds", ()) or ()
+    from uccl_tpu.models.inference import RING_GROUPS, RING_GROUPS_STAY
 
+    rings = [g for g in RING_GROUPS if g in kinds]
+    if "conv" in kinds and adapters is not None:
+        raise ValueError(
+            "LoRA adapters beside conv layers are not built: the adapter "
+            "tables are (wq, wv) deltas by layer, and a conv layer has "
+            "neither projection")
+    if not rings:
+        return 0
     if kv_tiers is not None:
-        raise ValueError(WINDOW_GROUPS_STAY + "kv_tiers demotes and "
+        raise ValueError(RING_GROUPS_STAY + "kv_tiers demotes and "
                          "promotes exported rows")
     if prefix_cache is not None:
-        raise ValueError(WINDOW_GROUPS_STAY + "prefix_cache copies a "
+        raise ValueError(RING_GROUPS_STAY + "prefix_cache copies a "
                          "donor's rows, whose ring no longer holds the "
-                         "prefix's last window - 1 positions")
+                         "prefix's last reach - 1 positions")
+    if preempt:
+        raise ValueError(RING_GROUPS_STAY + "preempt saves a victim's "
+                         "exported rows and restores them")
     if prefill_chunk is None:
         raise ValueError(
-            "a pool with window groups requires prefill_chunk: a whole "
-            "prompt in one write would wrap its window layers' ring")
+            "a pool with ring groups requires prefill_chunk: a whole "
+            "prompt in one write would wrap its window and conv layers' "
+            "rings")
     widest = max(prefill_chunk, (spec_k or 0) + 1)
-    if cfg.ring < cfg.window - 1 + widest:
-        raise ValueError(
-            f"the window layers' ring of {cfg.ring} rows must hold window - "
-            f"1 + the widest write ({cfg.window} - 1 + {widest}): raise "
-            f"window_ring or lower prefill_chunk / spec_k")
-    return cfg.window
+    for group in rings:
+        rows, reach = cfg.ring_rows(group), cfg.reach(group)
+        if rows < reach - 1 + widest:
+            raise ValueError(
+                f"the {group} layers' ring of {rows} rows must hold "
+                f"{RING_GROUPS[group]} - 1 + the widest write ({reach} - 1 "
+                f"+ {widest}): raise "
+                f"{group}_ring or lower prefill_chunk / spec_k")
+    return cfg.window if "window" in kinds else 0
 
 
 class ServingEngine:
@@ -304,8 +319,9 @@ class ServingEngine:
                     "chunk boundaries and resumes via the chunked "
                     "start-offset program"
                 )
-        self._window = _check_window_groups(
-            backend, prefill_chunk, spec_k, prefix_cache, kv_tiers)
+        self._window = _check_ring_groups(
+            backend, prefill_chunk, spec_k, prefix_cache, kv_tiers, preempt,
+            adapters)
         self.backend = backend
         self.spec_k = spec_k
         self.drafter = drafter

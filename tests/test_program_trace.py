@@ -205,10 +205,20 @@ def test_the_experts_count_arrives_as_before(profiled):
         assert any(wire[1] <= f <= t0 for f in fetch_ends)
 
 
+def test_a_decode_call_runs_one_program(profiled):
+    """(ISSUE 43) Whatever executes inside a ``wire.decode`` is the step's
+    own program: no trivial program is dispatched before or after it."""
+    decodes = [e for e in profiled if e[0] == "uccl.wire.decode"]
+    assert decodes
+    for _, t0, t1, _, _ in decodes:
+        ran = {e[4] for e in profiled if e[4] and t0 <= e[1] and e[2] <= t1}
+        assert ran == {"jit_uccl_moe_decode_slots"}, ran
+
+
 def test_module_names_tell_the_programs_apart(profiled, devices):
     modules = {e[4] for e in profiled if e[4]}
     assert {"jit_uccl_moe_prefill_slots",
-            "jit_uccl_moe_verify_slots"} <= modules
+            "jit_uccl_moe_decode_slots"} <= modules
     assert not {m for m in modules if m in ("jit_f", "jit_gen", "jit_run")}
     from uccl_tpu.models.dense import DenseConfig, init_params as dense_init
 
@@ -389,7 +399,7 @@ def test_training_step_carries_its_scopes(program_text, scope):
 def test_ll_path_and_module_names_of_the_compiled_programs(program_text):
     for scope in ("moe.route", "moe.dispatch", "moe.experts", "moe.combine"):
         assert f"/{scope}/" in program_text["decode_ll"]
-    assert "jit(uccl_moe_verify_slots)" in program_text["decode"]
+    assert "jit(uccl_moe_decode_slots)" in program_text["decode"]
     assert "jit(uccl_moe_prefill_slots)" in program_text["prefill"]
     assert "jit_train_step" in program_text["train_module"]
 

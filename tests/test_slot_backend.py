@@ -18,6 +18,7 @@ survive each other's steps; the three slot-row shims read and write
 
 import re
 from collections import Counter
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -65,7 +66,7 @@ def _dense():
     return backend, oracle, backend.programs._fns
 
 
-def _moe(devices, desc=GQA):
+def _moe(devices, desc=GQA, world=1, slots=N_SLOTS):
     from jax.sharding import Mesh
 
     from uccl_tpu.models.moe_inference import (
@@ -73,7 +74,7 @@ def _moe(devices, desc=GQA):
     )
 
     cfg = MoEServeConfig(**desc)
-    srv = MoEServer(cfg, Mesh(np.array(devices[:1]), ("dp",)))
+    srv = MoEServer(cfg, Mesh(np.array(devices[:world]), ("dp",)))
     placed = srv.shard_params(init_params(jax.random.PRNGKey(0), cfg))
 
     def oracle(p, prompt, n):
@@ -81,8 +82,8 @@ def _moe(devices, desc=GQA):
                             impl="sort")
         return np.asarray(want)[0, 0].tolist()
 
-    backend = MoEBackend(srv, placed, batch_local=N_SLOTS, max_seq=MAX_SEQ,
-                         decode_impl="sort")
+    backend = MoEBackend(srv, placed, batch_local=slots // world,
+                         max_seq=MAX_SEQ, decode_impl="sort")
     return backend, oracle, srv._fns
 
 
@@ -383,3 +384,152 @@ def test_a_program_that_fails_after_consuming_the_pool_says_so(stacks, stack):
     with pytest.raises(RuntimeError, match="consumed the slot pool") as e:
         _call(backend, "decode")
     assert isinstance(e.value.__cause__, FloatingPointError)
+
+
+# -- a call crosses to the device once each way (ISSUE 43) -------------------
+
+class _Compiles:
+    """Names of the programs the backend compiles while ``on``
+    (``jax.monitoring`` can take no listener off again)."""
+
+    def __init__(self):
+        self.on, self.seen = False, []
+        jax.monitoring.register_event_duration_secs_listener(self._event)
+
+    def _event(self, name, secs, fun_name=None, **_):
+        if self.on and name.endswith("backend_compile_duration"):
+            self.seen.append(fun_name)
+
+
+@pytest.fixture(scope="module")
+def compiles():
+    c = _Compiles()
+    yield c
+    c.on = False
+
+
+@pytest.mark.parametrize("world", [1, 2])
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_a_step_call_is_one_compiled_program(devices, compiles, kind, world):
+    """A first ``decode`` / ``verify`` call at a pool shape nothing else
+    here uses compiles ONE program, the step's own — no eager reshape of a
+    device array before or after it — and serves what ``verify_slots``
+    returns for the same window on a twin's pool, bit for bit: tokens,
+    experts read, pool."""
+    slots, width = 6, 3 if kind == "verify" else 1
+    backend, _, _ = _moe(devices, world=world, slots=slots)
+    srv, placed, twin = backend.server, backend.params, backend.clone()
+    rng = np.random.default_rng(world)
+    prompts = rng.integers(1, 64, (slots, CHUNK)).astype(np.int32)
+    active = np.arange(slots) % 3 != 1
+    for b in (backend, twin):
+        b.prefill(prompts, np.full(slots, CHUNK, np.int32), active)
+    window = rng.integers(1, 64, (slots, width)).astype(np.int32)
+    read = obs.counter("ep_experts_read_total")
+    before = read.get()
+    compiles.seen, compiles.on = [], True
+    try:
+        if kind == "decode":
+            got = [backend.decode(window[:, 0], active)]
+        else:
+            got = list(backend.verify(window, active))
+    finally:
+        compiles.on = False
+    assert compiles.seen == [f"jit(uccl_moe_{kind}_slots)"]
+    grid = (world, slots // world)
+    tok, n_acc, n_read, pool = srv.verify_slots(
+        placed, window.reshape(grid + (width,)), active.reshape(grid),
+        twin.cache, impl="sort")
+    want = ([np.asarray(tok)[..., 0]] if kind == "decode"
+            else [np.asarray(tok), np.asarray(n_acc)])
+    for g, w in zip(got, want):
+        assert isinstance(g, np.ndarray) and g.dtype == np.int32
+        np.testing.assert_array_equal(g, w.reshape((slots,) + w.shape[2:]))
+    assert read.get() - before == int(np.asarray(n_read).sum())
+    for a, b in zip(jax.tree.leaves(tuple(backend.cache)),
+                    jax.tree.leaves(tuple(pool))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+class _Stub:
+    """``Programs`` that run nothing: they keep what they were handed and
+    return outputs of the documented shapes, then the pool."""
+
+    def __init__(self, world, counted):
+        self.world, self.counted, self.calls = world, counted, []
+        for kind in ("prefill", "decode", "verify"):
+            setattr(self, kind, partial(self._call, kind))
+
+    def _out(self, kind, tokens):
+        lead = tokens.shape[:1 if self.world is None else 2]
+        out = [np.zeros(lead if kind != "verify" else tokens.shape, np.int32)]
+        if kind == "verify":
+            out.append(np.ones(lead, np.int32))
+        if self.counted and kind != "prefill":
+            out.append(np.full(self.world or 1, 5, np.int32))
+        return out
+
+    def _call(self, kind, params, *args, **kw):
+        *per_row, pool = args
+        self.calls.append((kind, per_row, kw))
+        return (*self._out(kind, per_row[0]), pool)
+
+
+@pytest.mark.parametrize("counted", [False, True], ids=["bare", "counted"])
+@pytest.mark.parametrize("world", [None, 1, 2])
+def test_run_hands_the_programs_host_arrays(world, counted):
+    """Every per-row argument reaches the program as a HOST array of its
+    documented dtype, ``[rows, ...]`` with no world and ``[W, rows / W,
+    ...]`` over one (the adapter tables, not per row, are broadcast); the
+    outputs come back flat per row; the experts' count is popped, and
+    counted, only where ``experts_held`` is set."""
+    from uccl_tpu.models.inference import SlotKVCache
+
+    rows, held = 4, 7
+    stub = _Stub(world, counted)
+    backend = SlotBackend(
+        None, None, stub,
+        lambda: SlotKVCache(*(jnp.zeros(1) for _ in range(3))),
+        n_slots=rows, max_seq=MAX_SEQ, rungs=(rows,), world=world,
+        experts_held=held if counted else 0)
+    # what an engine might hand over: lists, wider ints, 0/1 masks
+    toks = np.arange(rows * 3, dtype=np.int64).reshape(rows, 3)
+    mask = [1, 0, 1, 1]
+    sampling = (np.arange(rows), list(range(rows)), np.ones(rows),
+                np.ones(rows, np.float64), np.arange(rows))
+    tables = {t: (jnp.ones((2, 3)), jnp.ones((3, 2))) for t in ("wq", "wv")}
+    adapters = (tables, [0, 1, 0, 1])
+    read = obs.counter("ep_experts_read_total")
+    before = read.get()
+    out = {
+        "prefill": backend.prefill(toks, toks[:, 0], mask, start=toks[:, 1],
+                                   sampling=sampling, adapters=adapters,
+                                   slots=np.arange(rows)),
+        "decode": backend.decode(toks[:, 0], mask, sampling, adapters),
+        "verify": backend.verify(toks, mask, sampling, adapters),
+    }
+    assert read.get() - before == (2 * 5 * (world or 1) if counted else 0)
+    lead = (rows,) if world is None else (world, rows // world)
+    assert [k for k, _, _ in stub.calls] == ["prefill", "decode", "verify"]
+    for kind, args, kw in stub.calls:
+        want = {"prefill": [(3, np.int32), (0, np.int32), (0, bool)],
+                "decode": [(0, np.int32), (0, bool)],
+                "verify": [(3, np.int32), (0, bool)]}[kind]
+        per_row = list(args) + list(kw["sampling"]) + [kw["adapter_ids"]]
+        want = want + [(0, dt) for dt in (np.int32, np.int32, np.float32,
+                                          np.float32, np.int32, np.int32)]
+        if kind == "prefill":
+            per_row += [kw["start"], kw["slots"]]
+            want = want + [(0, np.int32)] * 2
+        else:
+            assert not {"start", "slots"} & set(kw)
+        assert len(per_row) == len(want)
+        for a, (width, dtype) in zip(per_row, want):
+            assert type(a) is np.ndarray, (kind, type(a))
+            assert a.dtype == dtype, (kind, a.dtype, dtype)
+            assert a.shape == lead + ((width,) if width else ()), kind
+        for ab in kw["adapters"].values():
+            assert all(a.shape[:-2] == lead[:-1] for a in ab)
+    np.testing.assert_array_equal(stub.calls[2][1][0].reshape(rows, 3), toks)
+    assert out["prefill"].shape == out["decode"].shape == (rows,)
+    assert [o.shape for o in out["verify"]] == [(rows, 3), (rows,)]

@@ -1089,10 +1089,11 @@ def spec_advance(lengths, active, n_acc):
 
 def decode_step_slots(
     params, token, active, cache: SlotKVCache, cfg: DenseConfig,
-    sampling=None, adapters=None, adapter_ids=None,
+    sampling=None, adapters=None, adapter_ids=None, ffn=None,
 ) -> Tuple[jax.Array, SlotKVCache]:
     """One masked autoregressive step over the slot pool — the S=1 case of
-    :func:`verify_slots` (no draft: nothing to accept, advance by one).
+    :func:`verify_slots` (no draft: nothing to accept, advance by one;
+    ``ffn`` as there).
 
     token: [B_slots] (inactive slots feed a dummy); active: [B_slots] bool.
     Active slots write their new KV at their own length and advance by one;
@@ -1102,7 +1103,7 @@ def decode_step_slots(
     """
     tok, _, cache = verify_slots(params, token[:, None], active, cache, cfg,
                                  sampling=sampling, adapters=adapters,
-                                 adapter_ids=adapter_ids)
+                                 adapter_ids=adapter_ids, ffn=ffn)
     return tok[:, 0], cache
 
 

@@ -1099,6 +1099,51 @@ class MoEServer:
                                *extra)
         return tok, MoESlotCache(nk, nv, nlen)
 
+    def _step_slots(self, kind, params, tokens, active, cache, impl,
+                    sampling, adapters, adapter_ids):
+        """The one builder of the decode and the verify program (``kind``):
+        :func:`inference.verify_slots`, or its one-token case
+        :func:`inference.decode_step_slots`, run per shard with the EP block
+        as its FFN — one compiled program a call, named after ``kind``
+        (``jit_uccl_moe_<kind>_slots``) and keyed apart in ``_fns``. Returns
+        the statement's own outputs, then the experts read [W], then the new
+        pool."""
+        self._check_drop_free()
+        cfg = self.cfg
+        sampled, adapted = sampling is not None, adapters is not None
+        extra = _flat_extra(sampling, adapters, adapter_ids)
+        counted = self.world == 1 and impl == "sort"
+        step, n_out = {"verify": (inference.verify_slots, 2),
+                       "decode": (inference.decode_step_slots, 1)}[kind]
+
+        def per_shard(p, tok, mask, kc, vc, ln, *rest):
+            samp, adp, ids = _split_extra([r[0] for r in rest], sampled,
+                                          adapted)
+            reads = [] if counted else None
+            *out, pool = step(
+                _strip_shard(p), tok[0], mask[0],
+                SlotKVCache(_member(kc), _member(vc), ln[0]), cfg,
+                sampling=samp, adapters=adp, adapter_ids=ids,
+                ffn=_moe_block(cfg, impl, reads))
+            if counted:
+                out.append(sum(reads))
+            return (*(o[None] for o in out), _lead(pool.k), _lead(pool.v),
+                    pool.lengths[None])
+
+        per_shard.__name__ = per_shard.__qualname__ = f"uccl_moe_{kind}_slots"
+        key = (f"{kind}_slots", impl, tokens.shape,
+               _shapes(cache.k, cache.v), sampled, adapted)
+        fn = self._fn(key, lambda: self._shard_mapped(
+            per_shard, 5 + len(extra), n_out + counted + 3, params,
+            donate=(3, 4, 5)))
+        *out, nk, nv, nlen = fn(params, tokens, active, cache.k, cache.v,
+                                cache.lengths, *extra)
+        if not counted:  # the batched GEMMs: every expert, whatever the rows
+            out.append(np.full(
+                self.world, cfg.n_held // self.world * cfg.n_moe_layers,
+                np.int32))
+        return (*out, MoESlotCache(nk, nv, nlen))
+
     def verify_slots(self, params, tokens, active, cache: MoESlotCache,
                      impl: str = "sort", sampling=None, adapters=None,
                      adapter_ids=None):
@@ -1118,50 +1163,22 @@ class MoEServer:
         handed down as the rows that count (``ep.ops.moe_ffn(..., rows=)``)
         and the program reads, and counts, the experts they reached;
         otherwise the program is what it always was and reads them all."""
-        self._check_drop_free()
-        cfg = self.cfg
-        sampled, adapted = sampling is not None, adapters is not None
-        extra = _flat_extra(sampling, adapters, adapter_ids)
-        counted = self.world == 1 and impl == "sort"
-
-        def uccl_moe_verify_slots(p, tok, mask, kc, vc, ln, *rest):
-            samp, adp, ids = _split_extra([r[0] for r in rest], sampled,
-                                          adapted)
-            reads = [] if counted else None
-            t, n_acc, out = inference.verify_slots(
-                _strip_shard(p), tok[0], mask[0],
-                SlotKVCache(_member(kc), _member(vc), ln[0]), cfg,
-                sampling=samp, adapters=adp, adapter_ids=ids,
-                ffn=_moe_block(cfg, impl, reads))
-            read = [sum(reads)[None]] if counted else []
-            return (t[None], n_acc[None], *read, _lead(out.k), _lead(out.v),
-                    out.lengths[None])
-
-        key = ("verify_slots", impl, tokens.shape,
-               _shapes(cache.k, cache.v), sampled, adapted)
-        fn = self._fn(key, lambda: self._shard_mapped(
-            uccl_moe_verify_slots, 5 + len(extra), 5 + counted, params,
-            donate=(3, 4, 5)))
-        *out, nk, nv, nlen = fn(params, tokens, active, cache.k, cache.v,
-                                cache.lengths, *extra)
-        if not counted:  # the batched GEMMs: every expert, whatever the rows
-            out.append(np.full(
-                self.world, cfg.n_held // self.world * cfg.n_moe_layers,
-                np.int32))
-        return (*out, MoESlotCache(nk, nv, nlen))
+        return self._step_slots("verify", params, tokens, active, cache,
+                                impl, sampling, adapters, adapter_ids)
 
     def decode_step_slots(self, params, token, active, cache: MoESlotCache,
                           impl: str = "ll", sampling=None, adapters=None,
                           adapter_ids=None):
         """One masked autoregressive step over the slot pool (packed LL EP
-        path by default) — the S=1 case of :meth:`verify_slots`.
-        token/active: [W, B_loc]; inactive slots neither write KV nor
-        advance their length. Returns (next greedy-or-sampled token
-        [W, B_loc], experts read [W], cache')."""
-        tok, _, read, cache = self.verify_slots(
-            params, token[..., None], active, cache, impl=impl,
-            sampling=sampling, adapters=adapters, adapter_ids=adapter_ids)
-        return tok[..., 0], read, cache
+        path by default) — the S=1 case of :meth:`verify_slots`, as ONE
+        compiled program: the ``[B_loc] -> [B_loc, 1]`` window and the
+        ``[:, 0]`` of its result are :func:`inference.decode_step_slots`'s,
+        inside the step, so host arrays go straight in and nothing is
+        dispatched around it. token/active: [W, B_loc]; inactive slots
+        neither write KV nor advance their length. Returns (next
+        greedy-or-sampled token [W, B_loc], experts read [W], cache')."""
+        return self._step_slots("decode", params, token, active, cache,
+                                impl, sampling, adapters, adapter_ids)
 
     def generate(self, params, prompt, new_tokens: int, max_seq: int,
                  impl: str = "ll", sampling=None):

@@ -180,13 +180,14 @@ class SlotBackend:
 
     ``programs`` are the compiled callables (:class:`Programs`);
     ``new_pool()`` bears a pool; ``world`` is how a flat per-slot array is
-    laid out for the programs — ``None``: as it is, numpy straight into jit;
-    ``W``: on the device as ``[W, rows / W]``, adapter tables broadcast
-    ``[W, ...]``; ``rungs`` are the row counts the chunked prefill may be
-    called at (:func:`prefill_rung`); ``experts_held`` is experts held x
-    expert layers where the decode and verify programs return, last before
-    the pool, how many experts' weights the step read (``[W]`` int32; the MoE
-    stack's do), and 0 where they return no such count."""
+    laid out for the programs, always as host arrays, numpy straight into
+    jit — ``None``: as it is; ``W``: reshaped ``[W, rows / W, ...]``, adapter
+    tables broadcast ``[W, ...]``; ``rungs`` are the row counts the chunked
+    prefill may be called at (:func:`prefill_rung`); ``experts_held`` is
+    experts held x expert layers where the decode and verify programs
+    return, last before the pool, how many experts' weights the step read
+    (``[W]`` int32; the MoE stack's do), and 0 where they return no such
+    count."""
 
     def __init__(self, params, cfg, programs: Programs, new_pool: Callable, *,
                  n_slots: int, max_seq: int, rungs: tuple,
@@ -237,19 +238,22 @@ class SlotBackend:
 
     # -- the one stage -> launch -> fetch sequence --------------------------
     def _lay(self, flat, dtype):
-        """A flat per-row array as the programs take it."""
+        """A flat per-row array as the programs take it: a HOST array of
+        ``dtype``, ``[W, rows / W, ...]`` over a ``world``. The compiled
+        call places all of a call's arguments itself, in one hand-over."""
+        flat = np.asarray(flat, dtype)
         if self.world is None:
             return flat
-        flat = np.asarray(flat)
-        return jnp.asarray(
-            flat.reshape((self.world, -1) + flat.shape[1:]).astype(dtype)
-        )
+        return flat.reshape((self.world, -1) + flat.shape[1:])
 
     def _run(self, kind, per_row, sampling, adapters, **rows_kw):
         """One call of the program ``kind`` ("prefill" | "decode" |
         "verify"). ``per_row``: its (flat array, dtype) arguments in order;
         ``rows_kw``: per-row int32 keyword arguments (None = not passed).
-        Returns every output but the pool, flat per row again. The program
+        Returns every output but the pool, flat per row again. A call
+        crosses to the device once each way: the per-row arguments go in as
+        host arrays (:meth:`_lay`) and the outputs come back in one blocking
+        read, every copy started before the first is waited for. The program
         consumes the pool it is handed and ``self.cache`` becomes the one
         it gives back, in one statement: nothing else may hold the pool
         across a call. The three spans are what
@@ -290,7 +294,7 @@ class SlotBackend:
             if _consumed(pool):
                 _POOL_IN_PLACE.inc(program=kind)
         with obs.span("backend.fetch", "wire"):
-            out = [np.asarray(o) for o in out]
+            out = jax.device_get(out)
             if self.world is not None:  # [W, rows / W, ...] -> [rows, ...]
                 out = [o.reshape((-1,) + o.shape[2:]) for o in out]
         if self.experts_held and kind != "prefill":

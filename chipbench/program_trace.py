@@ -24,9 +24,13 @@ runtime enqueued it. Causality bounds the lead from both sides
 (:func:`device_clock_lead`): no program starts before its
 ``DoEnqueueProgram`` and none is reported by ``CompleteCallbacks`` before
 it ended (same ``run_id``). :func:`load` moves the device's operations onto
-the host's clock by the middle of that bound, so idle time is charged to
-the span the host was really in; the bound's width (0.2 ms measured) is
-what the four ``idle_in_*`` readings can be off by between neighbours.
+the host's clock by the middle of that bound, so an operation is found in
+the span the host was really in (0 where the bounds cross: a fifth of the
+traces; ``step_timeline.lead_from_runtime`` leaves the stray pairs out).
+
+The reductions that depend on a model family's scope names are in
+``chipbench/scopes.py``, which takes the family; here are the parser, the
+clock, and the reductions over plain lists of operations.
 """
 
 from __future__ import annotations
@@ -51,14 +55,6 @@ MOE_EXPERTS = ("moe.experts",)
 MOE_EXCHANGE = ("moe.router", "moe.route", "moe.dispatch", "moe.combine")
 ATTENTION = ("attn.qkv", "attn.kv_write", "attn.core", "attn.out",
              "attn.flash")
-# idle time is charged to the innermost uccl.* span; these groups are the
-# four idle metrics
-IDLE_STAGE = (PREFIX + "backend.stage",)
-IDLE_LAUNCH = (PREFIX + "backend.launch",)
-IDLE_FETCH = (PREFIX + "backend.fetch",)
-IDLE_ENGINE = (STEP, PREFIX + "engine.admit", PREFIX + "engine.retire",
-               DECODE, PREFILL, PREFIX + "wire.verify")
-NO_SPAN = "(no span)"
 
 Op = Tuple  # (name, start_ns, dur_ns, scope path or "")
 
@@ -286,17 +282,22 @@ def spans_in(spans: Iterable[tuple], name: str, t0: float, t1: float
     return [sp for sp in spans if sp[0] == name and t0 <= sp[1] < t1]
 
 
-def busy_by_scope(ops: Sequence[Op], spans: Sequence[tuple], span_name: str
+def by_scope(ops: Sequence[Op], scopes: Tuple[str, ...] = SCOPES
+             ) -> Dict[Optional[str], float]:
+    """Device-busy ns of ``ops`` by scope of ``scopes`` (None: under none)."""
+    by: Dict[Optional[str], list] = {}
+    for ev in ops:
+        by.setdefault(scope_of(ev[3], scopes), []).append(ev)
+    return {s: tr.busy_ns(evs) for s, evs in by.items()}
+
+
+def busy_by_scope(ops: Sequence[Op], spans: Sequence[tuple], span_name: str,
+                  scopes: Tuple[str, ...] = SCOPES
                   ) -> List[Dict[Optional[str], float]]:
-    """For each span of ``span_name`` in ``spans``: device-busy ns of the
-    operations that start inside it, by scope (None: under no scope)."""
-    out = []
-    for group in tr.events_inside(ops, spans, span_name):
-        by: Dict[Optional[str], list] = {}
-        for ev in group:
-            by.setdefault(scope_of(ev[3]), []).append(ev)
-        out.append({s: tr.busy_ns(evs) for s, evs in by.items()})
-    return out
+    """For each span of ``span_name`` in ``spans``: :func:`by_scope` of the
+    operations that start inside it."""
+    return [by_scope(group, scopes)
+            for group in tr.events_inside(ops, spans, span_name)]
 
 
 def scope_ms(rows: Sequence[Dict[Optional[str], float]],
@@ -309,26 +310,22 @@ def scope_ms(rows: Sequence[Dict[Optional[str], float]],
                        for row in rows if row], 50) / 1e6
 
 
-def unscoped_share(ops: Sequence[Op]) -> Optional[float]:
-    """Share (%) of the operations' device-busy time under no known scope;
-    None where nothing is scoped (a program without scopes)."""
-    bare = [ev for ev in ops if scope_of(ev[3]) is None]
-    if len(bare) == len(ops):
+def unscoped_share(ops: Sequence[Op], scopes: Tuple[str, ...] = SCOPES
+                   ) -> Optional[float]:
+    """Share (%) of the operations' device-busy time in which NO operation
+    under a scope of ``scopes`` ran; None where nothing is scoped (a program
+    without scopes). A ``while`` the trace shows under no scope envelops its
+    body's operations: the time of the envelope that no scoped body
+    operation covers (the loop's own turns) is unscoped, the body's is
+    not."""
+    scoped = [ev for ev in ops if scope_of(ev[3], scopes) is not None]
+    if not scoped:
         return None
-    return 100.0 * tr.busy_ns(bare) / tr.busy_ns(ops)
+    busy = tr.busy_ns(ops)
+    return 100.0 * (busy - tr.busy_ns(scoped)) / busy
 
 
-def idle_by_span(ops: Sequence[Op], spans: Sequence[tuple], t0: float,
-                 t1: float) -> Dict[str, float]:
-    """Device idle ns inside [t0, t1] by the innermost program span that
-    covers it; ``NO_SPAN`` where none does."""
-    rows = tr.idle_gaps(ops, t0, t1, spans, n=1 << 30)
-    return {name: s * 1e9 for name, s in rows}
-
-
-# -- what the per-layer readers call -----------------------------------------
-# eleven readers share three reductions of one trace and one window: each is
-# made once (the window is view.window, a pair of floats)
+# -- what chipbench/scopes.py and the timelines read a view's trace through --
 
 def _loaded(view) -> Optional[ProgramTrace]:
     path = view.record.get("trace_path")
@@ -341,45 +338,3 @@ def _loaded(view) -> Optional[ProgramTrace]:
 @functools.lru_cache(maxsize=2)
 def _window_ops(path: str, t0: float, t1: float) -> List[Op]:
     return tr.clip(load(path).ops[0], t0, t1)
-
-
-@functools.lru_cache(maxsize=4)
-def _scope_rows(path: str, span_name: str, t0: float, t1: float):
-    spans = spans_in(load(path).spans, span_name, t0, t1)
-    return busy_by_scope(_window_ops(path, t0, t1), spans, span_name)
-
-
-@functools.lru_cache(maxsize=2)
-def _idle(path: str, t0: float, t1: float) -> Dict[str, float]:
-    loaded = load(path)
-    return idle_by_span(loaded.ops[0], loaded.spans, t0, t1)
-
-
-def scope_ms_in(view, span_name: str, scopes: Sequence[str]
-                ) -> Optional[float]:
-    """A reader's whole body: device ms under ``scopes`` in the operations
-    that start inside a span of ``span_name``, median over the window's."""
-    if _loaded(view) is None:
-        return None
-    rows = _scope_rows(view.record["trace_path"], span_name, *view.window)
-    return scope_ms(rows, scopes) if rows else None
-
-
-def unscoped_share_in(view) -> Optional[float]:
-    if _loaded(view) is None:
-        return None
-    return unscoped_share(_window_ops(view.record["trace_path"],
-                                      *view.window))
-
-
-def idle_ms_per_step(view, names: Sequence[str]) -> Optional[float]:
-    """Device idle ms whose innermost program span is one of ``names``, over
-    the number of engine steps that start in the window."""
-    loaded = _loaded(view)
-    if loaded is None:
-        return None
-    steps = len(spans_in(loaded.spans, STEP, *view.window))
-    if not steps:
-        return None
-    idle = _idle(view.record["trace_path"], *view.window)
-    return sum(idle.get(n, 0.0) for n in names) / steps / 1e6

@@ -13,8 +13,10 @@ on earlier lines.
 
 Driven by data: a cell is an entry of ``workloads``; its configuration is
 ``configs[].file``, its traffic ``chipbench/traffic/<traffic>.json``, and
-each per-layer metric a reader ``chipbench/layer_metrics/<metric>.py``.
-Nothing here names a cell, a configuration or a metric.
+each per-layer metric a reader ``chipbench/layer_metrics/<metric>.py``,
+which is handed the configuration's family (its scope names and counting
+functions: ``chipbench/families/<model_type>.py``) with the view.
+Nothing here names a cell, a configuration, a family or a metric.
 """
 
 from __future__ import annotations
@@ -229,14 +231,17 @@ def run_cell(bench: dict, workload: str, cfg: dict, mix: dict, ctx: Ctx
 
 class TraceView:
     """What a per-layer metric's reader is given: the reduced trace, the
-    benchmark's host spans, the run record, the configuration, the traffic
-    mix and the chip's peaks."""
+    benchmark's host spans, the run record, the configuration with its
+    family (``families.of``: None where it names none), the traffic mix and
+    the chip's peaks."""
 
     def __init__(self, trace, record, cfg, mix, peaks, chips):
+        from chipbench import families
         from chipbench import trace_reduce as tr
 
         self.trace, self.record = trace, record
         self.cfg, self.mix, self.peaks, self.chips = cfg, mix, peaks, chips
+        self.family = families.of(cfg)
         self.tr = tr
         self.window = tr.window(trace) if trace is not None else None
         self.host_spans = tr.host_spans(trace) if trace is not None else []

@@ -33,3 +33,19 @@ def run(workload, cfg, mix, *, seed=2**31 + 77, seconds=1.5, chips=1,
                 controls=tuple(controls),
                 log=(lines.append if lines is not None else (lambda s: None)))
     return R.run_cell(BENCH, workload, cfg, mix, ctx)
+
+
+def clear_trace_caches():
+    """Between two hand-made traces under one path: what the readers cache
+    by path and window."""
+    from chipbench import program_trace as pt
+    from chipbench import scopes as sc
+
+    sc._scope_rows.cache_clear()
+    pt._window_ops.cache_clear()
+
+
+def readings_of(cell: str):
+    """The per-layer entries of BENCHMARK.json that list ``cell``."""
+    bench = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
+    return R.metrics_for(bench["per_layer"], cell)

@@ -3,7 +3,7 @@ where program and reference both compute true float32: the seeded weights
 are the same numbers, the served tokens are the reference's own best
 (through rings that wrap), the bfloat16-activation control reads far above
 the sound run, a broken timed path comes out not correct; and the
-arithmetic of ``flops_afmoe``, the scope groups of ``scopes_afmoe`` and the
+arithmetic of ``flops_afmoe``, the scope groups of ``families/afmoe`` and the
 readers on hand-made events."""
 
 import json
@@ -13,8 +13,9 @@ import jax
 import numpy as np
 import pytest
 
+from chipbench import families
 from chipbench import run as R
-from helpers import fixture, run
+from helpers import clear_trace_caches, fixture, readings_of, run
 
 CELL = "trinity-large-preview-serve.doc-turns"
 
@@ -180,34 +181,35 @@ def test_flops_afmoe_counts_the_published_block():
 
 def test_scopes_by_kind_are_read_and_a_scopeless_program_reads_none():
     from chipbench import program_trace as pt
-    from chipbench import scopes_afmoe as sc
+    from chipbench.families import afmoe as fam
 
     path = "jit(uccl_moe_verify_slots)/attn.gate.window/dot_general:"
     assert pt.scope_of(path) is None  # not among the first model's twelve
-    assert pt.scope_of(path, sc.SCOPES) == "attn.gate.window"
-    assert pt.scope_of("jit(f)/attn.qkv.full/mul:", sc.SCOPES) \
+    assert pt.scope_of(path, fam.SCOPES) == "attn.gate.window"
+    assert pt.scope_of("jit(f)/attn.qkv.full/mul:", fam.SCOPES) \
         == "attn.qkv.full"
-    assert pt.scope_of("jit(f)/ffn.post_norm/mul:", sc.SCOPES) \
+    assert pt.scope_of("jit(f)/ffn.post_norm/mul:", fam.SCOPES) \
         == "ffn.post_norm"
-    assert pt.scope_of("jit(f)/moe.shared/dot:", sc.SCOPES) == "moe.shared"
-    assert len(sc.SCOPES) == len(set(sc.SCOPES)) == 12 + 8 + 1 + 2 + 2
+    assert pt.scope_of("jit(f)/moe.shared/dot:", fam.SCOPES) == "moe.shared"
+    assert len(fam.SCOPES) == len(set(fam.SCOPES)) == 12 + 8 + 1 + 2 + 2
 
     class View:  # a traced run of a program without spans: no trace read
         record = {"trace_path": None, "e2e": {}, "compiles_in_window": 0}
         window = None
         cfg = published()
+        family = families.of(cfg)
         peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 
-    b = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
-    mine = [m for m in b["per_layer"] if m["name"].endswith(".doc-turns")]
-    assert len(mine) == 25
-    assert all(m["workloads"] == [CELL] for m in mine)
+    mine = readings_of(CELL)
+    assert {"decode_moe_shared_dev_ms", "prefill_expert_mxu_share",
+            "kv_pool_ring_share"} <= {m["name"] for m in mine}
     for m in mine:
         if m["name"].split(".")[0] in ("decode_step_dev_ms",
                                        "prefill_step_dev_ms"):
             continue  # these read the benchmark's own spans (a full view)
         got = R.load_reader(m["name"]).read(View)
         assert got is None or m["name"].startswith("compiles_in_window")
+    b = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
     for e in b["end_to_end"]:
         assert "workloads" not in e or CELL in e["workloads"]
 
@@ -215,7 +217,6 @@ def test_scopes_by_kind_are_read_and_a_scopeless_program_reads_none():
 def test_readers_on_hand_made_events(monkeypatch):
     from chipbench import flops_afmoe as f
     from chipbench import program_trace as pt
-    from chipbench import scopes_afmoe as sc
 
     ms = 1e6
     spans = [(pt.DECODE, 0.0, 12 * ms,
@@ -237,8 +238,7 @@ def test_readers_on_hand_made_events(monkeypatch):
            ("h", 41 * ms, 5 * ms, j + "moe.experts/dot_general:")]
     trace = pt.ProgramTrace(spans, [ops])
     monkeypatch.setattr(pt, "load", lambda path: trace)
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()
 
     class View:
         record = {"trace_path": "hand-made",
@@ -246,14 +246,17 @@ def test_readers_on_hand_made_events(monkeypatch):
                                     "window": 1107296256.0}}
         window = (0.0, 60 * ms)
         cfg = published()
+        family = families.of(cfg)
         peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 
     def read(name):
-        return R.load_reader(name + ".doc-turns").read(View)
+        return R.load_reader(name).read(View)
 
     assert read("decode_full_attention_dev_ms") == 3.0
     assert read("decode_window_attention_dev_ms") == 1.0
-    assert read("decode_attn_gate_dev_ms") == 0.25
+    # the gate's scopes count with their kind's attention (the reading of
+    # the gate alone is retired: the decode program's gate lies fused under
+    # ``attn.out.<kind>`` since PR 41)
     assert read("prefill_full_attention_dev_ms") == 20.0
     assert read("decode_moe_experts_dev_ms") == 3.0
     assert read("decode_moe_exchange_dev_ms") == 1.0
@@ -271,7 +274,6 @@ def test_readers_on_hand_made_events(monkeypatch):
         100 * f.decode_step_bytes(c, 8, 40000, 24000) / 819e9 / 9.75e-3)
     assert read("prefill_expert_mxu_share") == pytest.approx(
         100 * f.routed_expert_flops(c, 128) / 197e12 / 5e-3)
-    assert read("kv_pool_window_share") == pytest.approx(
+    assert read("kv_pool_ring_share") == pytest.approx(
         100 * 1107296256 / (1073741824 + 1107296256))
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()

@@ -1,5 +1,7 @@
-"""``decode_experts_read_share.*``: the program's own count of the experts a
+"""``decode_experts_read_share``: the program's own count of the experts a
 decode step read, off its ``uccl.ep.experts`` span."""
+
+import os
 
 import pytest
 
@@ -7,6 +9,9 @@ from chipbench import program_trace as pt
 from chipbench import run as R
 
 MS = 1e6
+NAME = "decode_experts_read_share"
+CELLS = [m for m in R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))[
+    "per_layer"] if m["name"] == NAME][0]["workloads"]
 
 
 def _view(spans, monkeypatch):
@@ -20,8 +25,10 @@ def _view(spans, monkeypatch):
     return View
 
 
-@pytest.mark.parametrize("cell", ["doc-turns", "long-short"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_share_is_the_median_over_the_windows_decode_spans(cell, monkeypatch):
+    """One reader for every cell listed: the span is the backend's, whatever
+    the family."""
     count = pt.PREFIX + "ep.experts"
     spans = [
         (pt.DECODE, 0.0, 10 * MS, {"n": 1}),
@@ -38,13 +45,13 @@ def test_share_is_the_median_over_the_windows_decode_spans(cell, monkeypatch):
         (count, 129 * MS, 0.001 * MS, {"experts_read": 128,
                                        "experts_held": 128}),
     ]
-    read = R.load_reader("decode_experts_read_share." + cell).read
+    read = R.load_reader(NAME).read
     assert read(_view(spans, monkeypatch)) == 100.0 * 8 / 128
 
 
 def test_a_program_that_reports_no_count_reads_none(monkeypatch):
     spans = [(pt.DECODE, 0.0, 10 * MS, {"n": 1, "kv_rows": 100})]
-    read = R.load_reader("decode_experts_read_share.doc-turns").read
+    read = R.load_reader(NAME).read
     assert read(_view(spans, monkeypatch)) is None
 
     class Untraced:

@@ -3,7 +3,7 @@ where program and reference both compute true float32: the seeded weights
 are the same numbers, the served tokens are the reference's own best, the
 bfloat16-activation control reads far above the sound run, a broken timed
 path comes out not correct; and the arithmetic of ``flops_glm4`` and the
-scope groups of ``scopes_glm4``."""
+scope groups of ``families/glm4_moe_lite``."""
 
 import json
 import os
@@ -12,8 +12,11 @@ import jax
 import numpy as np
 import pytest
 
+from chipbench import families
 from chipbench import run as R
-from helpers import fixture, run
+from helpers import clear_trace_caches, fixture, readings_of, run
+
+CELL = "glm-4.7-flash-serve.code-turns"
 
 
 @pytest.fixture(scope="module")
@@ -110,28 +113,27 @@ def test_flops_glm4_counts_the_published_block():
 
 def test_new_scopes_are_read_and_a_scopeless_program_reads_none():
     from chipbench import program_trace as pt
-    from chipbench import scopes_glm4 as sc
+    from chipbench.families import glm4_moe_lite as fam
 
     path = "jit(uccl_moe_verify_slots)/attn.latent_kv/dot_general:"
     assert pt.scope_of(path) is None  # not among the first model's twelve
-    assert pt.scope_of(path, sc.SCOPES) == "attn.latent_kv"
-    assert pt.scope_of("jit(f)/moe.shared/mul:", sc.SCOPES) == "moe.shared"
-    assert pt.scope_of("jit(f)/ffn.dense/dot:", sc.SCOPES) == "ffn.dense"
-    assert pt.scope_of("jit(f)/attn.core/dot:", sc.SCOPES) == "attn.core"
+    assert pt.scope_of(path, fam.SCOPES) == "attn.latent_kv"
+    assert pt.scope_of("jit(f)/moe.shared/mul:", fam.SCOPES) == "moe.shared"
+    assert pt.scope_of("jit(f)/ffn.dense/dot:", fam.SCOPES) == "ffn.dense"
+    assert pt.scope_of("jit(f)/attn.core/dot:", fam.SCOPES) == "attn.core"
 
     class View:  # a traced run of a program without spans: no trace read
         record = {"trace_path": None, "e2e": {}, "compiles_in_window": 0}
         window = None
         cfg = published()
+        family = families.of(cfg)
         peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 
-    b = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
-    mine = [m["name"] for m in b["per_layer"]
-            if m["name"].endswith(".code-turns")]
-    assert len(mine) == 20
+    mine = [m["name"] for m in readings_of(CELL)]
+    assert {"decode_latent_attention_dev_ms", "prefill_expert_mxu_share",
+            "decode_latent_attention_roofline_share"} <= set(mine)
     for name in mine:
         if name.split(".")[0] in ("decode_step_dev_ms", "prefill_step_dev_ms",
-                                  "decode_hbm_roofline_share",
                                   "device_idle_share"):
             continue  # these read the benchmark's own spans (a full view)
         got = R.load_reader(name).read(View)
@@ -140,7 +142,7 @@ def test_new_scopes_are_read_and_a_scopeless_program_reads_none():
 
 def test_scope_rows_and_the_roofline_readers_on_hand_made_events(monkeypatch):
     from chipbench import program_trace as pt
-    from chipbench import scopes_glm4 as sc
+    from chipbench.families import glm4_moe_lite as fam
 
     ms = 1e6
     spans = [(pt.DECODE, 0.0, 10 * ms, {"n": 2, "kv_rows": 4096}),
@@ -155,29 +157,33 @@ def test_scope_rows_and_the_roofline_readers_on_hand_made_events(monkeypatch):
            ("g", 41 * ms, 5 * ms, j + "ffn.dense/dot_general:")]
     trace = pt.ProgramTrace(spans, [ops])
     monkeypatch.setattr(pt, "load", lambda path: trace)
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()
 
     class View:
         record = {"trace_path": "hand-made"}
         window = (0.0, 60 * ms)
         cfg = published()
+        family = families.of(cfg)
         peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 
-    assert sc.scope_ms_in(View, pt.DECODE, sc.LATENT_ATTENTION) == 3.0
-    assert sc.scope_ms_in(View, pt.DECODE, sc.SHARED_DENSE) == 1.0
-    assert sc.scope_ms_in(View, pt.PREFILL, pt.MOE_EXPERTS) == 20.0
-    assert sc.unscoped_share_in(View) == pytest.approx(100 * 1 / 33)
-    share = R.load_reader(
-        "decode_latent_attention_roofline_share.code-turns").read(View)
+    def read(name):
+        return R.load_reader(name).read(View)
+
+    assert read("decode_latent_attention_dev_ms") == 3.0
+    assert read("decode_shared_dense_ffn_dev_ms") == 1.0
+    assert read("prefill_moe_experts_dev_ms") == 20.0
+    assert read("unscoped_dev_share") == pytest.approx(100 * 1 / 33)
+    # a group the family has not: no reading, nothing raised
+    assert read("decode_window_attention_dev_ms") is None
+    assert read("decode_conv_roofline_share") is None
+    share = read("decode_latent_attention_roofline_share")
     # 4096 rows x 576 x 4 B x 6 layers over 819 GB/s is 69 us of the 2 ms
     assert share == pytest.approx(100 * (4096 * 576 * 4 * 6 / 819e9) / 2e-3)
     # a prefill span without ``rows`` (a program before PR 28) is the pool's
-    mxu = R.load_reader("prefill_expert_mxu_share.code-turns").read(View)
+    mxu = R.load_reader("prefill_expert_mxu_share").read(View)
     assert mxu == pytest.approx(
         100 * (5 * 1024 * 4 * 6 * 2048 * 1536 / 197e12) / 20e-3)
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()
 
 
 def test_expert_mxu_share_takes_each_programs_rows_from_its_span(monkeypatch):
@@ -185,7 +191,7 @@ def test_expert_mxu_share_takes_each_programs_rows_from_its_span(monkeypatch):
     the rows its own span names, the median of the quotients (not the
     median time over the pool's rows, which read 24 % where 3 % was true)."""
     from chipbench import program_trace as pt
-    from chipbench import scopes_glm4 as sc
+    from chipbench.families import glm4_moe_lite as fam
 
     ms = 1e6
     j = "jit(uccl_moe_prefill_slots)/"
@@ -201,24 +207,23 @@ def test_expert_mxu_share_takes_each_programs_rows_from_its_span(monkeypatch):
     spans.append((pt.PREFILL, 500 * ms, 10 * ms, {"n": 1, "rows": 1}))  # empty
     trace = pt.ProgramTrace(spans, [ops])
     monkeypatch.setattr(pt, "load", lambda path: trace)
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()
 
     class View:
         record = {"trace_path": "hand-made"}
         window = (0.0, 600 * ms)
         cfg = published()
+        family = families.of(cfg)
         peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 
     def share(rows, experts_ms):
         flops = 5 * rows * 128 * 4 * 6 * 2048 * 1536
         return 100 * flops / 197e12 / (experts_ms / 1e3)
 
-    got = R.load_reader("prefill_expert_mxu_share.code-turns").read(View)
+    got = R.load_reader("prefill_expert_mxu_share").read(View)
     quotients = sorted(share(r, t) for r, t in programs)
     assert got == pytest.approx(quotients[2])
     assert got == pytest.approx(share(1, 8.0))  # 3.07 %: a one-row program
     assert 2.9 < got < 3.2
     assert share(8, 41.6) == pytest.approx(4.72, abs=0.01)  # PR 26's reading
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()

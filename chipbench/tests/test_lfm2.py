@@ -4,7 +4,7 @@ weights are the same numbers, the served tokens are the reference's own best
 (through conv rings that wrap, over slots re-admitted), the
 bfloat16-activation control reads far above the sound run, a broken timed
 path comes out not correct; and the arithmetic of ``flops_lfm2``, the scope
-groups of ``scopes_lfm2`` and the readers on hand-made events."""
+groups of ``families/lfm2_moe`` and the readers on hand-made events."""
 
 import json
 import os
@@ -13,8 +13,9 @@ import jax
 import numpy as np
 import pytest
 
+from chipbench import families
 from chipbench import run as R
-from helpers import fixture, run
+from helpers import clear_trace_caches, fixture, readings_of, run
 
 CELL = "lfm2-24b-a2b-serve.long-answers"
 
@@ -197,38 +198,37 @@ def test_flops_lfm2_counts_the_published_block():
 
 def test_scopes_by_kind_are_read_and_a_scopeless_program_reads_none():
     from chipbench import program_trace as pt
-    from chipbench import scopes_lfm2 as sc
+    from chipbench.families import lfm2_moe as fam
 
     path = "jit(uccl_moe_verify_slots)/conv.state/scatter:"
     assert pt.scope_of(path) is None  # not among the first model's twelve
-    assert pt.scope_of(path, sc.SCOPES) == "conv.state"
-    assert pt.scope_of("jit(f)/conv.mix/mul:", sc.SCOPES) == "conv.mix"
-    assert pt.scope_of("jit(f)/attn.qkv.full/mul:", sc.SCOPES) \
+    assert pt.scope_of(path, fam.SCOPES) == "conv.state"
+    assert pt.scope_of("jit(f)/conv.mix/mul:", fam.SCOPES) == "conv.mix"
+    assert pt.scope_of("jit(f)/attn.qkv.full/mul:", fam.SCOPES) \
         == "attn.qkv.full"
-    assert pt.scope_of("jit(f)/attn.core.window/dot:", sc.SCOPES) is None
-    assert len(sc.SCOPES) == len(set(sc.SCOPES)) == 12 + 4 + 4 + 1
+    assert pt.scope_of("jit(f)/attn.core.window/dot:", fam.SCOPES) is None
+    assert len(fam.SCOPES) == len(set(fam.SCOPES)) == 12 + 4 + 4 + 1
 
     class View:  # a traced run of a program without spans: no trace read
         record = {"trace_path": None, "e2e": {}, "compiles_in_window": 0}
         window = None
         cfg = published()
+        family = families.of(cfg)
         peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 
     b = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
-    mine = [m for m in b["per_layer"] if CELL in m.get("workloads", ())]
+    mine = readings_of(CELL)
     assert len(b["per_layer"]) <= 128  # the benchmark's cap
-    assert mine and all(m["name"].endswith(".long-answers") for m in mine)
-    assert all(m["workloads"] == [CELL] for m in mine)
-    assert {m["name"] for m in b["per_layer"]
-            if m["name"].endswith(".long-answers")} \
-        == {m["name"] for m in mine}
+    # the nine of PR 42 under their readings' names, and the conv layers'
+    # two times by program that had no room then
+    assert {"decode_step_dev_ms", "prefill_step_dev_ms",
+            "decode_experts_read_share", "decode_hbm_roofline_share",
+            "decode_conv_roofline_share",
+            "decode_full_attention_roofline_share",
+            "prefill_expert_mxu_share", "device_idle_share",
+            "unscoped_dev_share", "decode_conv_dev_ms",
+            "prefill_conv_dev_ms"} <= {m["name"] for m in mine}
     for m in mine:
-        # every reader imports scopes_lfm2 and nothing else
-        with open(os.path.join(R.HERE, "layer_metrics",
-                               m["name"] + ".py")) as f:
-            imports = [l for l in f.read().splitlines()
-                       if l.startswith(("import ", "from "))]
-        assert imports == ["from chipbench import scopes_lfm2 as sc"]
         if m["name"].split(".")[0] in ("decode_step_dev_ms",
                                        "prefill_step_dev_ms"):
             continue  # these read the benchmark's own spans (a full view)
@@ -243,11 +243,11 @@ def test_scopes_by_kind_are_read_and_a_scopeless_program_reads_none():
 def test_readers_on_hand_made_events(monkeypatch):
     from chipbench import flops_lfm2 as f
     from chipbench import program_trace as pt
-    from chipbench import scopes_lfm2 as sc
+    from chipbench.experts_read import EXPERTS
 
     ms = 1e6
     spans = [(pt.DECODE, 0.0, 12 * ms, {"n": 12, "kv_rows": 9000}),
-             (sc.EXPERTS, 11 * ms, 0.0,
+             (EXPERTS, 11 * ms, 0.0,
               {"experts_read": 280, "experts_held": 512}),
              (pt.PREFILL, 20 * ms, 30 * ms, {"n": 1, "rows": 1,
                                              "chunk": 64})]
@@ -270,27 +270,28 @@ def test_readers_on_hand_made_events(monkeypatch):
            ("h", 43 * ms, 5 * ms, j + "moe.experts/dot_general:")]
     trace = pt.ProgramTrace(spans, [ops])
     monkeypatch.setattr(pt, "load", lambda path: trace)
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()
 
     class View:
         record = {"trace_path": "hand-made"}
         window = (0.0, 60 * ms)
         cfg = published()
+        family = families.of(cfg)
         peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 
     def read(name):
-        return R.load_reader(name + ".long-answers").read(View)
+        return R.load_reader(name).read(View)
 
-    # device ms by scope group, as the readers' bodies sum them
-    for span, scopes, want in ((sc.DECODE, sc.ATTENTION, 3.0),
-                               (sc.DECODE, sc.CONV, 1.0),
-                               (sc.PREFILL, sc.CONV, 2.0),
-                               (sc.PREFILL, sc.ATTENTION, 20.0),
-                               (sc.DECODE, sc.MOE_EXPERTS, 3.0),
-                               (sc.DECODE, sc.MOE_EXCHANGE, 1.0),
-                               (sc.PREFILL, sc.MOE_EXPERTS, 5.0)):
-        assert sc.scope_ms_in(View, span, scopes) == want
+    # device ms by scope group
+    for name, want in (("decode_full_attention_dev_ms", 3.0),
+                       ("decode_conv_dev_ms", 1.0),
+                       ("prefill_conv_dev_ms", 2.0),
+                       ("prefill_full_attention_dev_ms", 20.0),
+                       ("decode_moe_experts_dev_ms", 3.0),
+                       ("decode_moe_exchange_dev_ms", 1.0),
+                       ("prefill_moe_experts_dev_ms", 5.0)):
+        assert read(name) == want
+    assert read("decode_window_attention_dev_ms") is None  # no such group
     assert read("unscoped_dev_share") == pytest.approx(100 * 1 / 36.5)
     assert read("decode_experts_read_share") == pytest.approx(
         100 * 280 / 512)
@@ -308,9 +309,8 @@ def test_readers_on_hand_made_events(monkeypatch):
         100 * f.routed_expert_flops(c, 64) / 197e12 / 5e-3)
     # a program that reports no count: the whole step's share is not read,
     # the others are
-    trace.spans[:] = [sp for sp in spans if sp[0] != sc.EXPERTS]
-    sc._scope_rows.cache_clear()
+    trace.spans[:] = [sp for sp in spans if sp[0] != EXPERTS]
+    clear_trace_caches()
     assert read("decode_hbm_roofline_share") is None
     assert read("decode_conv_roofline_share") is not None
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()

@@ -3,7 +3,7 @@ where program and reference both compute true float32: the seeded weights
 are the same numbers, the served tokens are the reference's own best
 (through rings that wrap), the bfloat16-activation control reads far above
 the sound run, a broken timed path comes out not correct; and the
-arithmetic of ``flops_mimo``, the scope groups of ``scopes_mimo`` and the
+arithmetic of ``flops_mimo``, the scope groups of ``families/mimo_v2_flash`` and the
 readers on hand-made events."""
 
 import json
@@ -13,8 +13,11 @@ import jax
 import numpy as np
 import pytest
 
+from chipbench import families
 from chipbench import run as R
-from helpers import fixture, run
+from helpers import clear_trace_caches, fixture, readings_of, run
+
+CELL = "mimo-v2-flash-serve.long-short"
 
 
 @pytest.fixture(scope="module")
@@ -145,28 +148,27 @@ def test_flops_mimo_counts_the_published_block():
 
 def test_scopes_by_kind_are_read_and_a_scopeless_program_reads_none():
     from chipbench import program_trace as pt
-    from chipbench import scopes_mimo as sc
+    from chipbench.families import mimo_v2_flash as fam
 
     path = "jit(uccl_moe_verify_slots)/attn.core.window/dot_general:"
     assert pt.scope_of(path) is None  # not among the first model's twelve
-    assert pt.scope_of(path, sc.SCOPES) == "attn.core.window"
-    assert pt.scope_of("jit(f)/attn.qkv.full/mul:", sc.SCOPES) \
+    assert pt.scope_of(path, fam.SCOPES) == "attn.core.window"
+    assert pt.scope_of("jit(f)/attn.qkv.full/mul:", fam.SCOPES) \
         == "attn.qkv.full"
-    assert pt.scope_of("jit(f)/ffn.dense/dot:", sc.SCOPES) == "ffn.dense"
-    assert pt.scope_of("jit(f)/moe.experts/dot:", sc.SCOPES) == "moe.experts"
-    assert len(sc.SCOPES) == 12 + 8 + 1
+    assert pt.scope_of("jit(f)/ffn.dense/dot:", fam.SCOPES) == "ffn.dense"
+    assert pt.scope_of("jit(f)/moe.experts/dot:", fam.SCOPES) == "moe.experts"
+    assert len(fam.SCOPES) == 12 + 8 + 1
 
     class View:  # a traced run of a program without spans: no trace read
         record = {"trace_path": None, "e2e": {}, "compiles_in_window": 0}
         window = None
         cfg = published()
+        family = families.of(cfg)
         peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 
-    b = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
-    mine = [m for m in b["per_layer"] if m["name"].endswith(".long-short")]
-    assert len(mine) == 22
-    assert all(m["workloads"] == ["mimo-v2-flash-serve.long-short"]
-               for m in mine)
+    mine = readings_of(CELL)
+    assert {"decode_window_attention_roofline_share", "kv_pool_ring_share",
+            "prefill_full_attention_dev_ms"} <= {m["name"] for m in mine}
     for m in mine:
         if m["name"].split(".")[0] in ("decode_step_dev_ms",
                                        "prefill_step_dev_ms"):
@@ -178,7 +180,6 @@ def test_scopes_by_kind_are_read_and_a_scopeless_program_reads_none():
 def test_readers_on_hand_made_events(monkeypatch):
     from chipbench import flops_mimo as f
     from chipbench import program_trace as pt
-    from chipbench import scopes_mimo as sc
 
     ms = 1e6
     spans = [(pt.DECODE, 0.0, 12 * ms, {"n": 8, "kv_rows": 24000}),
@@ -195,8 +196,7 @@ def test_readers_on_hand_made_events(monkeypatch):
            ("h", 41 * ms, 5 * ms, j + "ffn.dense/dot_general:")]
     trace = pt.ProgramTrace(spans, [ops])
     monkeypatch.setattr(pt, "load", lambda path: trace)
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()
 
     class View:
         record = {"trace_path": "hand-made",
@@ -204,10 +204,11 @@ def test_readers_on_hand_made_events(monkeypatch):
                                     "window": 104857600.0}}
         window = (0.0, 60 * ms)
         cfg = published()
+        family = families.of(cfg)
         peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 
     def read(name):
-        return R.load_reader(name + ".long-short").read(View)
+        return R.load_reader(name).read(View)
 
     assert read("decode_full_attention_dev_ms") == 3.0
     assert read("decode_window_attention_dev_ms") == 0.75
@@ -221,9 +222,9 @@ def test_readers_on_hand_made_events(monkeypatch):
     assert read("decode_window_attention_roofline_share") == pytest.approx(
         100 * (4 * 5 * 8 * 128 * 2560 / 819e9) / 0.75e-3)
     # the whole program's 8.75 ms of operations against every byte it must read
+    assert read("decode_conv_dev_ms") is None  # no such group in this family
     assert read("decode_hbm_roofline_share") == pytest.approx(
         100 * f.decode_step_bytes(c, 8, 24000) / 819e9 / 8.75e-3)
-    assert read("kv_pool_window_share") == pytest.approx(
+    assert read("kv_pool_ring_share") == pytest.approx(
         100 * 104857600 / (1342177280 + 104857600))
-    sc._scope_rows.cache_clear()
-    pt._window_ops.cache_clear()
+    clear_trace_caches()

@@ -1,4 +1,5 @@
-"""``program_trace.py`` and the eleven readers over it, on a hand-made
+"""``program_trace.py`` and the readers over it (through ``scopes.py`` and the
+first model's family), on a hand-made
 trace: an XSpace encoded here field by field (one chip, one host thread),
 so the wire parser, the clock alignment, the scope matching and every
 reader's arithmetic are checked against numbers worked out by hand."""
@@ -8,6 +9,7 @@ import os
 
 import pytest
 
+from chipbench import flops
 from chipbench import program_trace as pt
 from chipbench import run as R
 from chipbench import trace_reduce as tr
@@ -252,47 +254,54 @@ def test_busy_by_scope_inside_one_span_name(trace_path):
     assert pt.unscoped_share(ops) == 100.0 * (3 + 3 + 3) / 80
 
 
-def test_idle_goes_to_the_innermost_program_span(trace_path):
-    t = _aligned(trace_path)
-    idle = pt.idle_by_span(t.ops[0], t.spans, 0, 200 * MS)
-    ms = {k: v / MS for k, v in idle.items()}
-    # busy: [17,57) [65,85) [105,125) of the window's 200 ms
-    assert ms == {
-        pt.NO_SPAN: 10 + 10 + 70,                 # [0,10) [90,100) [130,200)
-        "uccl.engine.admit": 2 + 1,
-        "uccl.wire.prefill": 1 + 1,               # [12,13) [59,60)
-        "uccl.backend.stage": 2 + 1 + 1,
-        "uccl.backend.launch": 2 + 2 + 2,         # until each program starts
-        "uccl.backend.fetch": 2 + 2 + 2,          # after each program ends
-        "uccl.engine.retire": 1 + 1 + 1,
-        "uccl.wire.decode": 1 + 1 + 1 + 1,        # [61,62) [87,88) [101,102) [127,128)
-        "uccl.engine.step": 1 + 1,                # [89,90) [129,130)
-    }
-    # acceptance (b): everything adds up to the window's idle time
-    assert sum(ms.values()) == 200 - 80
+def test_a_loops_envelope_is_unscoped_only_where_no_scoped_body_covers_it():
+    """The decode program's expert loop is a ``while`` the trace shows under
+    no scope, around its body's operations, which carry ``moe.experts``:
+    the union forms count the body once and the envelope's own turns as
+    unscoped."""
+    j = "jit(p)/"
+    ops = _ms([("%a = qkv", 0, 2, j + "attn.qkv/dot:"),
+               ("%while.1 = while", 2, 6, ""),            # [2, 8)
+               ("%b = experts", 2, 2, j + "moe.experts/dot:"),
+               ("%c = experts", 5, 2, j + "moe.experts/dot:"),  # gap [4, 5)
+               ("%copy.2 = copy", 8, 2, "")])
+    # busy 10 ms; scoped 2 + 2 + 2; unscoped: the loop's turn [4, 5), its
+    # tail [7, 8) and the copy
+    assert pt.unscoped_share(ops) == pytest.approx(100.0 * 4 / 10)
+    spans = _ms([(pt.DECODE, 0, 12, {"n": 1, "kv_rows": 5})])
+    (row,) = pt.busy_by_scope(ops, spans, pt.DECODE)
+    assert row == {"attn.qkv": 2 * MS, None: 8 * MS, "moe.experts": 4 * MS}
+    assert sum(row.values()) == 14 * MS  # what a sum over scopes would say
+    assert tr.busy_ns(ops) == 10 * MS  # what the union says
+    # a family's own scope list decides what is scoped
+    assert pt.unscoped_share(ops, ("attn.qkv",)) \
+        == pytest.approx(100.0 * 8 / 10)
+    assert pt.unscoped_share(ops, ("conv.mix",)) is None
 
 
 @pytest.fixture(scope="module")
 def view(trace_path):
     trace = tr.load_xplane(trace_path)
-    return R.TraceView(trace, {"trace_path": trace_path}, {}, {}, {}, 1)
+    return R.TraceView(trace, {"trace_path": trace_path}, MIXTRAL, {},
+                       PEAKS, 1)
 
 
+MIXTRAL = R.load_json(os.path.join(R.HERE, "configs",
+                                   "mixtral-8x7b-serve.json"))
+PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}
 EXPECTED = {
-    "decode_moe_experts_dev_ms.chat": 8.0,
-    "decode_moe_exchange_dev_ms.chat": 1.0,
-    "decode_attention_dev_ms.chat": 7.0,
-    "prefill_moe_experts_dev_ms.chat": 20.0,
-    "prefill_moe_exchange_dev_ms.chat": 3.0,
-    "prefill_attention_dev_ms.chat": 12.0,
-    "unscoped_dev_share.chat": 100.0 * 9 / 80,
-    # the loaded lead is 1.1 ms for a true 1.0: every program's start and
-    # end sit 0.1 ms late, which moves 0.1 ms per program from fetch to
-    # launch; two steps in the window
-    "idle_in_stage_ms_per_step.chat": 4 / 2,
-    "idle_in_launch_ms_per_step.chat": (6 + 0.3) / 2,
-    "idle_in_fetch_ms_per_step.chat": (6 - 0.3) / 2,
-    "idle_in_engine_ms_per_step.chat": (3 + 2 + 3 + 4 + 2) / 2,
+    "decode_moe_experts_dev_ms": 8.0,
+    "decode_moe_exchange_dev_ms": 1.0,
+    "decode_full_attention_dev_ms": 7.0,
+    "prefill_moe_experts_dev_ms": 20.0,
+    "prefill_moe_exchange_dev_ms": 3.0,
+    "prefill_full_attention_dev_ms": 12.0,
+    "unscoped_dev_share": 100.0 * 9 / 80,
+    # one row decoding reaches 2 of 8 experts; the program's 20 ms as one
+    # union, its two spans alike but for one cached row
+    "decode_hbm_roofline_share": 100.0 * (
+        flops.decode_step_bytes(MIXTRAL, 2.0, 300)
+        + flops.decode_step_bytes(MIXTRAL, 2.0, 301)) / 2 / 819e9 / 20e-3,
 }
 
 
@@ -301,12 +310,19 @@ def test_reader(view, metric):
     assert R.load_reader(metric).read(view) == pytest.approx(EXPECTED[metric])
 
 
-def test_every_new_metric_has_an_expectation_here_and_none_raises_on_a_parent():
+def test_every_scoped_reading_of_the_first_cell_is_here_and_none_raises_on_a_parent():
     bench = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
-    mine = [m["name"] for m in bench["per_layer"]
-            if "program_trace" in open(os.path.join(
-                R.HERE, "layer_metrics", m["name"] + ".py")).read()]
-    assert sorted(mine) == sorted(EXPECTED)
+    def scoped(name):  # a reader over ``chipbench/scopes.py``
+        with open(os.path.join(R.HERE, "layer_metrics", name + ".py")) as f:
+            return "chipbench import scopes" in f.read().replace(
+                "chipbench.scopes import", "chipbench import scopes")
+
+    mine = [m["name"] for m in R.metrics_for(
+        bench["per_layer"], "mixtral-8x7b-serve.chat") if scoped(m["name"])]
+    # the two programs' times by the benchmark's own spans are the
+    # fixture-trace test's (test_trace_reduce.py)
+    assert sorted(set(mine) - {"decode_step_dev_ms", "prefill_step_dev_ms"}) \
+        == sorted(EXPECTED)
     # a program without spans (the parent): every reader gives None
     plane = Plane("/device:TPU:0")
     plane.line("XLA Ops", _ms([("%fusion.1 = x", 5, 10, {}, "jit(f)/dot:")]))
@@ -318,9 +334,9 @@ def test_every_new_metric_has_an_expectation_here_and_none_raises_on_a_parent():
         path = os.path.join(d, "p.xplane.pb")
         with open(path, "wb") as f:
             f.write(plane.encode() + host.encode())
-        v = R.TraceView(tr.load_xplane(path), {"trace_path": path}, {}, {},
-                        {}, 1)
+        v = R.TraceView(tr.load_xplane(path), {"trace_path": path}, MIXTRAL,
+                        {}, PEAKS, 1)
         assert [R.load_reader(m).read(v) for m in mine] == [None] * len(mine)
-    v = R.TraceView(None, {"trace_path": None}, {}, {}, {}, 1)
+    v = R.TraceView(None, {"trace_path": None}, MIXTRAL, {}, PEAKS, 1)
     assert [R.load_reader(m).read(v) for m in mine] == [None] * len(mine)
     json.dumps(EXPECTED)

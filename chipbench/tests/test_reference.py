@@ -197,7 +197,7 @@ def test_steps_a_busy_host_delays_move_the_95th_percentile_gap_not_the_90th():
     class View:
         record = {"e2e": busy}
 
-    assert R.load_reader("itl_p95_ms.chat").read(View) == busy["itl_p95_ms"]
+    assert R.load_reader("itl_p95_ms").read(View) == busy["itl_p95_ms"]
 
 
 def test_an_altered_token_is_not_correct(serve_cfg, monkeypatch):

@@ -106,7 +106,7 @@ def test_engine_host_time_counts_the_windows_steps_alone(chat):
     # the measured window closes as the seventh step starts: three steps of
     # the fixture (and the seventh) lie in the drain
     view.window = (view.window[0], steps[6][1])
-    read = R.load_reader("engine_host_ms_per_step.chat").read
+    read = R.load_reader("engine_host_ms_per_step").read
     got = read(view)
     inside = tr.busy_per_span(view.ops(0), steps[:6], "chipbench.engine_step")
     assert got == pytest.approx(

@@ -1,51 +1,53 @@
-"""Device time by scope, per program, under a configuration's OWN scope list:
+"""Device time by scope, per program, under a family's own scope list:
 
-    python -m chipbench.tools.scope_table <xplane.pb> <scopes module> [rows]
+    python -m chipbench.tools.scope_table <xplane.pb> <model_type>
 
-``scope_report`` reads the first model's twelve scopes; a configuration
-whose programs carry others names them in a module beside it
-(``chipbench.scopes_glm4``, ``chipbench.scopes_mimo``: its ``SCOPES``). One
-JSON object: for the decode programs and for the prefill programs by their
-``rows`` argument, the number of spans and the median device ms under each
-scope (``None``: under none), their sum, and the largest operations under no
-scope. By hand, to see where a program's time lies before PERF.md says so."""
+``<model_type>`` names the family (``chipbench/families/<model_type>.py``:
+its ``SCOPES``), as a configuration's file does. One JSON object: for the
+decode programs and for the prefill programs by their ``rows`` argument, the
+number of spans and the median device ms under each scope (``None``: under
+none), their sum, the span's operations as one union, and the largest
+operations under no scope. By hand, to see where a program's time lies
+before PERF.md says so."""
 
-import importlib
 import json
 import sys
 
+from chipbench import families
 from chipbench import program_trace as pt
 from chipbench import trace_reduce as tr
 from chipbench.stats import percentile
 
 
-def table(path: str, module: str) -> dict:
-    scopes = importlib.import_module(module).SCOPES
+def table(path: str, model_type: str) -> dict:
+    scopes = tuple(families.named(model_type).SCOPES)
     win = tr.window(tr.load_xplane(path, everything=True))
     loaded = pt.load(path)
     ops = tr.clip(loaded.ops[0], *win)
     out = {"window_s": (win[1] - win[0]) / 1e9,
-           "busy_s": tr.busy_ns(ops) / 1e9, "programs": {}}
+           "busy_s": tr.busy_ns(ops) / 1e9,
+           "unscoped_share_pct": pt.unscoped_share(ops, scopes),
+           "programs": {}}
     for label, span in (("decode", pt.DECODE), ("prefill", pt.PREFILL)):
         spans = pt.spans_in(loaded.spans, span, *win)
         groups = {}
         for sp, evs in zip(spans, tr.events_inside(ops, spans, span)):
-            by = {}
-            for ev in evs:
-                by.setdefault(str(pt.scope_of(ev[3], scopes)), []).append(ev)
-            if by:
+            if evs:
                 rows = (sp[3] if len(sp) > 3 else {}).get("rows", "")
                 groups.setdefault(f"{label}{rows}", []).append(
-                    {s: tr.busy_ns(e) for s, e in by.items()})
+                    ({str(s): ns for s, ns in
+                      pt.by_scope(evs, scopes).items()}, tr.busy_ns(evs)))
         for name, rows in groups.items():
-            names = sorted({s for r in rows for s in r})
+            names = sorted({s for by, _ in rows for s in by})
             out["programs"][name] = {
                 "spans": len(rows),
                 "by_scope_ms": {s: round(percentile(
-                    [r.get(s, 0.0) for r in rows], 50) / 1e6, 4)
+                    [by.get(s, 0.0) for by, _ in rows], 50) / 1e6, 4)
                     for s in names},
                 "sum_ms": round(percentile(
-                    [sum(r.values()) for r in rows], 50) / 1e6, 4)}
+                    [sum(by.values()) for by, _ in rows], 50) / 1e6, 4),
+                "union_ms": round(percentile(
+                    [busy for _, busy in rows], 50) / 1e6, 4)}
     bare = {}
     for ev in ops:
         if pt.scope_of(ev[3], scopes) is None:

@@ -16,9 +16,8 @@ the first-token shares with the share of any other program and the
 of 100;
 (c) the lead's bounds from the runtime's events and from the calls as
 ``step_timeline`` takes them, and the strict ones ``program_trace.load``
-applied (empty: it fell back to a lead of 0), beside the ``idle_in_launch`` /
-``idle_in_fetch`` of the same trace, which trade by that fallback. By hand,
-after a traced run."""
+applied (empty: it fell back to a lead of 0). By hand, after a traced
+run."""
 
 import json
 import sys
@@ -85,15 +84,6 @@ def report(path: str) -> dict:
     out["first_token_waits"] = len(waits)
     out["first_token_shares_pct"] = None if t.runs is None \
         else rt.wait_shares(waits, t.runs, t.busy)
-    idle = pt.idle_by_span(loaded.ops[0], loaded.spans, *win)
-    steps = len(pt.spans_in(loaded.spans, pt.STEP, *win))
-    out["steps"] = steps
-    out["idle_in_ms_per_step"] = {
-        name: sum(idle.get(n, 0.0) for n in group) / steps / 1e6
-        for name, group in (("stage", pt.IDLE_STAGE),
-                            ("launch", pt.IDLE_LAUNCH),
-                            ("fetch", pt.IDLE_FETCH),
-                            ("engine", pt.IDLE_ENGINE))} if steps else None
     return out
 
 

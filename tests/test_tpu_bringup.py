@@ -242,3 +242,65 @@ def test_decode_program_reads_stacked_cache_groups_where_they_lie(v5e):
     full_v = slots * max_seq * cfg.kv_heads("full") * cfg.v_head_dim
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries < 2 * full_v, (temporaries, 2 * full_v)
+
+
+def test_slot_programs_advance_a_state_group_in_place(v5e):
+    """The decode and the pool-wide prefill program of a description whose
+    layers keep a STATE with no position axis (two retention layers at
+    Brumby's head sizes: 8 KV heads of 9,216 features x 128 a slot, 4 and 16
+    slots), compiled for the v5e: each layer's state is its own array,
+    replaced in the donated buffer it came in, so no operation copies a
+    layer's states and the temporaries stay under ONE layer's. One stacked
+    array a group made the compiler copy the whole group in and out (4.5 GB
+    of temporaries at 8 layers x 16 slots: PERF.md section 6, PR 45)."""
+    from uccl_tpu.models.moe_inference import (
+        MoEServeConfig, MoEServer, MoESlotCache, init_params,
+    )
+
+    cfg = MoEServeConfig(
+        vocab=1024, dim=512, n_layers=2, n_heads=40, n_kv_heads=8,
+        head_dim=128, rope_theta=1e6, moe_experts=0, moe_topk=0, moe_ffn=0,
+        layer_kinds=("retention",) * 2, qk_norm=True, first_k_dense=2,
+        dense_ffn=1024, param_dtype="bfloat16")
+    srv = MoEServer(cfg, v5e)
+    chip = NamedSharding(v5e, P())
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    placed = described(jax.eval_shape(
+        lambda key: srv.shard_params(init_params(key, cfg)),
+        jax.random.PRNGKey(0)))
+
+    def decode(p, tok, act, k, v, ln):
+        return srv.decode_step_slots(p, tok, act, MoESlotCache(k, v, ln),
+                                     impl="sort")
+
+    def prefill(p, tok, lens, mask, k, v, ln):
+        return srv.prefill_slots(p, tok, lens, mask, MoESlotCache(k, v, ln))
+
+    for slots, program in ((4, "decode"), (16, "prefill")):
+        pool = described(jax.eval_shape(
+            lambda: MoESlotCache.empty(cfg, 1, slots, 32768)))
+        layer = slots * 8 * 9216 * 128 * 4  # one layer's S of every slot
+        assert [a.shape for a in pool.k["retention"]] \
+            == [(1, slots, 8, 9216, 128)] * 2
+        if program == "decode":
+            compiled = jax.jit(decode, donate_argnums=(3, 4, 5)).lower(
+                placed, arg((1, slots), jnp.int32),
+                arg((1, slots), jnp.bool_), *pool).compile()
+        else:  # [16, 128]: the rows go one at a time, each where it lies
+            compiled = jax.jit(prefill, donate_argnums=(4, 5, 6)).lower(
+                placed, arg((1, slots, 128), jnp.int32),
+                arg((1, slots), jnp.int32), arg((1, slots), jnp.bool_),
+                *pool).compile()
+        moved = [(name, opcode, dtype, n) for name, opcode, dtype, n
+                 in _entry_results(compiled.as_text())
+                 if n * 4 >= layer and opcode == "copy"]
+        assert not moved, (program, moved)
+        temporaries = compiled.memory_analysis().temp_size_in_bytes
+        assert temporaries < layer, (program, temporaries, layer)

@@ -144,9 +144,10 @@ def _attend_latent(q_lat, q_rope, ckv_cache, kr_cache, length, scale):
 
 def layer_kinds(cfg) -> Tuple[str, ...]:
     """The per-layer operator kinds ("full" | "window" attention, "conv":
-    a gated short convolution) of a description whose layers differ
-    (``cfg.layer_kinds``); empty where every layer is the same block (the
-    dense stack, Mixtral, the latent family)."""
+    a gated short convolution, "retention": power retention) of a
+    description that names them (``cfg.layer_kinds``); empty where every
+    layer is the same attention block (the dense stack, Mixtral, the latent
+    family)."""
     return tuple(getattr(cfg, "layer_kinds", ()) or ())
 
 
@@ -166,15 +167,18 @@ def cache_groups(cfg):
     layer. Layers of one attention kind share one stacked cache array (a
     GROUP: its own row shape and, in the slot pool, its own number of rows
     a slot — all positions for "full", a ring for "window" and "conv":
-    :data:`RING_GROUPS`). A description without layer kinds has the one
-    group ``None``: the plain stacked array."""
+    :data:`RING_GROUPS`; a "retention" group has NO position axis, a slot's
+    state being one matrix a layer: :data:`STATE_GROUPS`). A description
+    without layer kinds has the one group ``None``: the plain stacked
+    array."""
     return indexed_groups(layer_kinds(cfg) or [None] * cfg.n_layers)
 
 
 def group_array(pool, group):
     """A cache group's array of ``pool``: the plain stacked array (group
     ``None``) or ``pool[group]`` — ``None`` where the group keeps no such
-    array (a conv group has no value rows: :func:`kv_row_shapes`)."""
+    array (a conv group has no value rows: :func:`kv_row_shapes`), a tuple
+    of arrays, one a layer, for a state group (:data:`STATE_GROUPS`)."""
     return pool if group is None else pool.get(group)
 
 
@@ -198,6 +202,44 @@ def _with_group(pool, group, new):
 # before anything reads it, and what they overwrote was already out of reach.
 RING_GROUPS = {"window": "window", "conv": "taps"}
 
+# The cache groups that hold a STATE, not rows by position: a slot's array
+# of such a group has no position axis (a "retention" layer's ``S [Hkv, F,
+# Dv]`` in ``k`` and ``z [Hkv, F]`` in ``v``: :func:`_power_retention`), and
+# the group is a TUPLE of arrays ``[B, *state]``, one a layer, not one
+# stacked array: a layer's whole state is read, advanced and replaced, and
+# a program handed its pool donated does that in the layer's own buffer,
+# where the update of one layer of a stacked array made the compiler copy
+# the whole group in and out (4.5 GB at Brumby's size on a described v5e).
+# THE invariant: a state group's slot holds the state AT the slot's length;
+# a call advances it by its real positions and nothing rolls it back. So a
+# call is told how many of a row's positions are real (``valid``:
+# :func:`_forward_slots`), a call that starts at position 0 reads a zero
+# state whatever the slot holds, and what needs a position's rows back
+# (:data:`STATE_GROUPS_STAY`) is refused until slots keep snapshots.
+STATE_GROUPS = ("retention",)
+
+
+def has_state_group(cfg) -> bool:
+    """Whether a layer of the description keeps a state group."""
+    return any(kind in STATE_GROUPS for kind in layer_kinds(cfg))
+
+
+def _ret_block(d: int) -> int:
+    """A head's block width in the feature map of :func:`_phi`: 16 where
+    the head's size is a multiple of 32 (128: 8 blocks, 36 block pairs),
+    else two halves (a tiny head of 8: 2 blocks of 4)."""
+    return 16 if d % 32 == 0 else max(d // 2, 1) if d % 2 == 0 else d
+
+
+def retention_features(d: int) -> int:
+    """Numbers :func:`_phi` makes of a head of ``d``: every pair of blocks
+    (a <= b) as a full ``block x block`` outer product — 9,216 of a head of
+    128 (the 8,256 distinct monomials, with each diagonal block's
+    off-diagonal products held twice)."""
+    blk = _ret_block(d)
+    n = d // blk
+    return blk * blk * n * (n + 1) // 2
+
 
 def kv_row_shapes(cfg, kind=None):
     """Per-position shapes of the two cache arrays of one layer, from the
@@ -217,11 +259,18 @@ def kv_row_shapes(cfg, kind=None):
     again, and the compiler makes a bfloat16 slice and a copy of the whole
     layer for it every step (PERF.md section 6, PR 41). A "conv" layer's
     position is ONE row, the ``dim`` numbers of its gated input ``y = b *
-    u`` (:func:`_short_conv`), and it keeps no second array: ``None``."""
+    u`` (:func:`_short_conv`), and it keeps no second array: ``None``. A
+    "retention" layer keeps no position at all: the two shapes are its
+    slot's STATE, ``S [Hkv, F, Dv]`` and ``z [Hkv, F]`` (:data:`STATE_GROUPS`:
+    one array a layer; :func:`retention_features`)."""
     if getattr(cfg, "attn", "gqa") == "mla":
         return (cfg.kv_lora_rank,), (cfg.qk_rope_dim,)
     if kind == "conv":
         return (cfg.dim,), None
+    if kind == "retention":
+        feats = retention_features(cfg.head_dim)
+        return (cfg.n_kv_heads, feats, cfg.v_head_dim or cfg.head_dim), \
+            (cfg.n_kv_heads, feats)
     if layer_kinds(cfg):
         hkv = cfg.kv_heads(kind)
         return (hkv * cfg.head_dim,), \
@@ -236,14 +285,29 @@ RING_GROUPS_STAY = (
     "peer as a prefix: ")
 
 
+STATE_GROUPS_STAY = (
+    "a pool with a state group keeps ONE state a slot and layer, the state "
+    "AT the slot's length (a retention layer's matrix), and no row by "
+    "position, so nothing can hand a prefix of it to another slot, a tier "
+    "or a peer, or roll it back, until slots keep snapshots: ")
+
+
+def groups_stay(kinds) -> str:
+    """The sentence that says why a pool of these layer kinds keeps its
+    rows to itself."""
+    return STATE_GROUPS_STAY if set(kinds) & set(STATE_GROUPS) \
+        else RING_GROUPS_STAY
+
+
 def kv_wire_dims(cfg):
     """(heads, width) of the two EQUAL arrays one layer's cached position
     leaves a pool as (``export_rows``): gqa's own ``[Hkv, D]``; a latent
     row's two halves, ``[1, (kv_lora_rank + qk_rope_dim) / 2]`` each. A
     pool of cache groups (layer kinds) has no one row to put on a wire."""
     if layer_kinds(cfg):
-        raise ValueError(RING_GROUPS_STAY + "the disaggregated wire "
-                         "format describes one row shape for every layer")
+        raise ValueError(groups_stay(layer_kinds(cfg)) + "the disaggregated "
+                         "wire format describes one row shape for every "
+                         "layer")
     k_row, v_row = kv_row_shapes(cfg)
     if k_row == v_row:
         return k_row
@@ -330,6 +394,33 @@ def _mla_attention(x, lp, ckv_pool, kr_pool, positions, length, write,
 _SCORES_AT_ONCE = 1 << 27
 
 
+def _project_qkv(x, lp, positions, cfg, kind):
+    """What every operator of a description with layer kinds starts with:
+    the normed input ``h``, and of it the queries ``[B, S, H, D]``, keys
+    ``[B, S, Hkv, D]`` and values ``[B, S, Hkv, Dv]`` of a layer of
+    ``kind`` — values scaled by ``value_scale``, each query and key head
+    RMS-normed where the layer has ``q_norm`` / ``k_norm``, then rotated
+    unless the kind is in ``cfg.unrotated``. Returns (h, q, k, v)."""
+    b, s, _ = x.shape
+    nh, d = cfg.n_heads, cfg.head_dim
+    dv = cfg.v_head_dim or d
+    hkv = cfg.kv_heads(kind)
+    theta = cfg.theta(kind)
+    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+    q = (h @ lp["wq"].astype(h.dtype)).reshape(b, s, nh, d)
+    kk = (h @ lp["wk"].astype(h.dtype)).reshape(b, s, hkv, d)
+    v = (h @ lp["wv"].astype(h.dtype)).reshape(b, s, hkv, dv)
+    if cfg.value_scale != 1.0:
+        v = v * jnp.asarray(cfg.value_scale, v.dtype)
+    if "q_norm" in lp:
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        kk = rms_norm(kk, lp["k_norm"], cfg.norm_eps)
+    if kind not in cfg.unrotated:
+        q = rope(q, positions, theta, cfg.rotary_dim)
+        kk = rope(kk, positions, theta, cfg.rotary_dim)
+    return h, q, kk, v
+
+
 def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
                        lora=None, *, kind):
     """One layer's grouped-query attention of a description with layer
@@ -396,20 +487,8 @@ def _grouped_attention(x, lp, k_pool, v_pool, positions, length, write, cfg,
     nh, d = cfg.n_heads, cfg.head_dim
     dv = cfg.v_head_dim or d
     hkv = cfg.kv_heads(kind)
-    theta = cfg.theta(kind)
     with jax.named_scope("attn.qkv." + kind):
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q = (h @ lp["wq"].astype(h.dtype)).reshape(b, s, nh, d)
-        kk = (h @ lp["wk"].astype(h.dtype)).reshape(b, s, hkv, d)
-        v = (h @ lp["wv"].astype(h.dtype)).reshape(b, s, hkv, dv)
-        if cfg.value_scale != 1.0:
-            v = v * jnp.asarray(cfg.value_scale, v.dtype)
-        if "q_norm" in lp:
-            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-            kk = rms_norm(kk, lp["k_norm"], cfg.norm_eps)
-        if kind not in cfg.unrotated:
-            q = rope(q, positions, theta, cfg.rotary_dim)
-            kk = rope(kk, positions, theta, cfg.rotary_dim)
+        h, q, kk, v = _project_qkv(x, lp, positions, cfg, kind)
     with jax.named_scope("attn.kv_write." + kind):
         # rows are cached flat (:func:`kv_row_shapes`): [.., Hkv * D]
         k_pool, k_cache = write(k_pool, kk.reshape(b, s, hkv * d))
@@ -530,16 +609,182 @@ def _short_conv(x, lp, k_pool, v_pool, positions, length, write, cfg,
     return x, k_pool, v_pool
 
 
-def _attention_of(cfg, i: int = 0):
+_OFF_DIAGONAL = math.sqrt(2.0)  # a product x_i x_j, i != j, stands for two
+_RET_EPS = 1e-6  # the normaliser's
+# One batch row of phi(q) at a time once all rows together pass this many
+# numbers (0.25 GB of float32): a chunk of 128 is 47 M a row at 40 heads of
+# 9,216 features, so one row goes at once and two or sixteen row by row.
+_FEATURES_AT_ONCE = 1 << 26
+
+
+def _phi(x):
+    """The degree-2 symmetric feature map of the heads ``x [..., D]``,
+    scaled so that ``phi(q) . phi(k) = (q . k)^2``: the head in blocks of
+    :func:`_ret_block`, every pair of blocks ``a <= b`` as the full outer
+    product of block a with block b — times sqrt(2) where ``a < b``, that
+    pair standing for (b, a) too. Built block row by block row: block a
+    against the head's tail from a on, ``[..., blk, D - a]`` flattened. No
+    gather, and :func:`retention_features` numbers a head."""
+    d = x.shape[-1]
+    blk = _ret_block(d)
+    parts = []
+    for a in range(0, d, blk):
+        weight = jnp.where(jnp.arange(d - a) < blk, 1.0, _OFF_DIAGONAL)
+        tail = x[..., a:] * weight.astype(x.dtype)
+        parts.append((x[..., a:a + blk, None] * tail[..., None, :]).reshape(
+            x.shape[:-1] + (blk * (d - a),)))
+    return jnp.concatenate(parts, axis=-1)
+
+
+def _power_retention(x, lp, k_pool, v_pool, positions, length, write, cfg,
+                     lora=None, *, valid=None):
+    """One layer's power retention (degree 2) with its residual — the
+    operator of a "retention" layer, with the signature the layer loops call
+    an attention by. Queries, keys and values as a grouped attention layer's
+    (:func:`_project_qkv`: QK-norm, rotation; query head j reads KV head ``j
+    // G``), and one gate a KV head and position, ``log g = log sigmoid(h wg
+    + bg)``. With ``G(i, j) = exp(sum of log g over j+1 .. i)``:
+
+        a(i, j) = G(i, j) (q_i . k_j)^2 / D,   j <= i
+        y_i = sum_j a(i, j) v_j / (sum_j a(i, j) + 1e-6)
+
+    and the heads' ``y`` through ``wo``. No softmax, and no row a position:
+    what a slot keeps of the layer is a STATE (:data:`STATE_GROUPS`), ``S =
+    sum_j G(t, j) phi(k_j) v_j^T`` ``[Hkv, F, Dv]`` in the layer's ``k``
+    group and ``z = sum_j G(t, j) phi(k_j)`` ``[Hkv, F]`` in its ``v`` group
+    (:func:`_phi`; the queries carry the ``1 / sqrt(D)``, so ``phi(q) .
+    phi(k)`` is ``a`` without its decay), float32 both.
+
+    A call of ``S`` positions a row is ONE chunk: with ``c`` the running
+    sum of ``log g`` over the call and ``(S0, z0)`` the state it found,
+
+        y_i = [sum_{j<=i in the call} a(i, j) v_j + e^{c_i} phi(q_i)^T S0]
+              / [sum a(i, j) + e^{c_i} phi(q_i)^T z0 + 1e-6]
+        S'  = e^{c_last} S0 + sum_j e^{c_last - c_j} phi(k_j) v_j^T;  z' alike
+
+    which at ``S == 1`` is the recurrence ``S' = g S0 + phi(k) v^T``. The
+    products that read the state run at the default precision like every
+    other product here; the state accumulates and is stored in float32.
+
+    ``write(pool, None)`` hands over the state the call finds (no row is
+    written first, as an attention's are) and ``write(pool, new)`` puts the
+    advanced one back. ``valid [B]``: how many of a row's positions are REAL
+    (None: all). A position at or past it contributes no key and no decay,
+    so a right-padded last chunk leaves the state exactly what the unpadded
+    prompt leaves, and a row with none is handed back the state it had, bit
+    for bit. A call that starts at position 0 (``length``) reads a ZERO
+    state whatever the pool holds (a slot's previous occupant left its own
+    there; it enters with the coefficient 0, so what it left is finite). All
+    rows' ``phi(q)`` at once is ``B x S x H x F`` numbers (3 GB at ``[16,
+    128]``): past :data:`_FEATURES_AT_ONCE` the rows go one at a time, each
+    row's state read from and put back into the rows' array where it lies.
+    Returns (x', k_pool', v_pool')."""
+    if lora is not None:
+        raise ValueError("LoRA adapters beside retention layers are not "
+                         "built: a slot's state is a sum over its prefix "
+                         "under ONE set of projections")
+    b, s, _ = x.shape
+    nh, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    dv = cfg.v_head_dim or d
+    with jax.named_scope("ret.qkv"):
+        h, q, kk, v = _project_qkv(x, lp, positions, cfg, "retention")
+        qg = (q * (1.0 / math.sqrt(d))).reshape(b, s, hkv, nh // hkv, d)
+    real = jnp.ones((b, s), bool) if valid is None \
+        else jnp.arange(s)[None, :] < valid[:, None]
+    with jax.named_scope("ret.gate"):
+        log_g = jax.nn.log_sigmoid(
+            h @ lp["wg"].astype(h.dtype) + lp["bg"].astype(h.dtype))
+        # c_i = log G(i, the position before the call)  [B, S, Hkv]
+        cum = jnp.cumsum(jnp.where(real[..., None], log_g, 0.0), axis=1)
+        kk = jnp.where(real[..., None, None], kk, 0.0)
+    _, s0 = write(k_pool, None)
+    _, z0 = write(v_pool, None)
+    # a call from position 0 finds a ZERO state: by the coefficients the
+    # found state enters with, not by a pass over the state itself
+    held = jnp.broadcast_to(length != 0, (b,))[:, None, None]
+    # a row with no real position hands its state back as it found it
+    moved = jnp.any(real, axis=1)[:, None, None]
+
+    def core(qr, kr, vr, cr, sr, zr, hr, mr):
+        """[B', S, Hkv, G, D] queries over the call's own keys and the
+        state [B', Hkv, F, Dv] it found (``hr`` [B', 1, 1]: it counts;
+        ``mr``: the row has a real position): (numerator [B', S, Hkv, G,
+        Dv], normaliser [B', S, Hkv, G], S', z')."""
+        with jax.named_scope("ret.intra"):
+            sc = jnp.einsum("bqhgd,bkhd->bhgqk", qr, kr,
+                            preferred_element_type=jnp.float32)
+            ch = jnp.moveaxis(cr, 1, -1)  # [B', Hkv, S]
+            since = jnp.where(jnp.tril(jnp.ones((s, s), bool)),
+                              ch[..., :, None] - ch[..., None, :], -jnp.inf)
+            a = jnp.exp(since)[:, :, None] * sc * sc
+            num = jnp.einsum("bhgqk,bkhd->bqhgd", a, vr)
+            den = jnp.moveaxis(jnp.sum(a, axis=-1), -1, 1)
+        with jax.named_scope("ret.state"):
+            pq, pk = _phi(qr), _phi(kr)
+            # G(i, the position before the call), 0 where none was
+            before = jnp.where(hr, jnp.exp(cr), 0.0)
+            num = num + before[..., None, None] * jnp.einsum(
+                "bshgf,bhfv->bshgv", pq, sr)
+            den = den + before[..., None] * jnp.einsum(
+                "bshgf,bhf->bshg", pq, zr)
+            last = cr[:, -1]  # [B', Hkv]
+            pk = pk * jnp.exp(last[:, None] - cr)[..., None]  # G(last, j)
+            s_new = jnp.where(mr[..., None], before[:, -1, :, None, None] * sr
+                              + jnp.einsum("bshf,bshv->bhfv", pk, vr), sr)
+            z_new = jnp.where(mr, before[:, -1, :, None] * zr
+                              + jnp.sum(pk, axis=1), zr)
+        return num, den, s_new, z_new
+
+    rows = (qg, kk, v, cum, held, moved)
+    if b > 1 and b * s * nh * s0.shape[2] > _FEATURES_AT_ONCE:
+        def one(r, carry):
+            """Row ``r``, its state read from and put back into the carried
+            arrays where it lies: no second copy of all the rows' states."""
+            s_all, z_all, num, den = carry
+
+            def at(t):
+                return lax.dynamic_slice_in_dim(t, r, 1, axis=0)
+
+            qr, kr, vr, cr, hr, mr = (at(t) for t in rows)
+            n_r, d_r, s_r, z_r = core(qr, kr, vr, cr, at(s_all), at(z_all),
+                                      hr, mr)
+            return tuple(lax.dynamic_update_slice_in_dim(t, o, r, axis=0)
+                         for t, o in ((s_all, s_r), (z_all, z_r), (num, n_r),
+                                      (den, d_r)))
+
+        s_new, z_new, num, den = lax.fori_loop(0, b, one, (
+            s0, z0, jnp.zeros((b, s, hkv, nh // hkv, dv), jnp.float32),
+            jnp.zeros((b, s, hkv, nh // hkv), jnp.float32)))
+    else:
+        num, den, s_new, z_new = core(*rows[:4], s0, z0, *rows[4:])
+    with jax.named_scope("ret.state"):
+        # the update stays an operation of its own, under this scope: fused
+        # with what follows the layer loop (the shard's leading axis put
+        # back) a decode step's 15 ms of it read as under no scope at all
+        s_new, z_new = lax.optimization_barrier((s_new, z_new))
+        k_pool, _ = write(k_pool, s_new)
+        v_pool, _ = write(v_pool, z_new)
+    with jax.named_scope("ret.out"):
+        y = num / (den[..., None] + _RET_EPS)
+        x = x + y.reshape(b, s, nh * dv) @ lp["wo"].astype(y.dtype)
+    return x, k_pool, v_pool
+
+
+def _attention_of(cfg, i: int = 0, valid=None):
     """The operator of layer ``i`` of a model description — an attention, or
-    where the layer's kind says so a short convolution: ``cfg.attn`` ("gqa"
-    | "mla"; descriptions without the field are gqa), and where the
-    description has layer kinds, :func:`_short_conv` for a "conv" layer and
-    the grouped attention at layer ``i``'s kind for every other."""
+    where the layer's kind says so a short convolution or a power retention:
+    ``cfg.attn`` ("gqa" | "mla"; descriptions without the field are gqa),
+    and where the description has layer kinds, :func:`_short_conv` for a
+    "conv" layer, :func:`_power_retention` for a "retention" layer (handed
+    ``valid``, the rows' counts of real positions, which no other operator
+    needs) and the grouped attention at layer ``i``'s kind for every
+    other."""
     kinds = layer_kinds(cfg)
     if kinds:
         if kinds[i] == "conv":
             return _short_conv
+        if kinds[i] == "retention":
+            return functools.partial(_power_retention, valid=valid)
         return functools.partial(_grouped_attention, kind=kinds[i])
     kind = getattr(cfg, "attn", "gqa")
     if kind == "gqa":
@@ -628,7 +873,9 @@ def _forward_cached(
     loop, one ``dynamic_update_slice`` a layer, attention over a slice.
     Where the description has layer kinds, ``cache.k`` / ``cache.v`` are
     ``{group: array}`` (:func:`cache_groups`), every group ``S_max`` rows
-    here: the one-shot path keeps a window layer's every position."""
+    here: the one-shot path keeps a window layer's every position. A state
+    group (:data:`STATE_GROUPS`) has no rows: its ``write`` hands the
+    layer's state over (``new`` None) or replaces it."""
     b, s = tokens.shape
     k, v = cache.k, cache.v
     x = _embed(params, tokens, cfg, jax.tree.leaves(k)[0].dtype)
@@ -639,6 +886,12 @@ def _forward_cached(
                 pool, new[None],
                 (gi, 0, cache.length) + (0,) * (new.ndim - 2))
             return pool, pool[gi]
+
+        if group in STATE_GROUPS:
+            def write(pool, new, gi=gi):  # noqa: F811 — the layer's state
+                if new is not None:
+                    pool = pool[:gi] + (new,) + pool[gi + 1:]
+                return pool, pool[gi]
 
         lp = _layer_params(params, i, cfg)
         x, nk, nv = _attention_of(cfg, i)(
@@ -813,9 +1066,16 @@ def _lora_delta(h, table, ids, layer):
     return jnp.einsum("bsr,bro->bso", jnp.einsum("bsh,bhr->bsr", h, al), bl)
 
 
+# The head contracts the ONE position a row whose logits a prefill program
+# reads, not all S, once all S of every row would pass this many logits
+# (1 GB of float32): [16, 128] rows of a 151,936-word vocabulary are 311 M
+# (1.16 GB, and as much again for the gather that then picks a row's one).
+_LOGITS_AT_ONCE = 1 << 28
+
+
 def _forward_slots(
     params, tokens, cache: SlotKVCache, start, write_mask, cfg, ffn=None,
-    adapters=None, adapter_ids=None, slots=None,
+    adapters=None, adapter_ids=None, slots=None, valid=None, head_at=None,
 ) -> Tuple[jax.Array, SlotKVCache]:
     """Masked batched forward: tokens [B, S] at positions [start_b, start_b+S).
 
@@ -842,7 +1102,18 @@ def _forward_slots(
     ``{group: array}`` (:func:`cache_groups`): a "full" group ``[L_full,
     B_slots, S_max, ...]`` and the ring groups (:data:`RING_GROUPS`: "window"
     ``[L_win, B_slots, ring, ...]``, "conv" ``[L_conv, B_slots, ring,
-    dim]`` in ``k`` alone), each carried and written the same way.
+    dim]`` in ``k`` alone), each carried and written the same way; a state
+    group (:data:`STATE_GROUPS`: "retention", a tuple of ``L_ret`` arrays
+    ``[B_slots, Hkv, F, Dv]`` in ``k`` and ``[B_slots, Hkv, F]`` in ``v``)
+    has no position axis: a row's whole state is read, advanced and put
+    back in the layer's own array, a masked row's left as it is bit for
+    bit. Such a layer is told how many of a row's ``S`` positions are REAL,
+    ``valid`` [B] (None: all ``S`` of a row in ``write_mask``; none of
+    another, whatever is passed): a padded position that advanced a state
+    would corrupt it, where a padded row by position lands past the slot's
+    length and is harmless. No other operator is handed it.
+    ``head_at`` [B] (None: every position): the one position a row whose
+    logits the caller reads; the logits are then ``[B, 1, V]``.
 
     ``slots`` ([R] int32, B == R) makes the rows COMPACT: row r is slot
     ``slots[r]`` of the pool — its new rows are written there, and attention
@@ -885,34 +1156,72 @@ def _forward_slots(
                 f"write = {reach - 1 + s} ({group}_ring), or the write "
                 f"would overwrite positions still to be read")
         pos_of[group] = jnp.where(write_mask[:, None], positions % rows, rows)
+    if has_state_group(cfg):  # a masked row has no real position
+        valid = jnp.where(write_mask, s if valid is None else valid, 0)
+
+    def rows_of(pool, gi):
+        """Layer ``gi``'s rows (or state) of the call's slots."""
+        if slots is None:
+            return pool[gi]
+        # a dynamic slice a row, straight from the stacked array (its
+        # start clamps into the pool): one row is read where it lies;
+        # a gather of whole slot rows cost the two-row program 1.6 ms
+        # a layer on the chip
+        zeros = (0,) * (pool.ndim - 2)
+        return jnp.concatenate([
+            lax.dynamic_slice(pool, (gi, slots[r]) + zeros,
+                              (1, 1) + pool.shape[2:])[0]
+            for r in range(b)])
+
     for i, (group, gi) in enumerate(groups):
-        pos = pos_of[group]
+        pos = pos_of.get(group)
 
         def write(pool, new, gi=gi, pos=pos):
             pool = pool.at[gi, bidx, pos].set(new, mode="drop")
-            if slots is None:
-                return pool, pool[gi]
-            # a dynamic slice a row, straight from the stacked array (its
-            # start clamps into the pool): one row is read where it lies;
-            # a gather of whole slot rows cost the two-row program 1.6 ms
-            # a layer on the chip
-            zeros = (0,) * (pool.ndim - 2)
-            return pool, jnp.concatenate([
-                lax.dynamic_slice(pool, (gi, slots[r]) + zeros,
-                                  (1, 1) + pool.shape[2:])[0]
-                for r in range(b)])
+            return pool, rows_of(pool, gi)
+
+        if group in STATE_GROUPS:
+            def write(pool, new, gi=gi):  # noqa: F811 — the layer's state
+                """The rows' states as the call finds them in the layer's
+                own array ``pool[gi]`` ``[B_slots, *state]`` (``new`` None),
+                or ``new`` put in their place: the whole array replaced (the
+                operator hands back a row without a real position, so every
+                masked row, what it found, bit for bit), or one slot a
+                compact row."""
+                layer = pool[gi]
+                zeros = (0,) * (layer.ndim - 1)
+                if new is None:
+                    if slots is None:
+                        return pool, layer
+                    return pool, jnp.concatenate([
+                        lax.dynamic_slice(layer, (slots[r],) + zeros,
+                                          (1,) + layer.shape[1:])
+                        for r in range(b)])
+                if slots is None:  # a masked row's ``new`` is what it held
+                    return pool[:gi] + (new,) + pool[gi + 1:], None
+                for r in range(b):
+                    # a padding row's slot clamps into the pool: it puts
+                    # back what it finds there
+                    at = (slots[r],) + zeros
+                    held = lax.dynamic_slice(layer, at,
+                                             (1,) + layer.shape[1:])
+                    layer = lax.dynamic_update_slice(layer, jnp.where(
+                        write_mask[r], new[r][None], held), at)
+                return pool[:gi] + (layer,) + pool[gi + 1:], None
 
         lp = _layer_params(params, i, cfg)
         lora = None
         if adapters is not None:
             def lora(h, target, i=i):
                 return _lora_delta(h, adapters[target], adapter_ids, i)
-        x, nk, nv = _attention_of(cfg, i)(
+        x, nk, nv = _attention_of(cfg, i, valid)(
             x, lp, group_array(k, group), group_array(v, group), positions,
             start, write, cfg, lora=lora)
         k, v = _with_group(k, group, nk), _with_group(v, group, nv)
         x = _ffn_half(x, lp, cfg, ffn, rows=write_mask,
                       place=_layer_place(params, i, cfg))
+    if head_at is not None:
+        x = jnp.take_along_axis(x, head_at[:, None, None], axis=1)
     logits = _head(x, params, cfg)
     return logits, SlotKVCache(k, v, cache.lengths)
 
@@ -990,17 +1299,29 @@ def prefill_slots(
     """
     if start is None:
         start = jnp.zeros_like(prompt_lens)
+    valid = None
+    if has_state_group(cfg):
+        # a state advances by a window's REAL positions: the prompt's own,
+        # not a last chunk's right padding
+        valid = jnp.where(
+            new_mask, jnp.clip(prompt_lens - start, 0, tokens.shape[1]), 0)
+    b, s = tokens.shape
+
+    def last_idx():
+        """Each slot's last valid prompt position WITHIN this window;
+        clipped so mid-prefill rows (prompt end beyond the window) gather
+        in-bounds — their token is garbage by contract and ignored by the
+        engine."""
+        return jnp.clip(prompt_lens - 1 - start, 0, s - 1)
+
+    narrow = b * s * params["embed"].shape[0] > _LOGITS_AT_ONCE
     logits, cache = _forward_slots(
         params, tokens, cache, start, new_mask, cfg, ffn=ffn,
-        adapters=adapters, adapter_ids=adapter_ids, slots=slots,
+        adapters=adapters, adapter_ids=adapter_ids, slots=slots, valid=valid,
+        head_at=last_idx() if narrow else None,
     )
-    # each slot's last valid prompt position WITHIN this window; clipped so
-    # mid-prefill rows (prompt end beyond the window) gather in-bounds —
-    # their token is garbage by contract and ignored by the engine
-    s = tokens.shape[1]
-    last_idx = jnp.clip(prompt_lens - 1 - start, 0, s - 1)
-    last = jnp.take_along_axis(
-        logits, last_idx[:, None, None], axis=1
+    last = logits[:, 0] if narrow else jnp.take_along_axis(
+        logits, last_idx()[:, None, None], axis=1
     )[:, 0]  # [B, V]
     if sampling is None:
         tok = jnp.argmax(last, axis=-1).astype(jnp.int32)

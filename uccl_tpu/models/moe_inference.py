@@ -89,9 +89,11 @@ class MoEServeConfig:
     # float32 either way, so a bfloat16 matrix is upcast where it is used
     # -- layer kinds: gqa layers that differ by layer (MiMo-V2-Flash: window
     # and full attention side by side), and layers that do not attend
-    # (LFM2: gated short convolutions between full attention layers).
-    # Empty = every layer the one block.
-    layer_kinds: Tuple[str, ...] = ()  # per layer: "full" | "window" | "conv"
+    # (LFM2: gated short convolutions between full attention layers;
+    # Brumby: every layer a power retention, whose state has no position
+    # axis). Empty = every layer the one block.
+    layer_kinds: Tuple[str, ...] = ()  # per layer: "full" | "window" |
+    # "conv" | "retention" (retention layers stand alone)
     window: int = 0  # a window layer's query at p sees [p - window + 1, p]
     window_kv_heads: int = 0  # the window kind's KV heads (n_kv_heads: full)
     window_rope_theta: float = 0.0  # the window kind's theta (rope_theta: full)
@@ -126,11 +128,20 @@ class MoEServeConfig:
         kinds = self.layer_kinds
         if kinds:
             if self.attn != "gqa" or len(kinds) != self.n_layers \
-                    or set(kinds) - {"full", "window", "conv"}:
+                    or set(kinds) - {"full", "window", "conv", "retention"}:
                 raise ValueError(
-                    f"layer_kinds names 'full', 'window' or 'conv' for each "
+                    f"layer_kinds names 'full', 'window', 'conv' or "
+                    f"'retention' for each "
                     f"of the {self.n_layers} gqa layers; got {kinds} "
                     f"({self.attn})")
+            if "retention" in kinds and (
+                    len(set(kinds)) > 1 or self.sink or self.unrotated
+                    or self.attn_gate or self.post_norms):
+                raise ValueError(
+                    f"retention layers beside another layer kind (a state "
+                    f"kept beside rows by position in one slot), or with a "
+                    f"sink, unrotated kinds, an attention gate or sandwich "
+                    f"norms, are not built; got {kinds}")
             if "window" in kinds and (self.window < 1
                                       or self.window_kv_heads < 1):
                 raise ValueError("window layers need window and "
@@ -171,10 +182,11 @@ class MoEServeConfig:
         if self.gate not in ep_ops.GATES:
             raise ValueError(f"gate {self.gate!r}: want one of "
                              f"{ep_ops.GATES}")
-        if not 0 <= self.first_k_dense < self.n_layers:
+        if not 0 <= self.first_k_dense <= self.n_layers:
             raise ValueError(
-                f"first_k_dense {self.first_k_dense} must leave at least "
-                f"one of the {self.n_layers} layers to the experts")
+                f"first_k_dense {self.first_k_dense} is not among the "
+                f"{self.n_layers} layers (all of them: a model with no "
+                f"expert layer)")
         if self.first_k_dense and self.dense_ffn <= 0:
             raise ValueError("first_k_dense needs dense_ffn, its width")
         if self.attn == "mla":
@@ -231,7 +243,8 @@ class MoEServeConfig:
         have keep their names (``dense_blocks``, ``blocks``: a "full" layer
         is the block they always were); a window layer's group is
         ``window_blocks`` / ``dense_window_blocks``, a conv layer's
-        ``conv_blocks`` / ``dense_conv_blocks``."""
+        ``conv_blocks`` / ``dense_conv_blocks``, a retention layer's
+        ``retention_blocks`` / ``dense_retention_blocks``."""
         kinds = self.layer_kinds or ("full",) * self.n_layers
         return inference.indexed_groups(
             ("dense_" if i < self.first_k_dense else "")
@@ -259,7 +272,12 @@ class MoEServeConfig:
         ``layer_types``, a gated short convolution of ``conv_L_cache`` taps
         whose state is a third cache group, QK-norm on a uniform gqa,
         ``num_dense_layers`` leading dense layers, then sigmoid-bias experts
-        with no shared one, a head tied to the embedding). A file that
+        with no shared one, a head tied to the embedding) or ``brumby``
+        (Brumby-14B-Base; keyed on ``model_type``: every layer a power
+        retention — QK-normed, rotary grouped queries and keys, one gate a
+        KV head with a bias, a state with no position axis — and a dense
+        SwiGLU, no expert layer at all: ``first_k_dense == n_layers``; an
+        untied head). A file that
         states a member's share
         gives ``n_routed_experts`` (``afmoe``: ``num_experts``) as the
         experts held and ``router_experts`` as the router's width
@@ -267,18 +285,38 @@ class MoEServeConfig:
         own fields (capacity_factor, param_dtype, window_ring ...)."""
         heads = hf["num_attention_heads"]
         n_layers = hf["num_hidden_layers"]
+        retention = hf.get("model_type") == "brumby"
         kw: Dict[str, Any] = dict(
             vocab=hf["vocab_size"], dim=hf["hidden_size"],
             n_layers=n_layers, n_heads=heads,
             rope_theta=float(hf.get("rope_theta", 10000.0)),
             norm_eps=float(hf.get("rms_norm_eps")
                            or hf.get("layernorm_epsilon") or 1e-6),
-            moe_topk=hf["num_experts_per_tok"],
+            moe_topk=0 if retention else hf["num_experts_per_tok"],
         )
         if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
             raise ValueError("group-limited routing (n_group > 1) is not "
                              "built")
-        if "conv_L_cache" in hf:
+        if retention:
+            for key, what in (
+                    ("use_sliding_window", "retention layers over a window"),
+                    ("rope_scaling", "a scaled rotation"),
+                    ("attention_bias", "biases on the q, k, v projections"),
+                    ("tie_word_embeddings", "a head tied to the embedding "
+                     "beside retention layers")):
+                if hf.get(key):
+                    raise ValueError(f"{key} {hf[key]!r} ({what}) is not "
+                                     f"built")
+            kw.update(
+                n_kv_heads=hf["num_key_value_heads"],
+                head_dim=hf.get("head_dim") or hf["hidden_size"] // heads,
+                layer_kinds=("retention",) * n_layers,
+                qk_norm=True, norm_gain_scale=NORM_GAIN_SCALE,
+                # no expert layer: every layer's FFN is the dense SwiGLU
+                first_k_dense=n_layers, dense_ffn=hf["intermediate_size"],
+                moe_experts=0, moe_ffn=0,
+            )
+        elif "conv_L_cache" in hf:
             kinds = _layer_kinds(hf, n_layers, {"conv": "conv",
                                                 "full_attention": "full"})
             if hf.get("conv_bias"):
@@ -460,15 +498,22 @@ def _cache_arrays(cfg: MoEServeConfig, world: int, batch_local: int,
     or — where the description has layer kinds — ``{group: array}`` with a
     group's own layer count, ``rows[group]`` and row shape
     (``inference.cache_groups`` / ``kv_row_shapes``; a "conv" group is in
-    ``k`` alone)."""
+    ``k`` alone; a state group, ``inference.STATE_GROUPS``, is a tuple of
+    one array a layer with no rows axis, ``[W, B_loc, *state]``)."""
     layers = Counter(group for group, _ in inference.cache_groups(cfg))
+
+    def array(group, n, which):
+        row = kv_row_shapes(cfg, group)[which]
+        if group in inference.STATE_GROUPS:  # one array a layer, no rows
+            return tuple(jnp.zeros((world, batch_local) + row, dtype,
+                                   device=sharding) for _ in range(n))
+        return jnp.zeros((world, n, batch_local, rows[group]) + row, dtype,
+                         device=sharding)
+
     out = []
     for which in (0, 1):
         arrays = {
-            group: jnp.zeros(
-                (world, n, batch_local, rows[group])
-                + kv_row_shapes(cfg, group)[which], dtype, device=sharding)
-            for group, n in layers.items()
+            group: array(group, n, which) for group, n in layers.items()
             # a conv group keeps one array: no value rows
             if kv_row_shapes(cfg, group)[which] is not None}
         out.append(arrays[None] if None in arrays else arrays)
@@ -478,7 +523,8 @@ def _cache_arrays(cfg: MoEServeConfig, world: int, batch_local: int,
 class MoEKVCache(NamedTuple):
     k: jax.Array  # [W, L, B_loc, S_max, *k_row] (inference.kv_row_shapes:
     v: jax.Array  # gqa [Hkv, D] each; mla [kv_lora_rank] and [qk_rope_dim]);
-    # with layer kinds {group: [W, L_group, B_loc, S_max, *row of the kind]}
+    # with layer kinds {group: [W, L_group, B_loc, S_max, *row of the kind]},
+    # a state group a tuple of L_group arrays [W, B_loc, *state]
     length: jax.Array  # [W] int32
 
     @staticmethod
@@ -499,9 +545,14 @@ class MoESlotCache(NamedTuple):
     Hkv * .], "window": [W, L_win, B_loc, ring, Hkv_win * .]}`` and ``k``
     alone ``"conv": [W, L_conv, B_loc, ring, dim]``: a full layer keeps
     every position of a slot, a window or conv layer a ring of
-    ``cfg.ring_rows(group)`` rows (position p at row p % ring). Rows of a
-    pool with ring groups cannot be exported, imported or copied between
-    slots: the three views below raise (``inference.RING_GROUPS_STAY``)."""
+    ``cfg.ring_rows(group)`` rows (position p at row p % ring); a retention
+    layer keeps no position, only its state AT the slot's length: ``k``
+    ``"retention"`` a tuple of ``L_ret`` arrays ``[W, B_loc, Hkv, F, Dv]``
+    (S) and ``v`` of ``[W, B_loc, Hkv, F]`` (z), one a layer
+    (``inference.STATE_GROUPS``). Rows of a
+    pool with ring or state groups cannot be exported, imported or copied
+    between slots: the three views below raise
+    (``inference.RING_GROUPS_STAY`` / ``STATE_GROUPS_STAY``)."""
 
     k: jax.Array  # [W, L, B_loc, S_max, *k_row] (inference.kv_row_shapes)
     v: jax.Array  # [W, L, B_loc, S_max, *v_row]
@@ -521,7 +572,7 @@ class MoESlotCache(NamedTuple):
 
     def _one_group(self, what: str) -> None:
         if isinstance(self.k, dict):
-            raise ValueError(inference.RING_GROUPS_STAY + what)
+            raise ValueError(inference.groups_stay(self.k) + what)
 
     # -- slot KV export/import views (the disaggregation surface) ----------
     #
@@ -619,20 +670,26 @@ _FOLD_KEY = {"wq_a": 21, "wq_b": 22, "wkv_a": 23, "wkv_b": 24,
              "ws_gate": 25, "ws_up": 26, "ws_down": 27, "router_bias": 28,
              "w_gate": 29, "w_up": 30, "w_down": 31, "sink": 32, "wg": 33,
              "q_norm": 34, "k_norm": 35, "ln1_post": 36, "ln2_post": 37,
-             "ln1": 38, "ln2": 39, "w_in": 40, "w_conv": 41, "w_out": 42}
+             "ln1": 38, "ln2": 39, "w_in": 40, "w_conv": 41, "w_out": 42,
+             "bg": 43}
 _DENSE_GROUP_FOLD = 64
 # a group's fold: the groups that always were keep theirs (so the uniform
 # descriptions' weights are what they were); a window group folds 128 more,
-# a conv group 256
+# a conv group 256, a retention group 512
 _GROUP_FOLD = {"blocks": 0, "dense_blocks": _DENSE_GROUP_FOLD,
                "window_blocks": 128,
                "dense_window_blocks": 128 + _DENSE_GROUP_FOLD,
                "conv_blocks": 256,
-               "dense_conv_blocks": 256 + _DENSE_GROUP_FOLD}
+               "dense_conv_blocks": 256 + _DENSE_GROUP_FOLD,
+               "retention_blocks": 512,
+               "dense_retention_blocks": 512 + _DENSE_GROUP_FOLD}
 SINK_SCALE = 1.0  # seeded sink logits: as large as the scores they sit
 # beside, so that a softmax without its sink column is told apart
 ROUTER_BIAS_SCALE = 0.01  # seeded gate bias: choosing by score + bias and
 # weighing by the score alone are then told apart
+GATE_BIAS_RANGE = (4.0, 7.0)  # a retention layer's gate bias ``bg``, drawn
+# uniform: g = sigmoid(. + bg) between 0.982 and 0.999, so a state remembers
+# hundreds of positions and a wrong chunk boundary far back still shows
 NORM_GAIN_SCALE = 0.1  # seeded norm gains (``from_hf``'s afmoe branch asks
 # for them): 1 + 0.1 x a normal, so that a norm whose gain is left out, or a
 # branch normed once where the model norms it twice, is told apart
@@ -644,8 +701,17 @@ def _attn_shapes(cfg: MoEServeConfig, kind: str = "full"):
     layer's, where the description has layer kinds). A "conv" layer's are
     its short convolution's: ``w_in`` (the three gates' projection),
     ``w_out`` and the filter ``w_conv`` ``[dim, taps]``, drawn at
-    1/sqrt(taps) so that a tap left out or the taps reversed shows."""
+    1/sqrt(taps) so that a tap left out or the taps reversed shows. A
+    "retention" layer's are a grouped attention's with QK-norm and ``wg
+    [dim, Hkv]``, one gate a KV head (its bias ``bg`` is ``init_params``'
+    own draw)."""
     h = cfg.dim
+    if kind == "retention":
+        qd, kd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+        return {"wq": ((h, qd), h), "wk": ((h, kd), h), "wv": ((h, kd), h),
+                "wo": ((qd, h), qd), "wg": ((h, cfg.n_kv_heads), h)}, \
+            {"ln1": h, "ln2": h, "q_norm": cfg.head_dim,
+             "k_norm": cfg.head_dim}
     if kind == "conv":
         return {"w_in": ((h, 3 * h), h), "w_out": ((h, h), h),
                 "w_conv": ((h, cfg.conv_taps), cfg.conv_taps)}, \
@@ -685,13 +751,15 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
     stacked groups by (FFN kind, attention kind) — ``cfg.param_groups()``:
     ``blocks`` [n_moe_layers, ...] and, where the description has a dense
     prefix, ``dense_blocks`` [first_k_dense, ...]; with layer kinds the
-    window layers' ``window_blocks`` / ``dense_window_blocks`` and the conv
-    layers' ``conv_blocks`` / ``dense_conv_blocks`` beside them.
+    window layers' ``window_blocks`` / ``dense_window_blocks``, the conv
+    layers' ``conv_blocks`` / ``dense_conv_blocks`` and the retention
+    layers' ``retention_blocks`` / ``dense_retention_blocks`` beside them.
     Every matrix is drawn in float32 (embedding 0.02, others 1/sqrt(fan-in))
     and stored in ``cfg.param_dtype``; a layer's norm gains are ones (or,
     where ``cfg.norm_gain_scale`` asks, 1 + that x a normal), the gate bias
-    a normal of scale 0.01 and a sink kind's per-head logit a normal of
-    scale 1.0, all float32."""
+    a normal of scale 0.01, a sink kind's per-head logit a normal of
+    scale 1.0 and a retention layer's gate bias ``bg`` uniform in
+    ``GATE_BIAS_RANGE``, all float32."""
     k = jax.random.split(key, 12)
     h, f, e = cfg.dim, cfg.moe_ffn, cfg.n_held
     dtype = jnp.dtype(cfg.param_dtype)
@@ -714,8 +782,8 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
              "w_down": ((fd, h), fd)}
 
     def group(name, n):
-        kind = next((k for k in ("window", "conv") if k + "_" in name),
-                    "full")
+        kind = next((k for k in ("window", "conv", "retention")
+                     if k + "_" in name), "full")
         fold = _GROUP_FOLD[name]
         mats, norms = _attn_shapes(cfg, kind)
         is_dense = name.startswith("dense_")
@@ -734,6 +802,10 @@ def init_params(key: jax.Array, cfg: MoEServeConfig) -> Dict[str, Any]:
             out["router_bias"] = jax.random.normal(
                 jax.random.fold_in(key, fold + _FOLD_KEY["router_bias"]),
                 (n, cfg.moe_experts), jnp.float32) * ROUTER_BIAS_SCALE
+        if kind == "retention":
+            out["bg"] = jax.random.uniform(
+                jax.random.fold_in(key, fold + _FOLD_KEY["bg"]),
+                (n, cfg.n_kv_heads), jnp.float32, *GATE_BIAS_RANGE)
         if kind in cfg.sink:
             out["sink"] = jax.random.normal(
                 jax.random.fold_in(key, fold + _FOLD_KEY["sink"]),
@@ -1026,15 +1098,26 @@ class MoEServer:
         pool_bytes = _obsc.gauge(
             "serving_kv_pool_bytes",
             "bytes of the slot pool's arrays by cache group (label group: "
-            "full | window | conv, or the attention kind of a pool with one)")
+            "full | window | conv | retention, or the attention kind of a "
+            "pool with one)")
         for group in {g for g, _ in inference.cache_groups(self.cfg)}:
             arrays = [a for a in (inference.group_array(pool, group)
                                   for pool in (cache.k, cache.v))
                       if a is not None]  # a conv group keeps one
             label = group or self.cfg.attn
+            pool_bytes.set(sum(a.nbytes for a in jax.tree.leaves(arrays)),
+                           group=label)
+            if group in inference.STATE_GROUPS:  # no row by position
+                _obsc.gauge(
+                    "serving_state_bytes_per_slot",
+                    "bytes ONE layer's state holds of one slot in a state "
+                    "group of the slot pool, whatever the slot's length "
+                    "(label kind: retention — S and z)"
+                ).set(sum(math.prod(a[0].shape[2:]) * a[0].dtype.itemsize
+                          for a in arrays), kind=label)
+                continue
             row_bytes.set(sum(math.prod(a.shape[4:]) * a.dtype.itemsize
                               for a in arrays), kind=label)
-            pool_bytes.set(sum(a.nbytes for a in arrays), group=label)
             if group in inference.RING_GROUPS:
                 _obsc.gauge(
                     "serving_kv_ring_rows",
@@ -1106,13 +1189,14 @@ class MoEServer:
         :func:`inference.decode_step_slots`, run per shard with the EP block
         as its FFN — one compiled program a call, named after ``kind``
         (``jit_uccl_moe_<kind>_slots``) and keyed apart in ``_fns``. Returns
-        the statement's own outputs, then the experts read [W], then the new
-        pool."""
+        the statement's own outputs, then the experts read [W] (a model
+        with no expert layer returns no such count), then the new pool."""
         self._check_drop_free()
         cfg = self.cfg
         sampled, adapted = sampling is not None, adapters is not None
         extra = _flat_extra(sampling, adapters, adapter_ids)
-        counted = self.world == 1 and impl == "sort"
+        experts = cfg.n_moe_layers > 0  # else no count is returned at all
+        counted = experts and self.world == 1 and impl == "sort"
         step, n_out = {"verify": (inference.verify_slots, 2),
                        "decode": (inference.decode_step_slots, 1)}[kind]
 
@@ -1138,7 +1222,8 @@ class MoEServer:
             donate=(3, 4, 5)))
         *out, nk, nv, nlen = fn(params, tokens, active, cache.k, cache.v,
                                 cache.lengths, *extra)
-        if not counted:  # the batched GEMMs: every expert, whatever the rows
+        if experts and not counted:  # the batched GEMMs: every expert,
+            # whatever the rows
             out.append(np.full(
                 self.world, cfg.n_held // self.world * cfg.n_moe_layers,
                 np.int32))

@@ -9,7 +9,9 @@ key head RMS-normed, the heads' output gated, each branch's output normed
 before the residual, the embedding scaled, a shared expert beside the held
 share — and the LFM2 (``lfm2_moe``) block: gated short convolutions in
 the "conv" layers between full attention layers with QK-norm, no shared
-expert, the head tied to the embedding). What tier-1 holds the program to.
+expert, the head tied to the embedding — and the Brumby (``brumby``) block:
+every layer a power retention of degree 2, a dense SwiGLU, no expert
+layer). What tier-1 holds the program to.
 
 Straightforward ``jax.numpy`` in float32 at
 ``default_matmul_precision("highest")``: a loop over the query heads with a
@@ -50,6 +52,17 @@ and in a "conv" layer (the LFM2 block), in place of the attention half:
     [b | c | u] = h W_in  (three H-wide parts);   y(t) = b(t) * u(t)
     z(t) = sum_{j < taps} w[:, j] * y(t - (taps - 1) + j),  y(t < 0) = 0
     x    = x + (c * z) W_out                                   (conv_taps)
+
+and in a "retention" layer (the Brumby block: power retention of degree 2,
+every layer of that model), in place of the attention half, the QUADRATIC
+form — the whole ``[T, T]`` matrix, no state, no chunks, no feature map:
+
+    q_j, k_g as above (RMS-normed, then rotated);  v_g = (h Wv)_g
+    log g_g(t) = log sigmoid((h Wg)_g + bg_g)              one a KV head
+    G_g(t, u)  = exp(sum of log g_g over u+1 .. t),  u <= t
+    a_j(t, u)  = G_g(t, u) (q_j(t).k_g(u) / sqrt(D))^2,    g = j // (H/Hkv)
+    y_j(t)     = sum_u a_j(t, u) v_g(u) / (sum_u a_j(t, u) + 1e-6)
+    x          = x + concat_j(y_j) Wo
 
 and where the head is tied (``tie_head``):  logits = RMSNorm(x) E^T
 """
@@ -140,6 +153,34 @@ def short_conv(x, lp, cfg):
     return x + (c * z) @ lp["w_out"]
 
 
+def power_retention(x, lp, cfg):
+    """One retention layer's operator half on one sequence [T, H], head by
+    head, in the quadratic form: the decay as a difference of cumulative
+    sums of ``log g``, the scores squared, each row divided by its sum."""
+    t = x.shape[0]
+    nh, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    pos = jnp.arange(t)
+    h = _norm(x, lp["ln1"], cfg.norm_eps)
+    q = (h @ lp["wq"]).reshape(t, nh, d)
+    k = (h @ lp["wk"]).reshape(t, hkv, d)
+    v = (h @ lp["wv"]).reshape(t, hkv, d)
+    since = jnp.cumsum(jax.nn.log_sigmoid(h @ lp["wg"] + lp["bg"]), axis=0)
+    seen = pos[None, :] <= pos[:, None]
+    heads = []
+    for j in range(nh):
+        g = j // (nh // hkv)
+        qj = _rotate(_norm(q[:, j], lp["q_norm"], cfg.norm_eps), pos,
+                     cfg.rope_theta, d)
+        kg = _rotate(_norm(k[:, g], lp["k_norm"], cfg.norm_eps), pos,
+                     cfg.rope_theta, d)
+        decay = jnp.where(seen, jnp.exp(jnp.where(
+            seen, since[:, None, g] - since[None, :, g], 0.0)), 0.0)
+        a = decay * (qj @ kg.T / math.sqrt(d)) ** 2
+        heads.append(a @ v[:, g] / (jnp.sum(a, axis=-1, keepdims=True)
+                                    + 1e-6))
+    return x + jnp.concatenate(heads, axis=-1) @ lp["wo"]
+
+
 def expert_layer_sum(h2, lp, cfg, first: int = None, held: int = None):
     """The routed experts' weighted sum for rows ``h2`` [T, H], over the
     experts ``[first, first + held)`` whose leaves ``lp`` carries (the
@@ -165,6 +206,7 @@ def forward_logits(params, tokens, cfg):
             lp = jax.tree.map(lambda a: a[j], p[group])
             kind = cfg.layer_kinds[i]
             x = short_conv(x, lp, cfg) if kind == "conv" \
+                else power_retention(x, lp, cfg) if kind == "retention" \
                 else attention(x, lp, cfg, kind)
             h2 = _norm(x, lp["ln2"], cfg.norm_eps)
             if "router" in lp:

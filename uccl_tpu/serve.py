@@ -187,8 +187,11 @@ def _moe_cfg(args):
     with QK-norm, rotary on the window layers only, sandwich norms, a shared
     expert beside a held share — or an ``lfm2_moe``-shaped one — gated
     short-convolution layers between full attention layers, their state a
-    third cache group, a head tied to the embedding; weights stored in its
-    ``torch_dtype``), or else the hand-sized flags (the uniform block)."""
+    third cache group, a head tied to the embedding — or a ``brumby``-shaped
+    one — every layer a power retention whose per-slot state is a cache
+    group with no position axis, a dense SwiGLU, no expert layer; weights
+    stored in its ``torch_dtype``), or else the hand-sized flags (the uniform
+    block)."""
     from uccl_tpu.models.inference import RING_GROUPS
     from uccl_tpu.models.moe_inference import MoEServeConfig
 
@@ -204,14 +207,14 @@ def _moe_cfg(args):
                          "no --ckpt-dir")
     with open(args.model_config) as f:
         hf = json.load(f)
-    experts = next(hf[k] for k in ("router_experts", "n_routed_experts",
-                                   "num_experts", "num_local_experts")
-                   if hf.get(k))
+    experts = next((hf[k] for k in ("router_experts", "n_routed_experts",
+                                    "num_experts", "num_local_experts")
+                    if hf.get(k)), 0)  # 0: a model with no expert layer
     cfg = MoEServeConfig.from_hf(
         hf,
         # the slot engine needs a drop-free wire: factor * top-k >= experts
         # (the ROUTED experts, whatever share of them is held here)
-        capacity_factor=max(8.0, experts / hf["num_experts_per_tok"]),
+        capacity_factor=max(8.0, experts / hf.get("num_experts_per_tok", 1)),
         param_dtype=hf.get("torch_dtype", "float32"),
     )
     # a ring group's rows hold reach - 1 + the widest write (window - 1 +,

@@ -156,35 +156,48 @@ def _bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
-def _check_ring_groups(backend, prefill_chunk, spec_k, prefix_cache,
-                       kv_tiers, preempt, adapters) -> int:
-    """What a pool with ring groups (a model description whose
-    ``layer_kinds`` name "window" or "conv" layers: ``inference.
-    RING_GROUPS``) cannot do yet, refused before a request could meet it;
-    and each ring against the widest write. Returns the window (0 for a
-    pool without window groups)."""
+def _check_cache_groups(backend, prefill_chunk, spec_k, prefix_cache,
+                        kv_tiers, preempt, adapters) -> int:
+    """What a pool of cache groups (a model description whose
+    ``layer_kinds`` name "window" or "conv" layers, ``inference.
+    RING_GROUPS``, or a layer whose slot state has no position axis,
+    ``inference.STATE_GROUPS``) cannot do yet, refused before a request
+    could meet it; and each ring against the widest write. Returns the
+    window (0 for a pool without window groups)."""
     cfg = getattr(backend, "cfg", None)
     kinds = getattr(cfg, "layer_kinds", ()) or ()
-    from uccl_tpu.models.inference import RING_GROUPS, RING_GROUPS_STAY
+    from uccl_tpu.models.inference import (
+        RING_GROUPS, STATE_GROUPS, groups_stay,
+    )
 
     rings = [g for g in RING_GROUPS if g in kinds]
-    if "conv" in kinds and adapters is not None:
+    states = [g for g in STATE_GROUPS if g in kinds]
+    if ("conv" in kinds or states) and adapters is not None:
         raise ValueError(
-            "LoRA adapters beside conv layers are not built: the adapter "
-            "tables are (wq, wv) deltas by layer, and a conv layer has "
-            "neither projection")
+            "LoRA adapters beside conv or retention layers are not built: "
+            "the adapter tables are (wq, wv) deltas by layer; a conv layer "
+            "has neither projection, and a retention layer's state is a sum "
+            "over its prefix under ONE set of projections")
+    if not rings and not states:
+        return 0
+    stay = groups_stay(kinds)
+    if kv_tiers is not None:
+        raise ValueError(stay + "kv_tiers demotes and promotes exported "
+                         "rows")
+    if prefix_cache is not None:
+        raise ValueError(stay + "prefix_cache copies a donor's rows, whose "
+                         "ring no longer holds the prefix's last reach - 1 "
+                         "positions and whose state is the donor's at its "
+                         "own length")
+    if preempt:
+        raise ValueError(stay + "preempt saves a victim's exported rows and "
+                         "restores them")
+    if states and spec_k:
+        raise ValueError(stay + "spec_k verifies a window of drafts and "
+                         "rolls the rejected ones back by the cursor, which "
+                         "a state has already taken in")
     if not rings:
         return 0
-    if kv_tiers is not None:
-        raise ValueError(RING_GROUPS_STAY + "kv_tiers demotes and "
-                         "promotes exported rows")
-    if prefix_cache is not None:
-        raise ValueError(RING_GROUPS_STAY + "prefix_cache copies a "
-                         "donor's rows, whose ring no longer holds the "
-                         "prefix's last reach - 1 positions")
-    if preempt:
-        raise ValueError(RING_GROUPS_STAY + "preempt saves a victim's "
-                         "exported rows and restores them")
     if prefill_chunk is None:
         raise ValueError(
             "a pool with ring groups requires prefill_chunk: a whole "
@@ -319,7 +332,7 @@ class ServingEngine:
                     "chunk boundaries and resumes via the chunked "
                     "start-offset program"
                 )
-        self._window = _check_ring_groups(
+        self._window = _check_cache_groups(
             backend, prefill_chunk, spec_k, prefix_cache, kv_tiers, preempt,
             adapters)
         self.backend = backend
@@ -1231,10 +1244,12 @@ class ServingEngine:
         lens = np.ones(r, np.int32)  # 1 (not 0): the gather index
         start = np.zeros(r, np.int32)  # clip stays in bounds on idle rows
         mask = np.zeros(r, bool)
+        real = 0  # prompt tokens in the call: the last chunk's padding is not
         for slot, req in rows:
             row = row_of[slot]
             chunk = req.prompt[req.prefill_pos:req.prefill_pos + c]
             tokens[row, :chunk.size] = chunk
+            real += chunk.size
             lens[row] = req.prompt.size
             start[row] = req.prefill_pos
             mask[row] = True
@@ -1256,7 +1271,7 @@ class ServingEngine:
         ts0 = tr.now_us() if tr is not None else 0.0
         t0 = now()
         with obs.span("wire.prefill", "wire", step=self._steps,
-                      n=len(rows), chunk=c, rows=r):
+                      n=len(rows), chunk=c, rows=r, tokens=int(real)):
             tok = self.backend.prefill(tokens, lens, mask, start=start, **kw)
         self.metrics.on_prefill(now() - t0, len(self._prefilling),
                                 chunked=True)

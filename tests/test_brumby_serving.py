@@ -399,30 +399,57 @@ def test_every_split_of_a_prompt_into_chunks_is_the_reference(model):
         final = state
 
 
-def test_rows_one_at_a_time_are_rows_at_once(model, monkeypatch):
-    """Past ``_FEATURES_AT_ONCE`` a call's rows go through the operator one
-    at a time, each state updated where it lies: the same logits and
-    states."""
+REAL_ROWS = {"none": (), "one": (2,), "some": (1, 3), "all": (0, 1, 2, 3)}
+
+
+@pytest.mark.parametrize("real", sorted(REAL_ROWS))
+@pytest.mark.parametrize("call", ("decode", "chunk", "chunk_from_0"))
+def test_the_row_loop_visits_the_rows_with_a_real_position(model, call, real):
+    """A call of four rows goes through the operator's row loop, which
+    visits the rows with a real position and no other — none, one, some that
+    are no prefix of the rows, all: a decode call ``[4, 1]``, a chunk ``[4,
+    4]`` whose row 3 has 3 real positions of 4, and the same chunk as a
+    prompt's first (position 0, over what a previous occupant left). A
+    visited row's logits are the reference's and its state what the row
+    reaches ALONE in a pool of its own (one path against another); a row the
+    loop never reaches keeps its ``S`` and ``z`` bit for bit, non-zero as
+    the previous occupant left them."""
     cfg, params, srv, placed = model
-    toks = np.stack([_tokens(8, seed=6), _tokens(8, seed=7)])
-    on = np.ones(2, bool)
-    out = {}
-    for limit in (inference._FEATURES_AT_ONCE, 0):
-        monkeypatch.setattr(inference, "_FEATURES_AT_ONCE", limit)
-        srv.__dict__.pop("_ret_logits_fns", None)
-        cache = srv.slot_cache(2, MAX_SEQ)
-        parts = []
-        for lo in (0, 4):
-            part, cache = _slot_logits(srv, placed, toks[:, lo:lo + 4],
-                                       cache, [lo, lo], on, valid=[4, 3])
-            parts.append(part)
-        out[limit] = (np.concatenate(parts, 1), _states(cache, 0),
-                      _states(cache, 1))
-    srv.__dict__.pop("_ret_logits_fns", None)
-    at_once, by_row = out[inference._FEATURES_AT_ONCE], out[0]
-    np.testing.assert_allclose(by_row[0], at_once[0], atol=PATH_TOL)
-    for a, b in zip(by_row[1] + by_row[2], at_once[1] + at_once[2]):
-        np.testing.assert_allclose(a, b, **STATE_TOL)
+    rows = REAL_ROWS[real]
+    on = np.isin(np.arange(4), rows)
+    # every slot's previous occupant: 9 positions of its own, 8 of them
+    # pool-wide (rows that are all real) and one by a decode call
+    before = np.stack([_tokens(9, seed=50 + r) for r in range(4)])
+    cache = srv.slot_cache(4, MAX_SEQ)
+    for lo in (0, 4):
+        _, cache = _slot_logits(srv, placed, before[:, lo:lo + 4], cache,
+                                [lo] * 4, np.ones(4, bool), valid=[4] * 4)
+    _, cache = _slot_logits(srv, placed, before[:, 8:], cache, [8] * 4,
+                            np.ones(4, bool), valid=[1] * 4)
+    held = [_states(cache, r) for r in range(4)]
+    assert all(np.any(a != 0) for state in held for a in state)
+    width = 1 if call == "decode" else 4
+    start = 0 if call == "chunk_from_0" else 9
+    new = np.stack([_tokens(width, seed=60 + r) for r in range(4)])
+    valid = np.where(on, np.where(np.arange(4) == 3, max(width - 1, 1),
+                                  width), 0)
+    got, cache = _slot_logits(srv, placed, new, cache, [start] * 4, on,
+                              valid=valid)
+    assert np.all(np.isfinite(got))
+    for r in range(4):
+        if r not in rows:
+            for a, b in zip(_states(cache, r), held[r]):
+                assert np.array_equal(a, b)
+            continue
+        n = int(valid[r])
+        prompt = new[r, :n] if start == 0 \
+            else np.concatenate([before[r], new[r, :n]])
+        want = np.asarray(ref.forward_logits(params, prompt, cfg))
+        np.testing.assert_allclose(got[r, :n], want[-n:], atol=LOGIT_TOL)
+        alone, pool = _serve(srv, placed, srv.slot_cache(2, MAX_SEQ), prompt)
+        np.testing.assert_allclose(got[r, :n], alone[-n:], atol=PATH_TOL)
+        for a, b in zip(_states(cache, r), _states(pool, 0)):
+            np.testing.assert_allclose(a, b, **STATE_TOL)
 
 
 def test_the_head_at_one_position_is_the_head_at_all(model, monkeypatch):
@@ -613,8 +640,7 @@ def test_chunk_form_is_recurrence_is_quadratic_form(model, chunk):
     np.testing.assert_allclose(recurrent, quadratic, atol=LOGIT_TOL)
     state = [(jnp.zeros((1, 2, 48, 8)),), (jnp.zeros((1, 2, 48)),)]
 
-    def write(pool, new):
-        return ((new,) if new is not None else pool), pool[0]
+    write = inference._state_write(0, jnp.arange(1))
 
     @jax.jit
     def step(xs, k, v, lo, valid):

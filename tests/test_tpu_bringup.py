@@ -244,7 +244,18 @@ def test_decode_program_reads_stacked_cache_groups_where_they_lie(v5e):
     assert temporaries < 2 * full_v, (temporaries, 2 * full_v)
 
 
-def test_slot_programs_advance_a_state_group_in_place(v5e):
+def _computations(compiled_text: str) -> dict:
+    """{name: body text} of every computation of a compiled program."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", compiled_text,
+        re.S | re.M)}
+
+
+@pytest.mark.parametrize("program,slots,rows", [
+    ("decode", 4, None), ("decode", 16, None), ("prefill", 16, None),
+    ("prefill", 16, 2), ("prefill", 16, 1)])
+def test_slot_programs_advance_a_state_group_in_place(v5e, program, slots,
+                                                      rows):
     """The decode and the pool-wide prefill program of a description whose
     layers keep a STATE with no position axis (two retention layers at
     Brumby's head sizes: 8 KV heads of 9,216 features x 128 a slot, 4 and 16
@@ -252,7 +263,23 @@ def test_slot_programs_advance_a_state_group_in_place(v5e):
     replaced in the donated buffer it came in, so no operation copies a
     layer's states and the temporaries stay under ONE layer's. One stacked
     array a group made the compiler copy the whole group in and out (4.5 GB
-    of temporaries at 8 layers x 16 slots: PERF.md section 6, PR 45)."""
+    of temporaries at 8 layers x 16 slots: PERF.md section 6, PR 45).
+
+    And the operator passes over the rows with a real position alone (PR
+    46): each layer's state goes from the entry's parameter through ONE
+    ``while`` (the row loop, its trip count read from the input) to the
+    entry's result — no fusion of the entry computation makes a whole
+    layer's ``[slots, 8, 9216, 128]``, which is what read and rewrote every
+    slot's state whatever decoded — and the loop's body reads a row's state
+    where it lies in the carried array and updates it there: no ``copy``,
+    ``slice`` or ``dynamic-slice`` instruction of its own hands on a row's
+    37.7 MB (in a fusion the slice is an address, not a pass). The compact
+    two-row rung (``rows`` 2 of 16 slots) is held to the same: its rows'
+    states are never gathered out of the layer's array, which the loop
+    carries whole (gathered, the two rows' 75 MB were moved in and out of
+    fast memory every turn of the loop). The compact one-row rung has no
+    loop: its row is updated in the layer's array by one in-place
+    operation of the entry computation."""
     from uccl_tpu.models.moe_inference import (
         MoEServeConfig, MoEServer, MoESlotCache, init_params,
     )
@@ -280,27 +307,59 @@ def test_slot_programs_advance_a_state_group_in_place(v5e):
         return srv.decode_step_slots(p, tok, act, MoESlotCache(k, v, ln),
                                      impl="sort")
 
-    def prefill(p, tok, lens, mask, k, v, ln):
-        return srv.prefill_slots(p, tok, lens, mask, MoESlotCache(k, v, ln))
+    def prefill(p, tok, lens, mask, k, v, ln, *named):
+        return srv.prefill_slots(p, tok, lens, mask, MoESlotCache(k, v, ln),
+                                 slots=named[0] if named else None)
 
-    for slots, program in ((4, "decode"), (16, "prefill")):
-        pool = described(jax.eval_shape(
-            lambda: MoESlotCache.empty(cfg, 1, slots, 32768)))
-        layer = slots * 8 * 9216 * 128 * 4  # one layer's S of every slot
-        assert [a.shape for a in pool.k["retention"]] \
-            == [(1, slots, 8, 9216, 128)] * 2
-        if program == "decode":
-            compiled = jax.jit(decode, donate_argnums=(3, 4, 5)).lower(
-                placed, arg((1, slots), jnp.int32),
-                arg((1, slots), jnp.bool_), *pool).compile()
-        else:  # [16, 128]: the rows go one at a time, each where it lies
-            compiled = jax.jit(prefill, donate_argnums=(4, 5, 6)).lower(
-                placed, arg((1, slots, 128), jnp.int32),
-                arg((1, slots), jnp.int32), arg((1, slots), jnp.bool_),
-                *pool).compile()
-        moved = [(name, opcode, dtype, n) for name, opcode, dtype, n
-                 in _entry_results(compiled.as_text())
-                 if n * 4 >= layer and opcode == "copy"]
-        assert not moved, (program, moved)
-        temporaries = compiled.memory_analysis().temp_size_in_bytes
-        assert temporaries < layer, (program, temporaries, layer)
+    pool = described(jax.eval_shape(
+        lambda: MoESlotCache.empty(cfg, 1, slots, 32768)))
+    row = 8 * 9216 * 128  # one slot's S in one layer, numbers
+    layer = slots * row * 4  # one layer's S of every slot, bytes
+    n = rows or slots  # the call's rows
+    assert [a.shape for a in pool.k["retention"]] \
+        == [(1, slots, 8, 9216, 128)] * 2
+    if program == "decode":
+        compiled = jax.jit(decode, donate_argnums=(3, 4, 5)).lower(
+            placed, arg((1, slots), jnp.int32),
+            arg((1, slots), jnp.bool_), *pool).compile()
+    else:  # [16 | 2 | 1, 128]: pool-wide, or compact over named slots
+        compiled = jax.jit(prefill, donate_argnums=(4, 5, 6)).lower(
+            placed, arg((1, n, 128), jnp.int32), arg((1, n), jnp.int32),
+            arg((1, n), jnp.bool_), *pool,
+            *([arg((1, n), jnp.int32)] if rows else [])).compile()
+    text = compiled.as_text()
+    entry = list(_entry_results(text))
+    moved = [(name, opcode, dtype, n) for name, opcode, dtype, n in entry
+             if n * 4 >= layer and opcode == "copy"]
+    assert not moved, (program, moved)
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < layer, (program, temporaries, layer)
+    # a whole layer's S is made by no operation of the entry computation:
+    # it is a parameter, what a row loop carries, or a view of either
+    computations = _computations(text)
+    entry_name = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+    whole = f"f32[{slots},8,9216,128]"
+    views = ("parameter", "bitcast", "get-tuple-element", "tuple", "while",
+             "opt-barrier")
+    made = [line.strip()[:160]
+            for line in computations[entry_name].splitlines()
+            if (m := re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(",
+                              line))
+            and whole in m.group(1) and m.group(2) not in views]
+    loops = re.findall(r"while\([^\n]*body=%?([\w.\-]+)", text)
+    if n == 1:  # one row: no loop, one in-place update a layer and array
+        assert not loops and len(made) == 2 and all(
+            "dynamic-update-slice" in line for line in made), (loops, made)
+        return
+    assert not made, (program, made)
+    assert len(loops) == 2, loops  # one row loop a retention layer
+    state = re.compile(r"f32\[(?:\d+,)?8,9216,128\]")
+    for body in loops:
+        assert state.search(computations[body]), body  # it carries S
+        passes = [line.strip()[:160]
+                  for line in computations[body].splitlines()
+                  if (m := re.match(
+                      r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line))
+                  and re.match(r"(copy|slice|dynamic-slice)", m.group(2))
+                  and state.search(m.group(1))]
+        assert not passes, (program, body, passes)

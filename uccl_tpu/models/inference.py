@@ -206,16 +206,20 @@ RING_GROUPS = {"window": "window", "conv": "taps"}
 # of such a group has no position axis (a "retention" layer's ``S [Hkv, F,
 # Dv]`` in ``k`` and ``z [Hkv, F]`` in ``v``: :func:`_power_retention`), and
 # the group is a TUPLE of arrays ``[B, *state]``, one a layer, not one
-# stacked array: a layer's whole state is read, advanced and replaced, and
-# a program handed its pool donated does that in the layer's own buffer,
-# where the update of one layer of a stacked array made the compiler copy
-# the whole group in and out (4.5 GB at Brumby's size on a described v5e).
+# stacked array: a program handed its pool donated advances a layer's state
+# in the layer's own buffer, where the update of one layer of a stacked
+# array made the compiler copy the whole group in and out (4.5 GB at
+# Brumby's size on a described v5e).
 # THE invariant: a state group's slot holds the state AT the slot's length;
 # a call advances it by its real positions and nothing rolls it back. So a
 # call is told how many of a row's positions are real (``valid``:
 # :func:`_forward_slots`), a call that starts at position 0 reads a zero
 # state whatever the slot holds, and what needs a position's rows back
-# (:data:`STATE_GROUPS_STAY`) is refused until slots keep snapshots.
+# (:data:`STATE_GROUPS_STAY`) is refused until slots keep snapshots. A row
+# without a real position keeps its state bit for bit, and at no cost: the
+# operator's row loop visits the rows that have one, where they lie in the
+# layer's array, and a slot it does not visit is never read
+# (:func:`_power_retention`).
 STATE_GROUPS = ("retention",)
 
 
@@ -611,10 +615,6 @@ def _short_conv(x, lp, k_pool, v_pool, positions, length, write, cfg,
 
 _OFF_DIAGONAL = math.sqrt(2.0)  # a product x_i x_j, i != j, stands for two
 _RET_EPS = 1e-6  # the normaliser's
-# One batch row of phi(q) at a time once all rows together pass this many
-# numbers (0.25 GB of float32): a chunk of 128 is 47 M a row at 40 heads of
-# 9,216 features, so one row goes at once and two or sixteen row by row.
-_FEATURES_AT_ONCE = 1 << 26
 
 
 def _phi(x):
@@ -666,18 +666,38 @@ def _power_retention(x, lp, k_pool, v_pool, positions, length, write, cfg,
     products that read the state run at the default precision like every
     other product here; the state accumulates and is stored in float32.
 
-    ``write(pool, None)`` hands over the state the call finds (no row is
-    written first, as an attention's are) and ``write(pool, new)`` puts the
-    advanced one back. ``valid [B]``: how many of a row's positions are REAL
-    (None: all). A position at or past it contributes no key and no decay,
-    so a right-padded last chunk leaves the state exactly what the unpadded
-    prompt leaves, and a row with none is handed back the state it had, bit
-    for bit. A call that starts at position 0 (``length``) reads a ZERO
-    state whatever the pool holds (a slot's previous occupant left its own
-    there; it enters with the coefficient 0, so what it left is finite). All
-    rows' ``phi(q)`` at once is ``B x S x H x F`` numbers (3 GB at ``[16,
-    128]``): past :data:`_FEATURES_AT_ONCE` the rows go one at a time, each
-    row's state read from and put back into the rows' array where it lies.
+    ``write(pool, None)`` hands over the layer's array of EVERY slot's
+    state with the places of the call's rows in it, ``(at [B], states
+    [B_slots, ...])`` (no row is written first, as an attention's are; row
+    r is slot ``at[r]``: itself pool-wide, the slot a compact call names),
+    and ``write(pool, new)`` replaces the array. ``valid [B]``: how many of
+    a row's positions are REAL (None: all). A position at or past it
+    contributes no key and no decay, so a right-padded last chunk leaves
+    the state exactly what the unpadded prompt leaves, and a row with none
+    keeps the state it had, bit for bit. A call that starts at position 0
+    (``length``) reads a ZERO state whatever the pool holds (a slot's
+    previous occupant left its own there; it enters with the coefficient 0,
+    so what it left is finite).
+
+    The operator passes over the state of the rows that have a real
+    position, and of no other, and a state never leaves the layer's array.
+    A call of ONE row (the one-row prefill rung, the one-shot path at batch
+    1) advances its row where it lies. Every call of more rows goes row by
+    row through a loop whose trip count is the NUMBER of rows with a real
+    position, read from ``valid`` inside the program (the rows ordered
+    real-first): a visited row's state is read from and put back into the
+    carried array at its slot — the product ``phi(q) S`` and the update
+    slice the array themselves, three passes over the row's state and no
+    copy of it —, and a slot the loop never visits (a masked row's, a
+    padding row's, one no row names) is NEVER READ and never written: its
+    state is what it was bit for bit at no cost; such a row's numerator and
+    normaliser stay zero (``y = 0``: finite, and nobody keeps its logits).
+    So a decode step over 6 of 16 slots moves 6 rows' state, not 16, and a
+    compact call gathers and scatters nothing (PERF.md section 6, PR 46).
+    A visit makes its row's feature maps itself (all rows' ``phi(q)`` at
+    once would be 3 GB at ``[16, 128]``; made before the loop at decode,
+    where they are small, they cost a step 0.9 ms more than the hundred
+    small operations a visit they saved: measured, PERF.md).
     Returns (x', k_pool', v_pool')."""
     if lora is not None:
         raise ValueError("LoRA adapters beside retention layers are not "
@@ -697,19 +717,20 @@ def _power_retention(x, lp, k_pool, v_pool, positions, length, write, cfg,
         # c_i = log G(i, the position before the call)  [B, S, Hkv]
         cum = jnp.cumsum(jnp.where(real[..., None], log_g, 0.0), axis=1)
         kk = jnp.where(real[..., None, None], kk, 0.0)
-    _, s0 = write(k_pool, None)
+    # every slot's state of the layer, and where the call's rows lie in it
+    at, s0 = write(k_pool, None)
     _, z0 = write(v_pool, None)
     # a call from position 0 finds a ZERO state: by the coefficients the
     # found state enters with, not by a pass over the state itself
     held = jnp.broadcast_to(length != 0, (b,))[:, None, None]
-    # a row with no real position hands its state back as it found it
-    moved = jnp.any(real, axis=1)[:, None, None]
+    # a row with no real position keeps its state as it found it
+    moved = jnp.any(real, axis=1)  # [B]
 
-    def core(qr, kr, vr, cr, sr, zr, hr, mr):
+    def core(qr, kr, vr, cr, hr, sr, zr):
         """[B', S, Hkv, G, D] queries over the call's own keys and the
-        state [B', Hkv, F, Dv] it found (``hr`` [B', 1, 1]: it counts;
-        ``mr``: the row has a real position): (numerator [B', S, Hkv, G,
-        Dv], normaliser [B', S, Hkv, G], S', z')."""
+        state [B', Hkv, F, Dv] it found (``hr`` [B', 1, 1]: it counts):
+        (numerator [B', S, Hkv, G, Dv], normaliser [B', S, Hkv, G], S',
+        z')."""
         with jax.named_scope("ret.intra"):
             sc = jnp.einsum("bqhgd,bkhd->bhgqk", qr, kr,
                             preferred_element_type=jnp.float32)
@@ -729,38 +750,56 @@ def _power_retention(x, lp, k_pool, v_pool, positions, length, write, cfg,
                 "bshgf,bhf->bshg", pq, zr)
             last = cr[:, -1]  # [B', Hkv]
             pk = pk * jnp.exp(last[:, None] - cr)[..., None]  # G(last, j)
-            s_new = jnp.where(mr[..., None], before[:, -1, :, None, None] * sr
-                              + jnp.einsum("bshf,bshv->bhfv", pk, vr), sr)
-            z_new = jnp.where(mr, before[:, -1, :, None] * zr
-                              + jnp.sum(pk, axis=1), zr)
+            s_new = before[:, -1, :, None, None] * sr \
+                + jnp.einsum("bshf,bshv->bhfv", pk, vr)
+            z_new = before[:, -1, :, None] * zr + jnp.sum(pk, axis=1)
         return num, den, s_new, z_new
 
-    rows = (qg, kk, v, cum, held, moved)
-    if b > 1 and b * s * nh * s0.shape[2] > _FEATURES_AT_ONCE:
-        def one(r, carry):
-            """Row ``r``, its state read from and put back into the carried
-            arrays where it lies: no second copy of all the rows' states."""
-            s_all, z_all, num, den = carry
+    rows = (qg, kk, v, cum, held)
 
-            def at(t):
-                return lax.dynamic_slice_in_dim(t, r, 1, axis=0)
+    def row_of(t, p):
+        return lax.dynamic_slice_in_dim(t, p, 1, axis=0)
 
-            qr, kr, vr, cr, hr, mr = (at(t) for t in rows)
-            n_r, d_r, s_r, z_r = core(qr, kr, vr, cr, at(s_all), at(z_all),
-                                      hr, mr)
-            return tuple(lax.dynamic_update_slice_in_dim(t, o, r, axis=0)
-                         for t, o in ((s_all, s_r), (z_all, z_r), (num, n_r),
-                                      (den, d_r)))
+    def put(t, new, p):
+        return lax.dynamic_update_slice_in_dim(t, new.astype(t.dtype), p,
+                                               axis=0)
 
-        s_new, z_new, num, den = lax.fori_loop(0, b, one, (
-            s0, z0, jnp.zeros((b, s, hkv, nh // hkv, dv), jnp.float32),
-            jnp.zeros((b, s, hkv, nh // hkv), jnp.float32)))
+    if b == 1:
+        sr, zr = row_of(s0, at[0]), row_of(z0, at[0])
+        num, den, s_r, z_r = core(*rows, sr, zr)
+        with jax.named_scope("ret.state"):
+            s_r, z_r = lax.optimization_barrier((
+                jnp.where(moved[0], s_r, sr), jnp.where(moved[0], z_r, zr)))
+            s_new, z_new = put(s0, s_r, at[0]), put(z0, z_r, at[0])
     else:
-        num, den, s_new, z_new = core(*rows[:4], s0, z0, *rows[4:])
+        with jax.named_scope("ret.state"):
+            # the rows with a real position first, in their order
+            order = jnp.argsort(~moved, stable=True)
+
+            def one(i, carry):
+                """The ``i``-th row with a real position, its state read
+                from and put back into the carried arrays where it lies:
+                no second copy of all the rows' states, and a row the loop
+                never reaches is neither read nor written."""
+                s_all, z_all, num, den = carry
+                r = order[i]
+                p = at[r]
+                n_r, d_r, s_r, z_r = core(
+                    *(row_of(t, r) for t in rows), row_of(s_all, p),
+                    row_of(z_all, p))
+                return (put(s_all, s_r, p), put(z_all, z_r, p),
+                        put(num, n_r, r), put(den, d_r, r))
+
+            s_new, z_new, num, den = lax.fori_loop(
+                0, jnp.sum(moved), one, (
+                    s0, z0, jnp.zeros((b, s, hkv, nh // hkv, dv), jnp.float32),
+                    jnp.zeros((b, s, hkv, nh // hkv), jnp.float32)))
     with jax.named_scope("ret.state"):
-        # the update stays an operation of its own, under this scope: fused
-        # with what follows the layer loop (the shard's leading axis put
-        # back) a decode step's 15 ms of it read as under no scope at all
+        # the advanced arrays stay values of their own, under this scope:
+        # fused with what follows the layer loop (the shard's leading axis
+        # put back) a one-row call's update reads as under no scope at all
+        # and its temporaries nearly double (0.20 -> 0.38 GB compiled for a
+        # described v5e); a row loop's result is the loop's own
         s_new, z_new = lax.optimization_barrier((s_new, z_new))
         k_pool, _ = write(k_pool, s_new)
         v_pool, _ = write(v_pool, z_new)
@@ -859,6 +898,24 @@ def _head(x, params, cfg):
         return x.astype(jnp.float32) @ params["head"].astype(jnp.float32)
 
 
+def _state_write(gi: int, at):
+    """The ``write`` a state group's layer ``gi`` is handed its operator
+    with (:data:`STATE_GROUPS`; ``pool`` the group's tuple of arrays, one a
+    layer): ``write(pool, None)`` gives the layer's own array ``pool[gi]``
+    ``[B_slots, *state]`` with the places ``at`` [B] of the call's rows in
+    it, ``write(pool, new)`` the pool with that array replaced by ``new`` —
+    the buffer the operator advanced its rows in, pool-wide and compact
+    alike: only the slots of rows with a real position were touched, and no
+    other was read (a masked row's, a padding row's clamped one, a slot no
+    row names)."""
+    def write(pool, new):
+        if new is None:
+            return at, pool[gi]
+        return pool[:gi] + (new,) + pool[gi + 1:], None
+
+    return write
+
+
 def _forward_cached(
     params, tokens, cache: KVCache, cfg, ffn=None
 ) -> Tuple[jax.Array, KVCache]:
@@ -875,7 +932,8 @@ def _forward_cached(
     ``{group: array}`` (:func:`cache_groups`), every group ``S_max`` rows
     here: the one-shot path keeps a window layer's every position. A state
     group (:data:`STATE_GROUPS`) has no rows: its ``write`` hands the
-    layer's state over (``new`` None) or replaces it."""
+    layer's state over with the rows' places in it (``new`` None: row b at
+    b) or replaces it."""
     b, s = tokens.shape
     k, v = cache.k, cache.v
     x = _embed(params, tokens, cfg, jax.tree.leaves(k)[0].dtype)
@@ -888,10 +946,7 @@ def _forward_cached(
             return pool, pool[gi]
 
         if group in STATE_GROUPS:
-            def write(pool, new, gi=gi):  # noqa: F811 — the layer's state
-                if new is not None:
-                    pool = pool[:gi] + (new,) + pool[gi + 1:]
-                return pool, pool[gi]
+            write = _state_write(gi, jnp.arange(b))
 
         lp = _layer_params(params, i, cfg)
         x, nk, nv = _attention_of(cfg, i)(
@@ -1106,12 +1161,14 @@ def _forward_slots(
     group (:data:`STATE_GROUPS`: "retention", a tuple of ``L_ret`` arrays
     ``[B_slots, Hkv, F, Dv]`` in ``k`` and ``[B_slots, Hkv, F]`` in ``v``)
     has no position axis: a row's whole state is read, advanced and put
-    back in the layer's own array, a masked row's left as it is bit for
-    bit. Such a layer is told how many of a row's ``S`` positions are REAL,
-    ``valid`` [B] (None: all ``S`` of a row in ``write_mask``; none of
-    another, whatever is passed): a padded position that advanced a state
-    would corrupt it, where a padded row by position lands past the slot's
-    length and is harmless. No other operator is handed it.
+    back where it lies in the layer's own array, pool-wide and compact
+    alike; a masked row's is never read and left as it is bit for bit
+    (:func:`_power_retention`). Such a layer is told how many of a row's
+    ``S`` positions are REAL, ``valid`` [B] (None: all ``S`` of a row in
+    ``write_mask``; none of another, whatever is passed): a padded position
+    that advanced a state would corrupt it, where a padded row by position
+    lands past the slot's length and is harmless. No other operator is
+    handed it.
     ``head_at`` [B] (None: every position): the one position a row whose
     logits the caller reads; the logits are then ``[B, 1, V]``.
 
@@ -1181,33 +1238,7 @@ def _forward_slots(
             return pool, rows_of(pool, gi)
 
         if group in STATE_GROUPS:
-            def write(pool, new, gi=gi):  # noqa: F811 — the layer's state
-                """The rows' states as the call finds them in the layer's
-                own array ``pool[gi]`` ``[B_slots, *state]`` (``new`` None),
-                or ``new`` put in their place: the whole array replaced (the
-                operator hands back a row without a real position, so every
-                masked row, what it found, bit for bit), or one slot a
-                compact row."""
-                layer = pool[gi]
-                zeros = (0,) * (layer.ndim - 1)
-                if new is None:
-                    if slots is None:
-                        return pool, layer
-                    return pool, jnp.concatenate([
-                        lax.dynamic_slice(layer, (slots[r],) + zeros,
-                                          (1,) + layer.shape[1:])
-                        for r in range(b)])
-                if slots is None:  # a masked row's ``new`` is what it held
-                    return pool[:gi] + (new,) + pool[gi + 1:], None
-                for r in range(b):
-                    # a padding row's slot clamps into the pool: it puts
-                    # back what it finds there
-                    at = (slots[r],) + zeros
-                    held = lax.dynamic_slice(layer, at,
-                                             (1,) + layer.shape[1:])
-                    layer = lax.dynamic_update_slice(layer, jnp.where(
-                        write_mask[r], new[r][None], held), at)
-                return pool[:gi] + (layer,) + pool[gi + 1:], None
+            write = _state_write(gi, bidx[:, 0])
 
         lp = _layer_params(params, i, cfg)
         lora = None

@@ -409,13 +409,13 @@ def test_engine_served_tokens_are_generates(model):
                          decode_impl="sort")
     in_place = obs.counter("serving_pool_in_place_total")
     before = {k: in_place.get(program=k) for k in ("prefill", "decode")}
-    calls, run = {"prefill": 0, "decode": 0}, backend._run
+    calls, launch = {"prefill": 0, "decode": 0}, backend._launch
 
     def counted(kind, *a, **kw):
         calls[kind] += 1
-        return run(kind, *a, **kw)
+        return launch(kind, *a, **kw)
 
-    backend._run = counted
+    backend._launch = counted
     eng = ServingEngine(backend, prefill_chunk=4)
     reqs = [eng.submit(_tokens(n, seed=20 + n), max_new_tokens=m)
             for n, m in ((5, 24), (23, 20), (11, 30))]

@@ -112,6 +112,12 @@ def _pool(backend):
     return [np.asarray(a).copy() for a in backend.cache]
 
 
+def _inside(e, w):
+    """Whether the ring's span ``e`` lies inside ``w``."""
+    return (w.ts_us <= e.ts_us
+            and e.ts_us + e.dur_us <= w.ts_us + w.dur_us + 1e-3)
+
+
 @pytest.mark.parametrize("cls", [DenseBackend, MoEBackend])
 def test_a_constructor_and_nothing_else(cls):
     assert issubclass(cls, SlotBackend)
@@ -137,12 +143,8 @@ def test_a_call_is_stage_launch_fetch_inside_its_wire_span(stacks, stack,
                    key=lambda e: e.ts_us)
     assert {e.track for e in inner} == {"wire"}
 
-    def inside(e, w):
-        return (w.ts_us <= e.ts_us
-                and e.ts_us + e.dur_us <= w.ts_us + w.dur_us + 1e-3)
-
     for w in wires:
-        mine = [e for e in inner if inside(e, w)]
+        mine = [e for e in inner if _inside(e, w)]
         # a clone's first chunked call builds its other rungs: each is one
         # more whole triple inside the same wire span
         assert len(mine) % 3 == 0 and mine
@@ -153,7 +155,7 @@ def test_a_call_is_stage_launch_fetch_inside_its_wire_span(stacks, stack,
             assert a.ts_us + a.dur_us <= b.ts_us + 1e-3
     # every backend span lies in SOME wire span: none runs bare
     every_wire = [e for e in spans if e.name.startswith("wire.")]
-    assert all(any(inside(e, w) for w in every_wire) for e in inner)
+    assert all(any(_inside(e, w) for w in every_wire) for e in inner)
 
 
 @pytest.mark.parametrize("pushed", [False, True], ids=["clone", "params"])
@@ -207,13 +209,13 @@ def test_clone_refuses_a_tree_that_does_not_match(stacks, stack, fault):
 
 def _count_runs(backend):
     """Count the backend's program calls by kind, from here on."""
-    calls, run = Counter(), backend._run
+    calls, launch = Counter(), backend._launch
 
     def counted(kind, *a, **kw):
         calls[kind] += 1
-        return run(kind, *a, **kw)
+        return launch(kind, *a, **kw)
 
-    backend._run = counted
+    backend._launch = counted
     return calls
 
 
@@ -533,3 +535,194 @@ def test_run_hands_the_programs_host_arrays(world, counted):
     np.testing.assert_array_equal(stub.calls[2][1][0].reshape(rows, 3), toks)
     assert out["prefill"].shape == out["decode"].shape == (rows,)
     assert [o.shape for o in out["verify"]] == [(rows, 3), (rows,)]
+
+
+# -- a chunk step launches both its programs before it reads either (ISSUE 47)
+
+CHUNK_CALLS = obs.counter("serving_chunk_step_calls_total")
+LABELS = ("together", "prompt_ends", "no_decode", "in_turn")
+# {engine step: lengths of the prompts that arrive before it}: prompts keep
+# arriving while earlier rows decode, some a multiple of the chunk
+SCHEDULES = {
+    "staggered": {0: [9], 2: [13], 5: [6, 17], 9: [8]},
+    "burst": {0: [5], 3: [25, 7, 12], 9: [26]},
+}
+
+
+class _InTurn:
+    """The backend with its two halves hidden: what a stub or an external
+    backend looks like to the engine, which then keeps its calls in turn."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def __getattr__(self, name):
+        if name in ("launch_prefill", "launch_decode", "fetch"):
+            raise AttributeError(name)
+        return getattr(self._inner, name)
+
+
+def _labels():
+    return {k: CHUNK_CALLS.get(calls=k) for k in LABELS}
+
+
+def _drive(backend, chunk, schedule, n_new=6, steps=60, **kw):
+    """Serve ``schedule`` step by step. Returns (every request's tokens with
+    the engine step each was emitted in, the ring's spans, how the counter
+    moved)."""
+    eng = ServingEngine(backend, prefill_chunk=chunk, **kw)
+    rng = np.random.default_rng(7)
+    reqs, emitted = [], []
+    before = _labels()
+    tr = obs.enable_tracing()
+    try:
+        for i in range(steps):
+            for n in schedule.get(i, ()):
+                reqs.append(eng.submit(rng.integers(1, 64, n).astype(np.int32),
+                                       max_new_tokens=n_new))
+                emitted.append([])
+            if eng.has_work():
+                eng.step()
+            for r, at in zip(reqs, emitted):
+                at.extend([i] * (len(r.out_tokens) - len(at)))
+        spans = sorted((e for e in tr.events() if e.ph == "X"),
+                       key=lambda e: e.ts_us)
+    finally:
+        obs.disable_tracing()
+    assert not eng.has_work() and all(r.is_done() for r in reqs)
+    moved = {k: v - before[k] for k, v in _labels().items()}
+    return [(r.out_tokens, at) for r, at in zip(reqs, emitted)], spans, moved
+
+
+@pytest.mark.parametrize("schedule", sorted(SCHEDULES))
+@pytest.mark.parametrize("chunk", [CHUNK, 2 * CHUNK])
+@pytest.mark.parametrize("stack", STACKS)
+def test_together_serves_in_turns_tokens_in_in_turns_steps(stacks, stack,
+                                                           chunk, schedule):
+    proto, oracle, _ = stacks[stack]
+    served, spans, moved = _drive(proto.clone(), chunk, SCHEDULES[schedule])
+    in_turn, _, hidden = _drive(_InTurn(proto.clone()), chunk,
+                                SCHEDULES[schedule])
+    assert served == in_turn  # every token, and the step it came in
+    # the counter's labels add up to the chunk steps taken, which are the
+    # same steps either way; only how their calls went differs
+    chunk_steps = sum(e.name == "wire.prefill" for e in spans)
+    assert sum(moved.values()) == sum(hidden.values()) == chunk_steps
+    assert moved["together"] > 0 and moved["in_turn"] == 0
+    assert hidden["together"] == 0 and hidden["in_turn"] == moved["together"]
+    for k in ("prompt_ends", "no_decode"):
+        assert moved[k] == hidden[k]
+    assert moved["prompt_ends"] > 0
+    # the step's span carries the same word
+    said = Counter(e.args["calls"] for e in spans
+                   if e.name == "engine.step" and "calls" in (e.args or {}))
+    assert said == Counter({k: int(v) for k, v in moved.items() if v})
+
+
+@pytest.mark.parametrize("stack", STACKS)
+def test_the_spans_of_a_step_by_how_its_calls_went(stacks, stack):
+    """``together``: wire.prefill holds stage, launch, stage, launch and the
+    prefill call's fetch, closes, and only then wire.decode opens, around
+    the decode call's fetch alone. ``prompt_ends``: the two calls in turn,
+    and the row whose prompt ended decodes in that same step."""
+    served, spans, moved = _drive(stacks[stack][0].clone(), CHUNK,
+                                  SCHEDULES["staggered"])
+    steps = [e for e in spans if e.name == "engine.step"
+             and (e.args or {}).get("calls") in ("together", "prompt_ends")]
+    assert {e.args["calls"] for e in steps} == {"together", "prompt_ends"}
+    for step in steps:
+        inner = [e for e in spans if _inside(e, step) and e is not step
+                 and e.name.startswith(("wire.", "backend."))]
+        pre, = [e for e in inner if e.name == "wire.prefill"]
+        dec, = [e for e in inner if e.name == "wire.decode"]
+        assert pre.ts_us + pre.dur_us <= dec.ts_us + 1e-3
+        assert dec.args["step"] == pre.args["step"] and dec.args["n"] >= 1
+        assert "kv_rows" in dec.args
+        names = lambda w: [e.name for e in inner
+                           if e is not w and _inside(e, w)]
+        triple = ["backend.stage", "backend.launch", "backend.fetch"]
+        if step.args["calls"] == "together":
+            assert names(pre) == triple[:2] * 2 + triple[2:]
+            assert names(dec) == triple[2:]
+        else:
+            assert names(pre) == names(dec) == triple
+    # a prompt that ends in a step takes its first AND its second token in it
+    joined = [at for _, at in served if at[0] == at[1]]
+    assert joined and moved["prompt_ends"] >= len(joined)
+
+
+@pytest.mark.parametrize("stack", STACKS)
+def test_chunk_sink_sees_a_steps_events_before_any_of_its_retirements(
+        stacks, stack):
+    """Also in a step whose decode program was launched before the sink ran:
+    the rows it retires are still in their slots when the sink is called."""
+    seen = []  # (rids not finished when the sink ran, the events' rids)
+
+    def sink(events):
+        seen.append(({r.rid for r in eng._by_slot.values()},
+                     [ev.req.rid for ev in events]))
+
+    eng = ServingEngine(stacks[stack][0].clone(), prefill_chunk=CHUNK,
+                        chunk_sink=sink)
+    first = eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=4)
+    before = _labels()
+    retired_together = False
+    for i in range(12):
+        if i == 2:  # three more chunk steps, beside ``first``'s last tokens
+            late = eng.submit(np.arange(1, 14, dtype=np.int32),
+                              max_new_tokens=2)
+        n_calls = len(seen)
+        together = CHUNK_CALLS.get(calls="together")
+        finished = eng.step() if eng.has_work() else []
+        if (first in finished
+                and CHUNK_CALLS.get(calls="together") > together):
+            retired_together = True
+            assert len(seen) == n_calls + 1
+            live, rids = seen[-1]
+            assert first.rid in live and rids == [late.rid]
+    assert retired_together and first.is_done() and late.is_done()
+    assert _labels()["together"] - before["together"] >= 2
+
+
+@pytest.mark.parametrize("stack", STACKS)
+def test_a_launch_that_fails_after_consuming_the_pool_leaves_nothing_unread(
+        stacks, stack):
+    """The launch half raises what the whole call raised, and a chunk step
+    whose decode launch fails has read and booked the prefill call it had
+    launched: the engine's state is in turn's at the failure."""
+    backend = stacks[stack][0].clone()
+    eng = ServingEngine(backend, prefill_chunk=CHUNK)
+    eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=8)
+    for _ in range(3):
+        eng.step()
+    late = eng.submit(np.arange(1, 14, dtype=np.int32), max_new_tokens=2)
+    real = backend.programs
+
+    class Broken:
+        prefill = staticmethod(real.prefill)
+
+        @staticmethod
+        def decode(params, tokens, active, pool, **kw):
+            for leaf in jax.tree.leaves((pool.k, pool.v)):
+                leaf.delete()
+            raise FloatingPointError("device fault")
+
+    backend.programs = Broken()
+    unread = Counter()
+    launch, fetch = backend._launch, backend._fetch
+    backend._launch = lambda kind, *a, **kw: (
+        unread.update([kind]), launch(kind, *a, **kw))[1]
+    backend._fetch = lambda kind, out: (
+        unread.subtract([kind]), fetch(kind, out))[1]
+    before = _labels()
+    with pytest.raises(RuntimeError, match="consumed the slot pool") as e:
+        eng.step()
+    assert isinstance(e.value.__cause__, FloatingPointError)
+    assert _labels()["together"] - before["together"] == 1
+    # the prefill call was launched (counted before the program ran), read
+    # and booked; the decode call never came to be
+    assert unread == Counter({"prefill": 0, "decode": 1})
+    assert late.prefill_pos == CHUNK
+    with pytest.raises(RuntimeError, match="consumed the slot pool"):
+        backend.launch_decode(np.zeros(N_SLOTS, np.int32),
+                              np.zeros(N_SLOTS, bool))

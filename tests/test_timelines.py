@@ -407,3 +407,173 @@ def test_the_leads_source_is_in_the_runs_log(monkeypatch, capsys):
     read(_view(monkeypatch, "log:none",
                runtime=lambda m: _runtime(m, stray=5)))
     assert "lead_from=runtime lead_bounds_ms=None" in capsys.readouterr().out
+
+
+# -- a chunk step whose two calls are launched before either is read (ISSUE 47)
+
+DECODE_PROGRAM = "jit_uccl_moe_decode_slots(9)"
+PRE_PATH = "jit(uccl_moe_prefill_slots)/moe.experts/dot_general:"
+DEC_PATH = "jit(uccl_moe_decode_slots)/attn.core/dot_general:"
+# the chunk step's two programs on the HOST's clock, operation by operation:
+# the prefill program [103.6, 119.6), the decode program behind it
+PRE_OPS = [(f"%p{i} = f32[8] fusion()", 103.6 + 4 * i, 4.0, PRE_PATH)
+           for i in range(4)]
+
+
+def _dec_ops(t):
+    """The decode program's three operations from ``t``: 7 ms."""
+    return [("%d0 = f32[8] fusion()", t, 1.0, DEC_PATH),
+            ("%d1 = f32[8] fusion()", t + 1.0, 2.3, DEC_PATH),
+            ("%d2 = f32[8] fusion()", t + 3.3, 3.7, DEC_PATH)]
+
+
+def _chunk_step(shape):
+    """One chunk step (``step`` 3) between decode-only steps 1, 2 (calls at
+    10, 21) and 4, 5 (calls at 150, 161), as ``parent`` lays it out — the
+    prefill call staged, launched and read, then the decode call — or as
+    the ``change`` does: both launched inside ``wire.prefill``, which closes
+    after the prefill call's fetch; ``wire.decode`` holds the decode call's
+    fetch alone, and the step's span says ``calls`` = ``together``. Returns
+    (spans, modules, operations), the device's events on the host's clock."""
+    spans, modules, ops = [], [], []
+    for t, step in ((10, 1), (21, 2), (150, 4), (161, 5)):
+        spans += _call(t, step)
+        spans.append(("uccl.engine.step", t - 0.5, 11, {"decoding": 1}))
+        modules += _device(t)
+    pre = {"step": 3, "n": 1, "chunk": 8, "rows": 1, "tokens": 8}
+    dec = {"step": 3, "n": 1, "kv_rows": 10}
+    modules.append((PREFILL, 103.6, 16.0))
+    if shape == "parent":
+        spans += [("uccl.engine.step", 100, 40, {"decoding": 1}),
+                  ("uccl.wire.prefill", 101, 20, pre),
+                  ("uccl.backend.stage", 101, 1, {}),
+                  ("uccl.backend.launch", 102, 1, {}),
+                  ("uccl.backend.fetch", 103, 17.5, {}),
+                  ("uccl.wire.decode", 122, 11, dec),
+                  ("uccl.backend.stage", 122, 1, {}),
+                  ("uccl.backend.launch", 123, 1, {}),
+                  ("uccl.backend.fetch", 124, 8.5, {})]
+        dec_at = 124.6
+    else:
+        spans += [("uccl.engine.step", 100, 35,
+                   {"decoding": 1, "calls": "together"}),
+                  ("uccl.wire.prefill", 101, 20, pre),
+                  ("uccl.backend.stage", 101, 1, {}),
+                  ("uccl.backend.launch", 102, 1, {}),
+                  ("uccl.backend.stage", 103, 1, {}),
+                  ("uccl.backend.launch", 104, 1, {}),
+                  ("uccl.backend.fetch", 105, 15.5, {}),
+                  ("uccl.wire.decode", 122, 6, dec),
+                  ("uccl.backend.fetch", 122, 5.5, {})]
+        dec_at = 119.7  # behind the prefill program, with no host between
+    modules.append((DECODE_PROGRAM, dec_at, 7.0))
+    ops = [(m[0], m[1], m[2], "") for m in modules
+           if m[0] not in (PREFILL, DECODE_PROGRAM)] \
+        + PRE_OPS + _dec_ops(dec_at)
+    key = lambda e: e[1]
+    return (sorted(_ns(spans), key=key), sorted(_ns(modules), key=key),
+            sorted(_ns(ops), key=key))
+
+
+def _chunk_view(monkeypatch, shape, path):
+    spans, modules, ops = _chunk_step(shape)
+    device = lambda evs: [(e[0], e[1] - LEAD * MS) + tuple(e[2:])
+                          for e in evs]
+    loaded = pt.ProgramTrace(spans, [ops], None)  # the host's clock: lead 0
+    monkeypatch.setattr(pt, "load", lambda p: loaded)
+    monkeypatch.setattr(pt, "host_events",
+                        lambda p: (spans,) + _runtime(device(modules)))
+    return _View(device(modules), device(ops), path)
+
+
+@pytest.mark.parametrize("shape", ["parent", "change"])
+def test_a_prefill_row_holds_every_operation_of_its_program(monkeypatch,
+                                                            shape):
+    from chipbench import scopes as sc
+    from chipbench import trace_reduce as tr
+
+    view = _chunk_view(monkeypatch, shape, "rows:" + shape)
+    spans = pt.load("x").spans
+    wire = {sp[0]: sp for sp in spans if sp[3].get("step") == 3}
+    pre, dec = wire[pt.PREFILL], wire[pt.DECODE]
+    fetches = [sp for sp in spans if sp[0] == st.FETCH
+               and pre[1] <= sp[1] < pre[1] + pre[2]]
+    # wire.prefill closes after its own fetch and before wire.decode opens
+    assert len(fetches) == 1
+    assert fetches[0][1] + fetches[0][2] <= pre[1] + pre[2] <= dec[1]
+    ops = pt._window_ops("ops:" + shape, *WINDOW)
+    inside, = tr.events_inside(ops, [pre], pt.PREFILL)
+    assert {o[0] for o in PRE_OPS} <= {o[0] for o in inside}
+    scopes = ("moe.experts", "attn.core")
+    row, = sc._scope_rows("rows:" + shape, pt.PREFILL, *WINDOW, scopes)
+    assert row.by["moe.experts"] == pytest.approx(16 * MS)
+    assert row.facts["tokens"] == 8
+    if shape == "parent":  # the call is read before the next is made
+        assert row.busy == pytest.approx(16 * MS)
+    else:  # ... with the decode program's first operations: never fewer
+        assert row.busy == pytest.approx((16 + 3.3) * MS)
+        assert row.by["attn.core"] == pytest.approx(3.3 * MS)
+    assert view.window == WINDOW
+
+
+@pytest.mark.parametrize("shape", ["parent", "change"])
+def test_an_overlapped_decode_call_is_left_out_of_the_calls(monkeypatch,
+                                                            shape):
+    view = _chunk_view(monkeypatch, shape, "calls:" + shape)
+    t = st.of(view)
+    assert t.lead_bounds == pytest.approx((0.8 * MS, 1.2 * MS))
+    # no launch inside its wire.decode: the overlapped call is no call
+    assert [c.step for c in t.calls] == (
+        [1, 2, 3, 4, 5] if shape == "parent" else [1, 2, 4, 5])
+    # ... and the medians are those of the decode-only calls (EXPECTED)
+    for c in t.calls:
+        if c.step != 3:
+            assert c.own[1] - c.launch == pytest.approx(1.6 * MS)
+            assert c.fetched - c.own[2] == pytest.approx(2.1 * MS)
+    assert st.decode_dispatch_latency_ms(view) == pytest.approx(1.6)
+    assert st.decode_completion_latency_ms(view) == pytest.approx(2.1)
+    assert st.device_programs_per_decode_call(view) == 4
+    assert t.prefill_steps == {3}  # (1, 2) and (4, 5) are back to back
+    assert st.host_between_calls_ms(view) == pytest.approx(2.5)
+
+
+@pytest.mark.parametrize("shape, host_ms, share", [
+    ("parent", 40 - 23, 0.0), ("change", 35 - 23, 100.0)])
+def test_the_chunk_steps_readers(monkeypatch, shape, host_ms, share):
+    view = _chunk_view(monkeypatch, shape, "chunk:" + shape)
+    host = R.load_reader("chunk_step_host_ms")
+    assert [sp[1] for sp in host.chunk_steps(view)] == [100 * MS]
+    assert host.read(view) == pytest.approx(host_ms)
+    together = R.load_reader("chunk_steps_together_share").read
+    assert together(view) == pytest.approx(share)
+    # a chunk step whose device events the trace lost (the profiler's
+    # buffer was full): counted as a step, left out of the host's time
+    spans = pt.load("x").spans
+    said = {"calls": "together"} if share else {}
+    spans += _ns([("uccl.engine.step", 180, 15, said),
+                  ("uccl.wire.prefill", 181, 8, {"step": 9}),
+                  ("uccl.wire.decode", 190, 4, {"step": 9})])
+    assert len(host.chunk_steps(view)) == 2
+    assert host.read(view) == pytest.approx(host_ms)
+    assert together(view) == pytest.approx(share)
+
+    class NoTrace:  # a traced run of a program without spans
+        record = {"trace_path": None}
+        window = None
+
+    assert host.read(NoTrace) is None and together(NoTrace) is None
+    # a window without a chunk step: nothing to take a median of
+    view.window = (0.0, 50.0 * MS)
+    assert host.read(view) is None and together(view) is None
+
+
+def test_the_two_chunk_step_readings_are_listed_in_every_cell():
+    with open(os.path.join(R.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = [w["name"] for w in bench["workloads"]]
+    last = bench["per_layer"][-2:]
+    assert [m["name"] for m in last] == ["chunk_step_host_ms",
+                                         "chunk_steps_together_share"]
+    for m in last:
+        assert m["workloads"] == cells and m["moves"] == "itl_p90_ms"
+        assert (m["layer"], m["source"]) == ("serving engine", "program_span")

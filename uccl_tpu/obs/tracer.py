@@ -22,7 +22,7 @@ steps, wire windows — with the properties the NPKit design proves out:
 
 The profiler bridge: :func:`span` ALSO writes the block into any open
 ``jax.profiler`` session as a ``TraceAnnotation`` named ``"uccl." + name``
-with the span's entry arguments — the host plane of the profiler's trace,
+with the span's arguments — the host plane of the profiler's trace,
 on the same clock as the device's operations — whether or not the ring is
 enabled. That is how a profiled run attributes device idle time to what the
 host was doing (chipbench's ``program_trace.py`` reads them back). This
@@ -254,8 +254,9 @@ def _annotation_cls():
 class _Span:
     """One :func:`span` block: a profiler annotation for its length and,
     when the ring is enabled, one "X" event. :meth:`add` attaches what is
-    only known at exit to the ring's record (the annotation's arguments are
-    fixed on entry)."""
+    only known inside the block to both: the ring's record takes it in
+    place of an entry argument of the same name, the annotation after its
+    entry arguments (a reader that makes a dict of them sees the same)."""
 
     __slots__ = ("_tracer", "_ann", "_name", "_track", "_args", "_t0")
 
@@ -281,6 +282,8 @@ class _Span:
 
     def add(self, **args) -> None:
         self._args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
 
 def enable(capacity: int = 65536) -> Tracer:
@@ -307,7 +310,8 @@ def span(name: str, track: Optional[str] = None, **args):
     """Span over the with-block: into the ring when tracing is on, and into
     any open ``jax.profiler`` session as ``"uccl." + name`` either way (see
     the module docstring). ``with span(...) as sp: ...; sp.add(k=v)`` adds
-    exit-time arguments to the ring's record."""
+    arguments known only inside the block, to the ring's record and the
+    annotation."""
     t = _tracer
     ann = _annotation_cls()
     if ann is not None:

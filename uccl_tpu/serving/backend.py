@@ -4,12 +4,14 @@ One class, :class:`SlotBackend`, stands between the engine loop
 (serving/engine.py, scheduling only) and the one definition of the slot
 programs (``models/inference.py::{prefill,verify}_slots``). It owns the
 params, the pool, the chunked prefill's rungs, the one ``stage -> launch ->
-fetch`` sequence every call goes through, and the slot-row shims the prefix
-cache and the disagg stream use. The dense and the MoE stack are two
-constructors of it (:class:`DenseBackend`, :class:`MoEBackend`): they differ
-in what they hand it — how the pool is born, how a flat per-slot array is
-laid out for the programs, the three compiled callables, the rungs — and in
-no method.
+fetch`` sequence every call goes through — in two halves, ``stage -> launch``
+and ``fetch``, which the engine's chunk step calls apart (both its programs
+launched before either is read) and every other caller in turn — and the
+slot-row shims the prefix cache and the disagg stream use. The dense and the
+MoE stack are two constructors of it (:class:`DenseBackend`,
+:class:`MoEBackend`): they differ in what they hand it — how the pool is
+born, how a flat per-slot array is laid out for the programs, the three
+compiled callables, the rungs — and in no method.
 
 Sizing of a compiled-program LRU (``DensePrograms._fns``, ``MoEServer._fns``;
 utils/lru.py): a chunked engine's steady set is its prefill programs (the
@@ -96,7 +98,7 @@ class Programs(NamedTuple):
     They are pure in params (nothing baked but shapes) and CONSUME the pool
     handed in: it is donated, written in place and handed back as the same
     buffers, so the caller drops every reference to the one it passed (the
-    backend's ``_run`` does, in the statement of the call). Backends of one
+    backend's ``_launch`` does, in the statement of the call). Backends of one
     shape share one of these — a replica set costs one warm-up, not N."""
     prefill: Callable
     decode: Callable
@@ -175,8 +177,21 @@ class DensePrograms:
 
 class SlotBackend:
     """Slot-pool serving of one model on one pool: the engine's whole view of
-    the model (``prefill`` / ``decode`` / ``verify``, the three slot-row
-    shims, ``n_slots``, ``max_seq``, ``prefill_rungs``).
+    the model (``prefill`` / ``decode`` / ``verify``, the halves of the
+    first two — ``launch_prefill`` / ``launch_decode`` then ``fetch`` —, the
+    three slot-row shims, ``n_slots``, ``max_seq``, ``prefill_rungs``).
+
+    A call is ``backend.stage -> backend.launch -> backend.fetch`` (the
+    spans ``chipbench/program_trace.py`` reads the device's idle time by),
+    nested inside the engine's ``wire.*``; a chunk step that launches both
+    its calls before it reads either has ``stage, launch, stage, launch,
+    fetch`` inside its ``wire.prefill`` and the decode call's ``fetch``
+    alone inside its ``wire.decode`` (docs/OBSERVABILITY.md). The pool has
+    one holder, ``self.cache``, which each launch moves to the pool its
+    program gives back: nothing else may hold the pool across a call, and
+    with two launches in flight that holds launch by launch — the second
+    takes what the first left there, a promise of the asynchronous dispatch
+    — while the slot-row shims read ``self.cache`` as it is when called.
 
     ``programs`` are the compiled callables (:class:`Programs`);
     ``new_pool()`` bears a pool; ``world`` is how a flat per-slot array is
@@ -246,19 +261,21 @@ class SlotBackend:
             return flat
         return flat.reshape((self.world, -1) + flat.shape[1:])
 
-    def _run(self, kind, per_row, sampling, adapters, **rows_kw):
-        """One call of the program ``kind`` ("prefill" | "decode" |
-        "verify"). ``per_row``: its (flat array, dtype) arguments in order;
+    def _launch(self, kind, per_row, sampling, adapters, **rows_kw) -> list:
+        """The first half of one call of the program ``kind`` ("prefill" |
+        "decode" | "verify"): stage its host arrays and launch it.
+        ``per_row``: its (flat array, dtype) arguments in order;
         ``rows_kw``: per-row int32 keyword arguments (None = not passed).
-        Returns every output but the pool, flat per row again. A call
-        crosses to the device once each way: the per-row arguments go in as
-        host arrays (:meth:`_lay`) and the outputs come back in one blocking
-        read, every copy started before the first is waited for. The program
-        consumes the pool it is handed and ``self.cache`` becomes the one
-        it gives back, in one statement: nothing else may hold the pool
-        across a call. The three spans are what
-        ``chipbench/program_trace.py`` reads the device's idle time by,
-        nested inside the engine's ``wire.*``."""
+        Returns every output but the pool, NOT YET READ (device arrays the
+        asynchronous dispatch has promised; :meth:`_fetch` reads them). The
+        per-row arguments go in as host arrays (:meth:`_lay`), one
+        hand-over. The program consumes the pool it is handed and
+        ``self.cache`` becomes the one it gives back, in one statement:
+        nothing else may hold the pool across a call — and that holds
+        launch by launch when two are in flight: a second launch before the
+        first is read takes the pool the first left in ``self.cache`` (a
+        promise too: no host wait), so the device runs the programs in the
+        order they were launched, on one pool."""
         with obs.span("backend.stage", "wire"):
             kw = {}
             if sampling is not None:
@@ -293,6 +310,13 @@ class SlotBackend:
                 raise
             if _consumed(pool):
                 _POOL_IN_PLACE.inc(program=kind)
+        return out
+
+    def _fetch(self, kind, out) -> list:
+        """The second half: what :meth:`_launch` returned, read — one
+        blocking read, every copy started before the first is waited for —
+        and flat per row again; a decode / verify call's experts count
+        taken off the end and counted."""
         with obs.span("backend.fetch", "wire"):
             out = jax.device_get(out)
             if self.world is not None:  # [W, rows / W, ...] -> [rows, ...]
@@ -320,19 +344,33 @@ class SlotBackend:
         [n_slots, ...] and row s is slot s. Compact form (``slots`` [R]
         given, a chunked call on a rung below the pool): every argument and
         the returned tokens are [R, ...] and row r is slot ``slots[r]``."""
+        return self.fetch(self.launch_prefill(
+            tokens, lens, mask, start, sampling, adapters, slots))
+
+    def launch_prefill(self, tokens, lens, mask, start=None, sampling=None,
+                       adapters=None, slots=None) -> tuple:
+        """:meth:`prefill` as far as its launch: the call, for
+        :meth:`fetch` to read. What the engine's chunk step calls apart, so
+        that its decode program is launched before this one is read."""
         if start is None:
             start = np.zeros(tokens.shape[0], np.int32)
         else:  # a chunked call
             self._build_other_rungs(*tokens.shape, sampling, adapters)
-        return self._run_prefill(tokens, lens, mask, start, sampling,
-                                 adapters, slots)
+        return self._launch_prefill(tokens, lens, mask, start, sampling,
+                                    adapters, slots)
 
-    def _run_prefill(self, tokens, lens, mask, start, sampling, adapters,
-                     slots) -> np.ndarray:
-        return self._run(
+    def _launch_prefill(self, tokens, lens, mask, start, sampling, adapters,
+                        slots) -> tuple:
+        return "prefill", self._launch(
             "prefill",
             [(tokens, np.int32), (lens, np.int32), (mask, bool)],
-            sampling, adapters, start=start, slots=slots)[0]
+            sampling, adapters, start=start, slots=slots)
+
+    def fetch(self, call: tuple) -> np.ndarray:
+        """The tokens of a launched prefill or decode call (the one
+        blocking read of :meth:`_fetch`). Calls are read in the order they
+        were launched."""
+        return self._fetch(*call)[0]
 
     def _build_other_rungs(self, rows: int, chunk: int, sampling,
                            adapters) -> None:
@@ -356,28 +394,35 @@ class SlotBackend:
                              for a in sampling)
             if adapters is not None:
                 adp = (adapters[0], np.zeros(r, np.int32))
-            self._run_prefill(
+            self.fetch(self._launch_prefill(
                 np.zeros((r, chunk), np.int32), np.ones(r, np.int32),
                 np.zeros(r, bool), np.zeros(r, np.int32), samp, adp,
                 # padding rows all: an index past the pool, dropped on the
                 # way back; the pool rung is the ungathered program
                 None if r == self.n_slots
-                else np.full(r, self.n_slots, np.int32))
+                else np.full(r, self.n_slots, np.int32)))
 
     def decode(self, tokens: np.ndarray, active: np.ndarray,
                sampling=None, adapters=None) -> np.ndarray:
-        return self._run(
+        return self.fetch(self.launch_decode(tokens, active, sampling,
+                                             adapters))
+
+    def launch_decode(self, tokens, active, sampling=None,
+                      adapters=None) -> tuple:
+        """:meth:`decode` as far as its launch (see
+        :meth:`launch_prefill`)."""
+        return "decode", self._launch(
             "decode",
-            [(tokens, np.int32), (active, bool)], sampling, adapters)[0]
+            [(tokens, np.int32), (active, bool)], sampling, adapters)
 
     def verify(self, tokens: np.ndarray, active: np.ndarray,
                sampling=None, adapters=None):
         """One batched [n_slots, k+1] draft-verify window (spec decode):
         returns (target tokens [n_slots, k+1], n_accepted [n_slots]) —
         greedy argmaxes, or lockstep-keyed samples under ``sampling``."""
-        return tuple(self._run(
+        return tuple(self._fetch("verify", self._launch(
             "verify",
-            [(tokens, np.int32), (active, bool)], sampling, adapters))
+            [(tokens, np.int32), (active, bool)], sampling, adapters)))
 
     # slot KV movement (prefix-cache hits + the disagg p2p stream) — thin
     # shims over the pool's export/import views, which take flat slot ids

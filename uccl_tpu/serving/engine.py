@@ -34,7 +34,7 @@ over the dense and the MoE model).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, List, Optional
+from typing import Callable, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -82,6 +82,16 @@ _PREFILL_RUNG = obs.counter(
     "serving_prefill_rung_total",
     "chunked-prefill program calls per rung (labels: rows = the slot rows "
     "the program ran: 1, 2 or the whole pool)",
+)
+_CHUNK_CALLS = obs.counter(
+    "serving_chunk_step_calls_total",
+    "chunked-mode steps that ran a prefill chunk, by how the step's two "
+    "calls went (labels: calls = together: both programs launched before "
+    "either was read | prompt_ends: in turn, a prompt's last chunk hands "
+    "this step's decode its first token | no_decode: nothing decodes "
+    "beside the chunk | in_turn: a backend without the launch / fetch "
+    "halves, or spec decode); the step's ``engine.step`` span carries the "
+    "same word as ``calls``",
 )
 _DROPPED = obs.counter(
     "serving_rejected_total",
@@ -131,6 +141,17 @@ class ChunkEvent:
     done: bool  # this event completes the request's prefill
     first_token: Optional[int]  # set iff done
     reused: bool
+
+
+class _Call(NamedTuple):
+    """One slot-program call of a step, built and not yet made: what the
+    chunk step needs apart to launch its two calls before it reads either."""
+
+    rows: list  # the (slot, request) pairs the call covers
+    args: tuple  # the backend method's positional arguments
+    kw: dict  # ... and its keyword arguments
+    attrs: dict  # the arguments of the call's wire.* span
+    row_of: Optional[dict] = None  # prefill: slot -> its row of the call
 
 
 def _kv_rows(decoding, window: int = 0) -> dict:
@@ -651,7 +672,19 @@ class ServingEngine:
         """One iteration: admit + prefill work, one masked decode, retire.
         Whole-prompt mode prefills admitted prompts in full; chunked mode
         advances every mid-prefill request by one chunk (budget-gated
-        admission). Returns requests finished during this step."""
+        admission). Returns requests finished during this step.
+
+        The calls of one step are launched before any of them is read
+        whenever neither needs the other's tokens: a chunked-mode step with
+        rows prefilling and rows decoding, in which no prompt ends, goes
+        :meth:`_chunk_and_decode_together` (its span layout is there and in
+        docs/OBSERVABILITY.md); every other step makes its calls in turn.
+        ``serving_chunk_step_calls_total{calls}`` counts the chunk steps by
+        how their calls went — ``together`` | ``prompt_ends`` |
+        ``no_decode`` | ``in_turn`` (:meth:`_chunk_step_calls`) — and the
+        step's ``engine.step`` span carries the same word as ``calls``.
+        Either way the rows that decode in a step, every token and the step
+        it is emitted in are the same."""
         if self.dead:
             raise RuntimeError(
                 "engine is dead (killed): a dead replica cannot step — "
@@ -663,7 +696,8 @@ class ServingEngine:
         # spans nest on this one thread (engine.step > engine.admit |
         # wire.* > backend.* | engine.retire): the innermost one covering an
         # instant is what the host was doing then. Arguments are the state
-        # on entry; the ring's record also gets the exit-time ones.
+        # on entry; ``sp.add`` brings what is known only later (how a chunk
+        # step's calls went; the state at exit).
         with obs.span("engine.step", "engine", queued=self.sched.qsize,
                       active=len(self._by_slot),
                       prefilling=len(self._prefilling),
@@ -690,14 +724,22 @@ class ServingEngine:
                 if self._by_slot:
                     self._decode(finished)
             else:
-                # one batched chunk over every mid-prefill slot, then the
-                # step's single decode pass (requests whose cursor just
-                # reached the prompt end join it immediately — same step,
-                # like the whole-prompt path)
-                if self._prefilling:
-                    self._prefill_chunk_step(finished, events)
-                if len(self._by_slot) > len(self._prefilling):
-                    self._decode(finished)
+                # one batched chunk over every mid-prefill slot and the
+                # step's single decode pass: launched together where
+                # neither needs the other's tokens, else in turn (requests
+                # whose cursor just reached the prompt end join the decode
+                # immediately — same step, like the whole-prompt path)
+                calls = self._chunk_step_calls()
+                if calls is not None:
+                    _CHUNK_CALLS.inc(calls=calls)
+                    sp.add(calls=calls)
+                if calls == "together":
+                    self._chunk_and_decode_together(finished, events)
+                else:
+                    if self._prefilling:
+                        self._prefill_chunk_step(finished, events)
+                    if len(self._by_slot) > len(self._prefilling):
+                        self._decode(finished)
             dt = now() - t0
             self.metrics.on_step(dt)
             sp.add(active=len(self._by_slot), queued=self.sched.qsize,
@@ -1214,21 +1256,38 @@ class ServingEngine:
                 self._emit_first_token(slot, req, tok[slot], t_done,
                                        finished)
 
-    def _prefill_chunk_step(self, finished,
-                            events: Optional[List[ChunkEvent]] = None,
-                            ) -> None:
-        """Advance every mid-prefill slot by one C-token chunk (ONE batched
-        call). The call carries R rows, the rung of :func:`prefill_rung` for
-        the slots that are prefilling: below the pool's size the rows are
-        COMPACT — row i is the i-th prefilling slot, named in ``slots``, and
-        the model runs those rows of the pool and no others; at the pool's
-        size row s is slot s, as ever. Rows whose cursor
-        reaches the prompt end emit their first token and leave
-        PARTIAL_PREFILL; other rows' returned tokens are garbage by the
-        model contract and ignored here. ``events`` carries this step's
-        admission-time prefix-cache copies; the chunk advances are appended
-        and the whole batch goes to ``chunk_sink`` BEFORE any retirement,
-        so a sink can export rows while slots still hold them."""
+    def _chunk_step_calls(self) -> Optional[str]:
+        """How this step's prefill call and decode call go (the label of
+        ``serving_chunk_step_calls_total``; None: no slot is prefilling),
+        from what the engine sees in its own state. ``together`` needs rows
+        prefilling AND rows decoding whose tokens do not cross: the decode
+        program takes the last tokens of the rows that were already
+        decoding, so the one link is a row whose cursor reaches its
+        prompt's end in this chunk — it joins this step's decode with the
+        token the prefill program returns, and that step runs in turn."""
+        if not self._prefilling:
+            return None
+        c = self.prefill_chunk
+        if any(r.prefill_pos + c >= r.prompt.size
+               for r in self._prefilling.values()):
+            return "prompt_ends"
+        if len(self._by_slot) == len(self._prefilling):
+            return "no_decode"
+        # hasattr: a backend without the two halves (the tests' stubs,
+        # anything external) keeps the calls in turn, as one without
+        # ``prefill_rungs`` keeps the whole-pool program
+        if self.spec_k is not None or not hasattr(self.backend,
+                                                  "launch_decode"):
+            return "in_turn"
+        return "together"
+
+    def _chunk_call(self) -> _Call:
+        """This step's prefill call: every mid-prefill slot advanced by one
+        C-token chunk (ONE batched call). The call carries R rows, the rung
+        of :func:`prefill_rung` for the slots that are prefilling: below the
+        pool's size the rows are COMPACT — row i is the i-th prefilling
+        slot, named in ``slots``, and the model runs those rows of the pool
+        and no others; at the pool's size row s is slot s, as ever."""
         c = self.prefill_chunk
         n = self.backend.n_slots
         rows = list(self._prefilling.items())
@@ -1254,6 +1313,7 @@ class ServingEngine:
             start[row] = req.prefill_pos
             mask[row] = True
         kw = self._extra_kw(rows)
+        kw["start"] = start
         if compact:
             # a padding row names no slot (an index past the pool); the
             # per-slot extras travel with their rows (a padding row takes
@@ -1267,17 +1327,42 @@ class ServingEngine:
                 tables, ids = kw["adapters"]
                 kw["adapters"] = (tables, ids[at])
         _PREFILL_RUNG.inc(rows=r)
-        tr = obs.get_tracer()
-        ts0 = tr.now_us() if tr is not None else 0.0
+        return _Call(rows, (tokens, lens, mask), kw,
+                     dict(step=self._steps, n=len(rows), chunk=c, rows=r,
+                          tokens=int(real)), row_of)
+
+    def _prefill_chunk_step(self, finished,
+                            events: Optional[List[ChunkEvent]] = None,
+                            ) -> None:
+        """The step's prefill call (:meth:`_chunk_call`) made and read, in
+        turn, and its bookkeeping (:meth:`_chunk_done`)."""
+        call = self._chunk_call()
         t0 = now()
-        with obs.span("wire.prefill", "wire", step=self._steps,
-                      n=len(rows), chunk=c, rows=r, tokens=int(real)):
-            tok = self.backend.prefill(tokens, lens, mask, start=start, **kw)
-        self.metrics.on_prefill(now() - t0, len(self._prefilling),
-                                chunked=True)
+        with obs.span("wire.prefill", "wire", **call.attrs):
+            tok = self.backend.prefill(*call.args, **call.kw)
+        self._chunk_done(call, tok, t0, finished, events)
+
+    def _chunk_done(self, call: _Call, tok, t0: float, finished,
+                    events: Optional[List[ChunkEvent]]) -> None:
+        """The prefill call's bookkeeping once its tokens are read (``t0``:
+        when its ``wire.prefill`` opened). Rows
+        whose cursor reaches the prompt end emit their first token and
+        leave PARTIAL_PREFILL; other rows' returned tokens are garbage by
+        the model contract and ignored here. ``events`` carries this step's
+        admission-time prefix-cache copies; the chunk advances are appended
+        and the whole batch goes to ``chunk_sink`` BEFORE any retirement,
+        so a sink can export rows while slots still hold them."""
+        c = self.prefill_chunk
+        row_of = call.row_of
         t_done = now()
+        self.metrics.on_prefill(t_done - t0, len(self._prefilling),
+                                chunked=True)
+        tr = obs.get_tracer()
         if tr is not None:
-            dur = tr.now_us() - ts0
+            # one measured window on every covered request's track (the
+            # ring's clock is the engine's: both are perf_counter)
+            dur = (t_done - t0) * 1e6
+            ts0 = tr.now_us() - dur
             for slot, req in self._prefilling.items():
                 tr.complete("prefill_chunk", ts0, dur, req.track,
                             slot=slot, offset=req.prefill_pos)
@@ -1313,28 +1398,81 @@ class ServingEngine:
                 self._emit_first_token(slot, req, tok[row_of[slot]], t_done,
                                        finished)
 
-    def _decode(self, finished) -> None:
-        decoding = {s: r for s, r in self._by_slot.items()
-                    if s not in self._prefilling}
-        if self.spec_k is not None:
-            self._spec_decode(decoding, finished)
-            return
+    def _chunk_and_decode_together(self, finished, events) -> None:
+        """A chunk step whose two calls need nothing of each other
+        (:meth:`_chunk_step_calls`): both are built, the prefill program is
+        launched, then the decode program — it takes the pool the prefill
+        launch left in the backend, so the device runs prefill then decode
+        as in turn and the pool's contents are those of in turn — and only
+        then is either read, so the host's turn around the prefill call
+        hides behind the decode program. The prefill call is read first
+        and booked (cursors, chunk events, ``chunk_sink``) before the
+        decode call is read and its rows retire: the order of in turn.
+
+        The spans: ``wire.prefill`` opens before the prefill call's
+        ``backend.stage`` and closes after its ``backend.fetch``, with the
+        decode call's ``backend.stage`` and ``backend.launch`` inside it;
+        ``wire.decode`` opens after that and holds the decode call's
+        ``backend.fetch`` (and ``ep.experts``). A reader that gives a span
+        the device operations that start inside it then finds all of the
+        prefill program in ``wire.prefill`` (with the decode program's
+        first operations, as long as the prefill call's read takes), never
+        a part of it."""
+        pre, dec = self._chunk_call(), self._decode_call()
+        t0 = now()
+        with obs.span("wire.prefill", "wire", **pre.attrs):
+            first = self.backend.launch_prefill(*pre.args, **pre.kw)
+            try:
+                second = self.backend.launch_decode(*dec.args, **dec.kw)
+            except BaseException:
+                # the step fails where it would in turn: after the prefill
+                # call, which is launched, was read and booked
+                self._chunk_done(pre, self.backend.fetch(first), t0,
+                                 finished, events)
+                raise
+            tok = self.backend.fetch(first)
+        self._chunk_done(pre, tok, t0, finished, events)
+        t0 = now()
+        with obs.span("wire.decode", "wire", **dec.attrs):
+            tok = self.backend.fetch(second)
+        self._decode_done(dec, tok, t0, finished)
+
+    def _decoding(self) -> dict:
+        return {s: r for s, r in self._by_slot.items()
+                if s not in self._prefilling}
+
+    def _decode_call(self) -> _Call:
+        """This step's decode call: one token for every slot that holds a
+        request past its prefill."""
+        decoding = self._decoding()
         active = np.zeros(self.backend.n_slots, bool)
         pos0 = np.zeros(self.backend.n_slots, np.int32)
         for slot, req in decoding.items():
             active[slot] = True
             pos0[slot] = req.n_generated  # this step's output index
         rows = list(decoding.items())
+        return _Call(rows, (self._last_tok.copy(), active),
+                     self._extra_kw(rows, pos0),
+                     dict(step=self._steps, n=len(decoding),
+                          **_kv_rows(decoding, self._window)))
+
+    def _decode(self, finished) -> None:
+        if self.spec_k is not None:
+            self._spec_decode(self._decoding(), finished)
+            return
+        call = self._decode_call()
         t0 = now()
-        with obs.span("wire.decode", "wire", step=self._steps,
-                      n=len(decoding), **_kv_rows(decoding, self._window)):
-            tok = self.backend.decode(self._last_tok.copy(), active,
-                                      **self._extra_kw(rows, pos0))
-        self.metrics.on_decode_step(now() - t0, len(decoding),
-                                    tokens=len(decoding))
+        with obs.span("wire.decode", "wire", **call.attrs):
+            tok = self.backend.decode(*call.args, **call.kw)
+        self._decode_done(call, tok, t0, finished)
+
+    def _decode_done(self, call: _Call, tok, t0: float, finished) -> None:
+        """The decode call's tokens, read, to their requests; retire."""
+        self.metrics.on_decode_step(now() - t0, len(call.rows),
+                                    tokens=len(call.rows))
         t_done = now()
         with obs.span("engine.retire", "engine"):
-            for slot, req in rows:
+            for slot, req in call.rows:
                 self._last_tok[slot] = tok[slot]
                 req.out_tokens.append(int(tok[slot]))
                 self._maybe_retire(slot, req, t_done, finished)

@@ -265,6 +265,94 @@ def test_the_entry_point_builds_the_example_configuration():
             cfg.param_dtype) == (64, 16, 0, 8.0, "bfloat16")
 
 
+# -- every description's pool answers for itself ------------------------------
+
+_RING = "a pool with ring groups keeps a slot's last reach - 1 positions"
+_STATE = "a pool with a state group keeps ONE state a slot and layer"
+_FREE = inference.PoolTraits()
+
+# description (None: the hand-sized flags; a file under examples/configs or
+# chipbench/configs) -> ``serve._moe_cfg``'s (capacity_factor, window_ring,
+# conv_ring) at (--prefill-chunk 4) and at (--prefill-chunk 128 --spec-k 3),
+# frozen from the parent of PR 48 (e5bbe6a), where serve.py sized the rings
+# and re-read the expert keys itself; then the pool's traits at the first:
+# how its ``rows_stay`` starts (None: a slot's rows may leave it) and the
+# other fields.
+POOLS = {
+    None: ((8.0, 0, 0), (8.0, 0, 0), None, _FREE),
+    "examples/configs/glm4_moe_lite_tiny.json":
+        ((8.0, 0, 0), (8.0, 0, 0), None, _FREE),
+    "examples/configs/mimo_v2_flash_tiny.json":
+        ((8.0, 16, 0), (8.0, 135, 0), _RING, _FREE._replace(
+            widest_write=9, tightest=("window", "window", 16, 8), window=8)),
+    "examples/configs/afmoe_tiny.json":
+        ((8.0, 16, 0), (8.0, 135, 0), _RING, _FREE._replace(
+            widest_write=9, tightest=("window", "window", 16, 8), window=8)),
+    "examples/configs/lfm2_moe_tiny.json":
+        ((8.0, 0, 6), (8.0, 0, 130), _RING, _FREE._replace(
+            projections=False, widest_write=4,
+            tightest=("conv", "taps", 6, 3))),
+    "examples/configs/brumby_tiny.json":
+        ((8.0, 0, 0), (8.0, 0, 0), _STATE, _FREE._replace(
+            rollback=False, projections=False)),
+    "chipbench/configs/mixtral-8x7b-serve.json":
+        ((8.0, 0, 0), (8.0, 0, 0), None, _FREE),
+    "chipbench/configs/glm-4.7-flash-serve.json":
+        ((16.0, 0, 0), (16.0, 0, 0), None, _FREE),
+    "chipbench/configs/mimo-v2-flash-serve.json":
+        ((32.0, 256, 0), (32.0, 256, 0), _RING, _FREE._replace(
+            widest_write=129, tightest=("window", "window", 256, 128),
+            window=128)),
+    "chipbench/configs/trinity-large-preview-serve.json":
+        ((64.0, 8192, 0), (64.0, 8192, 0), _RING, _FREE._replace(
+            widest_write=4097, tightest=("window", "window", 8192, 4096),
+            window=4096)),
+    "chipbench/configs/lfm2-24b-a2b-serve.json":
+        ((16.0, 0, 6), (16.0, 0, 130), _RING, _FREE._replace(
+            projections=False, widest_write=4,
+            tightest=("conv", "taps", 6, 3))),
+    "chipbench/configs/brumby-14b-base-serve.json":
+        ((8.0, 0, 0), (8.0, 0, 0), _STATE, _FREE._replace(
+            rollback=False, projections=False)),
+}
+
+
+@pytest.mark.parametrize("path", POOLS, ids=lambda p: str(p).split("/")[-1])
+def test_a_description_sizes_itself_and_says_what_its_pool_can_do(path):
+    """``MoEServeConfig.sized_for_serving`` through ``serve._moe_cfg`` gives
+    what the parent's ``_moe_cfg`` gave, field by field, and
+    ``inference.pool_traits`` of it the one answer the engine, the pool's
+    row shims and the disaggregated wire refuse from."""
+    import argparse
+    import os
+
+    from uccl_tpu import serve
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    chunk_4, chunk_128, stay, want = POOLS[path]
+
+    def sized(chunk, spec_k):
+        return serve._moe_cfg(argparse.Namespace(
+            model_config=path and os.path.join(root, path), ckpt_dir="",
+            prefill_chunk=chunk, spec_k=spec_k, vocab=512, dim=128, layers=2,
+            heads=4, kv_heads=2, experts=8, ffn=256))
+
+    for cfg, fields in ((sized(4, 0), chunk_4), (sized(128, 3), chunk_128)):
+        assert (cfg.capacity_factor, cfg.window_ring, cfg.conv_ring) == fields
+        assert cfg.capacity_factor >= cfg.drop_free_factor
+    got = inference.pool_traits(sized(4, 0))
+    assert got.rows_stay is None if stay is None \
+        else got.rows_stay.startswith(stay)
+    assert got._replace(rows_stay=None) == want
+    # the three statements of the ring rule are one: the sized ring takes
+    # the widest write it was sized for, and the shims' sentence is the
+    # traits' own
+    kinds = sized(4, 0).layer_kinds
+    assert got.rows_stay == (inference.rows_stay(kinds) if kinds else None)
+    wide = inference.pool_traits(sized(128, 3)).widest_write
+    assert wide is None or wide >= 128
+
+
 # -- program against reference, through every program ------------------------
 
 def test_full_forward_is_the_reference(model):
@@ -452,24 +540,46 @@ def test_the_row_loop_visits_the_rows_with_a_real_position(model, call, real):
             np.testing.assert_allclose(a, b, **STATE_TOL)
 
 
-def test_the_head_at_one_position_is_the_head_at_all(model, monkeypatch):
-    """Past ``_LOGITS_AT_ONCE`` the prefill program contracts the head with
-    the one position a row whose token it returns: the same tokens."""
-    cfg, params, srv, placed = model
+@pytest.mark.parametrize("stack", ("dense", "uniform_moe", "layer_kinds"))
+def test_the_head_at_one_position_is_the_head_at_all(model, devices, stack):
+    """The prefill program contracts the head with the ONE position a row
+    whose token it returns (``head_at``): the token is the argmax at that
+    position of the head over every position (``head_at=None``, what the
+    verify and decode programs read), in both stacks."""
     toks = np.stack([_tokens(8, seed=8), _tokens(8, seed=9)])
-    lens = jnp.asarray([[7, 8]], jnp.int32)
-    got = []
-    for limit in (inference._LOGITS_AT_ONCE, 0):
-        monkeypatch.setattr(inference, "_LOGITS_AT_ONCE", limit)
-        srv._fns = type(srv._fns)(16)
-        tok, cache = srv.prefill_slots(
-            placed, jnp.asarray(toks)[None], lens, jnp.ones((1, 2), bool),
-            srv.slot_cache(2, MAX_SEQ))
-        got.append((np.asarray(tok), _states(cache, 0)))
-    srv._fns = type(srv._fns)(16)
-    assert np.array_equal(got[0][0], got[1][0])
-    for a, b in zip(got[0][1], got[1][1]):
-        assert np.array_equal(a, b)
+    lens = np.asarray([7, 8], np.int32)
+    if stack == "dense":
+        from uccl_tpu.models import dense
+
+        cfg = dense.DenseConfig(vocab=VOCAB, dim=32, n_layers=2, n_heads=4,
+                                n_kv_heads=2, head_dim=8, ffn=64)
+        params = dense.init_params(jax.random.PRNGKey(3), cfg)
+        pool = functools.partial(SlotKVCache.empty, cfg, 2, MAX_SEQ)
+        tok, _ = inference.prefill_slots(
+            params, jnp.asarray(toks), jnp.asarray(lens),
+            jnp.ones(2, bool), pool(), cfg)
+        logits, _ = _forward_slots(
+            params, jnp.asarray(toks), pool(), jnp.zeros(2, jnp.int32),
+            jnp.ones(2, bool), cfg)
+    else:
+        if stack == "layer_kinds":
+            cfg, params, srv, placed = model
+        else:
+            cfg = MoEServeConfig(vocab=VOCAB, dim=32, n_layers=2, n_heads=4,
+                                 n_kv_heads=2, head_dim=8, moe_experts=4,
+                                 moe_ffn=32)
+            srv = _server(devices, cfg)
+            placed = srv.shard_params(
+                init_params(jax.random.PRNGKey(3), cfg))
+        tok, _ = srv.prefill_slots(
+            placed, jnp.asarray(toks)[None], jnp.asarray(lens)[None],
+            jnp.ones((1, 2), bool), srv.slot_cache(2, MAX_SEQ))
+        tok = np.asarray(tok)[0]
+        logits, _ = _slot_logits(srv, placed, toks, srv.slot_cache(2, MAX_SEQ),
+                                 [0, 0], np.ones(2, bool))
+    assert np.asarray(logits).shape == (2, 8, VOCAB)
+    want = np.argmax(np.asarray(logits)[np.arange(2), lens - 1], axis=-1)
+    assert np.array_equal(np.asarray(tok), want)
 
 
 def test_compact_rungs_are_the_pool_wide_rung(model):
@@ -884,24 +994,26 @@ def test_programs_carry_nothing_of_another_block(program_text, program,
 # -- the five accepted descriptions are what they were -----------------------
 
 # sha256 (16 hex digits) of each accepted tiny preset's seeded leaves and of
-# its lowered decode and pool-wide prefill programs, computed on the parent
-# of the PR that added retention layers (227c55c) and equal on its tree: the
-# operator, the state group and the ``valid`` count cost a description
-# without them not one operation. A PR that changes an accepted program on
-# purpose writes the new digests here.
+# its lowered decode and pool-wide prefill programs. Leaves and decode were
+# computed on the parent of the PR that added retention layers (227c55c) and
+# equal on its tree: the operator, the state group and the ``valid`` count
+# cost a description without them not one operation. The prefill digests
+# are PR 48's, which made the head at ONE position a row the only prefill
+# head (``inference.prefill_slots``) and so changed all five on purpose. A
+# PR that changes an accepted program on purpose writes the new digests here.
 ACCEPTED = {
     "mixtral": (lambda: MoEServeConfig(**latent.GQA),
-                "a50740ad09eb5ce6", "cd015405e57683e5", "c62c7773ca1e403c"),
+                "a50740ad09eb5ce6", "cd015405e57683e5", "9e9eb1509842f2b7"),
     "glm4_moe_lite": (lambda: MoEServeConfig(**latent.LATENT),
                       "9793a0a1f08dd700", "adf0e0363716091d",
-                      "ba4d507c6fca5feb"),
+                      "2c5e282c0a475060"),
     "mimo_v2_flash": (lambda: MoEServeConfig(**hybrid.HYBRID),
                       "442d55cd1a0f1355", "a95582a83b6cb367",
-                      "ab558b8525ab1544"),
+                      "d2d75a3471ae2ea9"),
     "afmoe": (lambda: MoEServeConfig.from_hf(afmoe.TINY, **afmoe.OVERRIDES),
-              "8ec3830eb98d6533", "287dd824d75a6684", "a87d34e1bb956664"),
+              "8ec3830eb98d6533", "287dd824d75a6684", "c2f1737ade3e19cd"),
     "lfm2_moe": (lambda: MoEServeConfig.from_hf(lfm2.TINY, **lfm2.OVERRIDES),
-                 "d3a3903f3f1b09bd", "3595552de7c09d5b", "5debd7d8122c7a6c"),
+                 "d3a3903f3f1b09bd", "3595552de7c09d5b", "ebc8e67c92dc7e4a"),
 }
 
 

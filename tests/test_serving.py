@@ -230,6 +230,61 @@ class TestChunkedScheduling:
             ServingEngine(_ChunkStubBackend(), prefill_chunk=8,
                           step_tokens=4)
 
+    @pytest.mark.parametrize("traits,kw,says", [
+        ({}, dict(spec_k=3, prefix_cache=4, preempt=True), None),
+        (dict(rows_stay="rows stay: "), dict(prefix_cache=4),
+         "rows stay: prefix_cache copies"),
+        (dict(rows_stay="rows stay: "), dict(prefix_cache=4, kv_tiers=True),
+         "rows stay: kv_tiers demotes"),
+        (dict(rows_stay="rows stay: "), dict(preempt=True),
+         "rows stay: preempt saves"),
+        (dict(rows_stay="rows stay: "), dict(spec_k=3), None),
+        (dict(rollback=False), dict(spec_k=3), "spec_k verifies a window"),
+        (dict(rollback=False), dict(), None),
+        (dict(projections=False), dict(adapters=True), "LoRA adapters"),
+        (dict(widest_write=5, tightest=("g", "taps", 7, 3)),
+         dict(prefill_chunk=None), "requires prefill_chunk"),
+        (dict(widest_write=5, tightest=("g", "taps", 7, 3)),
+         dict(prefill_chunk=5, spec_k=4), None),
+        (dict(widest_write=5, tightest=("g", "taps", 7, 3)),
+         dict(prefill_chunk=6),
+         r"the g layers' ring of 7 rows must hold taps - 1 \+ the widest "
+         r"write \(3 - 1 \+ 6\): raise g_ring"),
+        (dict(widest_write=5, tightest=("g", "taps", 7, 3)), dict(spec_k=5),
+         "widest write"),
+        (dict(window=8), dict(), None),
+    ])
+    def test_features_are_refused_by_the_pools_traits_alone(
+            self, traits, kw, says):
+        """A backend that carries ``traits`` and no model description: the
+        engine asks the pool what it can do and reads nothing else."""
+        from uccl_tpu.models.inference import PoolTraits
+        from uccl_tpu.serving.adapters import AdapterStore
+        from uccl_tpu.serving.kv_tiers import TieredKVCache
+        from uccl_tpu.serving.prefix_cache import PrefixCache
+
+        backend = _ChunkStubBackend()
+        backend.traits = PoolTraits(**traits)
+        kw = {"prefill_chunk": 4, **kw}
+        if kw.get("prefix_cache"):
+            kw["prefix_cache"] = PrefixCache(kw["prefix_cache"])
+        if kw.get("kv_tiers"):
+            kw["kv_tiers"] = TieredKVCache(host_bytes=1 << 20)
+        if kw.get("preempt"):
+            kw["priority_classes"] = True
+        if kw.get("adapters"):  # refused before any use
+            kw["adapters"] = AdapterStore.__new__(AdapterStore)
+        if says is not None:
+            with pytest.raises(ValueError, match=says):
+                ServingEngine(backend, **kw)
+            return
+        eng = ServingEngine(backend, **kw)
+        assert eng._window == traits.get("window", 0)
+        if set(kw) == {"prefill_chunk"}:  # what the stub can serve
+            r = eng.submit(list(range(10)), max_new_tokens=2)
+            eng.drain()
+            assert r.state is RequestState.FINISHED
+
     def test_cursor_resumes_across_steps(self):
         """A 10-token prompt under chunk 4 prefills at starts 0, 4, 8 and
         only then emits its first token (PARTIAL_PREFILL → ACTIVE)."""

@@ -341,34 +341,50 @@ NEEDS_THIS_PR = ("host_between_calls_ms", "ttft_prefill_dev_share",
                  "ttft_decode_dev_share", "ttft_idle_share")
 
 
-def _new_entries():
+def _base(name):
+    """A per-layer entry's reading: its name, less the cell's suffix where
+    the entry is one cell's copy (``decode_dispatch_latency_ms.chat``)."""
+    return name if name in EXPECTED else name.rsplit(".", 1)[0]
+
+
+def _new_readings():
+    """``[(entry, cell)]``: each of this file's readings with each cell an
+    entry of it lists, whatever form ``BENCHMARK.json`` has — 28 one-cell
+    entries give the same pairs as seven entries that list four cells."""
     with open(os.path.join(R.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    return [m for m in bench["per_layer"]
-            if m["name"].rsplit(".", 1)[0] in EXPECTED]
+    return [(m, cell) for m in bench["per_layer"]
+            if _base(m["name"]) in EXPECTED for cell in m["workloads"]]
+
+
+def _pair_id(pair):
+    return f"{_base(pair[0]['name'])}@{pair[1]}"
 
 
 def test_each_new_metric_is_in_all_four_cells():
-    entries = _new_entries()
+    pairs = _new_readings()
     cells = {w["name"] for w in json.load(open(os.path.join(
         R.ROOT, "BENCHMARK.json")))["workloads"]}
-    assert len(entries) == 7 * 4
-    for m in entries:
+    assert len(pairs) >= 7 * 4
+    assert len({_pair_id(p) for p in pairs}) == len(pairs)  # none twice
+    for m, cell in pairs:
         assert m["layer"] == "serving engine"
-        assert m["source"] == "program_span" and len(m["workloads"]) == 1
-    # the four cells the benchmark had when these readings were added; a
-    # later cell has what the benchmark's cap of 128 per-layer metrics left
-    # room for (PERF.md section 3)
-    four = {m["workloads"][0] for m in entries}
-    assert len(four) == 4 and four <= cells
+        assert m["source"] == "program_span" and cell in cells
+    # every reading in the same cells: the four the benchmark had when
+    # these readings were added, or more of those it has now (a later cell
+    # has what the benchmark's cap of 128 per-layer metrics left room for:
+    # PERF.md section 3)
+    listed = {cell for _, cell in pairs}
+    assert len(listed) >= 4
     for name in EXPECTED:
-        assert {m["workloads"][0] for m in entries
-                if m["name"].rsplit(".", 1)[0] == name} == four
+        assert {cell for m, cell in pairs
+                if _base(m["name"]) == name} == listed
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in _new_entries()])
-def test_reader(monkeypatch, metric):
-    base = metric.rsplit(".", 1)[0]
+@pytest.mark.parametrize("pair", _new_readings(), ids=_pair_id)
+def test_reader(monkeypatch, pair):
+    metric = pair[0]["name"]  # the reader is found by the name the entry has
+    base = _base(metric)
     read = R.load_reader(metric).read
     assert read(_view(monkeypatch, "a:" + metric)) \
         == pytest.approx(EXPECTED[base])
@@ -397,7 +413,9 @@ def test_reader(monkeypatch, metric):
 
 
 def test_the_leads_source_is_in_the_runs_log(monkeypatch, capsys):
-    read = R.load_reader("decode_dispatch_latency_ms.chat").read
+    read = R.load_reader(next(
+        m["name"] for m, _ in _new_readings()
+        if _base(m["name"]) == "decode_dispatch_latency_ms")).read
     read(_view(monkeypatch, "log:runtime"))
     assert "chipbench: step_timeline lead_from=runtime " \
         "lead_bounds_ms=[0.8, 1.2]" in capsys.readouterr().out

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -215,7 +215,7 @@ RING_GROUPS = {"window": "window", "conv": "taps"}
 # call is told how many of a row's positions are real (``valid``:
 # :func:`_forward_slots`), a call that starts at position 0 reads a zero
 # state whatever the slot holds, and what needs a position's rows back
-# (:data:`STATE_GROUPS_STAY`) is refused until slots keep snapshots. A row
+# (:func:`pool_traits`: ``rows_stay``) is refused until slots keep snapshots. A row
 # without a real position keeps its state bit for bit, and at no cost: the
 # operator's row loop visits the rows that have one, where they lie in the
 # layer's array, and a slot it does not visit is never read
@@ -226,6 +226,68 @@ STATE_GROUPS = ("retention",)
 def has_state_group(cfg) -> bool:
     """Whether a layer of the description keeps a state group."""
     return any(kind in STATE_GROUPS for kind in layer_kinds(cfg))
+
+
+def ring_rows_for(reach: int, widest: int) -> int:
+    """Rows a ring needs to take a write of ``widest`` positions under
+    THE invariant of :data:`RING_GROUPS`: ``reach - 1 + widest``."""
+    return reach - 1 + widest
+
+
+def rows_stay(groups) -> str:
+    """The sentence that says why a pool of cache groups (``groups``: a
+    description's layer kinds, not empty) keeps a slot's rows to itself."""
+    if set(groups) & set(STATE_GROUPS):
+        return (
+            "a pool with a state group keeps ONE state a slot and layer, "
+            "the state AT the slot's length (a retention layer's matrix), "
+            "and no row by position, so nothing can hand a prefix of it to "
+            "another slot, a tier or a peer, or roll it back, until slots "
+            "keep snapshots: ")
+    return (
+        "a pool with ring groups keeps a slot's last reach - 1 positions of "
+        "its window and conv layers (window - 1, taps - 1) in a ring and "
+        "nothing older, so a slot's rows cannot be handed to another slot, "
+        "a tier or a peer as a prefix: ")
+
+
+class PoolTraits(NamedTuple):
+    """What a slot pool can do, in the serving layer's words: the ONE answer
+    the engine, the pool's row shims and the disaggregated wire refuse from
+    (:func:`pool_traits`). The defaults: one stacked array, every position
+    kept, which can do everything."""
+
+    # why a slot's rows cannot leave it or enter it as a prefix
+    # (``export_rows`` / ``import_rows`` / ``copy_prefix``); None: they can
+    rows_stay: Optional[str] = None
+    rollback: bool = True  # a cursor rollback undoes a write
+    projections: bool = True  # every layer has the adapters' (wq, wv)
+    # the widest write one call may make (None: any; bounded, a whole prompt
+    # cannot go in one) and the ring that sets it: (group, what its reach
+    # counts, rows, reach)
+    widest_write: Optional[int] = None
+    tightest: Optional[Tuple[str, str, int, int]] = None
+    window: int = 0  # the window layers' reach (0 without them)
+
+
+def pool_traits(cfg) -> PoolTraits:
+    """The :class:`PoolTraits` of a description's slot pool: unrestricted
+    without layer kinds (the dense stack, Mixtral, the latent family); with
+    them the pool is cache groups and a slot's rows stay, a ring group
+    (:data:`RING_GROUPS`) bounds the widest write by its rows, a state group
+    (:data:`STATE_GROUPS`) cannot be rolled back, and a "conv" or a state
+    layer has no (wq, wv)."""
+    kinds = layer_kinds(cfg)
+    state = has_state_group(cfg)
+    rings = [(cfg.ring_rows(g) - ring_rows_for(cfg.reach(g), 0),
+              (g, RING_GROUPS[g], cfg.ring_rows(g), cfg.reach(g)))
+             for g in RING_GROUPS if g in kinds]
+    widest, tightest = min(rings, key=lambda r: r[0], default=(None, None))
+    return PoolTraits(
+        rows_stay=rows_stay(kinds) if kinds else None, rollback=not state,
+        projections=not state and "conv" not in kinds,
+        widest_write=widest, tightest=tightest,
+        window=cfg.window if "window" in kinds else 0)
 
 
 def _ret_block(d: int) -> int:
@@ -282,36 +344,15 @@ def kv_row_shapes(cfg, kind=None):
     return (cfg.n_kv_heads, cfg.head_dim), (cfg.n_kv_heads, cfg.head_dim)
 
 
-RING_GROUPS_STAY = (
-    "a pool with ring groups keeps a slot's last reach - 1 positions of its "
-    "window and conv layers (window - 1, taps - 1) in a ring and nothing "
-    "older, so a slot's rows cannot be handed to another slot, a tier or a "
-    "peer as a prefix: ")
-
-
-STATE_GROUPS_STAY = (
-    "a pool with a state group keeps ONE state a slot and layer, the state "
-    "AT the slot's length (a retention layer's matrix), and no row by "
-    "position, so nothing can hand a prefix of it to another slot, a tier "
-    "or a peer, or roll it back, until slots keep snapshots: ")
-
-
-def groups_stay(kinds) -> str:
-    """The sentence that says why a pool of these layer kinds keeps its
-    rows to itself."""
-    return STATE_GROUPS_STAY if set(kinds) & set(STATE_GROUPS) \
-        else RING_GROUPS_STAY
-
-
 def kv_wire_dims(cfg):
     """(heads, width) of the two EQUAL arrays one layer's cached position
     leaves a pool as (``export_rows``): gqa's own ``[Hkv, D]``; a latent
     row's two halves, ``[1, (kv_lora_rank + qk_rope_dim) / 2]`` each. A
     pool of cache groups (layer kinds) has no one row to put on a wire."""
-    if layer_kinds(cfg):
-        raise ValueError(groups_stay(layer_kinds(cfg)) + "the disaggregated "
-                         "wire format describes one row shape for every "
-                         "layer")
+    stay = pool_traits(cfg).rows_stay
+    if stay:
+        raise ValueError(stay + "the disaggregated wire format describes "
+                         "one row shape for every layer")
     k_row, v_row = kv_row_shapes(cfg)
     if k_row == v_row:
         return k_row
@@ -1121,13 +1162,6 @@ def _lora_delta(h, table, ids, layer):
     return jnp.einsum("bsr,bro->bso", jnp.einsum("bsh,bhr->bsr", h, al), bl)
 
 
-# The head contracts the ONE position a row whose logits a prefill program
-# reads, not all S, once all S of every row would pass this many logits
-# (1 GB of float32): [16, 128] rows of a 151,936-word vocabulary are 311 M
-# (1.16 GB, and as much again for the gather that then picks a row's one).
-_LOGITS_AT_ONCE = 1 << 28
-
-
 def _forward_slots(
     params, tokens, cache: SlotKVCache, start, write_mask, cfg, ffn=None,
     adapters=None, adapter_ids=None, slots=None, valid=None, head_at=None,
@@ -1204,13 +1238,12 @@ def _forward_slots(
         # a ring of ``rows``: position p lives at row p % rows, and the
         # invariant (RING_GROUPS) is asked of each ring group by its own
         # rows and reach
-        rows, reach = k[group].shape[2], cfg.reach(group)
-        if rows < reach - 1 + s:
+        rows, need = k[group].shape[2], ring_rows_for(cfg.reach(group), s)
+        if rows < need:
             raise ValueError(
                 f"a {group} layer's ring of {rows} rows cannot take a write "
                 f"of {s} positions: it must hold {RING_GROUPS[group]} - 1 + "
-                f"the widest "
-                f"write = {reach - 1 + s} ({group}_ring), or the write "
+                f"the widest write = {need} ({group}_ring), or the write "
                 f"would overwrite positions still to be read")
         pos_of[group] = jnp.where(write_mask[:, None], positions % rows, rows)
     if has_state_group(cfg):  # a masked row has no real position
@@ -1252,7 +1285,14 @@ def _forward_slots(
         x = _ffn_half(x, lp, cfg, ffn, rows=write_mask,
                       place=_layer_place(params, i, cfg))
     if head_at is not None:
-        x = jnp.take_along_axis(x, head_at[:, None, None], axis=1)
+        # picked by a masked sum, exact (the other positions add zeros), and
+        # not by a gather: x then ends in a fusion as it does under a head
+        # over every position, where a gather of the residual stream made
+        # the TPU compiler plan a one-row rung's fast memory anew and drop
+        # three layers' weight prefetches (+1.1 ms a chunk step on
+        # ``.long-short``: PERF.md section 6, PR 48)
+        pick = jnp.arange(s)[None, :] == head_at[:, None]
+        x = jnp.sum(jnp.where(pick[:, :, None], x, 0), axis=1, keepdims=True)
     logits = _head(x, params, cfg)
     return logits, SlotKVCache(k, v, cache.lengths)
 
@@ -1330,30 +1370,22 @@ def prefill_slots(
     """
     if start is None:
         start = jnp.zeros_like(prompt_lens)
+    s = tokens.shape[1]
     valid = None
     if has_state_group(cfg):
         # a state advances by a window's REAL positions: the prompt's own,
         # not a last chunk's right padding
-        valid = jnp.where(
-            new_mask, jnp.clip(prompt_lens - start, 0, tokens.shape[1]), 0)
-    b, s = tokens.shape
-
-    def last_idx():
-        """Each slot's last valid prompt position WITHIN this window;
-        clipped so mid-prefill rows (prompt end beyond the window) gather
-        in-bounds — their token is garbage by contract and ignored by the
-        engine."""
-        return jnp.clip(prompt_lens - 1 - start, 0, s - 1)
-
-    narrow = b * s * params["embed"].shape[0] > _LOGITS_AT_ONCE
+        valid = jnp.where(new_mask, jnp.clip(prompt_lens - start, 0, s), 0)
+    # the head runs at ONE position a row, the one whose token is returned:
+    # each slot's last valid prompt position WITHIN this window; clipped so
+    # mid-prefill rows (prompt end beyond the window) gather in-bounds —
+    # their token is garbage by contract and ignored by the engine
     logits, cache = _forward_slots(
         params, tokens, cache, start, new_mask, cfg, ffn=ffn,
         adapters=adapters, adapter_ids=adapter_ids, slots=slots, valid=valid,
-        head_at=last_idx() if narrow else None,
+        head_at=jnp.clip(prompt_lens - 1 - start, 0, s - 1),
     )
-    last = logits[:, 0] if narrow else jnp.take_along_axis(
-        logits, last_idx()[:, None, None], axis=1
-    )[:, 0]  # [B, V]
+    last = logits[:, 0]  # [B, V]
     if sampling is None:
         tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
     else:

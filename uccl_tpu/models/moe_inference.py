@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import math
 from collections import Counter
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -229,6 +229,24 @@ class MoEServeConfig:
         a conv layer's filter (p - conv_taps, p]. A ring holds ``reach - 1 +
         the widest write`` rows or more."""
         return self.window if group == "window" else self.conv_taps
+
+    @property
+    def drop_free_factor(self) -> float:
+        """The least ``capacity_factor`` whose EP wire drops no row whatever
+        the routing: ROUTED experts / top-k, whatever share is held here
+        (``MoEServer._check_drop_free``); 0 with no expert layer."""
+        return self.moe_experts / self.moe_topk if self.moe_topk else 0.0
+
+    def sized_for_serving(self, widest: int) -> "MoEServeConfig":
+        """This description sized for a serving process whose widest write
+        (a prefill chunk, a verify window) is ``widest`` positions: each
+        ring at least ``inference.ring_rows_for`` its reach and that write,
+        ``capacity_factor`` at least :attr:`drop_free_factor`."""
+        rings = {g + "_ring": max(
+            self.ring_rows(g), inference.ring_rows_for(self.reach(g), widest))
+            for g in inference.RING_GROUPS if g in self.layer_kinds}
+        return replace(self, **rings, capacity_factor=max(
+            self.capacity_factor, self.drop_free_factor))
 
     def kv_heads(self, kind: str) -> int:
         return self.window_kv_heads if kind == "window" else self.n_kv_heads
@@ -552,7 +570,7 @@ class MoESlotCache(NamedTuple):
     (``inference.STATE_GROUPS``). Rows of a
     pool with ring or state groups cannot be exported, imported or copied
     between slots: the three views below raise
-    (``inference.RING_GROUPS_STAY`` / ``STATE_GROUPS_STAY``)."""
+    (``inference.rows_stay``, the sentence of ``pool_traits``)."""
 
     k: jax.Array  # [W, L, B_loc, S_max, *k_row] (inference.kv_row_shapes)
     v: jax.Array  # [W, L, B_loc, S_max, *v_row]
@@ -572,7 +590,7 @@ class MoESlotCache(NamedTuple):
 
     def _one_group(self, what: str) -> None:
         if isinstance(self.k, dict):
-            raise ValueError(inference.groups_stay(self.k) + what)
+            raise ValueError(inference.rows_stay(self.k) + what)
 
     # -- slot KV export/import views (the disaggregation surface) ----------
     #
@@ -1067,7 +1085,7 @@ class MoEServer:
         past capacity and change its output depending on who shares the
         batch."""
         cfg = self.cfg
-        if cfg.capacity_factor * cfg.moe_topk < cfg.moe_experts:
+        if cfg.capacity_factor < cfg.drop_free_factor:
             raise ValueError(
                 f"slot serving needs a drop-free EP wire: capacity_factor "
                 f"({cfg.capacity_factor}) * moe_topk ({cfg.moe_topk}) must "

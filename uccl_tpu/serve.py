@@ -32,7 +32,6 @@ CI serving smoke tier:
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import sys
@@ -179,20 +178,10 @@ def _params_dtype(params) -> str:
 
 def _moe_cfg(args):
     """The MoE stack's model description: the published keys of a Hugging
-    Face ``config.json`` (``--model-config``: a ``mixtral``-, a
-    ``glm4_moe_lite``/``deepseek_v3``- — latent attention, a dense prefix,
-    sigmoid-bias gate, shared expert —, a ``mimo_v2_flash``-shaped file —
-    window and full attention layers with their own cache groups, a held
-    share of the experts — or an ``afmoe``-shaped one — gated attention
-    with QK-norm, rotary on the window layers only, sandwich norms, a shared
-    expert beside a held share — or an ``lfm2_moe``-shaped one — gated
-    short-convolution layers between full attention layers, their state a
-    third cache group, a head tied to the embedding — or a ``brumby``-shaped
-    one — every layer a power retention whose per-slot state is a cache
-    group with no position axis, a dense SwiGLU, no expert layer; weights
-    stored in its ``torch_dtype``), or else the hand-sized flags (the uniform
-    block)."""
-    from uccl_tpu.models.inference import RING_GROUPS
+    Face ``config.json`` (``--model-config``: any family
+    ``MoEServeConfig.from_hf`` reads, weights stored in its
+    ``torch_dtype``), sized for this process's widest write and a drop-free
+    wire; or else the hand-sized flags (the uniform block)."""
     from uccl_tpu.models.moe_inference import MoEServeConfig
 
     if not args.model_config:
@@ -207,24 +196,9 @@ def _moe_cfg(args):
                          "no --ckpt-dir")
     with open(args.model_config) as f:
         hf = json.load(f)
-    experts = next((hf[k] for k in ("router_experts", "n_routed_experts",
-                                    "num_experts", "num_local_experts")
-                    if hf.get(k)), 0)  # 0: a model with no expert layer
     cfg = MoEServeConfig.from_hf(
-        hf,
-        # the slot engine needs a drop-free wire: factor * top-k >= experts
-        # (the ROUTED experts, whatever share of them is held here)
-        capacity_factor=max(8.0, experts / hf.get("num_experts_per_tok", 1)),
-        param_dtype=hf.get("torch_dtype", "float32"),
-    )
-    # a ring group's rows hold reach - 1 + the widest write (window - 1 +,
-    # taps - 1 +)
-    widest = max(args.prefill_chunk, args.spec_k + 1)
-    for group in RING_GROUPS:
-        if group in cfg.layer_kinds:
-            cfg = dataclasses.replace(cfg, **{group + "_ring": max(
-                cfg.ring_rows(group), cfg.reach(group) - 1 + widest)})
-    return cfg
+        hf, param_dtype=hf.get("torch_dtype", "float32"))
+    return cfg.sized_for_serving(max(args.prefill_chunk, args.spec_k + 1))
 
 
 def _moe_paths(cfg, impl, world, params):

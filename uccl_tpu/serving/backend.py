@@ -34,8 +34,8 @@ import numpy as np
 
 from uccl_tpu import obs
 from uccl_tpu.models.inference import (
-    SlotKVCache, _flat_extra, _split_extra, decode_step_slots, prefill_slots,
-    verify_slots,
+    SlotKVCache, _flat_extra, _split_extra, decode_step_slots, pool_traits,
+    prefill_slots, verify_slots,
 )
 from uccl_tpu.utils.lru import LRUFnCache
 
@@ -209,6 +209,7 @@ class SlotBackend:
                  world: Optional[int] = None, experts_held: int = 0):
         self.params = params
         self.cfg = cfg
+        self.traits = pool_traits(cfg)
         self.programs = programs
         self.n_slots = n_slots
         self.max_seq = max_seq

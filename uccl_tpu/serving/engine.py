@@ -177,63 +177,49 @@ def _bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
-def _check_cache_groups(backend, prefill_chunk, spec_k, prefix_cache,
-                        kv_tiers, preempt, adapters) -> int:
-    """What a pool of cache groups (a model description whose
-    ``layer_kinds`` name "window" or "conv" layers, ``inference.
-    RING_GROUPS``, or a layer whose slot state has no position axis,
-    ``inference.STATE_GROUPS``) cannot do yet, refused before a request
-    could meet it; and each ring against the widest write. Returns the
-    window (0 for a pool without window groups)."""
-    cfg = getattr(backend, "cfg", None)
-    kinds = getattr(cfg, "layer_kinds", ()) or ()
-    from uccl_tpu.models.inference import (
-        RING_GROUPS, STATE_GROUPS, groups_stay,
-    )
-
-    rings = [g for g in RING_GROUPS if g in kinds]
-    states = [g for g in STATE_GROUPS if g in kinds]
-    if ("conv" in kinds or states) and adapters is not None:
-        raise ValueError(
-            "LoRA adapters beside conv or retention layers are not built: "
-            "the adapter tables are (wq, wv) deltas by layer; a conv layer "
-            "has neither projection, and a retention layer's state is a sum "
-            "over its prefix under ONE set of projections")
-    if not rings and not states:
+def _check_pool_traits(backend, prefill_chunk, spec_k, prefix_cache,
+                       kv_tiers, preempt, adapters) -> int:
+    """The requested features against what the backend's pool can do
+    (``backend.traits``: rows that stay in their slot, a write no cursor
+    rolls back, layers without the adapters' projections, a widest write;
+    a backend without the attribute has a pool that can do everything),
+    refused before a request could meet them. Returns the window layers'
+    reach (0 without them)."""
+    traits = getattr(backend, "traits", None)
+    if traits is None:
         return 0
-    stay = groups_stay(kinds)
-    if kv_tiers is not None:
-        raise ValueError(stay + "kv_tiers demotes and promotes exported "
-                         "rows")
-    if prefix_cache is not None:
-        raise ValueError(stay + "prefix_cache copies a donor's rows, whose "
-                         "ring no longer holds the prefix's last reach - 1 "
-                         "positions and whose state is the donor's at its "
-                         "own length")
-    if preempt:
-        raise ValueError(stay + "preempt saves a victim's exported rows and "
-                         "restores them")
-    if states and spec_k:
-        raise ValueError(stay + "spec_k verifies a window of drafts and "
-                         "rolls the rejected ones back by the cursor, which "
-                         "a state has already taken in")
-    if not rings:
-        return 0
-    if prefill_chunk is None:
+    stay = traits.rows_stay or ""
+    for refused, why in (
+        (adapters is not None and not traits.projections,
+         "LoRA adapters beside conv or retention layers are not built: "
+         "the adapter tables are (wq, wv) deltas by layer; a conv layer "
+         "has neither projection, and a retention layer's state is a sum "
+         "over its prefix under ONE set of projections"),
+        (stay and kv_tiers is not None,
+         stay + "kv_tiers demotes and promotes exported rows"),
+        (stay and prefix_cache is not None,
+         stay + "prefix_cache copies a donor's rows, whose ring no longer "
+         "holds the prefix's last reach - 1 positions and whose state is "
+         "the donor's at its own length"),
+        (stay and preempt,
+         stay + "preempt saves a victim's exported rows and restores them"),
+        (spec_k and not traits.rollback,
+         stay + "spec_k verifies a window of drafts and rolls the rejected "
+         "ones back by the cursor, which a state has already taken in"),
+        (traits.widest_write is not None and prefill_chunk is None,
+         "a pool with ring groups requires prefill_chunk: a whole prompt "
+         "in one write would wrap its window and conv layers' rings"),
+    ):
+        if refused:
+            raise ValueError(why)
+    widest = max(prefill_chunk or 0, (spec_k or 0) + 1)
+    if traits.widest_write is not None and widest > traits.widest_write:
+        group, counts, rows, reach = traits.tightest
         raise ValueError(
-            "a pool with ring groups requires prefill_chunk: a whole "
-            "prompt in one write would wrap its window and conv layers' "
-            "rings")
-    widest = max(prefill_chunk, (spec_k or 0) + 1)
-    for group in rings:
-        rows, reach = cfg.ring_rows(group), cfg.reach(group)
-        if rows < reach - 1 + widest:
-            raise ValueError(
-                f"the {group} layers' ring of {rows} rows must hold "
-                f"{RING_GROUPS[group]} - 1 + the widest write ({reach} - 1 "
-                f"+ {widest}): raise "
-                f"{group}_ring or lower prefill_chunk / spec_k")
-    return cfg.window if "window" in kinds else 0
+            f"the {group} layers' ring of {rows} rows must hold {counts} - 1 "
+            f"+ the widest write ({reach} - 1 + {widest}): raise "
+            f"{group}_ring or lower prefill_chunk / spec_k")
+    return traits.window
 
 
 class ServingEngine:
@@ -353,7 +339,7 @@ class ServingEngine:
                     "chunk boundaries and resumes via the chunked "
                     "start-offset program"
                 )
-        self._window = _check_cache_groups(
+        self._window = _check_pool_traits(
             backend, prefill_chunk, spec_k, prefix_cache, kv_tiers, preempt,
             adapters)
         self.backend = backend
